@@ -52,7 +52,31 @@ def set_matmul_precision(precision):
         jax.config.update("jax_default_matmul_precision", precision)
 
 
+def _place_compile_cache():
+    """Point JAX's persistent compilation cache at one fixed place.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it by itself and
+    nothing is set here.  Otherwise the cache is ``<checkout>/.jax_cache``,
+    derived from this package's location: the directory is part of the
+    cache key, so it must not depend on the working directory, the
+    process or the time.  ``jax_persistent_cache_min_compile_time_secs``
+    keeps its default of 1 s on purpose: the step and serving executables
+    (seconds to minutes each) are stored, the per-op eager executables
+    (milliseconds each, thousands of them) are not.  Returns the
+    directory set, or None when it was left to the environment."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    import jax
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def _init_from_env():
+    _place_compile_cache()
     prec = os.environ.get("MXNET_TPU_MATMUL_PRECISION", "highest")
     if prec != "default":
         try:

@@ -130,6 +130,8 @@ class Parameter:
                 actual_init = init_mod.create(actual_init)
             desc = init_mod.InitDesc(self.name)
             actual_init(desc, data)
+            # initializers write a value built on JAX's default device
+            data._set(_ndm._place(data._get(), ctx[0]))
             self._init_impl(data, ctx)
 
     def _init_impl(self, data, ctx_list):

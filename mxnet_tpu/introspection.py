@@ -98,13 +98,9 @@ def _device_count():
 def flops_of(compiled):
     """FLOP count of a compiled executable from XLA's cost model, or
     None when unavailable (the graceful-fallback contract: an absent
-    count means an absent gauge, never a wrong one).  Accepts both
-    cost_analysis shapes across jax versions (dict or list-of-dict)."""
+    count means an absent gauge, never a wrong one)."""
     try:
-        cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
-        v = float(cost.get("flops", 0.0))
+        v = float(compiled.cost_analysis().get("flops", 0.0))
         return v if v > 0 else None
     except Exception:
         return None

@@ -764,6 +764,8 @@ def invoke(opname, nd_args, attrs, out=None, ctx=None):
         _prof_rec(opname, _prof_t0, _time.perf_counter())
 
     outs = list(out_vals) if multi else [out_vals]
+    if od.creation:
+        outs = [_place(v, out_ctx) for v in outs]
     if _NAN_CHECK["on"]:
         _check_finite(opname, outs)
     nd_outs = []
@@ -815,6 +817,21 @@ _NAN_CHECK = {"on": False}
 
 def _call_with_attrs(fn, attrs, *arrays):
     return fn(*arrays, **attrs)
+
+
+def _place(v, ctx):
+    """Put a freshly created value on the device its context names.
+
+    A creation op has no input to follow, so JAX builds its result on the
+    default device whatever ``ctx`` says.  A value already there is left
+    as it is (uncommitted); any other is committed to ``ctx.device``, as
+    ``nd.array`` does.  A tracer has no device: inside a jit trace the
+    enclosing computation decides."""
+    jax = _jax()
+    if isinstance(v, jax.core.Tracer) or not isinstance(v, jax.Array):
+        return v
+    dev = ctx.device
+    return v if dev in v.devices() else jax.device_put(v, dev)
 
 
 def _check_finite(opname, vals):

@@ -12,23 +12,45 @@ kv_heads (GQA).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 
 import numpy as _np
 
 NEG_INF = -1e30
 
+_SCOPE = threading.local()   # .value: (mesh, batch_axes) while a sharded
+#                              step is being traced, see batch_sharded
+
+
+@contextlib.contextmanager
+def batch_sharded(mesh, batch_axes):
+    """Trace-time scope of a step that GSPMD partitions over ``mesh``.
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so inside this scope the Pallas forward
+    runs under a ``shard_map`` that splits the batch dim over
+    ``batch_axes``, the way the step's batch is sharded.  Heads are not
+    split: under ``tp`` every rank of that axis computes all heads."""
+    prev = getattr(_SCOPE, "value", None)
+    _SCOPE.value = (mesh, tuple(batch_axes))
+    try:
+        yield
+    finally:
+        _SCOPE.value = prev
+
 
 def _use_pallas(q):
+    """Static gate for the Pallas forward: a head size the kernel tiles, a
+    sequence long enough to pay for it, and a TPU to compile it for.  The
+    platform is JAX's default backend, not where ``q`` lives (a tracer
+    lives nowhere), so a CPU-context call on a TPU host is not covered."""
     import jax
 
     if q.shape[-1] % 128 != 0 and q.shape[-1] not in (64, 128, 256):
         return False
-    try:
-        platform = jax.devices()[0].platform
-    except Exception:  # pragma: no cover
-        return False
-    return platform == "tpu" and q.shape[-2] >= 256
+    return jax.default_backend() == "tpu" and q.shape[-2] >= 256
 
 
 # --------------------------------------------------------------------------
@@ -194,8 +216,22 @@ def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q=None, block_k=None):
             jax.ShapeDtypeStruct((b * h, lq, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, 8, lq), jnp.float32),
         ],
+        name="mxnet_flash_attention_fwd",
     )(qf, kf, vf)
     return o.reshape(b, h, lq, d), lse[:, 0, :].reshape(b, h, lq)
+
+
+def _fa_forward(q, k, v, causal, sm_scale):
+    """The Pallas forward, per batch shard when a ``batch_sharded`` step
+    is being traced."""
+    fwd = functools.partial(_fa_forward_pallas, causal=causal,
+                            sm_scale=sm_scale)
+    scope = getattr(_SCOPE, "value", None)
+    if scope is not None:
+        from ..parallel.collectives import shard_map_over_batch
+
+        fwd = shard_map_over_batch(fwd, *scope)
+    return fwd(q, k, v)
 
 
 # --------------------------------------------------------------------------
@@ -265,7 +301,7 @@ def _make_flash(causal, sm_scale_key):
 
     def _dispatch_fwd(q, k, v):
         if _use_pallas(q):
-            o, lse = _fa_forward_pallas(q, k, v, causal, sm_scale)
+            o, lse = _fa_forward(q, k, v, causal, sm_scale)
         else:
             o, lse = _mha_with_lse(q, k, v, causal, sm_scale)
         return o, (q, k, v, o, lse)

@@ -100,29 +100,21 @@ def fetch_global(arr):
 
 
 def shard_map(fn, mesh, in_specs, out_specs):
-    """Version-compat ``shard_map`` with replication checking off.
-
-    jax >= 0.6 exposes ``jax.shard_map`` (``check_vma=``) and deprecates
-    ``jax.experimental.shard_map`` (``check_rep=``); older jax only has the
-    experimental one.  Every shard_map in this repo wants the check off
-    (collectives make replication explicit), so one helper owns the
-    divergence instead of each call site pinning an API generation.
-    """
-    import inspect
-
+    """``jax.shard_map`` with the varying-axes check off: every shard_map
+    in this repo makes replication explicit through its collectives."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        impl = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as impl
-    # pick the check kwarg by signature, not API location: the 0.6-era
-    # promotion window had jax.shard_map still spelling it check_rep
-    params = inspect.signature(impl).parameters
-    check = {"check_vma": False} if "check_vma" in params else \
-        {"check_rep": False}
-    return impl(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                **check)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
+def shard_map_over_batch(fn, mesh, batch_axes):
+    """``fn`` applied to each batch shard: every operand and every result
+    is split on dim 0 over ``batch_axes`` of ``mesh`` and whole otherwise."""
+    from jax.sharding import PartitionSpec as P
+
+    spec = P(tuple(batch_axes))
+    return shard_map(fn, mesh, in_specs=spec, out_specs=spec)
 
 
 def psum(x, axis_name="dp"):

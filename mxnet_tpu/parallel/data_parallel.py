@@ -318,6 +318,17 @@ class TrainStep:
 
             amp_scope = partial(_cast_scope, dtype)
 
+        # GSPMD cannot partition the Pallas attention kernel: under a mesh
+        # it runs per batch shard (ops.flash_attention.batch_sharded).  The
+        # pipelined forward is already inside a shard_map over pp.
+        if mesh is None or self._pipeline is not None:
+            from contextlib import nullcontext as attention_scope
+        else:
+            from ..ops.flash_attention import batch_sharded
+
+            attention_scope = partial(batch_sharded, mesh,
+                                      self._plan.batch_axes)
+
         pipeline_cfg = self._pipeline
         mesh_ = mesh
         # planner flag: keep the jax-0.4.37 GSPMD replicated workaround
@@ -378,7 +389,7 @@ class TrainStep:
             def loss_of(tp):
                 p = dict(rest_params)
                 p.update(tp)
-                with amp_scope():
+                with amp_scope(), attention_scope():
                     if pipeline_cfg is not None:
                         out = pipelined_forward(p, rng, x)
                         state = {}
@@ -434,7 +445,7 @@ class TrainStep:
         # than the plan placed, and the re-lower at the drifted-stable
         # layout is the same silent recompile jit dispatch performed
         # here before the AOT path existed
-        self._compiled = {}      # batch sig -> [(compiled|None, flops)]
+        self._compiled = {}      # batch sig -> [(compiled, flops)]
         pipe_key = None
         if self._pipeline is not None:
             pipe_key = (self._pipeline["M"], self._pipeline["axis"],
@@ -609,21 +620,9 @@ class TrainStep:
 
     def _aot_step(self, args):
         """Lower + compile one operand tuple ahead of time and capture
-        its cost-analysis FLOPs.  Graceful fallback: when the AOT path
-        is unavailable (platform quirk), the jit dispatch path serves
-        the signature and the FLOP count — hence the MFU gauge — is
-        simply absent, never wrong."""
-        try:
-            compiled = self._step.lower(*args).compile()
-        except Exception as e:
-            import warnings
-
-            warnings.warn(
-                f"TrainStep AOT compile unavailable ({e!r}); falling "
-                "back to jit dispatch (no per-step FLOPs for this "
-                "signature — MFU gauge unaffected, just unfed)",
-                stacklevel=3)
-            return (None, None)
+        its cost-analysis FLOPs.  A compile error propagates: there is
+        no second dispatch path for the compiler to refuse again."""
+        compiled = self._step.lower(*args).compile()
         from .. import introspection as _introspection
 
         return (compiled, _introspection.flops_of(compiled))
@@ -648,9 +647,6 @@ class TrainStep:
         if not variants:
             variants.append(self._aot_step(args))
         for i, (compiled, flops) in enumerate(variants):
-            if compiled is None:
-                # AOT unavailable for this signature: jit dispatch
-                return self._step(*args), None
             try:
                 out = compiled(*args)
             except ValueError:
@@ -662,8 +658,6 @@ class TrainStep:
         variants.insert(0, entry)
         del variants[4:]
         compiled, flops = entry
-        if compiled is None:
-            return self._step(*args), None
         return compiled(*args), flops
 
     def run(self, batches, steps=None, prefetch=None, guard=None):

@@ -66,10 +66,7 @@ def _lower_stablehlo(block, sig_avals):
                    for k, v in params.items()}
     key_aval = jax.ShapeDtypeStruct((2,), _np.uint32)
     lowered = jax.jit(apply_fn).lower(param_avals, key_aval, *sig_avals)
-    try:
-        return lowered.as_text(dialect="stablehlo")
-    except TypeError:        # older jax: no dialect kwarg (default IS mlir)
-        return lowered.as_text()
+    return lowered.as_text(dialect="stablehlo")
 
 
 def write_manifest(block, path, epoch=0, signatures=None, include_ir=True):

@@ -17,16 +17,9 @@ import pytest
 
 import mxnet_tpu as mx
 
+# conftest.py skips these without MXNET_TEST_TPU=1 and, with it, refuses to
+# start when JAX has no chip
 pytestmark = pytest.mark.tpu
-
-
-def _on_tpu():
-    import jax
-
-    return jax.default_backend() == "tpu"
-
-
-requires_tpu = pytest.mark.skipif(not _on_tpu(), reason="no TPU present")
 
 _R = np.random.RandomState(0)
 
@@ -77,7 +70,6 @@ def check_consistency(op, arrays, attrs=None, rtol=ELEMWISE_TOL,
                                    err_msg=f"op {op} diverges CPU vs TPU")
 
 
-@requires_tpu
 @pytest.mark.parametrize("op", _UNARY)
 def test_unary_consistency(op):
     x = _R.uniform(0.1, 2.0, (4, 37)).astype("float32")
@@ -88,7 +80,6 @@ def test_unary_consistency(op):
         check_consistency(op, [x])
 
 
-@requires_tpu
 @pytest.mark.parametrize("op", _BINARY)
 def test_binary_consistency(op):
     a = _R.uniform(0.5, 2.0, (4, 37)).astype("float32")
@@ -98,14 +89,12 @@ def test_binary_consistency(op):
     check_consistency(op, [a, b])
 
 
-@requires_tpu
 @pytest.mark.parametrize("op", _REDUCE)
 def test_reduce_consistency(op):
     x = _R.uniform(-1, 1, (5, 6, 7)).astype("float32")
     check_consistency(op, [x], {"axis": 1} if op not in ("norm",) else {})
 
 
-@requires_tpu
 @pytest.mark.parametrize("op,attrs", [
     ("dot", {}),
     ("batch_dot", {}),
@@ -122,7 +111,6 @@ def test_matmul_consistency(op, attrs):
     check_consistency(op, arrays, attrs, rtol=MATMUL_TOL, atol=1e-2)
 
 
-@requires_tpu
 @pytest.mark.parametrize("op,mk", [
     ("Convolution", lambda: ([_R.randn(2, 3, 16, 16).astype("f"),
                               _R.randn(8, 3, 3, 3).astype("f")],
@@ -145,7 +133,6 @@ def test_nn_op_consistency(op, mk):
     check_consistency(op, arrays, attrs, rtol=MATMUL_TOL, atol=1e-2)
 
 
-@requires_tpu
 def test_model_fwd_bwd_consistency():
     """One model forward+backward on both backends (reference:
     test_gluon_gpu.py model consistency)."""
@@ -176,7 +163,6 @@ def test_model_fwd_bwd_consistency():
 # Pallas flash attention on-device (VERDICT r1: the kernel previously had
 # zero coverage on its actual target)
 # ---------------------------------------------------------------------------
-@requires_tpu
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("seq,heads,kv_heads,dim", [
     (256, 4, 4, 64),
@@ -202,7 +188,6 @@ def test_flash_attention_pallas_forward(causal, seq, heads, kv_heads, dim):
                                rtol=2e-2, atol=2e-3)
 
 
-@requires_tpu
 def test_flash_attention_pallas_grads():
     import jax
     import jax.numpy as jnp
@@ -226,7 +211,6 @@ def test_flash_attention_pallas_grads():
                                    rtol=5e-2, atol=5e-2)
 
 
-@requires_tpu
 def test_flash_attention_pallas_decode_offset():
     """lq < lk (decode): the diagonal offset must match the reference."""
     import jax.numpy as jnp
@@ -242,7 +226,6 @@ def test_flash_attention_pallas_decode_offset():
                                rtol=2e-2, atol=2e-3)
 
 
-@requires_tpu
 def test_trainstep_bf16_on_tpu():
     """The AMP jit path executes on the chip with finite decreasing loss."""
     from mxnet_tpu import gluon
@@ -270,7 +253,6 @@ def test_trainstep_bf16_on_tpu():
 
 # ---- widened op families (VERDICT r3 weak #6: BN/Pooling/Deconv/dtype
 # coverage on chip) ---------------------------------------------------------
-@requires_tpu
 @pytest.mark.parametrize("attrs", [
     {"kernel": (2, 2), "stride": (2, 2), "pool_type": "max"},
     {"kernel": (3, 3), "stride": (2, 2), "pad": (1, 1), "pool_type": "avg"},
@@ -283,7 +265,6 @@ def test_pooling_consistency(attrs):
     check_consistency("Pooling", [x], attrs)
 
 
-@requires_tpu
 @pytest.mark.parametrize("cin,cout,stride", [(2, 4, (2, 2)), (3, 3, (1, 1))])
 def test_deconvolution_consistency(cin, cout, stride):
     x = _R.randn(1, cin, 5, 5).astype("f")
@@ -294,7 +275,6 @@ def test_deconvolution_consistency(cin, cout, stride):
                       rtol=MATMUL_TOL, atol=1e-3)
 
 
-@requires_tpu
 @pytest.mark.parametrize("training", [False, True])
 def test_batchnorm_consistency(training):
     x = _R.randn(4, 3, 6, 6).astype("f")
@@ -308,7 +288,6 @@ def test_batchnorm_consistency(training):
                       rtol=1e-4, atol=1e-4)
 
 
-@requires_tpu
 def test_conv_nhwc_consistency():
     x = _R.randn(2, 9, 9, 4).astype("f")
     w = _R.randn(8, 3, 3, 4).astype("f")  # OHWI
@@ -318,7 +297,6 @@ def test_conv_nhwc_consistency():
                       rtol=MATMUL_TOL, atol=1e-3)
 
 
-@requires_tpu
 def test_proposal_greedy_nms_consistency():
     cls = _R.uniform(0, 1, (1, 2, 6, 6)).astype("f")
     bbox = (_R.randn(1, 4, 6, 6) * 0.1).astype("f")
@@ -329,7 +307,6 @@ def test_proposal_greedy_nms_consistency():
                       rtol=1e-4, atol=1e-3)
 
 
-@requires_tpu
 @pytest.mark.parametrize("dt,tol", [("float16", 1e-2), ("bfloat16", 2e-2)])
 def test_low_precision_dot_consistency(dt, tol):
     a = _R.uniform(-1, 1, (32, 64)).astype("f")
@@ -345,7 +322,6 @@ def test_low_precision_dot_consistency(dt, tol):
 
 
 # ---- round-5 additions: new op surface must hold on the chip ----------
-@requires_tpu
 def test_deconvolution_nhwc_consistency():
     x = _R.randn(1, 5, 5, 3).astype("f")
     w = _R.randn(3, 3, 3, 4).astype("f")  # (in, kh, kw, out/g)
@@ -356,7 +332,6 @@ def test_deconvolution_nhwc_consistency():
                       rtol=MATMUL_TOL, atol=1e-3)
 
 
-@requires_tpu
 def test_rnn_use_sequence_length_consistency():
     from mxnet_tpu.ops.nn import rnn_param_size
 
@@ -374,7 +349,6 @@ def test_rnn_use_sequence_length_consistency():
                       rtol=TRANSCENDENTAL_TOL, atol=TRANSCENDENTAL_TOL)
 
 
-@requires_tpu
 def test_correlation_consistency():
     a = _R.randn(1, 2, 8, 8).astype("f")
     b = _R.randn(1, 2, 8, 8).astype("f")
@@ -383,7 +357,6 @@ def test_correlation_consistency():
                        "pad_size": 3}, rtol=MATMUL_TOL, atol=1e-4)
 
 
-@requires_tpu
 def test_pdf_ops_consistency():
     s = _R.uniform(0.2, 2.0, (2, 5)).astype("f")
     check_consistency("_random_pdf_gamma",
@@ -394,14 +367,13 @@ def test_pdf_ops_consistency():
                       rtol=TRANSCENDENTAL_TOL, atol=TRANSCENDENTAL_TOL)
 
 
-@requires_tpu
 def test_s2d_stem_resnet_consistency():
     """The space-to-depth stem variant forwards identically on chip."""
     from mxnet_tpu.gluon.model_zoo import vision
 
     net = vision.resnet18_v1(classes=10, layout="NHWC", stem="s2d")
     net.initialize(ctx=mx.cpu())
-    x = mx.nd.array(_R.randn(2, 32, 32, 3).astype("f"))
+    x = mx.nd.array(_R.randn(2, 32, 32, 3).astype("f"), ctx=mx.cpu())
     y_cpu = net(x).asnumpy()
     net_t = vision.resnet18_v1(classes=10, layout="NHWC", stem="s2d")
     net_t.initialize(ctx=mx.tpu())
@@ -414,7 +386,6 @@ def test_s2d_stem_resnet_consistency():
     np.testing.assert_allclose(y_tpu, y_cpu, rtol=MATMUL_TOL, atol=1e-2)
 
 
-@requires_tpu
 def test_moe_swiglu_consistency():
     x = _R.randn(1, 6, 8).astype("f")
     router = _R.randn(8, 2).astype("f")

@@ -24,10 +24,17 @@ how the reference CI ran its dist kvstore tests without a cluster
 out of scope for a single-pod TPU job: multi-host pods are provisioned by
 the TPU runtime which starts one process per host with the coordinator env
 already present.
+
+On a host with TPU chips a local job of several workers is refused unless
+the workers are pinned to the CPU (``JAX_PLATFORMS=cpu``): every worker
+gets a copy of this environment, none gets a chip of its own, so each
+would try to take them all.  One process drives all the chips of a host
+(``mesh=make_mesh()``).
 """
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import signal
 import socket
@@ -42,6 +49,13 @@ def _free_port():
     port = s.getsockname()[1]
     s.close()
     return port
+
+
+def _local_chips():
+    """TPU device nodes of this host (``/dev/vfio/<n>``, or ``/dev/accel<n>``
+    under the older driver), found without touching JAX: the launcher
+    must stay off the chip its workers need."""
+    return glob.glob("/dev/vfio/[0-9]*") or glob.glob("/dev/accel[0-9]*")
 
 
 def main(argv=None):
@@ -68,6 +82,17 @@ def main(argv=None):
     if cmd and cmd[0] == "--":
         cmd = cmd[1:]
 
+    extra_env = dict(kv.partition("=")[::2] for kv in args.env)
+    platforms = extra_env.get("JAX_PLATFORMS",
+                              os.environ.get("JAX_PLATFORMS", ""))
+    chips = _local_chips()
+    if args.num_workers > 1 and platforms != "cpu" and chips:
+        ap.error(
+            f"{args.num_workers} local workers on a host with TPU chips "
+            f"({', '.join(sorted(chips))}): each would try to take "
+            "every chip.  Run one process over all chips, or pin the "
+            "workers to the CPU with --env JAX_PLATFORMS=cpu")
+
     port = args.port or _free_port()
     coord = f"{args.host}:{port}"
     procs = []
@@ -85,9 +110,7 @@ def main(argv=None):
                 "DMLC_WORKER_ID": str(wid),
                 "DMLC_ROLE": "worker",
             })
-            for kv in args.env:
-                k, _, v = kv.partition("=")
-                env[k] = v
+            env.update(extra_env)
             procs.append(subprocess.Popen(cmd, env=env))
         # poll the whole group: one worker dying early must tear the job
         # down immediately (a sequential wait() would hang forever on the
