@@ -47,6 +47,9 @@ _DEPTH = _telemetry.gauge(
 _WAIT = _telemetry.histogram(
     "mxnet_prefetch_wait_seconds",
     "time the consumer blocked waiting for a prefetched batch")
+_STAGE = _telemetry.histogram(
+    "mxnet_prefetch_stage_seconds",
+    "time the producer thread spent staging one batch on the device")
 
 _ITEM, _END, _ERR = 0, 1, 2
 
@@ -178,7 +181,12 @@ class PrefetchIterator:
     def _producer(self):
         try:
             for item in self._source:
-                staged = self._stage(item)
+                # a plain annotation, not a telemetry.phase: the step
+                # timeline belongs to the consumer's thread
+                t0 = _time.perf_counter()
+                with _telemetry.trace_annotation("prefetch.stage"):
+                    staged = self._stage(item)
+                _STAGE.observe(_time.perf_counter() - t0)
                 if not self._put((_ITEM, staged)):
                     return
             self._put((_END, None))
@@ -201,19 +209,23 @@ class PrefetchIterator:
                 raise
         t0 = _time.perf_counter()
         hit = not self._q.empty()
-        while True:
-            try:
-                kind, val = self._q.get(timeout=0.2)
-                break
-            except queue.Empty:
-                if self._thread is not None and not self._thread.is_alive():
-                    # producer died without managing to enqueue a sentinel
-                    self._done = True
-                    if self._error is not None:
-                        raise self._error
-                    raise MXNetError(
-                        "prefetch thread died without delivering a batch "
-                        "or an error (crashed interpreter thread?)")
+        with _telemetry.trace_annotation("prefetch.wait"):
+            while True:
+                try:
+                    kind, val = self._q.get(timeout=0.2)
+                    break
+                except queue.Empty:
+                    if self._thread is not None \
+                            and not self._thread.is_alive():
+                        # producer died without managing to enqueue a
+                        # sentinel
+                        self._done = True
+                        if self._error is not None:
+                            raise self._error
+                        raise MXNetError(
+                            "prefetch thread died without delivering a "
+                            "batch or an error (crashed interpreter "
+                            "thread?)")
         _WAIT.observe(_time.perf_counter() - t0)
         _DEPTH.set(self._q.qsize())
         if kind == _ITEM:

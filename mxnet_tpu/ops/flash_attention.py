@@ -18,6 +18,8 @@ import threading
 
 import numpy as _np
 
+from ..profiler import SCOPE_ATTENTION_BWD, SCOPE_ATTENTION_PLAIN_FWD
+
 NEG_INF = -1e30
 
 _SCOPE = threading.local()   # .value: (mesh, batch_axes) while a sharded
@@ -57,26 +59,28 @@ def _use_pallas(q):
 # jax reference path (CPU tests, short sequences, fallback)
 # --------------------------------------------------------------------------
 def _mha_with_lse(q, k, v, causal, sm_scale):
+    import jax
     import jax.numpy as jnp
 
-    b, hq, lq, d = q.shape
-    hkv = k.shape[1]
-    if hq != hkv:
-        rep = hq // hkv
-        k = jnp.repeat(k, rep, axis=1)
-        v = jnp.repeat(v, rep, axis=1)
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * sm_scale
-    if causal:
-        lk = k.shape[2]
-        mask = jnp.tril(jnp.ones((lq, lk), dtype=bool), k=lk - lq)
-        scores = jnp.where(mask, scores, NEG_INF)
-    m = scores.max(axis=-1, keepdims=True)
-    e = jnp.exp(scores - m)
-    denom = e.sum(axis=-1, keepdims=True)
-    p = e / denom
-    o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
-    lse = (m + jnp.log(denom))[..., 0]
+    with jax.named_scope(SCOPE_ATTENTION_PLAIN_FWD):
+        b, hq, lq, d = q.shape
+        hkv = k.shape[1]
+        if hq != hkv:
+            rep = hq // hkv
+            k = jnp.repeat(k, rep, axis=1)
+            v = jnp.repeat(v, rep, axis=1)
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                            k.astype(jnp.float32)) * sm_scale
+        if causal:
+            lk = k.shape[2]
+            mask = jnp.tril(jnp.ones((lq, lk), dtype=bool), k=lk - lq)
+            scores = jnp.where(mask, scores, NEG_INF)
+        m = scores.max(axis=-1, keepdims=True)
+        e = jnp.exp(scores - m)
+        denom = e.sum(axis=-1, keepdims=True)
+        p = e / denom
+        o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
+        lse = (m + jnp.log(denom))[..., 0]
     return o, lse
 
 
@@ -242,46 +246,47 @@ def _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
     import jax
     import jax.numpy as jnp
 
-    b, h, lq, d = q.shape
-    lk = k.shape[2]
-    block_k = min(block_k, lk)
-    if lk % block_k != 0:
-        block_k = lk
-    nkb = lk // block_k
+    with jax.named_scope(SCOPE_ATTENTION_BWD):
+        b, h, lq, d = q.shape
+        lk = k.shape[2]
+        block_k = min(block_k, lk)
+        if lk % block_k != 0:
+            block_k = lk
+        nkb = lk // block_k
 
-    acc_t = jnp.result_type(q.dtype, jnp.float32)
-    qf = q.astype(acc_t)
-    gf = g.astype(acc_t)
-    of = o.astype(acc_t)
-    delta = jnp.sum(of * gf, axis=-1)                      # (b,h,lq)
+        acc_t = jnp.result_type(q.dtype, jnp.float32)
+        qf = q.astype(acc_t)
+        gf = g.astype(acc_t)
+        of = o.astype(acc_t)
+        delta = jnp.sum(of * gf, axis=-1)                      # (b,h,lq)
 
-    kb = k.reshape(b, h, nkb, block_k, d).astype(acc_t)
-    vb = v.reshape(b, h, nkb, block_k, d).astype(acc_t)
+        kb = k.reshape(b, h, nkb, block_k, d).astype(acc_t)
+        vb = v.reshape(b, h, nkb, block_k, d).astype(acc_t)
 
-    q_pos = jnp.arange(lq)
+        q_pos = jnp.arange(lq)
 
-    def step(dq, idx):
-        kblk = kb[:, :, idx]                               # (b,h,bk,d)
-        vblk = vb[:, :, idx]
-        s = jnp.einsum("bhqd,bhkd->bhqk", qf, kblk) * sm_scale
-        if causal:
-            # same diagonal offset as the forward (q_i attends keys up to
-            # i + lk - lq when lengths differ, e.g. decode)
-            k_pos = idx * block_k + jnp.arange(block_k)
-            mask = (q_pos[:, None] + (lk - lq)) >= k_pos[None, :]
-            s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse[..., None])                    # (b,h,q,bk)
-        dv = jnp.einsum("bhqk,bhqd->bhkd", p, gf)
-        dp = jnp.einsum("bhqd,bhkd->bhqk", gf, vblk)
-        ds = p * (dp - delta[..., None]) * sm_scale
-        dk = jnp.einsum("bhqk,bhqd->bhkd", ds, qf)
-        dq = dq + jnp.einsum("bhqk,bhkd->bhqd", ds, kblk)
-        return dq, (dk, dv)
+        def step(dq, idx):
+            kblk = kb[:, :, idx]                               # (b,h,bk,d)
+            vblk = vb[:, :, idx]
+            s = jnp.einsum("bhqd,bhkd->bhqk", qf, kblk) * sm_scale
+            if causal:
+                # same diagonal offset as the forward (q_i attends keys up to
+                # i + lk - lq when lengths differ, e.g. decode)
+                k_pos = idx * block_k + jnp.arange(block_k)
+                mask = (q_pos[:, None] + (lk - lq)) >= k_pos[None, :]
+                s = jnp.where(mask, s, NEG_INF)
+            p = jnp.exp(s - lse[..., None])                    # (b,h,q,bk)
+            dv = jnp.einsum("bhqk,bhqd->bhkd", p, gf)
+            dp = jnp.einsum("bhqd,bhkd->bhqk", gf, vblk)
+            ds = p * (dp - delta[..., None]) * sm_scale
+            dk = jnp.einsum("bhqk,bhqd->bhkd", ds, qf)
+            dq = dq + jnp.einsum("bhqk,bhkd->bhqd", ds, kblk)
+            return dq, (dk, dv)
 
-    dq0 = jnp.zeros_like(qf)
-    dq, (dks, dvs) = jax.lax.scan(step, dq0, jnp.arange(nkb))
-    dk = jnp.moveaxis(dks, 0, 2).reshape(b, h, lk, d)
-    dv = jnp.moveaxis(dvs, 0, 2).reshape(b, h, lk, d)
+        dq0 = jnp.zeros_like(qf)
+        dq, (dks, dvs) = jax.lax.scan(step, dq0, jnp.arange(nkb))
+        dk = jnp.moveaxis(dks, 0, 2).reshape(b, h, lk, d)
+        dv = jnp.moveaxis(dvs, 0, 2).reshape(b, h, lk, d)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 

@@ -20,11 +20,20 @@ from __future__ import annotations
 from collections import OrderedDict
 from functools import partial
 
+from .. import profiler as _profiler
+from .. import telemetry as _telemetry
 from ..base import MXNetError
 from .functional import functionalize
 
 __all__ = ["TrainStep", "make_sgd_update", "make_adam_update",
            "replicated_specs", "fsdp_specs"]
+
+# host phases of one TrainStep call (``telemetry.phase``): staging, key,
+# signature and tree walks / the call into the executable alone / the
+# compile of a fresh signature.  What follows the call is left unspanned.
+PHASE_PREPARE = "train_step.prepare"
+PHASE_EXECUTE = "train_step.execute"
+PHASE_COMPILE = "train_step.compile"
 
 
 def _jax():
@@ -385,7 +394,16 @@ class TrainStep:
                 in_jit_sharding=pipe_in_jit)
             return d["post_fn"]({k: p[k] for k in d["post_names"]}, rng, h)
 
-        def step(train_params, rest_params, opt_state, rng, x, y):
+        # Named ``train_step`` so that a trace shows the module as
+        # ``jit_train_step``.  The name is also part of JAX's persistent
+        # compile-cache key, which the scopes below are not: an executable
+        # cached under another name by a build without them is not loaded
+        # in place of this one (it would carry that build's op names).
+        def train_step(train_params, rest_params, opt_state, rng, x, y):
+            # the scopes only name the ops (profiler.scopes_of reads them
+            # back); the backward needs none, JAX derives its ops' names
+            # from the forward's: transpose(jvp(mx_forward))
+            @jax.named_scope(_profiler.SCOPE_FORWARD)
             def loss_of(tp):
                 p = dict(rest_params)
                 p.update(tp)
@@ -406,7 +424,8 @@ class TrainStep:
 
             (loss, state), grads = jax.value_and_grad(
                 loss_of, has_aux=True)(train_params)
-            new_tp, new_opt = update(train_params, grads, opt_state)
+            with jax.named_scope(_profiler.SCOPE_OPTIMIZER):
+                new_tp, new_opt = update(train_params, grads, opt_state)
             new_rest = dict(rest_params)
             for k, v in state.items():
                 if k in new_rest:
@@ -414,7 +433,7 @@ class TrainStep:
             return loss, new_tp, new_rest, new_opt
 
         donate_argnums = (0, 1, 2) if donate else ()
-        self._step = jax.jit(step, donate_argnums=donate_argnums)
+        self._step = jax.jit(train_step, donate_argnums=donate_argnums)
         self._rng_seed = 0
         self.step_count = 0      # steps taken (lifecycle train_state)
         self._seen_sigs = set()  # telemetry: (x, y) avals already compiled
@@ -544,45 +563,50 @@ class TrainStep:
     def __call__(self, x, y):
         from jax import random as jr
 
-        x = self._stage_batch(x)
-        y = self._stage_batch(y)
-        rng = jr.PRNGKey(self._rng_seed)
-        self._rng_seed += 1
-        # telemetry compile tracer: an unseen batch signature means this
-        # call traces+compiles the whole step before running it.  The set
-        # is capped like dispatch_cache._COMPILE_SEEN — a variable-shape
-        # workload must not leak memory proportional to distinct sigs
-        # (past the cap fresh compiles simply go unrecorded)
-        sig = (tuple(getattr(x, "shape", ())), str(getattr(x, "dtype", "")),
-               tuple(getattr(y, "shape", ())), str(getattr(y, "dtype", "")))
-        step_fn = self._step
-        flops = None
-        if self._cc is not None:
-            cached = self._cc_fns[sig] if sig in self._cc_fns else \
-                self._cc_lookup(sig, rng, x, y)
-            if cached is not None:
-                # warm start: no trace happens, so no compile event —
-                # the cache-hit counter carries the observability and
-                # the zero-fresh-trace assertion holds by construction.
-                # The FLOP count rides the cache entry (stored with the
-                # executable), so MFU accounting stays warm too.
-                step_fn = cached
-                self._seen_sigs.add(sig)
-                flops = self._cc_meta.get(sig, {}).get("flops")
-        fresh = sig not in self._seen_sigs and len(self._seen_sigs) < 4096
-        if fresh:
-            import time as _t
+        with _telemetry.phase(PHASE_PREPARE):
+            x = self._stage_batch(x)
+            y = self._stage_batch(y)
+            rng = jr.PRNGKey(self._rng_seed)
+            self._rng_seed += 1
+            # telemetry compile tracer: an unseen batch signature means
+            # this call traces+compiles the whole step before running it.
+            # The set is capped like dispatch_cache._COMPILE_SEEN — a
+            # variable-shape workload must not leak memory proportional to
+            # distinct sigs (past the cap fresh compiles simply go
+            # unrecorded)
+            sig = (tuple(getattr(x, "shape", ())),
+                   str(getattr(x, "dtype", "")),
+                   tuple(getattr(y, "shape", ())),
+                   str(getattr(y, "dtype", "")))
+            step_fn = self._step
+            flops = None
+            if self._cc is not None:
+                cached = self._cc_fns[sig] if sig in self._cc_fns else \
+                    self._cc_lookup(sig, rng, x, y)
+                if cached is not None:
+                    # warm start: no trace happens, so no compile event —
+                    # the cache-hit counter carries the observability and
+                    # the zero-fresh-trace assertion holds by construction.
+                    # The FLOP count rides the cache entry (stored with
+                    # the executable), so MFU accounting stays warm too.
+                    step_fn = cached
+                    self._seen_sigs.add(sig)
+                    flops = self._cc_meta.get(sig, {}).get("flops")
+            fresh = sig not in self._seen_sigs \
+                and len(self._seen_sigs) < 4096
+            if fresh:
+                import time as _t
 
-            self._seen_sigs.add(sig)
-            t0 = _t.perf_counter()
-        # plain-dict calling convention for EVERY dispatch (see
-        # _plain_tree): the step's state trees drift OrderedDict→dict
-        # across calls, and both the AOT executable and a cached
-        # exported artifact are structure-strict; key-based flattening
-        # keeps the leaf mapping identical either way
-        args = (self._plain_tree(self.train_params),
-                self._plain_tree(self.rest_params),
-                self._plain_tree(self.opt_state), rng, x, y)
+                self._seen_sigs.add(sig)
+                t0 = _t.perf_counter()
+            # plain-dict calling convention for EVERY dispatch (see
+            # _plain_tree): the step's state trees drift OrderedDict→dict
+            # across calls, and both the AOT executable and a cached
+            # exported artifact are structure-strict; key-based flattening
+            # keeps the leaf mapping identical either way
+            args = (self._plain_tree(self.train_params),
+                    self._plain_tree(self.rest_params),
+                    self._plain_tree(self.opt_state), rng, x, y)
         if step_fn is self._step:
             # per-signature AOT: the cold path lowers + compiles ONCE
             # (capturing XLA's cost_analysis FLOPs while the executable
@@ -590,7 +614,7 @@ class TrainStep:
             # no retrace, no host sync, no new work
             out, flops = self._call_aot(sig, args)
         else:
-            out = step_fn(*args)
+            out = self._execute(step_fn, args)
         loss, self.train_params, self.rest_params, self.opt_state = out
         self.step_count += 1
         if flops:
@@ -598,8 +622,6 @@ class TrainStep:
 
             _introspection.account_flops(flops, kind="train_step")
         if fresh:
-            from .. import telemetry as _telemetry
-
             _telemetry.compile_event(
                 "train_step", type(self._net).__name__,
                 _t.perf_counter() - t0,
@@ -618,13 +640,24 @@ class TrainStep:
                     meta={"flops": flops} if flops else None)
         return loss
 
+    @staticmethod
+    def _execute(fn, args):
+        """The call into the executable, alone in its phase."""
+        with _telemetry.phase(PHASE_EXECUTE):
+            return fn(*args)
+
     def _aot_step(self, args):
         """Lower + compile one operand tuple ahead of time and capture
         its cost-analysis FLOPs.  A compile error propagates: there is
         no second dispatch path for the compiler to refuse again."""
-        compiled = self._step.lower(*args).compile()
         from .. import introspection as _introspection
 
+        with _telemetry.phase(PHASE_COMPILE):
+            compiled = self._step.lower(*args).compile()
+            # the op-to-scope table of this executable, for whoever reads
+            # a device trace of it (profiler.op_scopes)
+            _profiler.register_executable(
+                f"train_step:{type(self._net).__name__}", compiled)
         return (compiled, _introspection.flops_of(compiled))
 
     def _call_aot(self, sig, args):
@@ -648,7 +681,7 @@ class TrainStep:
             variants.append(self._aot_step(args))
         for i, (compiled, flops) in enumerate(variants):
             try:
-                out = compiled(*args)
+                out = self._execute(compiled, args)
             except ValueError:
                 continue
             if i:
@@ -658,7 +691,7 @@ class TrainStep:
         variants.insert(0, entry)
         del variants[4:]
         compiled, flops = entry
-        return compiled(*args), flops
+        return self._execute(compiled, args), flops
 
     def run(self, batches, steps=None, prefetch=None, guard=None):
         """Drive the fused step over an iterator of ``(x, y)`` batches with

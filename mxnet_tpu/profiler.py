@@ -18,13 +18,27 @@ from __future__ import annotations
 
 import json
 import os
+import re
+import sys
 import threading
 import time
+from collections import OrderedDict
 
 from .base import MXNetError
 
 __all__ = ["set_config", "start", "stop", "dump", "dumps", "pause", "resume",
-           "Task", "Frame", "Marker", "Counter", "Domain", "Scope"]
+           "Task", "Frame", "Marker", "Counter", "Domain", "Scope",
+           "scopes_of", "register_executable", "op_scopes"]
+
+# ``jax.named_scope`` names inside the compiled programs: they land in
+# every HLO instruction's ``op_name`` metadata, which is how a device trace
+# (and ``scopes_of``) tells one part of a fused step from another.  The
+# backward pass needs no scope of its own: JAX names the ops it derives
+# from the forward ``transpose(jvp(mx_forward))``.
+SCOPE_FORWARD = "mx_forward"
+SCOPE_OPTIMIZER = "mx_optimizer"
+SCOPE_ATTENTION_BWD = "mxnet_flash_attention_bwd"
+SCOPE_ATTENTION_PLAIN_FWD = "mxnet_attention_plain_fwd"
 
 _CONFIG = {"filename": "profile.json", "profile_all": False,
            "profile_imperative": False, "dir": None, "jax_trace": True,
@@ -253,6 +267,144 @@ def dumps(reset=False):
         lines.append(f"  {seam:<30}{c['calls']:>12}{c['trips']:>10}"
                      f"{c['retries']:>10}")
     return "\n".join(lines)
+
+
+# --------------------------------------------------------------------------
+# op-to-scope tables: which part of the step an HLO instruction belongs to
+# --------------------------------------------------------------------------
+_OP_SCOPES: OrderedDict = OrderedDict()   # name -> table, newest last
+_OP_SCOPES_CAP = 8
+
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_HLO_OPCODE = re.compile(r"\s([a-z][a-z0-9\-]*)\(")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+# computations whose instructions a device trace shows beside the entry's:
+# loop bodies and conditions, branches, and what a ``call`` applies
+_HLO_SHOWN = re.compile(
+    r"\b(?:body|condition|true_computation|false_computation)=%?([\w.\-]+)"
+    r"|\bbranch_computations=\{([^}]*)\}")
+_HLO_TO_APPLY = re.compile(r"\bto_apply=%?([\w.\-]+)")
+
+
+def _scope_classes(op_names):
+    """Sorted classes among ``forward`` / ``backward`` / ``optimizer`` that
+    the ``op_name``s fall in."""
+    found = set()
+    for name in op_names:
+        if SCOPE_OPTIMIZER in name:
+            found.add("optimizer")
+        elif SCOPE_FORWARD in name:
+            found.add("backward" if "transpose(" in name else "forward")
+    return sorted(found)
+
+
+def _hlo_computations(text):
+    """``(entry, {computation: [(instruction, own op_name, what follows the
+    name on its line)]})`` of an HLO module's text."""
+    computations = {}
+    entry = current = None
+    for line in text.splitlines():
+        if not line:
+            continue
+        if line[0] not in " }" and line.endswith("{"):
+            head = line.split(None, 2)
+            is_entry = head[0] == "ENTRY"
+            name = head[1 if is_entry else 0].lstrip("%")
+            current = computations[name] = []
+            if is_entry:
+                entry = name
+        elif line[0] == "}":
+            current = None
+        elif current is not None:
+            m = _HLO_INSTRUCTION.match(line)
+            if m:
+                own = _HLO_OP_NAME.search(line)
+                current.append((m.group(1), own.group(1) if own else "",
+                                line[m.end() - 1:]))
+    return entry, computations
+
+
+def scopes_of(compiled):
+    """``{instruction: {"scope": op_name, "classes": [...]}}`` for every
+    instruction of a compiled executable that a device trace can show: the
+    entry computation's, and those of loop bodies, branches and calls.
+
+    ``scope`` is the instruction's ``op_name`` metadata verbatim (the
+    ``jax.named_scope`` path it was traced under); ``classes`` is the
+    sorted set of ``forward`` / ``backward`` / ``optimizer`` over that name
+    and, for a fusion, the names of the instructions it fused (those of
+    nested fusions too).  One class: cleanly attributed.  Two or more: a
+    fusion the compiler made across the parts.  None: unscoped (copies,
+    infeed).  Parsed once from ``compiled.as_text()``; empty where the
+    text cannot be had or carries no metadata, so that a reader finds
+    nothing rather than something wrong."""
+    try:
+        text = compiled.as_text()
+    except Exception:   # a runtime that keeps no text for this executable
+        return {}
+    if not text:
+        return {}
+    entry, computations = _hlo_computations(text)
+
+    def fused_names(comp, seen):
+        if comp in seen:
+            return []
+        seen.add(comp)
+        out = []
+        for _, own, rest in computations.get(comp, ()):
+            out.append(own)
+            for called in _HLO_CALLS.findall(rest):
+                out += fused_names(called, seen)
+        return out
+
+    table = {}
+    todo, shown = [entry], set()
+    while todo:
+        comp = todo.pop()
+        if comp in shown or comp not in computations:
+            continue
+        shown.add(comp)
+        for name, own, rest in computations[comp]:
+            names = [own]
+            for called in _HLO_CALLS.findall(rest):
+                names += fused_names(called, set())
+            table[name] = {"scope": sys.intern(own),
+                           "classes": _scope_classes(names)}
+            for one, many in _HLO_SHOWN.findall(rest):
+                todo += [one] if one else [
+                    c.strip().lstrip("%") for c in many.split(",")]
+            opcode = _HLO_OPCODE.search(rest)
+            if opcode and opcode.group(1) == "call":
+                todo += _HLO_TO_APPLY.findall(rest)
+    if not any(row["scope"] for row in table.values()):
+        return {}
+    return table
+
+
+def register_executable(name, compiled):
+    """Keep ``scopes_of(compiled)`` under ``name`` for ``op_scopes()`` and
+    return it.  The table is kept, never the executable (a loaded step
+    program holds gigabytes of device scratch); the newest
+    ``_OP_SCOPES_CAP`` names stay, and a name registered again replaces
+    its table."""
+    table = scopes_of(compiled)
+    with _LOCK:
+        _OP_SCOPES.pop(name, None)
+        _OP_SCOPES[name] = table
+        while len(_OP_SCOPES) > _OP_SCOPES_CAP:
+            _OP_SCOPES.popitem(last=False)
+    return table
+
+
+def op_scopes():
+    """The registered op-to-scope tables, ``{name: table}``, oldest first.
+    ``TrainStep`` registers ``train_step:<NetClass>`` at each compile.
+    With a Perfetto trace of ``fusion.801`` in hand,
+    ``op_scopes()["train_step:MyNet"]["fusion.801"]`` says which part of
+    the step it is."""
+    with _LOCK:
+        return OrderedDict(_OP_SCOPES)
 
 
 class Domain:

@@ -54,7 +54,8 @@ __all__ = ["Counter", "Gauge", "Histogram", "counter", "gauge", "histogram",
            "render_prometheus", "start_http_server", "stop_http_server",
            "register_http_route", "unregister_http_route",
            "step_begin", "step_end", "step_abort", "step_scope", "phase",
-           "maybe_phase", "timeline", "compile_event", "compile_events",
+           "maybe_phase", "trace_annotation", "timeline", "compile_event",
+           "compile_events",
            "goodput_note", "goodput_summary",
            "heartbeat", "last_heartbeat", "reset"]
 
@@ -297,7 +298,8 @@ def register_collector(fn):
 # --------------------------------------------------------------------------
 _TIMELINE_CAP = max(1, _env.get_int("MXNET_TELEMETRY_TIMELINE_STEPS", 256))
 _STEPS: deque = deque(maxlen=_TIMELINE_CAP)
-_CUR = None          # active step: {"step", "t0", "wall0", "phases", "stack"}
+_CUR = None          # active step: {"step", "thread", "t0", "wall0", "phases",
+#                      "stack"}
 _STEP_SEQ = [0]
 
 _PHASE_HIST = histogram(
@@ -453,8 +455,9 @@ def step_begin(step=None):
             step = _STEP_SEQ[0]
         step = int(step)
         _STEP_SEQ[0] = step + 1
-        _CUR = {"step": step, "t0": time.perf_counter(),
-                "wall0": time.time(), "phases": {}, "stack": []}
+        _CUR = {"step": step, "thread": threading.get_ident(),
+                "t0": time.perf_counter(), "wall0": time.time(),
+                "phases": {}, "stack": []}
     # return the local, not _CUR["step"]: a concurrent step_end/abort may
     # have nulled _CUR the instant the lock dropped
     _flight_note("step", event="begin", step=step)
@@ -535,19 +538,34 @@ def step_abort():
         _CUR = None
 
 
+def trace_annotation(name):
+    """``jax.profiler.TraceAnnotation("mx:" + name)``: a host span on the
+    clock of whatever JAX trace is running (the device trace's), and next
+    to nothing when none is.  For spans that are not phases, such as those
+    of a producer thread."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation("mx:" + name)
+
+
 class _PhaseScope:
-    __slots__ = ("name", "_t0")
+    __slots__ = ("name", "_t0", "_note")
 
     def __init__(self, name):
         self.name = name
         self._t0 = None
+        self._note = None
 
     def __enter__(self):
         now = time.perf_counter()
         self._t0 = now
         with _LOCK:
             cur = _CUR
-            if cur is not None:
+            # the step's stack belongs to the thread that opened the step:
+            # a phase on another thread (a prefetch producer, an async
+            # checkpoint write) must not pause or charge that thread's
+            # open phase, and observes into the histogram instead
+            if cur is not None and cur["thread"] == threading.get_ident():
                 stack = cur["stack"]
                 if stack:
                     # pause the outer phase: charge it up to now
@@ -556,30 +574,34 @@ class _PhaseScope:
                         cur["phases"].get(oname, 0.0) + (now - ot)
                     stack[-1][1] = now
                 stack.append([self.name, now])
+        self._note = trace_annotation(self.name)
+        self._note.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self._note.__exit__(*exc)
         now = time.perf_counter()
         with _LOCK:
             cur = _CUR
-            if cur is not None and cur["stack"] \
-                    and cur["stack"][-1][0] == self.name:
+            if cur is None or cur["thread"] != threading.get_ident():
+                # phase outside a step: still observable in the histogram
+                _PHASE_HIST.labels(phase=self.name).observe(now - self._t0)
+            elif cur["stack"] and cur["stack"][-1][0] == self.name:
                 _, t = cur["stack"].pop()
                 cur["phases"][self.name] = \
                     cur["phases"].get(self.name, 0.0) + (now - t)
                 if cur["stack"]:
                     cur["stack"][-1][1] = now  # outer phase resumes
-            elif cur is None:
-                # phase outside a step: still observable in the histogram
-                _PHASE_HIST.labels(phase=self.name).observe(now - self._t0)
         _chrome_span(f"phase:{self.name}", self._t0, now, "step_phase")
         return False
 
 
 def phase(name):
     """Context manager attributing its (exclusive) duration to ``name`` in
-    the active step; outside a step it records straight to the phase
-    histogram."""
+    the active step; outside a step (or on another thread than the step's)
+    it records straight to the phase histogram.  Either way it is also a
+    ``jax.profiler.TraceAnnotation`` named ``mx:<name>``, so it shows in
+    any JAX trace that is running, on the device trace's clock."""
     return _PhaseScope(name)
 
 
