@@ -733,8 +733,10 @@ def describe():
         ("MXNET_CPU_WORKER_NTHREADS", "decode/augment pool width"),
         ("MXNET_PROFILER_AUTOSTART", "start profiler at import"),
         ("MXNET_KVSTORE_BIGARRAY_BOUND", "dist kvstore bucket threshold"),
-        ("MXNET_FLASH_BLOCK_Q", "flash-attention q tile (default 128)"),
-        ("MXNET_FLASH_BLOCK_KV", "flash-attention kv tile (default 128)"),
+        ("MXNET_FLASH_BLOCK_Q", "flash-attention q tile (unset: from the "
+         "call's shape)"),
+        ("MXNET_FLASH_BLOCK_KV", "flash-attention kv tile (unset: from the "
+         "call's shape)"),
         ("MXNET_COORDINATOR_ADDRESS", "jax.distributed coordinator"),
         ("MXNET_TEST_TPU", "real-chip test lane"),
         ("MXNET_EAGER_JIT", "eager jit-cache fast path (default 1; "

@@ -3,9 +3,10 @@
 Reference scope: MXNet 1.x has NO fused attention — GluonNLP ran full O(L²)
 softmax(QKᵀ)V through `src/operator/contrib/transformer.cc`'s interleaved
 matmuls (SURVEY.md §6.7).  This module is the net-new TPU capability the
-BASELINE Llama config requires: an online-softmax blocked kernel that keeps
-the L×L score matrix out of HBM, tiled to the MXU (128-lane blocks), with a
-memory-efficient blockwise backward (lax.scan recompute — O(L) memory).
+BASELINE Llama config requires: a blocked softmax kernel that keeps the L×L
+score matrix out of HBM, fed to the MXU in the input's dtype with tiles
+chosen from the call's shape (`_fa_block_sizes`), with a memory-efficient
+blockwise backward (lax.scan recompute — O(L) memory).
 
 Layout: (batch, heads, seq, head_dim) — q_heads may be a multiple of
 kv_heads (GQA).
@@ -91,106 +92,159 @@ def _mha_reference(q, k, v, causal, sm_scale):
 # --------------------------------------------------------------------------
 # Pallas forward kernel
 # --------------------------------------------------------------------------
+# q @ k.T as one dot_general contracting both last dims: no transposed tile
+_NT_DIMS = (((1,), (1,)), ((), ()))
+
+# what the default tile choice may spend on one grid step's float32 score
+# tile and its q / k / v operand tiles; the exp'd copy, the buffers' second
+# halves and the resident K/V row come on top and stay under Mosaic's
+# scoped VMEM limit (16 MiB on a v5e) with this
+_TILE_VMEM_BUDGET = 4 << 20
+
+
 def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
                    sm_scale, seq_k, diag_offset=0):
-    """One (q-block × full-K sweep): online softmax accumulation.
+    """One q block against its head's whole K/V row.
 
     Grid: (batch*heads, num_q_blocks).  Block shapes:
       q_ref (block_q, d) VMEM; k_ref/v_ref (seq_k, d) VMEM (whole K/V row
       for this head — fine at the seq lengths VMEM allows; longer sequences
       ring through context parallelism instead).
+
+    The matmuls take their operands in the input's dtype and accumulate in
+    float32 (bf16 products are exact in float32; float32 inputs keep
+    float32 operands), and ``p`` goes to ``v``'s dtype for ``p @ v`` as in
+    ``_mha_with_lse``.  Scores, max, exp, sum, accumulator and lse are
+    float32, the statistics ``(block_q, 1)`` so that they broadcast over
+    the score tile's lanes without a relayout.  A K row that is one block
+    takes the plain softmax (no rescale); several blocks take the online
+    update, unrolled when not causal, and skipping the fully masked blocks
+    when causal.
     """
     import jax
     import jax.numpy as jnp
+    from jax.experimental import pallas as pl
 
     block_q, d = q_ref.shape
-    qi = pl_program_id(1)
-
-    q = q_ref[:].astype(jnp.float32) * sm_scale
-
-    m = jnp.full((block_q,), NEG_INF, dtype=jnp.float32)
-    l = jnp.zeros((block_q,), dtype=jnp.float32)
-    acc = jnp.zeros((block_q, d), dtype=jnp.float32)
-
+    qi = pl.program_id(1)
     num_kb = seq_k // block_k
 
-    def body(kb, carry):
-        m, l, acc = carry
-        k_blk = pl_load(k_ref, kb, block_k).astype(jnp.float32)
-        v_blk = pl_load(v_ref, kb, block_k).astype(jnp.float32)
-        s = q @ k_blk.T                                     # (bq, bk)
+    q = q_ref[:]
+    # float32 operands follow the process's matmul precision as they always
+    # have; narrower ones are one exact MXU pass, and Mosaic refuses them
+    # the float32 contraction that the process default (highest) asks for
+    precision = None if q.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+    # a power of two scales q exactly in any float dtype (1/8 at head size
+    # 64): one multiply a q element in place of one a score
+    scale_q = _np.frexp(sm_scale)[0] == 0.5
+    if scale_q:
+        q = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
+
+    def scores(kb):
+        k_blk = k_ref[pl.ds(kb * block_k, block_k), :]
+        s = jax.lax.dot_general(q, k_blk, _NT_DIMS, precision=precision,
+                                preferred_element_type=jnp.float32)
+        if not scale_q:
+            s = s * sm_scale
         if causal:
             q_pos = diag_offset + qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_new = l * alpha + p.sum(axis=-1)
-        acc_new = acc * alpha[:, None] + p @ v_blk
-        return m_new, l_new, acc_new
+        return s
 
-    if causal:
-        # skip fully-masked K blocks beyond this q block (offset-aware)
-        max_kb = jnp.minimum(
-            ((qi + 1) * block_q + diag_offset + block_k - 1) // block_k,
-            num_kb)
+    def weighted_v(p, kb):
+        v_blk = v_ref[pl.ds(kb * block_k, block_k), :]
+        return jax.lax.dot(p.astype(v_blk.dtype), v_blk, precision=precision,
+                           preferred_element_type=jnp.float32)
+
+    if num_kb == 1:
+        s = scores(0)
+        m = s.max(axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        l = p.sum(axis=-1, keepdims=True)
+        acc = weighted_v(p, 0)
     else:
-        max_kb = num_kb
-    m, l, acc = jax.lax.fori_loop(0, max_kb, body, (m, l, acc))
+        def body(kb, carry):
+            m, l, acc = carry
+            s = scores(kb)
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            return (m_new, l * alpha + p.sum(axis=-1, keepdims=True),
+                    acc * alpha + weighted_v(p, kb))
+
+        carry = (jnp.full((block_q, 1), NEG_INF, dtype=jnp.float32),
+                 jnp.zeros((block_q, 1), dtype=jnp.float32),
+                 jnp.zeros((block_q, d), dtype=jnp.float32))
+        if causal:
+            # skip fully-masked K blocks beyond this q block (offset-aware)
+            max_kb = jnp.minimum(
+                ((qi + 1) * block_q + diag_offset + block_k - 1) // block_k,
+                num_kb)
+            carry = jax.lax.fori_loop(0, max_kb, body, carry)
+        else:
+            for kb in range(num_kb):
+                carry = body(kb, carry)
+        m, l, acc = carry
 
     l = jnp.maximum(l, 1e-30)
-    o_ref[:] = (acc / l[:, None]).astype(o_ref.dtype)
+    o_ref[:] = (acc / l).astype(o_ref.dtype)
     # lse tile is (8, block_q) to satisfy TPU (sublane, lane) tiling; the
-    # vector is broadcast across the 8 sublanes and row 0 is read back
+    # column goes to a row once a q block, is broadcast across the 8
+    # sublanes and row 0 is read back
     lse = (m + jnp.log(l)).astype(lse_ref.dtype)
-    lse_ref[:] = jnp.broadcast_to(lse[None, :], lse_ref.shape)
+    lse_ref[:] = jnp.broadcast_to(lse.reshape(1, block_q), lse_ref.shape)
 
 
-def pl_program_id(axis):
-    from jax.experimental import pallas as pl
+def _fa_block_sizes(lq, lk, d, itemsize):
+    """Forward kernel tile sizes.  The tuning funnel's answer where it has
+    one (MXNET_FLASH_BLOCK_Q / MXNET_FLASH_BLOCK_KV pins > MXNET_TUNE=1
+    stored winners; values must divide the padded sequence length), and
+    where it answers ``default``, from what the call shows.  Measured on a
+    v5e (PERF.md section 6, PR 25): the larger q tile wins up to 512, and
+    one pass over the whole K row beats the online update while the score
+    tile stays small.  So ``block_q`` is the largest of 512 / 256 / 128
+    that divides ``lq`` (else ``lq``), and ``block_k`` the whole K row
+    where one grid step's float32 score tile and operand tiles fit
+    ``_TILE_VMEM_BUDGET``, else the largest of 512 / 256 / 128 that
+    divides ``lk`` and fits.  What was chosen is set in
+    ``mxnet_tuning_chosen_value{knob}`` either way.  Re-read per call on
+    purpose — the op is jit_safe=False exactly so sweeps/trials can vary
+    the tile between calls."""
+    from .. import tuning as _tuning
 
-    return pl.program_id(axis)
+    block_q, q_from = _tuning.resolve_info("flash_block_q")
+    block_k, k_from = _tuning.resolve_info("flash_block_kv")
+    if q_from == "default":
+        block_q = next((b for b in (512, 256, 128) if lq % b == 0), lq)
+    block_q = min(int(block_q), lq)
+    if k_from == "default":
+        def fits(bk):
+            return (block_q * bk * 4 + (block_q + 2 * bk) * d * itemsize
+                    <= _TILE_VMEM_BUDGET)
 
-
-def pl_load(ref, block_idx, block_size):
-    from jax.experimental import pallas as pl
-
-    return ref[pl.ds(block_idx * block_size, block_size), :]
-
-
-def _fa_block_sizes():
-    """Forward kernel tile sizes, resolved through the tuning funnel
-    (MXNET_FLASH_BLOCK_Q / MXNET_FLASH_BLOCK_KV pins > MXNET_TUNE=1
-    stored winners > 128 = one MXU lane tile).  Re-read per call on
-    purpose — the op is jit_safe=False exactly so sweeps/trials can
-    vary the tile between calls.  Values must divide the padded
-    sequence length."""
-    try:
-        from .. import tuning as _tuning
-
-        return (int(_tuning.resolve("flash_block_q")),
-                int(_tuning.resolve("flash_block_kv")))
-    except Exception:
-        import os
-
-        return (int(os.environ.get("MXNET_FLASH_BLOCK_Q", 128)),
-                int(os.environ.get("MXNET_FLASH_BLOCK_KV", 128)))
+        k_tiles = [lk] + [b for b in (512, 256, 128)
+                          if b < lk and lk % b == 0]
+        block_k = next((b for b in k_tiles if fits(b)), k_tiles[-1])
+    block_k = min(int(block_k), lk)
+    _tuning.note_chosen("flash_block_q", block_q)
+    _tuning.note_chosen("flash_block_kv", block_k)
+    return block_q, block_k
 
 
 def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q=None, block_k=None):
-    if block_q is None or block_k is None:
-        bq, bk = _fa_block_sizes()
-        block_q = bq if block_q is None else block_q
-        block_k = bk if block_k is None else block_k
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     b, h, lq, d = q.shape
     lk = k.shape[2]
+    if block_q is None or block_k is None:
+        bq, bk = _fa_block_sizes(lq, lk, d, q.dtype.itemsize)
+        block_q = bq if block_q is None else block_q
+        block_k = bk if block_k is None else block_k
     block_q = min(block_q, lq)
     block_k = min(block_k, lk)
     assert lq % block_q == 0 and lk % block_k == 0, (

@@ -139,11 +139,17 @@ register_knob(Knob(
     "flash_block_q", "MXNET_FLASH_BLOCK_Q", int, 128,
     (128, 256, 512), "training",
     "flash-attention forward q tile (must divide the padded sequence; "
-    "ops/flash_attention.py)"))
+    "ops/flash_attention.py).  Unpinned and untuned, the kernel takes "
+    "the largest of 512 / 256 / 128 that divides the q length, not "
+    "this nominal default"))
 register_knob(Knob(
     "flash_block_kv", "MXNET_FLASH_BLOCK_KV", int, 128,
     (128, 256, 512), "training",
-    "flash-attention forward kv tile (ops/flash_attention.py)"))
+    "flash-attention forward kv tile (ops/flash_attention.py).  "
+    "Unpinned and untuned, the kernel takes the whole K row while the "
+    "score tile fits its VMEM budget (one-pass softmax), else the "
+    "largest of 512 / 256 / 128 that divides it, not this nominal "
+    "default"))
 register_knob(Knob(
     "prefetch_buffer", "MXNET_PREFETCH_BUFFER", int, 2,
     (0, 1, 2, 4, 8), "training",

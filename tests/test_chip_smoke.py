@@ -62,24 +62,113 @@ def test_main_refuses_without_a_chip(capsys):
     assert out == "" and "no TPU" in err
 
 
-@pytest.mark.parametrize("lq,lk,dim,causal", [
-    (256, 256, 64, False), (512, 512, 64, True), (256, 512, 128, True)])
-def test_flash_forward_interpreted_matches_reference(lq, lk, dim, causal):
+def _gauge_of(knob):
+    from mxnet_tpu import telemetry
+
+    samples = telemetry.snapshot()["metrics"][
+        "mxnet_tuning_chosen_value"]["samples"]
+    return {s["labels"].get("knob"): s["value"] for s in samples}[knob]
+
+
+@pytest.fixture
+def flash_pins(monkeypatch):
+    """Pin the tiles as an operator would, through the environment."""
+    from mxnet_tpu import tuning
+
+    def pin(block_q=None, block_kv=None):
+        for var, value in (("MXNET_FLASH_BLOCK_Q", block_q),
+                           ("MXNET_FLASH_BLOCK_KV", block_kv)):
+            if value is None:
+                monkeypatch.delenv(var, raising=False)
+            else:
+                monkeypatch.setenv(var, str(value))
+        tuning.reset()
+
+    pin()
+    yield pin
+    tuning.reset()
+
+
+# float32 inputs keep float32 operands: the one-pass softmax where the K
+# row is one block, and under a pinned tile the online update, unrolled
+# and (causal) skipping.  bf16 inputs feed the MXU as they are, against the
+# plain path on the same bf16 inputs.
+@pytest.mark.parametrize("lq,lk,dim,causal,dtype,pin_kv", [
+    (256, 256, 64, False, "float32", None),
+    (512, 512, 64, True, "float32", None),
+    (256, 512, 128, True, "float32", None),
+    (512, 512, 64, False, "float32", 128),
+    (512, 512, 64, True, "float32", 128),
+    (512, 512, 64, False, "bfloat16", None),
+    (1024, 1024, 64, False, "bfloat16", 256),
+    (1024, 1024, 64, True, "bfloat16", 256),
+    (256, 512, 64, True, "bfloat16", None),
+    (512, 512, 128, False, "bfloat16", None),
+    (256, 512, 128, True, "bfloat16", None)])
+def test_flash_forward_interpreted_matches_reference(lq, lk, dim, causal,
+                                                     dtype, pin_kv,
+                                                     flash_pins):
     from jax.experimental.pallas import tpu as pltpu
 
     from mxnet_tpu.ops.flash_attention import (_fa_forward_pallas,
                                                _mha_with_lse)
 
+    flash_pins(block_kv=pin_kv)
     rs = np.random.RandomState(0)
-    q = jnp.asarray(rs.randn(1, 1, lq, dim).astype("f"))
-    k = jnp.asarray(rs.randn(1, 1, lk, dim).astype("f"))
-    v = jnp.asarray(rs.randn(1, 1, lk, dim).astype("f"))
+    q = jnp.asarray(rs.randn(1, 1, lq, dim).astype("f")).astype(dtype)
+    k = jnp.asarray(rs.randn(1, 1, lk, dim).astype("f")).astype(dtype)
+    v = jnp.asarray(rs.randn(1, 1, lk, dim).astype("f")).astype(dtype)
     scale = 1.0 / np.sqrt(dim)
     with pltpu.force_tpu_interpret_mode():
         o, lse = _fa_forward_pallas(q, k, v, causal, scale)
     ref_o, ref_lse = _mha_with_lse(q, k, v, causal, scale)
-    np.testing.assert_allclose(o, ref_o, atol=2e-6)
-    np.testing.assert_allclose(lse, ref_lse, atol=2e-6)
+    assert o.dtype == q.dtype and lse.dtype == jnp.float32
+    assert _gauge_of("flash_block_kv") == (pin_kv or lk)
+    if dtype == "float32":
+        np.testing.assert_allclose(o, ref_o, atol=2e-6)
+        np.testing.assert_allclose(lse, ref_lse, atol=2e-6)
+    else:
+        # two units in bf16's last place at the outputs' size
+        np.testing.assert_allclose(o.astype("float32"),
+                                   ref_o.astype("float32"),
+                                   rtol=2e-2, atol=1.6e-2)
+        np.testing.assert_allclose(lse, ref_lse, atol=1e-2)
+
+
+@pytest.mark.parametrize("lq,lk,dim,dtype,pins,tiles", [
+    # from the shape: the q tile as large as divides, the whole K row while
+    # the score tile fits the budget, else the largest that divides and fits
+    (512, 512, 64, "bfloat16", {}, (512, 512)),
+    (512, 512, 64, "float32", {}, (512, 512)),
+    (1024, 1024, 64, "bfloat16", {}, (512, 1024)),
+    (2048, 2048, 128, "bfloat16", {}, (512, 512)),
+    (256, 512, 128, "bfloat16", {}, (256, 512)),
+    (384, 384, 64, "bfloat16", {}, (128, 384)),
+    # a pin wins, each knob by itself
+    (512, 512, 64, "bfloat16", {"block_q": 128}, (128, 512)),
+    (512, 512, 64, "bfloat16", {"block_kv": 256}, (512, 256)),
+    (512, 512, 64, "bfloat16", {"block_q": 128, "block_kv": 128},
+     (128, 128))])
+def test_flash_tiles_come_from_the_shape_unless_pinned(lq, lk, dim, dtype,
+                                                       pins, tiles,
+                                                       flash_pins):
+    from mxnet_tpu import tuning
+    from mxnet_tpu.ops.flash_attention import (_fa_block_sizes,
+                                               _fa_forward_pallas)
+
+    flash_pins(**pins)
+    assert _fa_block_sizes(lq, lk, dim, jnp.dtype(dtype).itemsize) == tiles
+    # what the kernel is traced with is what a scrape of the gauge says
+    q = jax.ShapeDtypeStruct((1, 2, lq, dim), dtype)
+    k = jax.ShapeDtypeStruct((1, 2, lk, dim), dtype)
+    with tuning.trial_override("flash_block_q", 128):
+        jax.eval_shape(lambda q, k: _fa_forward_pallas(q, k, k, False, 0.125),
+                       q, k)
+        assert _gauge_of("flash_block_q") == 128    # a trial wins over both
+    o, lse = jax.eval_shape(
+        lambda q, k: _fa_forward_pallas(q, k, k, False, 0.125), q, k)
+    assert (_gauge_of("flash_block_q"), _gauge_of("flash_block_kv")) == tiles
+    assert o.shape == q.shape and lse.shape == (1, 2, lq)
 
 
 def test_sharded_step_runs_the_kernel_per_batch_shard(toy_bert, monkeypatch):

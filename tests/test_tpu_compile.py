@@ -1,0 +1,61 @@
+"""The Pallas attention forward, compiled at real widths for a TPU v5e that
+is described and not attached: what the chip's compiler refuses (an operand
+type, a slice off the tiling, too much VMEM) the TPU interpreter of
+``test_chip_smoke.py`` lets through.  Nothing runs, so nothing here is a
+time or a result.  All of these stay in this one file: the worker that is
+given it is the only one that loads the TPU's library."""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a program compiled for a described chip is written to the persistent
+    # cache and cannot be read back without one: the next run would warn
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("shape,dim,dtype,causal,blocks", [
+    # BERT-base as the benchmark's cell runs it: one pass over the K row
+    ((16, 12, 512, 512), 64, "bfloat16", False, (None, None)),
+    ((16, 12, 512, 512), 64, "float32", False, (None, None)),
+    # the widest score tile the default rule gives (the budget's edge)
+    ((2, 4, 512, 1792), 64, "bfloat16", True, (None, None)),
+    ((2, 4, 1024, 1024), 128, "float32", False, (None, None)),
+    # several K blocks: unrolled, and causal with its skip and lq < lk
+    ((2, 12, 2048, 2048), 64, "bfloat16", False, (None, None)),
+    ((2, 16, 2048, 2048), 128, "bfloat16", True, (None, None)),
+    ((2, 16, 256, 512), 128, "bfloat16", True, (128, 128)),
+    ((2, 4, 320, 320), 64, "bfloat16", True, (None, None)),
+])
+def test_flash_forward_compiles_for_v5e(one_chip, shape, dim, dtype, causal,
+                                        blocks):
+    from mxnet_tpu.ops.flash_attention import _fa_forward_pallas
+
+    b, h, lq, lk = shape
+    fwd = functools.partial(_fa_forward_pallas, causal=causal,
+                            sm_scale=1.0 / dim ** 0.5, block_q=blocks[0],
+                            block_k=blocks[1])
+    q = jax.ShapeDtypeStruct((b, h, lq, dim), dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, h, lk, dim), dtype, sharding=one_chip)
+    text = jax.jit(fwd).lower(q, kv, kv).compile().as_text()
+    assert "tpu_custom_call" in text and "mxnet_flash_attention_fwd" in text
