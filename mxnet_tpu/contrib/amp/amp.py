@@ -59,10 +59,13 @@ def _make_wrap(target_dtype, target_ops, fp32_ops):
         else:
             return fn
 
+        keep = lists.KEEP_DTYPE_INPUTS.get(name, ())
+
         def cast_fn(*arrays):
             cast = tuple(
-                a.astype(to) if _floating(a) and a.dtype != to else a
-                for a in arrays)
+                a.astype(to)
+                if _floating(a) and a.dtype != to and i not in keep else a
+                for i, a in enumerate(arrays))
             return fn(*cast)
 
         return cast_fn

@@ -25,8 +25,17 @@ TARGET_DTYPE_OPS = [
     "_contrib_interleaved_matmul_encdec_qk",
     "_contrib_interleaved_matmul_encdec_valatt",
     "_contrib_flash_attention",
+    "_contrib_moe_swiglu",
     "RNN",
 ]
+
+# inputs (by position) of a TARGET_DTYPE_OPS op that keep their dtype: the
+# expert layer's tokens and router weight, so that its router logits, its
+# softmax and the choice of experts are float32; the op casts the tokens it
+# gathers to the expert weights' dtype itself
+KEEP_DTYPE_INPUTS = {
+    "_contrib_moe_swiglu": (0, 1),
+}
 
 # numerically sensitive ops pinned to fp32
 FP32_OPS = [

@@ -29,19 +29,46 @@ class LlamaConfig:
                  rope_base=500000.0, max_seq_len=8192, rms_eps=1e-5,
                  dtype="float32", tie_embeddings=False, remat=False,
                  num_experts=0, moe_capacity_factor=1.25,
-                 moe_aux_loss_weight=0.01):
-        # num_experts > 0: Mixtral-style MoE FFN (switch top-1 routing,
-        # parallel.expert_parallel) replaces the dense SwiGLU MLP; shard
-        # the expert dim over the 'ep' mesh axis in TrainStep specs
+                 moe_aux_loss_weight=0.01, head_dim=None, qk_norm=False,
+                 moe_top_k=1, moe_renormalize=False, moe_experts_held=None,
+                 moe_intermediate_size=None, block_diffusion=0):
+        # num_experts > 0: an MoE FFN (parallel.expert_parallel) replaces
+        # the dense SwiGLU MLP in every layer; num_experts is the router's
+        # width.  moe_capacity_factor=<number>: switch top-1 routing with
+        # capacity dropping (Mixtral-style; shard the expert dim over the
+        # 'ep' mesh axis in TrainStep specs).  moe_capacity_factor=None:
+        # dropless routing of moe_top_k experts a token (gates divided by
+        # their sum when moe_renormalize), of which this net holds
+        # moe_experts_held = (first, count) -- one chip's share of an
+        # expert-parallel deployment; default all -- and adds their part
+        # of the result only.  moe_intermediate_size is the experts' width
+        # where it differs from the dense intermediate_size.
         self.num_experts = num_experts
         self.moe_capacity_factor = moe_capacity_factor
+        self.moe_top_k = moe_top_k
+        self.moe_renormalize = moe_renormalize
+        self.moe_experts_held = tuple(moe_experts_held) \
+            if moe_experts_held is not None else (0, num_experts)
+        self.moe_intermediate_size = moe_intermediate_size \
+            or intermediate_size
         # Switch load-balance loss coefficient, injected into the backward
-        # via parallel.expert_parallel.inject_aux_loss (0 disables)
+        # via parallel.expert_parallel.inject_aux_loss (0 disables; the
+        # dropless path has none)
         self.moe_aux_loss_weight = moe_aux_loss_weight
         # remat: rematerialize each decoder layer's activations in backward
         # (jax.checkpoint) — trades ~1/3 more FLOPs for O(num_layers) less
         # activation HBM, the standard lever for bigger per-chip batches
         self.remat = remat
+        # qk_norm: an RMSNorm over the head size on q and on k before RoPE
+        # (a learned vector each, shared by the heads)
+        self.qk_norm = qk_norm
+        # block_diffusion = B > 0: the training layout of block diffusion
+        # (Arriola et al. 2025).  The net takes rows [xt ; x0] of a noised
+        # copy and the clean copy of L tokens each, both halves at
+        # positions 0..L-1, attends under the block-diffusion mask of
+        # blocks of B (ops/flash_attention.py) and returns the logits of
+        # the noised half.  0: causal, one token a position.
+        self.block_diffusion = block_diffusion
         self.vocab_size = vocab_size
         self.hidden_size = hidden_size
         self.num_layers = num_layers
@@ -53,7 +80,7 @@ class LlamaConfig:
         self.rms_eps = rms_eps
         self.dtype = dtype
         self.tie_embeddings = tie_embeddings
-        if hidden_size % num_heads:
+        if head_dim is None and hidden_size % num_heads:
             raise MXNetError(
                 f"num_heads ({num_heads}) must divide hidden_size "
                 f"({hidden_size})")
@@ -61,7 +88,7 @@ class LlamaConfig:
             raise MXNetError(
                 f"num_kv_heads ({num_kv_heads}) must divide num_heads "
                 f"({num_heads}) for GQA")
-        self.head_dim = hidden_size // num_heads
+        self.head_dim = head_dim or hidden_size // num_heads
 
 
 class RMSNorm(HybridBlock):
@@ -94,6 +121,9 @@ class LlamaAttention(HybridBlock):
             self.o_proj = nn.Dense(d, use_bias=False, flatten=False,
                                    in_units=cfg.num_heads * hd,
                                    prefix="o_proj_")
+            if cfg.qk_norm:
+                self.q_norm = RMSNorm(hd, cfg.rms_eps, prefix="q_norm_")
+                self.k_norm = RMSNorm(hd, cfg.rms_eps, prefix="k_norm_")
 
     def hybrid_forward(self, F, x):
         cfg = self._cfg
@@ -105,10 +135,22 @@ class LlamaAttention(HybridBlock):
             (0, 2, 1, 3))
         v = self.v_proj(x).reshape((b, l, cfg.num_kv_heads, hd)).transpose(
             (0, 2, 1, 3))
-        q = F.rope(q, base=cfg.rope_base)
-        k = F.rope(k, base=cfg.rope_base)
-        o = F.flash_attention(q, k, v, causal=True,
-                              sm_scale=1.0 / math.sqrt(hd))
+        if cfg.qk_norm:
+            q, k = self.q_norm(q), self.k_norm(k)
+        if cfg.block_diffusion:
+            # [xt ; x0]: both halves of the row carry positions 0..L-1
+            half = F.arange(0, l // 2, dtype="int32")
+            pos = F.concat(half, half, dim=0)
+            q = F.rope(q, pos, base=cfg.rope_base)
+            k = F.rope(k, pos, base=cfg.rope_base)
+            o = F.flash_attention(q, k, v, mask="block_diffusion",
+                                  mask_block=cfg.block_diffusion,
+                                  sm_scale=1.0 / math.sqrt(hd))
+        else:
+            q = F.rope(q, base=cfg.rope_base)
+            k = F.rope(k, base=cfg.rope_base)
+            o = F.flash_attention(q, k, v, causal=True,
+                                  sm_scale=1.0 / math.sqrt(hd))
         o = o.transpose((0, 2, 1, 3)).reshape((b, l, cfg.num_heads * hd))
         return self.o_proj(o)
 
@@ -133,28 +175,42 @@ class LlamaMLP(HybridBlock):
 
 
 class LlamaMoEMLP(HybridBlock):
-    """Switch-MoE SwiGLU FFN (Mixtral-style; net-new vs the reference).
+    """MoE SwiGLU FFN (net-new vs the reference): switch top-1 with a
+    capacity, or dropless top-k over the experts held (``LlamaConfig``).
 
-    Expert weights are stacked with a leading expert axis so
-    parallel.expert_parallel's dispatch/combine einsums (and the ep
-    sharding) apply directly."""
+    Expert weights are stacked with a leading expert axis, one entry an
+    expert held, so parallel.expert_parallel's dispatch/combine einsums
+    (and the ep sharding) or its grouped products apply directly; the
+    router keeps the width ``num_experts``."""
 
     def __init__(self, cfg, **kwargs):
         super().__init__(**kwargs)
         self._cfg = cfg
-        E, H, I = cfg.num_experts, cfg.hidden_size, cfg.intermediate_size
+        E, H, I = cfg.num_experts, cfg.hidden_size, cfg.moe_intermediate_size
+        N = cfg.moe_experts_held[1]
+        if cfg.moe_capacity_factor is not None and (
+                N != E or cfg.moe_top_k != 1):
+            raise MXNetError(
+                "a moe_capacity_factor is the switch top-1 layer over every "
+                "expert; moe_top_k > 1 and moe_experts_held route dropless "
+                "(moe_capacity_factor=None)")
         with self.name_scope():
             self.router = self.params.get("router_weight", shape=(H, E))
             self.gate_proj = self.params.get("gate_proj_weight",
-                                             shape=(E, H, I))
-            self.up_proj = self.params.get("up_proj_weight", shape=(E, H, I))
+                                             shape=(N, H, I))
+            self.up_proj = self.params.get("up_proj_weight", shape=(N, H, I))
             self.down_proj = self.params.get("down_proj_weight",
-                                             shape=(E, I, H))
+                                             shape=(N, I, H))
 
     def hybrid_forward(self, F, x, router, gate_proj, up_proj, down_proj):
         # a registered op (not a raw apply_fn), so the block traces to
         # Symbol and exports/imports like the rest of the zoo
         cfg = self._cfg
+        if cfg.moe_capacity_factor is None:
+            return F.moe_swiglu(x, router, gate_proj, up_proj, down_proj,
+                                capacity_factor=0.0, top_k=cfg.moe_top_k,
+                                renormalize=cfg.moe_renormalize,
+                                experts_first=cfg.moe_experts_held[0])
         return F.moe_swiglu(x, router, gate_proj, up_proj, down_proj,
                             capacity_factor=cfg.moe_capacity_factor,
                             aux_loss_weight=cfg.moe_aux_loss_weight)
@@ -192,18 +248,36 @@ class LlamaDecoderLayer(HybridBlock):
                 # jax.jit/grad over the functionalized net): checkpoint the
                 # whole layer — closed-over parameter tracers differentiate
                 # normally, activations are recomputed in backward
-                def body_pure(v):
-                    return self._body(
-                        NDArray._from_jax(v, getattr(x, "context", None))
-                    )._get()
+                # what the layer gives to telemetry.step_scalar leaves the
+                # checkpoint as an output and is given again outside
+                from .... import telemetry as _telemetry
 
-                out = jax.checkpoint(body_pure)(xv)
+                def body_pure(v):
+                    with _telemetry.collect_step_scalars() as scalars:
+                        out = self._body(
+                            NDArray._from_jax(v, getattr(x, "context", None))
+                        )._get()
+                    return out, scalars.stacked()
+
+                out, scalars = jax.checkpoint(body_pure)(xv)
+                for name, values in scalars.items():
+                    _telemetry.step_scalar(name, values)
                 return NDArray._from_jax(out, getattr(x, "context", None))
             # eager tape (autograd.record) and hybridize() both lack a
             # remat node — warn rather than silently skipping the memory
             # saving the user asked for
             from .... import autograd as _ag
+            from ....symbol.symbol import _TRACE_OBSERVER
 
+            if type(x).__name__ == "SymbolTracer" \
+                    and _TRACE_OBSERVER[0] is not None:
+                # the graph tier's trace (functionalize, the cached op): a
+                # flat list of ops has no node for a checkpoint.  Raising
+                # sends the caller to its imperative jit trace, where the
+                # branch above applies
+                raise MXNetError(
+                    "LlamaConfig(remat=True): the graph tier cannot express "
+                    "a per-layer checkpoint; the imperative trace can")
             if type(x).__name__ == "SymbolTracer" or _ag.is_recording():
                 import warnings
 
@@ -246,7 +320,11 @@ class LlamaForCausalLM(HybridBlock):
                                     prefix="lm_head_")
 
     def hybrid_forward(self, F, input_ids):
-        return self.lm_head(self.model(input_ids))
+        h = self.model(input_ids)
+        if self._cfg.block_diffusion:
+            # rows are [xt ; x0]: logits over the noised half only
+            h = F.slice_axis(h, axis=1, begin=0, end=h.shape[1] // 2)
+        return self.lm_head(h)
 
     @property
     def config(self):

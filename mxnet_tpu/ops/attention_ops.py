@@ -7,6 +7,8 @@ All pure jax; the fused-attention hot path is ops/flash_attention.py.
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as _np
 
 from .registry import register
@@ -129,30 +131,72 @@ def swiglu(gate, up):
 
 @register("_contrib_moe_swiglu", aliases=("moe_swiglu",))
 def moe_swiglu(x, router_weight, gate_proj, up_proj, down_proj,
-               capacity_factor=1.25, aux_loss_weight=0.0):
-    """Switch-MoE SwiGLU FFN over stacked expert weights (Mixtral-style;
-    net-new vs the reference).  Registered as a first-class op so MoE
-    models trace to Symbol and export/SymbolBlock-import like any other
-    graph (fused RNN set the precedent for stateful library ops).
+               capacity_factor=1.25, aux_loss_weight=0.0, top_k=1,
+               renormalize=False, experts_first=0):
+    """MoE SwiGLU FFN over stacked expert weights (net-new vs the
+    reference).  Registered as a first-class op so MoE models trace to
+    Symbol and export/SymbolBlock-import like any other graph (fused RNN
+    set the precedent for stateful library ops).
 
-    x (B, L, H); router (H, E); gate/up (E, H, I); down (E, I, H).
-    The aux load-balance loss rides the backward pass via inject_aux_loss
-    when aux_loss_weight > 0 (Switch Transformer eq. 4)."""
+    x (B, L, H); router (H, E); gate/up (N, H, I); down (N, I, H).
+
+    ``capacity_factor > 0``: switch top-1 routing with capacity dropping
+    over all ``N = E`` experts (Mixtral-style stacking); the aux
+    load-balance loss rides the backward pass via inject_aux_loss when
+    aux_loss_weight > 0 (Switch Transformer eq. 4).
+
+    ``capacity_factor = 0``: dropless ``top_k`` routing over the router's
+    ``E`` outputs (gates divided by their sum when ``renormalize``), of
+    which this layer holds the ``N`` experts from ``experts_first`` on and
+    computes their part of the result, by grouped products over the pairs
+    sorted by expert (``parallel.expert_parallel.moe_apply``).  Router
+    logits and gates are float32 whatever the products' dtype, which is the
+    expert weights' (under AMP the target dtype: ``x`` and the router are
+    exempt from the cast, contrib/amp/lists.py).  The layer's routed pairs
+    and load imbalance go to ``telemetry.step_scalar``."""
+    from jax import lax, nn
+
+    from .. import telemetry
     from ..parallel.expert_parallel import inject_aux_loss, moe_apply
 
     capacity_factor = float(capacity_factor)
     aux_loss_weight = float(aux_loss_weight)
-
-    def expert_fn(p, toks):
-        from jax import nn
-
-        return (nn.silu(toks @ p["g"]) * (toks @ p["u"])) @ p["d"]
-
+    params = {"g": gate_proj, "u": up_proj, "d": down_proj}
     b, l, h = x.shape
     toks = x.reshape(-1, h)
-    out, aux = moe_apply(
-        expert_fn, {"g": gate_proj, "u": up_proj, "d": down_proj},
-        router_weight, toks, capacity_factor=capacity_factor)
+
+    if capacity_factor <= 0:
+        def grouped_fn(p, rows, sizes):
+            jnp = _jnp()
+            rows = rows.astype(p["g"].dtype)
+            # float32 operands follow the process's matmul precision;
+            # narrower ones are one exact MXU pass (the TPU's grouped
+            # product refuses them a float32 contraction, as the attention
+            # kernel does)
+            dot = functools.partial(
+                lax.ragged_dot, group_sizes=sizes,
+                precision=None if rows.dtype == jnp.float32
+                else lax.Precision.DEFAULT)
+            hidden = nn.silu(dot(rows, p["g"])) * dot(rows, p["u"])
+            return dot(hidden, p["d"])
+
+        out, aux = moe_apply(
+            grouped_fn, params, router_weight, toks, capacity_factor=None,
+            top_k=int(top_k), renormalize=bool(renormalize),
+            held=(int(experts_first), gate_proj.shape[0]))
+        telemetry.step_scalar(telemetry.MOE_ROUTED_PAIRS.name,
+                              aux["routed_pairs"])
+        telemetry.step_scalar(telemetry.MOE_LOAD_MAX_OVER_MEAN.name,
+                              aux["load_max_over_mean"])
+        return out.reshape(b, l, h)
+
+    def expert_fn(p, toks):
+        dt = p["g"].dtype
+        toks = toks.astype(dt)
+        return (nn.silu(toks @ p["g"]) * (toks @ p["u"])) @ p["d"]
+
+    out, aux = moe_apply(expert_fn, params, router_weight, toks,
+                         capacity_factor=capacity_factor)
     out = out.reshape(b, l, h)
     if aux_loss_weight:
         # router balance term rides the backward pass; without it routing

@@ -57,9 +57,92 @@ def _use_pallas(q):
 
 
 # --------------------------------------------------------------------------
+# masks: one predicate over positions, shared by the kernel, the plain path
+# and the backward; no (lq, lk) array leaves the trace of the first and last
+# --------------------------------------------------------------------------
+BLOCK_DIFFUSION = "block_diffusion"
+
+
+def _mask_key(mask, mask_block, causal):
+    """The static mask of a call: None, or ``(BLOCK_DIFFUSION, B)``."""
+    from ..base import MXNetError
+
+    if mask is None:
+        return None
+    if mask != BLOCK_DIFFUSION:
+        raise MXNetError(f"flash_attention: unknown mask {mask!r}; known: "
+                         f"{BLOCK_DIFFUSION!r}")
+    if causal:
+        raise MXNetError("flash_attention: a mask takes the place of causal")
+    if int(mask_block) < 1:
+        raise MXNetError("flash_attention: mask='block_diffusion' needs "
+                         "mask_block, the block length")
+    return (BLOCK_DIFFUSION, int(mask_block))
+
+
+def _block_of(xp, pos, half, block):
+    """Diffusion block of a position of the row ``[noised ; clean]``, and
+    whether it lies in the noised half."""
+    noised = pos < half
+    return xp.where(noised, pos, pos - half) // block, noised
+
+
+def _visible(xp, q_pos, k_pos, causal, mask, lq, lk):
+    """May query row ``q_pos`` see key column ``k_pos``?  Broadcasts; None
+    where every pair is visible.  ``xp`` is numpy or jax.numpy.
+
+    ``causal``: keys up to the query's own position, the diagonal moved by
+    ``lk - lq`` (decode).  ``(BLOCK_DIFFUSION, B)``: the training mask of
+    block diffusion (Arriola et al. 2025) over a row of a noised copy
+    followed by the clean copy, blocks of ``B``: a noised query sees the
+    noised keys of its own block and the clean keys of earlier blocks; a
+    clean query sees the clean keys of its own and earlier blocks."""
+    if mask is not None:
+        half = lk // 2
+        qb, q_noised = _block_of(xp, q_pos, half, mask[1])
+        kb, k_noised = _block_of(xp, k_pos, half, mask[1])
+        # as two comparisons of integers (Mosaic selects no booleans): a
+        # noised key is seen by the noised queries of its block, a clean
+        # key by the queries of later blocks and by the clean ones of its own
+        far = 1 << 30
+        return ((xp.where(k_noised, kb, -2) == xp.where(q_noised, qb, -1))
+                | (xp.where(k_noised, far, kb)
+                   < xp.where(q_noised, qb, qb + 1)))
+    if causal:
+        return q_pos + (lk - lq) >= k_pos
+    return None
+
+
+@functools.lru_cache(maxsize=64)
+def _live_tiles(causal, mask, lq, lk, block_q, block_k):
+    """numpy bool ``(lq / block_q, lk / block_k)``: tiles in which some pair
+    is visible.  From the predicate itself, a strip of query rows at a
+    time; static per shape, so computed once."""
+    nq, nk = lq // block_q, lk // block_k
+    if mask is None and not causal:
+        return _np.ones((nq, nk), bool)
+    k_pos = _np.arange(lk)[None, :]
+    live = _np.empty((nq, nk), bool)
+    for i in range(nq):
+        q_pos = _np.arange(i * block_q, (i + 1) * block_q)[:, None]
+        seen = _visible(_np, q_pos, k_pos, causal, mask, lq, lk)
+        live[i] = seen.reshape(block_q, nk, block_k).any(axis=(0, 2))
+    return live
+
+
+def _check_mask_shape(mask, lq, lk):
+    from ..base import MXNetError
+
+    if mask is not None and (lq != lk or lk % (2 * mask[1])):
+        raise MXNetError(
+            f"flash_attention: mask {mask} wants a row of a noised and a "
+            f"clean copy, each a whole number of blocks; got lq {lq}, lk {lk}")
+
+
+# --------------------------------------------------------------------------
 # jax reference path (CPU tests, short sequences, fallback)
 # --------------------------------------------------------------------------
-def _mha_with_lse(q, k, v, causal, sm_scale):
+def _mha_with_lse(q, k, v, causal, sm_scale, mask=None):
     import jax
     import jax.numpy as jnp
 
@@ -72,10 +155,11 @@ def _mha_with_lse(q, k, v, causal, sm_scale):
             v = jnp.repeat(v, rep, axis=1)
         scores = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                             k.astype(jnp.float32)) * sm_scale
-        if causal:
-            lk = k.shape[2]
-            mask = jnp.tril(jnp.ones((lq, lk), dtype=bool), k=lk - lq)
-            scores = jnp.where(mask, scores, NEG_INF)
+        lk = k.shape[2]
+        seen = _visible(jnp, jnp.arange(lq)[:, None], jnp.arange(lk)[None, :],
+                        causal, mask, lq, lk)
+        if seen is not None:
+            scores = jnp.where(seen, scores, NEG_INF)
         m = scores.max(axis=-1, keepdims=True)
         e = jnp.exp(scores - m)
         denom = e.sum(axis=-1, keepdims=True)
@@ -85,8 +169,8 @@ def _mha_with_lse(q, k, v, causal, sm_scale):
     return o, lse
 
 
-def _mha_reference(q, k, v, causal, sm_scale):
-    return _mha_with_lse(q, k, v, causal, sm_scale)[0]
+def _mha_reference(q, k, v, causal, sm_scale, mask=None):
+    return _mha_with_lse(q, k, v, causal, sm_scale, mask)[0]
 
 
 # --------------------------------------------------------------------------
@@ -102,8 +186,35 @@ _NT_DIMS = (((1,), (1,)), ((), ()))
 _TILE_VMEM_BUDGET = 4 << 20
 
 
+def _bd_tile_ranges(r0, r1, half, block, block_k):
+    """K tiles a q tile of rows ``[r0, r1)`` can see under the
+    block-diffusion mask: ``(a_lo, a_hi, c_lo, c_hi)``, tiles ``[a_lo,
+    a_hi)`` among the noised keys (its own blocks) and ``[c_lo, c_hi)``
+    among the clean ones (from the clean half's start up to its last row's
+    block), the second range starting where the first ended if they meet.
+    Integer arithmetic that holds for Python ints and traced scalars."""
+    import jax.numpy as jnp
+
+    def ceil_div(a, b):
+        return (a + b - 1) // b
+
+    n_end = jnp.minimum(r1, half)
+    has_noised = r0 < half
+    a_lo = jnp.where(has_noised, (r0 // block) * block // block_k, 0)
+    a_hi = jnp.where(has_noised,
+                     ceil_div(ceil_div(n_end, block) * block, block_k), 0)
+    # clean columns seen: by the noised rows the blocks before their last
+    # row's, by the clean rows their last row's block too
+    seen = jnp.maximum(
+        jnp.where(has_noised, (n_end - 1) // block * block, 0),
+        jnp.where(r1 > half, ((r1 - 1 - half) // block + 1) * block, 0))
+    c_lo = half // block_k
+    c_hi = jnp.where(seen > 0, ceil_div(half + seen, block_k), c_lo)
+    return a_lo, a_hi, jnp.maximum(c_lo, a_hi), c_hi
+
+
 def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
-                   sm_scale, seq_k, diag_offset=0):
+                   sm_scale, seq_k, diag_offset=0, mask=None):
     """One q block against its head's whole K/V row.
 
     Grid: (batch*heads, num_q_blocks).  Block shapes:
@@ -118,8 +229,10 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
     float32, the statistics ``(block_q, 1)`` so that they broadcast over
     the score tile's lanes without a relayout.  A K row that is one block
     takes the plain softmax (no rescale); several blocks take the online
-    update, unrolled when not causal, and skipping the fully masked blocks
-    when causal.
+    update, unrolled when nothing is masked, and skipping the K blocks no
+    row of the q block can see when causal or under ``mask`` (``_visible``
+    is the predicate, evaluated here from a column of row positions and a
+    row of column positions).
     """
     import jax
     import jax.numpy as jnp
@@ -152,6 +265,13 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+        elif mask is not None:
+            q_pos = qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, 1), 0)
+            k_pos = kb * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block_k), 1)
+            s = jnp.where(_visible(jnp, q_pos, k_pos, False, mask, seq_k,
+                                   seq_k), s, NEG_INF)
         return s
 
     def weighted_v(p, kb):
@@ -159,7 +279,7 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
         return jax.lax.dot(p.astype(v_blk.dtype), v_blk, precision=precision,
                            preferred_element_type=jnp.float32)
 
-    if num_kb == 1:
+    if num_kb == 1:   # a mask included: its tiles are all live at this size
         s = scores(0)
         m = s.max(axis=-1, keepdims=True)
         p = jnp.exp(s - m)
@@ -184,6 +304,12 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
                 ((qi + 1) * block_q + diag_offset + block_k - 1) // block_k,
                 num_kb)
             carry = jax.lax.fori_loop(0, max_kb, body, carry)
+        elif mask is not None:
+            a_lo, a_hi, c_lo, c_hi = _bd_tile_ranges(
+                qi * block_q, (qi + 1) * block_q, seq_k // 2, mask[1],
+                block_k)
+            carry = jax.lax.fori_loop(a_lo, a_hi, body, carry)
+            carry = jax.lax.fori_loop(c_lo, c_hi, body, carry)
         else:
             for kb in range(num_kb):
                 carry = body(kb, carry)
@@ -234,7 +360,8 @@ def _fa_block_sizes(lq, lk, d, itemsize):
     return block_q, block_k
 
 
-def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q=None, block_k=None):
+def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q=None, block_k=None,
+                       mask=None):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -257,7 +384,7 @@ def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q=None, block_k=None):
 
     kernel = functools.partial(_fa_fwd_kernel, block_k=block_k,
                                causal=causal, sm_scale=sm_scale, seq_k=lk,
-                               diag_offset=lk - lq)
+                               diag_offset=lk - lq, mask=mask)
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -279,11 +406,11 @@ def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q=None, block_k=None):
     return o.reshape(b, h, lq, d), lse[:, 0, :].reshape(b, h, lq)
 
 
-def _fa_forward(q, k, v, causal, sm_scale):
+def _fa_forward(q, k, v, causal, sm_scale, mask=None):
     """The Pallas forward, per batch shard when a ``batch_sharded`` step
     is being traced."""
     fwd = functools.partial(_fa_forward_pallas, causal=causal,
-                            sm_scale=sm_scale)
+                            sm_scale=sm_scale, mask=mask)
     scope = getattr(_SCOPE, "value", None)
     if scope is not None:
         from ..parallel.collectives import shard_map_over_batch
@@ -296,7 +423,17 @@ def _fa_forward(q, k, v, causal, sm_scale):
 # blockwise backward (jax, O(L) memory via scan recompute)
 # --------------------------------------------------------------------------
 def _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
-                           block_k=512):
+                           block_k=512, mask=None, block_q=512):
+    """Gradients of q, k and v, recomputing the probabilities a K block at a
+    time from the saved log-sum-exp: float32 operands, O(L) memory.
+
+    Where no tile of the score matrix is wholly masked (no mask, or a row
+    of one q tile), one scan over the K blocks takes every query row at
+    once.  Where some are (``causal`` or ``mask`` at lengths of several
+    tiles), the scan runs over the live ``(q tile, k tile)`` pairs alone,
+    which are static (``_live_tiles``): the dead ones cost nothing, as in
+    the forward kernel.  Both evaluate ``_visible`` on positions; neither
+    holds an ``(lq, lk)`` array."""
     import jax
     import jax.numpy as jnp
 
@@ -307,40 +444,81 @@ def _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
         if lk % block_k != 0:
             block_k = lk
         nkb = lk // block_k
+        block_q = min(block_q, lq)
+        if lq % block_q != 0:
+            block_q = lq
+        live = _live_tiles(causal, mask, lq, lk, block_q, block_k)
 
         acc_t = jnp.result_type(q.dtype, jnp.float32)
-        qf = q.astype(acc_t)
-        gf = g.astype(acc_t)
-        of = o.astype(acc_t)
-        delta = jnp.sum(of * gf, axis=-1)                      # (b,h,lq)
 
-        kb = k.reshape(b, h, nkb, block_k, d).astype(acc_t)
-        vb = v.reshape(b, h, nkb, block_k, d).astype(acc_t)
+        def tile_grads(qt, gt, delta_t, lse_t, kt, vt, q_pos, k_pos):
+            """One tile's ``(dq part, dk part, dv part)``; operands float32,
+            ``q_pos`` a column and ``k_pos`` a row of positions."""
+            s = jnp.einsum("bhqd,bhkd->bhqk", qt, kt) * sm_scale
+            # same diagonal offset as the forward (q_i attends keys up to
+            # i + lk - lq when lengths differ, e.g. decode)
+            seen = _visible(jnp, q_pos, k_pos, causal, mask, lq, lk)
+            if seen is not None:
+                s = jnp.where(seen, s, NEG_INF)
+            p = jnp.exp(s - lse_t[..., None])                  # (b,h,q,bk)
+            dv = jnp.einsum("bhqk,bhqd->bhkd", p, gt)
+            dp = jnp.einsum("bhqd,bhkd->bhqk", gt, vt)
+            ds = p * (dp - delta_t[..., None]) * sm_scale
+            dk = jnp.einsum("bhqk,bhqd->bhkd", ds, qt)
+            return jnp.einsum("bhqk,bhkd->bhqd", ds, kt), dk, dv
 
-        q_pos = jnp.arange(lq)
+        if live.all():
+            qf = q.astype(acc_t)
+            gf = g.astype(acc_t)
+            of = o.astype(acc_t)
+            delta = jnp.sum(of * gf, axis=-1)                  # (b,h,lq)
 
-        def step(dq, idx):
-            kblk = kb[:, :, idx]                               # (b,h,bk,d)
-            vblk = vb[:, :, idx]
-            s = jnp.einsum("bhqd,bhkd->bhqk", qf, kblk) * sm_scale
-            if causal:
-                # same diagonal offset as the forward (q_i attends keys up to
-                # i + lk - lq when lengths differ, e.g. decode)
-                k_pos = idx * block_k + jnp.arange(block_k)
-                mask = (q_pos[:, None] + (lk - lq)) >= k_pos[None, :]
-                s = jnp.where(mask, s, NEG_INF)
-            p = jnp.exp(s - lse[..., None])                    # (b,h,q,bk)
-            dv = jnp.einsum("bhqk,bhqd->bhkd", p, gf)
-            dp = jnp.einsum("bhqd,bhkd->bhqk", gf, vblk)
-            ds = p * (dp - delta[..., None]) * sm_scale
-            dk = jnp.einsum("bhqk,bhqd->bhkd", ds, qf)
-            dq = dq + jnp.einsum("bhqk,bhkd->bhqd", ds, kblk)
-            return dq, (dk, dv)
+            kb = k.reshape(b, h, nkb, block_k, d).astype(acc_t)
+            vb = v.reshape(b, h, nkb, block_k, d).astype(acc_t)
+            q_pos = jnp.arange(lq)[:, None]
 
-        dq0 = jnp.zeros_like(qf)
-        dq, (dks, dvs) = jax.lax.scan(step, dq0, jnp.arange(nkb))
-        dk = jnp.moveaxis(dks, 0, 2).reshape(b, h, lk, d)
-        dv = jnp.moveaxis(dvs, 0, 2).reshape(b, h, lk, d)
+            def step(dq, idx):
+                k_pos = idx * block_k + jnp.arange(block_k)[None, :]
+                dq_part, dk, dv = tile_grads(
+                    qf, gf, delta, lse, kb[:, :, idx], vb[:, :, idx],
+                    q_pos, k_pos)
+                return dq + dq_part, (dk, dv)
+
+            dq0 = jnp.zeros_like(qf)
+            dq, (dks, dvs) = jax.lax.scan(step, dq0, jnp.arange(nkb))
+            dk = jnp.moveaxis(dks, 0, 2).reshape(b, h, lk, d)
+            dv = jnp.moveaxis(dvs, 0, 2).reshape(b, h, lk, d)
+        else:
+            delta = jnp.sum(o.astype(acc_t) * g.astype(acc_t), axis=-1)
+            # K tile by K tile, so that a K tile's gradient is finished
+            # before the next one's begins
+            pairs = jnp.asarray(_np.argwhere(live.T)[:, ::-1], jnp.int32)
+
+            def rows(x, start, size):
+                return jax.lax.dynamic_slice_in_dim(x, start, size, axis=2)
+
+            def add_rows(acc, part, start):
+                size = part.shape[2]
+                return jax.lax.dynamic_update_slice_in_dim(
+                    acc, rows(acc, start, size) + part, start, axis=2)
+
+            def step(carry, pair):
+                dq, dk, dv = carry
+                q0, k0 = pair[0] * block_q, pair[1] * block_k
+                dq_part, dk_part, dv_part = tile_grads(
+                    rows(q, q0, block_q).astype(acc_t),
+                    rows(g, q0, block_q).astype(acc_t),
+                    rows(delta, q0, block_q), rows(lse, q0, block_q),
+                    rows(k, k0, block_k).astype(acc_t),
+                    rows(v, k0, block_k).astype(acc_t),
+                    q0 + jnp.arange(block_q)[:, None],
+                    k0 + jnp.arange(block_k)[None, :])
+                return (add_rows(dq, dq_part, q0), add_rows(dk, dk_part, k0),
+                        add_rows(dv, dv_part, k0)), None
+
+            zeros = (jnp.zeros(q.shape, acc_t), jnp.zeros(k.shape, acc_t),
+                     jnp.zeros(v.shape, acc_t))
+            (dq, dk, dv), _ = jax.lax.scan(step, zeros, pairs)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -348,7 +526,7 @@ def _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
 # public op with custom vjp
 # --------------------------------------------------------------------------
 @functools.lru_cache(maxsize=None)
-def _make_flash(causal, sm_scale_key):
+def _make_flash(causal, sm_scale_key, mask=None):
     import jax
     import jax.numpy as jnp
 
@@ -360,9 +538,9 @@ def _make_flash(causal, sm_scale_key):
 
     def _dispatch_fwd(q, k, v):
         if _use_pallas(q):
-            o, lse = _fa_forward(q, k, v, causal, sm_scale)
+            o, lse = _fa_forward(q, k, v, causal, sm_scale, mask)
         else:
-            o, lse = _mha_with_lse(q, k, v, causal, sm_scale)
+            o, lse = _mha_with_lse(q, k, v, causal, sm_scale, mask)
         return o, (q, k, v, o, lse)
 
     def fwd(q, k, v):
@@ -371,16 +549,24 @@ def _make_flash(causal, sm_scale_key):
 
     def bwd(res, g):
         q, k, v, o, lse = res
-        return _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale)
+        return _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
+                                      mask=mask)
 
     flash.defvjp(fwd, bwd)
     return flash
 
 
-def flash_attention(q, k, v, causal=False, sm_scale=None):
-    """q (B,Hq,Lq,D); k,v (B,Hkv,Lk,D) with Hq % Hkv == 0 (GQA)."""
+def flash_attention(q, k, v, causal=False, sm_scale=None, mask=None,
+                    mask_block=0):
+    """q (B,Hq,Lq,D); k,v (B,Hkv,Lk,D) with Hq % Hkv == 0 (GQA).
+
+    ``mask="block_diffusion"`` with ``mask_block`` the block length: the
+    training mask of block diffusion over rows of a noised copy followed by
+    the clean copy (``_visible``); it takes the place of ``causal``."""
     import jax.numpy as jnp
 
+    mask = _mask_key(mask, mask_block, causal)
+    _check_mask_shape(mask, q.shape[2], k.shape[2])
     d = q.shape[-1]
     if sm_scale is None:
         sm_scale = 1.0 / _np.sqrt(d)
@@ -391,7 +577,7 @@ def flash_attention(q, k, v, causal=False, sm_scale=None):
         rep = hq // hkv
         k = jnp.repeat(k, rep, axis=1)
         v = jnp.repeat(v, rep, axis=1)
-    fn = _make_flash(bool(causal), float(sm_scale))
+    fn = _make_flash(bool(causal), float(sm_scale), mask)
     return fn(q, k, v)
 
 
@@ -404,7 +590,9 @@ from .registry import register
 # eager executable; per-call overhead is irrelevant at attention sizes
 @register("_contrib_flash_attention", aliases=("flash_attention",),
           jit_safe=False)
-def flash_attention_op(q, k, v, causal=False, sm_scale=None):
+def flash_attention_op(q, k, v, causal=False, sm_scale=None, mask=None,
+                       mask_block=0):
     """Fused scaled-dot-product attention (net-new vs reference; the TPU
     answer to contrib/transformer.cc's unfused attention path)."""
-    return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale)
+    return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
+                           mask=mask, mask_block=mask_block)

@@ -407,7 +407,10 @@ class TrainStep:
             def loss_of(tp):
                 p = dict(rest_params)
                 p.update(tp)
-                with amp_scope(), attention_scope():
+                # numbers the layers give to telemetry.step_scalar leave the
+                # step beside the loss (an expert layer's routed pairs)
+                with amp_scope(), attention_scope(), \
+                        _telemetry.collect_step_scalars() as scalars:
                     if pipeline_cfg is not None:
                         out = pipelined_forward(p, rng, x)
                         state = {}
@@ -420,9 +423,9 @@ class TrainStep:
                     out = jax.tree_util.tree_map(
                         lambda o: o.astype(jnp.float32)
                         if jnp.issubdtype(o.dtype, jnp.floating) else o, out)
-                return jnp.mean(loss_fn(out, y)), state
+                return jnp.mean(loss_fn(out, y)), (state, scalars.stacked())
 
-            (loss, state), grads = jax.value_and_grad(
+            (loss, (state, scalars)), grads = jax.value_and_grad(
                 loss_of, has_aux=True)(train_params)
             with jax.named_scope(_profiler.SCOPE_OPTIMIZER):
                 new_tp, new_opt = update(train_params, grads, opt_state)
@@ -430,7 +433,7 @@ class TrainStep:
             for k, v in state.items():
                 if k in new_rest:
                     new_rest[k] = v
-            return loss, new_tp, new_rest, new_opt
+            return loss, new_tp, new_rest, new_opt, scalars
 
         donate_argnums = (0, 1, 2) if donate else ()
         self._step = jax.jit(train_step, donate_argnums=donate_argnums)
@@ -615,7 +618,11 @@ class TrainStep:
             out, flops = self._call_aot(sig, args)
         else:
             out = self._execute(step_fn, args)
-        loss, self.train_params, self.rest_params, self.opt_state = out
+        loss, self.train_params, self.rest_params, self.opt_state, \
+            scalars = out
+        # read when they are there, by a later call or by who reads the
+        # metrics; nothing waits here
+        _telemetry.defer_step_scalars(scalars)
         self.step_count += 1
         if flops:
             from .. import introspection as _introspection

@@ -39,6 +39,11 @@ SCOPE_FORWARD = "mx_forward"
 SCOPE_OPTIMIZER = "mx_optimizer"
 SCOPE_ATTENTION_BWD = "mxnet_flash_attention_bwd"
 SCOPE_ATTENTION_PLAIN_FWD = "mxnet_attention_plain_fwd"
+# the expert layer (parallel/expert_parallel.py, dropless routing): router,
+# top-k, sort, gather and scatter; and the grouped products over the
+# experts held.  Backward ops carry transpose(jvp(<scope>))
+SCOPE_MOE_ROUTE = "mx_moe_route"
+SCOPE_MOE_EXPERTS = "mx_moe_experts"
 
 _CONFIG = {"filename": "profile.json", "profile_all": False,
            "profile_imperative": False, "dir": None, "jax_trace": True,

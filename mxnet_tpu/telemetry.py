@@ -55,7 +55,8 @@ __all__ = ["Counter", "Gauge", "Histogram", "counter", "gauge", "histogram",
            "register_http_route", "unregister_http_route",
            "step_begin", "step_end", "step_abort", "step_scope", "phase",
            "maybe_phase", "trace_annotation", "timeline", "compile_event",
-           "compile_events",
+           "compile_events", "step_scalar", "collect_step_scalars",
+           "defer_step_scalars", "drain_step_scalars",
            "goodput_note", "goodput_summary",
            "heartbeat", "last_heartbeat", "reset"]
 
@@ -294,6 +295,83 @@ def register_collector(fn):
 
 
 # --------------------------------------------------------------------------
+# step scalars: numbers a layer computes on the device inside a fused step
+# (pairs an expert layer routed, its fullest expert's load) and the
+# registered counter or histogram each belongs to.  They leave the step as
+# outputs beside the loss and are read once they are there, never on the
+# dispatch path: a step waits for nothing of this.
+# --------------------------------------------------------------------------
+_SCALARS = threading.local()     # .open: the innermost collector's dict
+_DEFERRED: deque = deque()       # one {family name: device array} a step
+
+
+class collect_step_scalars:
+    """Trace-time collector: within it ``step_scalar`` calls gather into
+    ``.values`` (``{family name: [traced scalars]}``).  Collectors nest: a
+    ``jax.checkpoint``-ed layer opens its own, returns ``stacked()`` with
+    its outputs and hands that to ``step_scalar`` again outside, so that no
+    traced value crosses the checkpoint's boundary but as an output."""
+
+    def __enter__(self):
+        self._outer = getattr(_SCALARS, "open", None)
+        self.values = _SCALARS.open = {}
+        return self
+
+    def __exit__(self, *exc):
+        _SCALARS.open = self._outer
+
+    def stacked(self):
+        """``{family name: 1-d array}`` of what was gathered."""
+        import jax.numpy as jnp
+
+        return {name: jnp.concatenate([jnp.ravel(v) for v in got])
+                for name, got in self.values.items()}
+
+
+def step_scalar(name, value):
+    """While a fused step is traced: give ``value`` (a traced scalar, or an
+    array of them) to the registered counter or histogram ``name``.  It
+    reaches the family when the step that computed it has completed: a
+    counter is increased by the sum, a histogram observes each.  Outside a
+    collector (eager code, inference) nothing is recorded."""
+    found = getattr(_SCALARS, "open", None)
+    if found is not None:
+        if name not in _FAMILIES:
+            raise KeyError(f"step_scalar: no metric family {name!r}")
+        found.setdefault(name, []).append(value)
+
+
+def defer_step_scalars(arrays):
+    """A dispatched step's scalars (``{family name: device array}``): kept
+    until they are ready.  Those of earlier steps that have completed
+    meanwhile are recorded now, without waiting."""
+    if arrays:
+        _DEFERRED.append(arrays)
+    drain_step_scalars(wait=False)
+
+
+def drain_step_scalars(wait=True):
+    """Record the deferred scalars of every completed step, oldest first;
+    with ``wait`` those of the steps still running too.  ``snapshot()``
+    and ``render_prometheus()`` call it: who reads the metrics is not
+    dispatching."""
+    import numpy as np
+
+    while _DEFERRED:
+        arrays = _DEFERRED[0]
+        if not wait and not all(a.is_ready() for a in arrays.values()):
+            return
+        _DEFERRED.popleft()
+        for name, a in arrays.items():
+            fam, got = _FAMILIES[name], np.asarray(a, dtype=np.float64)
+            if fam.type == "counter":
+                fam.inc(float(got.sum()))
+            else:
+                for v in got.ravel():
+                    fam.observe(float(v))
+
+
+# --------------------------------------------------------------------------
 # step timeline
 # --------------------------------------------------------------------------
 _TIMELINE_CAP = max(1, _env.get_int("MXNET_TELEMETRY_TIMELINE_STEPS", 256))
@@ -323,6 +401,17 @@ _GOODPUT = counter(
     "wall time by goodput bucket (productive = step wall minus in-step "
     "checkpoint time; checkpoint/restart/reshard/stall/rewind noted by "
     "their owning seams)", labelnames=("bucket",))
+
+
+MOE_ROUTED_PAIRS = counter(
+    "mxnet_moe_routed_pairs_total",
+    "(token, expert) pairs the dropless expert layers of this process "
+    "computed: those routed to the experts held here (step scalar)")
+MOE_LOAD_MAX_OVER_MEAN = histogram(
+    "mxnet_moe_expert_load_max_over_mean",
+    "fullest held expert's pairs over the mean of the held experts, one "
+    "observation a layer a step (step scalar)",
+    buckets=exponential_buckets(1.0, 1.1, 30))
 
 
 def goodput_note(bucket, seconds):
@@ -779,6 +868,7 @@ def _fmt_value(v):
 def render_prometheus():
     """Prometheus text exposition (version 0.0.4) of every registered
     family plus collector output."""
+    drain_step_scalars()
     lines = []
     with _LOCK:
         families = list(_FAMILIES.values())
@@ -812,6 +902,7 @@ def snapshot():
     """JSON-able snapshot: every metric family (registered + collected),
     the step timeline, compile events, and aggregate summaries.  Embedded
     in ``profiler.dump()`` otherData and ``bench.py`` extras."""
+    drain_step_scalars()
     metrics = {}
     with _LOCK:
         families = list(_FAMILIES.values())
@@ -884,6 +975,7 @@ def reset():
             if not fam.labelnames:
                 fam._children.setdefault((), fam._new_child())
         _STEPS.clear()
+        _DEFERRED.clear()
         _COMPILE_EVENTS.clear()
         _CUR = None
         _STEP_SEQ[0] = 0

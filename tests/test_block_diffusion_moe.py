@@ -1,0 +1,464 @@
+"""What ISSUE 26 added for a decoder trained by block diffusion over a share
+of its experts: the block-diffusion mask in the attention op (kernel under
+the TPU interpreter, plain path, blockwise backward) against a dense boolean
+mask; dropless top-k routing over the experts held (the shares add up, the
+worst imbalance loses nothing); and the program's loss and gradients against
+the configuration's plain reference at a small size of the same shape of
+layer.  All on the CPU, seeded random weights."""
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import nd, telemetry
+from mxnet_tpu.ops import flash_attention as fa
+from mxnet_tpu.parallel.expert_parallel import _PART_ROWS, moe_apply
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# --------------------------------------------------------------------------
+# the mask
+# --------------------------------------------------------------------------
+def dense_mask(length, block):
+    """The mask as the paper words it, entry by entry (no shared code with
+    ``_visible``): row i of ``[noised ; clean]`` may see column j."""
+    n = 2 * length
+    out = np.zeros((n, n), bool)
+    for i in range(n):
+        for j in range(n):
+            bi, bj = (i % length) // block, (j % length) // block
+            if i < length:
+                out[i, j] = (bi == bj) if j < length else (bj < bi)
+            else:
+                out[i, j] = j >= length and bj <= bi
+    return out
+
+
+def dense_attention(q, k, v, seen, scale):
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), -1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+# lengths whose diffusion blocks do and do not end on a tile's edge, and one
+# whose halves do not (length 192 under tiles of 128: a tile holds rows of
+# both halves)
+MASK_CASES = [(128, 4, 128, 128), (192, 6, 128, 128), (384, 6, 256, 128),
+              (256, 32, 128, 256), (40, 5, None, None)]
+
+
+@pytest.mark.parametrize("length,block,block_q,block_k", MASK_CASES)
+def test_block_diffusion_predicate_and_tile_ranges(length, block, block_q,
+                                                   block_k):
+    n = 2 * length
+    mask = (fa.BLOCK_DIFFUSION, block)
+    want = dense_mask(length, block)
+    got = fa._visible(np, np.arange(n)[:, None], np.arange(n)[None, :],
+                      False, mask, n, n)
+    assert (got == want).all()
+    assert want.sum() == length * block + block * block * (
+        length // block) ** 2      # own blocks, then the two triangles
+    if block_q is None:
+        return
+    # the K tiles the kernel visits for a q tile are exactly the live ones
+    live = fa._live_tiles(False, mask, n, n, block_q, block_k)
+    by_hand = want.reshape(n // block_q, block_q, n // block_k,
+                           block_k).any(axis=(1, 3))
+    assert (live == by_hand).all()
+    for qi in range(n // block_q):
+        a_lo, a_hi, c_lo, c_hi = (int(x) for x in fa._bd_tile_ranges(
+            qi * block_q, (qi + 1) * block_q, length, block, block_k))
+        visited = np.zeros(n // block_k, bool)
+        visited[a_lo:a_hi] = True
+        visited[c_lo:c_hi] = True
+        assert (visited == live[qi]).all(), (qi, visited, live[qi])
+        assert a_hi <= c_lo or a_lo == a_hi     # no tile twice
+
+
+@pytest.mark.parametrize("length,block,block_q,block_k", MASK_CASES)
+def test_masked_attention_forward_matches_dense_mask(length, block, block_q,
+                                                     block_k):
+    """The interpreted kernel and the plain path against softmax under the
+    dense mask, float32: 2e-6, a few units in the last place of outputs of
+    size 1 (both accumulate in float32, in another order)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    n = 2 * length
+    rs = np.random.RandomState(length)
+    q, k, v = (jnp.asarray(rs.randn(1, 2, n, 64).astype("f"))
+               for _ in range(3))
+    mask = (fa.BLOCK_DIFFUSION, block)
+    want = dense_attention(q, k, v, dense_mask(length, block), 0.125)
+    plain, plain_lse = fa._mha_with_lse(q, k, v, False, 0.125, mask)
+    np.testing.assert_allclose(plain, want, atol=2e-6)
+    if block_q is None:
+        return
+    with pltpu.force_tpu_interpret_mode():
+        o, lse = fa._fa_forward_pallas(q, k, v, False, 0.125,
+                                       block_q=block_q, block_k=block_k,
+                                       mask=mask)
+    np.testing.assert_allclose(o, want, atol=2e-6)
+    np.testing.assert_allclose(lse, plain_lse, atol=2e-6)
+
+
+@pytest.mark.parametrize("length,block,block_q,block_k", MASK_CASES)
+def test_masked_attention_backward_matches_dense_mask(length, block, block_q,
+                                                      block_k):
+    """``_fa_backward_blockwise`` over the live tile pairs, and as one scan
+    where a q tile is the whole row, against autodiff through the dense
+    mask: 2e-5, the float32 noise of sums over up to 768 keys."""
+    n = 2 * length
+    rs = np.random.RandomState(length + 1)
+    q, k, v, g = (jnp.asarray(rs.randn(1, 2, n, 32).astype("f"))
+                  for _ in range(4))
+    mask = (fa.BLOCK_DIFFUSION, block)
+    seen = dense_mask(length, block)
+    want = jax.grad(lambda *a: jnp.sum(dense_attention(*a, seen, 0.2) * g),
+                    (0, 1, 2))(q, k, v)
+    o, lse = fa._mha_with_lse(q, k, v, False, 0.2, mask)
+    for bq, bk in ((block_q or n, block_k or n), (n, block_k or n)):
+        if n // bq > 1:
+            assert not fa._live_tiles(False, mask, n, n, bq, bk).all()
+        got = fa._fa_backward_blockwise(q, k, v, o, lse, g, False, 0.2,
+                                        block_k=bk, mask=mask, block_q=bq)
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+def test_causal_backward_skips_dead_tiles_and_matches():
+    """Causal attention at several tiles takes the live-pairs scan too."""
+    rs = np.random.RandomState(3)
+    q, k, v, g = (jnp.asarray(rs.randn(1, 2, 256, 32).astype("f"))
+                  for _ in range(4))
+    seen = np.tril(np.ones((256, 256), bool))
+    want = jax.grad(lambda *a: jnp.sum(dense_attention(*a, seen, 0.2) * g),
+                    (0, 1, 2))(q, k, v)
+    o, lse = fa._mha_with_lse(q, k, v, True, 0.2)
+    assert not fa._live_tiles(True, None, 256, 256, 64, 64).all()
+    got = fa._fa_backward_blockwise(q, k, v, o, lse, g, True, 0.2,
+                                    block_k=64, block_q=64)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+def test_flash_attention_op_takes_the_mask_with_gqa_and_refuses_misuse():
+    rs = np.random.RandomState(0)
+    q = nd.array(rs.randn(2, 4, 64, 16).astype("f"))
+    kv = nd.array(rs.randn(2, 2, 64, 16).astype("f"))
+    o = nd.flash_attention(q, kv, kv, mask="block_diffusion", mask_block=4)
+    k32 = jnp.repeat(kv._get(), 2, axis=1)
+    want = dense_attention(q._get(), k32, k32, dense_mask(32, 4), 0.25)
+    np.testing.assert_allclose(o.asnumpy(), want, atol=2e-6)
+    with pytest.raises(mx.MXNetError):
+        nd.flash_attention(q, kv, kv, mask="block_diffusion", mask_block=4,
+                           causal=True)
+    with pytest.raises(mx.MXNetError):
+        nd.flash_attention(q, kv, kv, mask="block_diffusion")   # no length
+    with pytest.raises(mx.MXNetError):
+        nd.flash_attention(q, kv, kv, mask="block_diffusion", mask_block=5)
+    with pytest.raises(mx.MXNetError):
+        nd.flash_attention(q, kv, kv, mask="sliding")
+
+
+# --------------------------------------------------------------------------
+# the expert layer
+# --------------------------------------------------------------------------
+def _expert_weights(rs, experts, hidden, width):
+    return {name: jnp.asarray(0.3 * rs.randn(*shape).astype("f"))
+            for name, shape in (("g", (experts, hidden, width)),
+                                ("u", (experts, hidden, width)),
+                                ("d", (experts, width, hidden)))}
+
+
+def _grouped(p, rows, sizes):
+    hidden = jax.nn.silu(jax.lax.ragged_dot(rows, p["g"], sizes)) \
+        * jax.lax.ragged_dot(rows, p["u"], sizes)
+    return jax.lax.ragged_dot(hidden, p["d"], sizes)
+
+
+def _dense_moe(x, router, p, top_k, renormalize, experts):
+    """Every expert of ``experts`` on every token, weighed by its gate."""
+    probs = jax.nn.softmax(x @ router, -1)
+    gates, chosen = jax.lax.top_k(probs, top_k)
+    if renormalize:
+        gates = gates / gates.sum(-1, keepdims=True)
+    y = jnp.zeros_like(x)
+    for e in experts:
+        w = jnp.sum(jnp.where(chosen == e, gates, 0.0), -1)
+        out = (jax.nn.silu(x @ p["g"][e]) * (x @ p["u"][e])) @ p["d"][e]
+        y = y + w[:, None] * out
+    return y
+
+
+def test_the_eight_shares_add_up_to_the_whole_layer():
+    """16 experts in 8 shares of 2, 4 a token: the shares' partial results
+    sum to the uncut layer's, and that to every expert applied densely.
+    1e-5: float32 sums of 4 terms of size 1 in another order."""
+    rs = np.random.RandomState(0)
+    x = jnp.asarray(rs.randn(96, 24).astype("f"))
+    router = jnp.asarray(rs.randn(24, 16).astype("f"))
+    p = _expert_weights(rs, 16, 24, 12)
+    whole, aux = moe_apply(_grouped, p, router, x, capacity_factor=None,
+                           top_k=4, renormalize=True)
+    assert int(aux["routed_pairs"]) == 96 * 4
+    parts, pairs = 0.0, 0
+    for share in range(8):
+        mine = {k: v[2 * share:2 * share + 2] for k, v in p.items()}
+        out, aux = moe_apply(_grouped, mine, router, x, capacity_factor=None,
+                             top_k=4, renormalize=True, held=(2 * share, 2))
+        parts, pairs = parts + out, pairs + int(aux["routed_pairs"])
+    assert pairs == 96 * 4          # every pair on exactly one share
+    np.testing.assert_allclose(parts, whole, atol=1e-5)
+    np.testing.assert_allclose(
+        whole, _dense_moe(x, router, p, 4, True, range(16)), atol=1e-5)
+
+
+@pytest.mark.parametrize("tokens", [128, 16384, 24000])
+def test_dropless_under_the_worst_imbalance(tokens):
+    """Every token to the same two experts: all ``tokens * k`` pairs are
+    computed, in one part of the sorted rows, in one that is exactly full,
+    and in two of which an expert's pairs span both, and the result and its
+    gradients are the dense ones."""
+    rs = np.random.RandomState(1)
+    # positive tokens and a router whose columns 5 and 2 are positive: every
+    # token's two largest logits are those two, by a wide margin
+    x = jnp.asarray(np.abs(rs.randn(tokens, 16)).astype("f"))
+    router = np.zeros((16, 8), "f")
+    router[:, 5], router[:, 2] = 3.0, 1.5
+    router = jnp.asarray(router)
+    p = _expert_weights(rs, 8, 16, 8)
+
+    def layer(x, p):
+        return moe_apply(_grouped, p, router, x, capacity_factor=None,
+                         top_k=2, renormalize=True)
+
+    out, aux = layer(x, p)
+    assert int(aux["routed_pairs"]) == tokens * 2
+    assert int(aux["dropped"]) == 0
+    assert np.asarray(aux["expert_load"]).tolist() == [
+        0, 0, tokens, 0, 0, tokens, 0, 0]
+    assert float(aux["load_max_over_mean"]) == pytest.approx(4.0)
+    # positive tokens make large sums: the tolerances are relative here
+    np.testing.assert_allclose(
+        out, _dense_moe(x, router, p, 2, True, range(8)), rtol=1e-5,
+        atol=1e-5)
+    got = jax.grad(lambda x, p: jnp.sum(jnp.cos(layer(x, p)[0])),
+                   (0, 1))(x, p)
+    want = jax.grad(lambda x, p: jnp.sum(jnp.cos(
+        _dense_moe(x, router, p, 2, True, range(8)))), (0, 1))(x, p)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+
+
+def test_switch_routing_is_the_same_function_with_a_capacity():
+    rs = np.random.RandomState(2)
+    x = jnp.asarray(rs.randn(32, 8).astype("f"))
+    router = jnp.asarray(rs.randn(8, 4).astype("f"))
+    p = _expert_weights(rs, 4, 8, 6)
+
+    def one(pe, toks):
+        return (jax.nn.silu(toks @ pe["g"]) * (toks @ pe["u"])) @ pe["d"]
+
+    out, aux = moe_apply(one, p, router, x, capacity_factor=8.0)
+    assert int(aux["dropped"]) == 0 and "load_balance_loss" in aux
+    # with room for every token, top-1 with its raw gate is the dense sum
+    np.testing.assert_allclose(
+        out, _dense_moe(x, router, p, 1, False, range(4)), atol=1e-5)
+    with pytest.raises(mx.MXNetError):
+        moe_apply(one, p, router, x, capacity_factor=1.25, top_k=2)
+    with pytest.raises(mx.MXNetError):
+        moe_apply(one, p, router, x, capacity_factor=1.25, held=(0, 2))
+
+
+def test_amp_keeps_the_router_float32_and_feeds_the_experts_bf16():
+    """Under the bf16 cast policy the op's tokens and router weight arrive
+    as they are and the expert weights in bf16."""
+    from mxnet_tpu.contrib.amp import _cast_scope, lists
+
+    assert lists.KEEP_DTYPE_INPUTS["_contrib_moe_swiglu"] == (0, 1)
+    seen = {}
+
+    def spy(expert_fn, params, router, x, **kw):
+        seen.update(x=x.dtype, router=router.dtype,
+                    experts=params["g"].dtype)
+        return moe_apply(expert_fn, params, router, x, **kw)
+
+    import mxnet_tpu.parallel.expert_parallel as ep
+
+    rs = np.random.RandomState(0)
+    args = [nd.array(rs.randn(*s).astype("f")) for s in
+            ((2, 8, 16), (16, 8), (8, 16, 4), (8, 16, 4), (8, 4, 16))]
+    orig = ep.moe_apply
+    ep.moe_apply = spy
+    try:
+        with _cast_scope("bfloat16"):
+            out = nd.moe_swiglu(*args, capacity_factor=0.0, top_k=2,
+                                renormalize=True)
+    finally:
+        ep.moe_apply = orig
+    assert seen == {"x": jnp.float32, "router": jnp.float32,
+                    "experts": jnp.bfloat16}
+    assert out.dtype == np.float32
+
+
+# --------------------------------------------------------------------------
+# the decoder by configuration, against the configuration's reference
+# --------------------------------------------------------------------------
+def _small_sdar():
+    """The benchmark's configuration at a small size of the same shape of
+    layer: top-2 of 8 experts with 4 held (the second share), block length
+    4, L = 32, GQA 4 over 2, q/k norm, two layers."""
+    from chipbench.harness.cell import ROOT as BENCH_ROOT, _module
+
+    with open(os.path.join(ROOT, "chipbench", "configs", "sdar_30b_a3b",
+                           "config.json")) as f:
+        cfg = json.load(f)
+    cfg.update(vocab_size=96, hidden_size=64, num_hidden_layers=2,
+               num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+               moe_intermediate_size=32, num_experts=4, router_width=8,
+               num_experts_per_tok=2, experts_first=4)
+    cfg["assumed"] = dict(cfg["assumed"], mask_token_id=95)
+    mods = [_module(BENCH_ROOT, "configs", "sdar_30b_a3b", name)
+            for name in ("build", "reference")]
+    return (cfg, *mods, _module(BENCH_ROOT, "drivers", "fused_step"))
+
+
+@pytest.mark.parametrize("amp,tolerance", [
+    # float32 against float32: the gap is the order of the sums (the
+    # program sorts pairs by expert, the reference runs every expert on
+    # every token): a few 1e-7 measured, 1e-5 allowed
+    (None, {"loss_gap": 1e-5, "first_gradient_gap": 1e-5,
+            "first_gradient_error": 1e-5, "change_gap": 1e-3}),
+    # bf16 operands: three decimal digits a product, and a router near-tie
+    # may pick another expert for a token: 1.2e-4 / 0.003 / 0.011 / 0.004
+    # measured at seed 5; a float32 result would read a hundred times less
+    ("bfloat16", {"loss_gap": 2e-3, "first_gradient_gap": 0.03,
+                  "first_gradient_error": 0.06, "change_gap": 0.03}),
+])
+def test_program_matches_the_reference_loss_and_every_gradient(amp,
+                                                               tolerance):
+    from chipbench.harness import check, loop
+
+    cfg, build, reference, driver = _small_sdar()
+    spec = {"batch": 2, "seq": 32, "optimizer": "adam", "amp_dtype": amp,
+            "optimizer_params": {"learning_rate": 1e-6}}
+    telemetry.reset()
+    runner = driver.Runner(spec, cfg, build, reference.init_params(cfg, 5))
+    pool = loop.make_pool(build, cfg, spec, 5)
+    feed = loop.open_feed(pool)
+    try:
+        got = loop.first_steps(runner, feed, 2)
+    finally:
+        feed.close()
+    ref = check.follow(reference, cfg, "float32",
+                       reference.init_params(cfg, 5), pool[:2], spec)
+    assert set(got["first_gradient"]) == set(reference.param_shapes(cfg))
+    stats = check.compare(got, ref)
+    for name, (value, where) in stats.items():
+        assert value <= tolerance[name], (name, value, where)
+    # every leaf got a gradient of its own, the router's and the norms' too
+    for leaf, g in got["first_gradient"].items():
+        assert np.abs(g).max() > 0, leaf
+
+    # the layers' device scalars left the steps beside the loss: 2 steps x 2
+    # layers, about tokens * k * held / width pairs each
+    metrics = telemetry.snapshot()["metrics"]
+    pairs = metrics["mxnet_moe_routed_pairs_total"]["samples"][0]["value"]
+    load = metrics["mxnet_moe_expert_load_max_over_mean"]["samples"][0]
+    assert load["count"] == 4 and load["sum"] / 4 >= 1.0
+    assert 0.6 < pairs / (4 * 2 * 64 * 2 * 4 / 8) < 1.4
+
+
+def test_remat_is_real_in_the_fused_step_and_scopes_are_in_its_table():
+    """``LlamaConfig(remat=True)`` recomputes under ``TrainStep`` (the graph
+    tier steps aside), and the expert layer's scopes reach the op table."""
+    from mxnet_tpu import profiler
+    from mxnet_tpu.parallel.data_parallel import TrainStep
+
+    cfg, build, reference, _ = _small_sdar()
+    net = build.build_net(cfg, mx.current_context())
+    step = TrainStep(net, build.step_loss, optimizer="adam",
+                     optimizer_params={"learning_rate": 1e-6})
+    batch = build.make_batch(cfg, {"batch": 2, "seq": 32},
+                             np.random.default_rng(0))
+    assert np.isfinite(float(step(*batch)))
+    table = list(profiler.op_scopes().values())[-1]
+    scopes = [row["scope"] for row in table.values()]
+    assert any("rematted_computation" in s for s in scopes)
+    for name in (profiler.SCOPE_MOE_ROUTE, profiler.SCOPE_MOE_EXPERTS):
+        assert any(name in s and "transpose(" not in s for s in scopes), name
+        assert any(name in s and "transpose(" in s for s in scopes), name
+    assert any(profiler.SCOPE_ATTENTION_BWD in s for s in scopes)
+
+
+def test_counts_of_the_configuration():
+    """``counts.py`` against the mask and a hand value."""
+    from chipbench.configs.sdar_30b_a3b import counts
+
+    assert counts.visible_pairs(32, 4) == dense_mask(32, 4).sum()
+    assert counts.visible_pairs(4096, 4) == 16_793_600   # a quarter of 8192^2
+    with open(os.path.join(ROOT, "chipbench", "configs", "sdar_30b_a3b",
+                           "config.json")) as f:
+        cfg = json.load(f)
+    assert counts.expected_pairs_per_token(cfg) == 1.0
+    assert counts.routed_pair_fwd_flops(cfg) == 6 * 2048 * 768
+    # the issue's hand values for one sample's forward, TFLOP
+    assert counts.attention_fwd_flops(cfg, 4096, 4) / 1e12 == \
+        pytest.approx(0.275, abs=1e-3)
+    assert counts.train_flops_per_sample(cfg, 4096, 4) * 2 / 1e12 == \
+        pytest.approx(17.9, abs=0.05)
+
+
+@pytest.mark.parametrize("tokens", [64, 16400])
+def test_rows_past_the_last_pair_may_hold_anything(tokens):
+    """The TPU's grouped product leaves rows outside its groups unwritten,
+    forward and backward (the CPU's writes zeros, so no other test here sees
+    it).  The rows past the last pair enter the experts as zeros; an expert
+    function that answers NaN there, in its output and in its rows'
+    cotangent, changes nothing: not the result, not the tokens' gradient,
+    not the gates'.  With 16,400 tokens the sorted rows are two parts, and
+    the second, which holds no pair, is skipped."""
+    rs = np.random.RandomState(4)
+    x = jnp.asarray(rs.randn(tokens, 16).astype("f"))
+    router = jnp.asarray(rs.randn(16, 8).astype("f"))
+    p = _expert_weights(rs, 2, 16, 8)
+
+    def past(rows):
+        return jnp.all(rows == 0, axis=1, keepdims=True)
+
+    @jax.custom_vjp
+    def dirty(p, rows, sizes):
+        return _dirty_fwd(p, rows, sizes)[0]
+
+    def _dirty_fwd(p, rows, sizes):
+        y, vjp = jax.vjp(lambda p, rows: _grouped(p, rows, sizes), p, rows)
+        return jnp.where(past(rows), jnp.nan, y), (vjp, past(rows))
+
+    def _dirty_bwd(res, g):
+        vjp, was_past = res
+        dp, drows = vjp(jnp.where(was_past, 0.0, g))
+        return dp, jnp.where(was_past, jnp.nan, drows), None
+
+    dirty.defvjp(_dirty_fwd, _dirty_bwd)
+
+    def loss(fn, x, p, router):
+        out, aux = moe_apply(fn, p, router, x, capacity_factor=None, top_k=2,
+                             renormalize=True, held=(3, 2))
+        return jnp.sum(jnp.sin(out)), aux["routed_pairs"]
+
+    (got, pairs), got_g = jax.value_and_grad(loss, (1, 2, 3), has_aux=True)(
+        dirty, x, p, router)
+    (want, _), want_g = jax.value_and_grad(loss, (1, 2, 3), has_aux=True)(
+        _grouped, x, p, router)
+    # a part with slack rows, and for two parts none in the second
+    assert 0 < int(pairs) < min(tokens * 2, _PART_ROWS)
+    for a, b in zip(jax.tree_util.tree_leaves((got, got_g)),
+                    jax.tree_util.tree_leaves((want, want_g))):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(a, b, atol=1e-6)

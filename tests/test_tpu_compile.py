@@ -59,3 +59,44 @@ def test_flash_forward_compiles_for_v5e(one_chip, shape, dim, dtype, causal,
     kv = jax.ShapeDtypeStruct((b, h, lk, dim), dtype, sharding=one_chip)
     text = jax.jit(fwd).lower(q, kv, kv).compile().as_text()
     assert "tpu_custom_call" in text and "mxnet_flash_attention_fwd" in text
+
+
+@pytest.mark.parametrize("shape,dim,dtype,block,blocks", [
+    # the decoder cell's own call: 2 samples of [xt ; x0], L = 4096, the 4
+    # key-value heads repeated to the 32 query heads, head 128, block 4;
+    # the whole K row of 8,192 resident and tiles of 512 from the shape
+    ((2, 32, 8192, 8192), 128, "bfloat16", 4, (None, None)),
+    # a block length that is no power of two (vector integer division), a
+    # K tile that holds keys of both halves, float32 operands
+    ((1, 4, 768, 768), 128, "float32", 6, (256, 256)),
+    ((2, 4, 512, 512), 64, "bfloat16", 32, (128, 128)),
+])
+def test_masked_flash_forward_compiles_for_v5e(one_chip, shape, dim, dtype,
+                                               block, blocks):
+    from mxnet_tpu.ops.flash_attention import (BLOCK_DIFFUSION,
+                                               _fa_forward_pallas)
+
+    b, h, lq, lk = shape
+    fwd = functools.partial(_fa_forward_pallas, causal=False,
+                            sm_scale=1.0 / dim ** 0.5, block_q=blocks[0],
+                            block_k=blocks[1], mask=(BLOCK_DIFFUSION, block))
+    q = jax.ShapeDtypeStruct((b, h, lq, dim), dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, h, lk, dim), dtype, sharding=one_chip)
+    text = jax.jit(fwd).lower(q, kv, kv).compile().as_text()
+    assert "tpu_custom_call" in text and "mxnet_flash_attention_fwd" in text
+
+
+def test_masked_flash_backward_compiles_for_v5e(one_chip):
+    """The blockwise backward over the live tile pairs at the cell's shape:
+    plain XLA, and under a gigabyte of scratch (the dead three quarters of
+    the tiles are neither computed nor held)."""
+    from mxnet_tpu.ops.flash_attention import (BLOCK_DIFFUSION,
+                                               _fa_backward_blockwise)
+
+    bwd = functools.partial(_fa_backward_blockwise, causal=False,
+                            sm_scale=128 ** -0.5, mask=(BLOCK_DIFFUSION, 4))
+    x = jax.ShapeDtypeStruct((2, 32, 8192, 128), "bfloat16",
+                             sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((2, 32, 8192), "float32", sharding=one_chip)
+    compiled = jax.jit(bwd).lower(x, x, x, x, lse, x).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
