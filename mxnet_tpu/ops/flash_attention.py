@@ -5,8 +5,14 @@ softmax(QKᵀ)V through `src/operator/contrib/transformer.cc`'s interleaved
 matmuls (SURVEY.md §6.7).  This module is the net-new TPU capability the
 BASELINE Llama config requires: a blocked softmax kernel that keeps the L×L
 score matrix out of HBM, fed to the MXU in the input's dtype with tiles
-chosen from the call's shape (`_fa_block_sizes`), with a memory-efficient
-blockwise backward (lax.scan recompute — O(L) memory).
+chosen from the call's shape (`_fa_block_sizes`), and a backward kernel that
+recomputes the probabilities a tile at a time in VMEM from the saved
+log-sum-exp (`_fa_bwd_kernel`: the live tile pairs alone, five products a
+pair on operands in the input's dtype, float32 accumulation).  Where the
+kernels do not apply (no TPU, rows under 256, a length that does not divide
+into tiles) the forward is plain jax and the backward a blockwise lax.scan
+over the same tiles on the same operands (`_fa_backward_blockwise`, O(L)
+memory).
 
 Layout: (batch, heads, seq, head_dim) — q_heads may be a multiple of
 kv_heads (GQA).
@@ -45,9 +51,10 @@ def batch_sharded(mesh, batch_axes):
 
 
 def _use_pallas(q):
-    """Static gate for the Pallas forward: a head size the kernel tiles, a
-    sequence long enough to pay for it, and a TPU to compile it for.  The
-    platform is JAX's default backend, not where ``q`` lives (a tracer
+    """Static gate for the Pallas forward, and the first half of the
+    backward's (``_use_pallas_bwd``): a head size the kernels tile, a
+    sequence long enough to pay for them, and a TPU to compile them for.
+    The platform is JAX's default backend, not where ``q`` lives (a tracer
     lives nowhere), so a CPU-context call on a TPU host is not covered."""
     import jax
 
@@ -114,20 +121,30 @@ def _visible(xp, q_pos, k_pos, causal, mask, lq, lk):
 
 
 @functools.lru_cache(maxsize=64)
-def _live_tiles(causal, mask, lq, lk, block_q, block_k):
-    """numpy bool ``(lq / block_q, lk / block_k)``: tiles in which some pair
-    is visible.  From the predicate itself, a strip of query rows at a
-    time; static per shape, so computed once."""
+def _tile_visibility(causal, mask, lq, lk, block_q, block_k):
+    """numpy bool ``(some, every)``, each ``(lq / block_q, lk / block_k)``:
+    tiles in which some pair is visible, and in which every pair is.  From
+    the predicate itself, a strip of query rows at a time; static per
+    shape, so computed once."""
     nq, nk = lq // block_q, lk // block_k
     if mask is None and not causal:
-        return _np.ones((nq, nk), bool)
+        return _np.ones((nq, nk), bool), _np.ones((nq, nk), bool)
     k_pos = _np.arange(lk)[None, :]
-    live = _np.empty((nq, nk), bool)
+    some = _np.empty((nq, nk), bool)
+    every = _np.empty((nq, nk), bool)
     for i in range(nq):
         q_pos = _np.arange(i * block_q, (i + 1) * block_q)[:, None]
         seen = _visible(_np, q_pos, k_pos, causal, mask, lq, lk)
-        live[i] = seen.reshape(block_q, nk, block_k).any(axis=(0, 2))
-    return live
+        seen = seen.reshape(block_q, nk, block_k)
+        some[i] = seen.any(axis=(0, 2))
+        every[i] = seen.all(axis=(0, 2))
+    return some, every
+
+
+def _live_tiles(causal, mask, lq, lk, block_q, block_k):
+    """numpy bool ``(lq / block_q, lk / block_k)``: tiles in which some pair
+    is visible."""
+    return _tile_visibility(causal, mask, lq, lk, block_q, block_k)[0]
 
 
 def _check_mask_shape(mask, lq, lk):
@@ -178,6 +195,20 @@ def _mha_reference(q, k, v, causal, sm_scale, mask=None):
 # --------------------------------------------------------------------------
 # q @ k.T as one dot_general contracting both last dims: no transposed tile
 _NT_DIMS = (((1,), (1,)), ((), ()))
+_NN_DIMS = (((1,), (0,)), ((), ()))
+
+
+def _operand_precision(dtype):
+    """The ``precision`` of a product whose operands arrive in ``dtype``.
+    float32 operands follow the process's matmul precision as they always
+    have (None); narrower ones are one exact MXU pass, and Mosaic refuses
+    them the float32 contraction that the process default (highest) asks
+    for ("Bad lhs type")."""
+    import jax
+    import jax.numpy as jnp
+
+    return None if dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+
 
 # what the default tile choice may spend on one grid step's float32 score
 # tile and its q / k / v operand tiles; the exp'd copy, the buffers' second
@@ -243,10 +274,7 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
     num_kb = seq_k // block_k
 
     q = q_ref[:]
-    # float32 operands follow the process's matmul precision as they always
-    # have; narrower ones are one exact MXU pass, and Mosaic refuses them
-    # the float32 contraction that the process default (highest) asks for
-    precision = None if q.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+    precision = _operand_precision(q.dtype)
     # a power of two scales q exactly in any float dtype (1/8 at head size
     # 64): one multiply a q element in place of one a score
     scale_q = _np.frexp(sm_scale)[0] == 0.5
@@ -324,6 +352,11 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
     lse_ref[:] = jnp.broadcast_to(lse.reshape(1, block_q), lse_ref.shape)
 
 
+def _largest_tile(length):
+    """The largest of 512 / 256 / 128 that divides ``length``, or None."""
+    return next((b for b in (512, 256, 128) if length % b == 0), None)
+
+
 def _fa_block_sizes(lq, lk, d, itemsize):
     """Forward kernel tile sizes.  The tuning funnel's answer where it has
     one (MXNET_FLASH_BLOCK_Q / MXNET_FLASH_BLOCK_KV pins > MXNET_TUNE=1
@@ -344,7 +377,7 @@ def _fa_block_sizes(lq, lk, d, itemsize):
     block_q, q_from = _tuning.resolve_info("flash_block_q")
     block_k, k_from = _tuning.resolve_info("flash_block_kv")
     if q_from == "default":
-        block_q = next((b for b in (512, 256, 128) if lq % b == 0), lq)
+        block_q = _largest_tile(lq) or lq
     block_q = min(int(block_q), lq)
     if k_from == "default":
         def fits(bk):
@@ -406,26 +439,267 @@ def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q=None, block_k=None,
     return o.reshape(b, h, lq, d), lse[:, 0, :].reshape(b, h, lq)
 
 
-def _fa_forward(q, k, v, causal, sm_scale, mask=None):
-    """The Pallas forward, per batch shard when a ``batch_sharded`` step
-    is being traced."""
+def _per_batch_shard(fn, sharded):
+    """``fn`` per batch shard of ``sharded``, the ``(mesh, batch_axes)`` of
+    the ``batch_sharded`` step being traced, or ``fn`` itself under none."""
+    if sharded is None:
+        return fn
+    from ..parallel.collectives import shard_map_over_batch
+
+    return shard_map_over_batch(fn, *sharded)
+
+
+def _fa_forward(q, k, v, causal, sm_scale, mask=None, sharded=None):
+    """The Pallas forward, per batch shard under ``sharded``."""
     fwd = functools.partial(_fa_forward_pallas, causal=causal,
                             sm_scale=sm_scale, mask=mask)
-    scope = getattr(_SCOPE, "value", None)
-    if scope is not None:
-        from ..parallel.collectives import shard_map_over_batch
-
-        fwd = shard_map_over_batch(fwd, *scope)
-    return fwd(q, k, v)
+    return _per_batch_shard(fwd, sharded)(q, k, v)
 
 
 # --------------------------------------------------------------------------
-# blockwise backward (jax, O(L) memory via scan recompute)
+# Pallas backward kernel
+# --------------------------------------------------------------------------
+# a.T @ b as one dot_general contracting both first dims
+_TN_DIMS = (((0,), (0,)), ((), ()))
+
+# flags of a row of the backward's table of tile pairs
+_FIRST_OF_K, _LAST_OF_K, _PARTLY_SEEN = 1, 2, 4
+
+# Mosaic's scoped VMEM limit where a call states none, and the most the
+# backward may state for itself (a v5e core has 128 MiB)
+_VMEM_DEFAULT_LIMIT = 16 << 20
+_VMEM_MOST = 96 << 20
+
+
+def _fa_bwd_block_sizes(lq, lk):
+    """Backward kernel tile sizes, from the call's shape alone: the largest
+    of 512 / 256 / 128 that divides each length, or None where none does
+    (the scores are held transposed, so the q tile is their lane dim and
+    has to be whole lane tiles)."""
+    return _largest_tile(lq), _largest_tile(lk)
+
+
+def _fa_bwd_vmem_bytes(lq, d, itemsize, block_q, block_k):
+    """What one grid step of the backward kernel holds in VMEM: the row of
+    ``dq`` (float32 accumulator, and the output's two buffers), two buffers
+    of each operand and result tile, the ``dk`` / ``dv`` accumulators, and
+    the float32 score-shaped tiles (``s``, ``p``, ``dp``, ``ds``, and the
+    narrow copies of ``p`` and ``ds``, with room for what Mosaic keeps
+    beside them)."""
+    return (lq * d * (4 + 2 * itemsize)
+            + 4 * (block_q + 2 * block_k) * d * itemsize
+            + 2 * block_k * d * 4 + 8 * block_q * block_k * 4)
+
+
+@functools.lru_cache(maxsize=64)
+def _fa_bwd_pairs(causal, mask, lq, lk, block_q, block_k):
+    """The table the backward kernel walks, int32 ``(3, pairs)``: q tile, k
+    tile and flags of every live tile pair (``_live_tiles``), K tile by K
+    tile so that a K tile's ``dk`` and ``dv`` are finished before the next
+    one's begin.  Flags: first and last pair of their K tile, and whether
+    the mask hides some pair of the tile (``_visible`` is evaluated in those
+    alone).  Every K tile is in it (under each mask here the last query
+    sees every key), so every tile of ``dk`` and ``dv`` is written."""
+    some, every = _tile_visibility(causal, mask, lq, lk, block_q, block_k)
+    assert some.any(axis=0).all(), "a K tile that no query sees"
+    pairs = _np.argwhere(some.T)[:, ::-1]
+    turn = pairs[1:, 1] != pairs[:-1, 1]
+    flags = (_FIRST_OF_K * _np.r_[True, turn] + _LAST_OF_K * _np.r_[turn, True]
+             + _PARTLY_SEEN * ~every[pairs[:, 0], pairs[:, 1]])
+    return _np.stack([pairs[:, 0], pairs[:, 1], flags]).astype(_np.int32)
+
+
+def _fa_bwd_kernel(pairs_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
+                   dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, causal,
+                   sm_scale, seq_q, seq_k, mask):
+    """One live tile pair: its five products, nothing recomputed.
+
+    Grid: (batch*heads, live tile pairs), the pairs K tile by K tile
+    (``_fa_bwd_pairs``, prefetched to SMEM; the block index maps read it, so
+    a dead tile costs no step and no copy).  Blocks: q_ref / g_ref
+    (block_q, d) and lse_ref / delta_ref (1, block_q) of the pair's q tile;
+    k_ref / v_ref and dk_ref / dv_ref (block_k, d) of its K tile; dq_ref the
+    head's whole (seq_q, d) row.  Scratch, float32: dq_acc (q tiles, d,
+    block_q), the head's ``dq`` transposed, added to across the K tiles;
+    dk_acc / dv_acc (block_k, d), added to across a K tile's q tiles.
+
+    Scores are held transposed, (block_k, block_q): the log-sum-exp and
+    ``delta`` are then rows that broadcast down the sublanes, ``p^T g`` and
+    ``ds^T q`` are plain products, and the one transposed operand is the
+    narrow K tile of ``k^T ds^T = dq^T``.  Operands in the input's dtype,
+    float32 accumulation (``_fa_fwd_kernel``); ``p`` and ``ds`` go to the
+    input's dtype for their products; ``sm_scale`` is applied to ``dq`` and
+    ``dk`` as they are written, not to every ``ds``.
+    """
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    block_q, d = q_ref.shape
+    block_k = k_ref.shape[0]
+    t = pl.program_id(1)
+    qi, ki, flags = pairs_ref[0, t], pairs_ref[1, t], pairs_ref[2, t]
+
+    @pl.when(t == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when((flags & _FIRST_OF_K) != 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    q, k, v, g = q_ref[...], k_ref[...], v_ref[...], g_ref[...]
+    dot = functools.partial(jax.lax.dot_general,
+                            precision=_operand_precision(q.dtype),
+                            preferred_element_type=jnp.float32)
+    # a power of two scales q exactly, as in the forward
+    scale_q = _np.frexp(sm_scale)[0] == 0.5
+    if scale_q:
+        s = dot(k, (q.astype(jnp.float32) * sm_scale).astype(q.dtype),
+                _NT_DIMS)
+    else:
+        s = dot(k, q, _NT_DIMS) * sm_scale
+    if causal or mask is not None:
+        def hide(s):
+            q_pos = qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block_q), 1)
+            k_pos = ki * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, 1), 0)
+            return jnp.where(_visible(jnp, q_pos, k_pos, causal, mask, seq_q,
+                                      seq_k), s, NEG_INF)
+
+        s = jax.lax.cond((flags & _PARTLY_SEEN) != 0, hide, lambda s: s, s)
+    p = jnp.exp(s - lse_ref[...])
+    dv_acc[...] += dot(p.astype(g.dtype), g, _NN_DIMS)
+    dp = dot(v, g, _NT_DIMS)
+    ds = (p * (dp - delta_ref[...])).astype(q.dtype)
+    dk_acc[...] += dot(ds, q, _NN_DIMS)
+    dq_acc[qi] += dot(k, ds, _TN_DIMS)
+
+    @pl.when((flags & _LAST_OF_K) != 0)
+    def _():
+        dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _():
+        def put(i, carry):
+            dq_ref[pl.ds(i * block_q, block_q), :] = (
+                dq_acc[i].T * sm_scale).astype(dq_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, seq_q // block_q, put, None)
+
+
+def _fa_backward_pallas(q, k, v, o, lse, g, causal, sm_scale, mask=None):
+    """Gradients of q, k and v from one Pallas call over the live tile
+    pairs (``_fa_bwd_kernel``); ``delta = rowsum(o * g)`` is the one
+    reduction left to XLA.  No score-shaped array reaches HBM."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    with jax.named_scope(SCOPE_ATTENTION_BWD):
+        b, h, lq, d = q.shape
+        lk = k.shape[2]
+        block_q, block_k = _fa_bwd_block_sizes(lq, lk)
+        pairs = _fa_bwd_pairs(causal, mask, lq, lk, block_q, block_k)
+        nq = lq // block_q
+
+        delta = jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
+        rows = lambda x: x.astype(jnp.float32).reshape(b * h, nq, 1, block_q)
+        flat = lambda x: x.reshape(b * h, x.shape[2], d)
+
+        q_tile = pl.BlockSpec((None, block_q, d),
+                              lambda bh, t, pairs: (bh, pairs[0, t], 0))
+        k_tile = pl.BlockSpec((None, block_k, d),
+                              lambda bh, t, pairs: (bh, pairs[1, t], 0))
+        q_row = pl.BlockSpec((None, None, 1, block_q),
+                             lambda bh, t, pairs: (bh, pairs[0, t], 0, 0))
+        need = _fa_bwd_vmem_bytes(lq, d, q.dtype.itemsize, block_q, block_k)
+        kernel = functools.partial(_fa_bwd_kernel, causal=causal,
+                                   sm_scale=sm_scale, seq_q=lq, seq_k=lk,
+                                   mask=mask)
+        dq, dk, dv = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(b * h, pairs.shape[1]),
+                in_specs=[q_tile, k_tile, k_tile, q_tile, q_row, q_row],
+                out_specs=[pl.BlockSpec((None, lq, d),
+                                        lambda bh, t, pairs: (bh, 0, 0)),
+                           k_tile, k_tile],
+                scratch_shapes=[pltpu.VMEM((nq, d, block_q), jnp.float32),
+                                pltpu.VMEM((block_k, d), jnp.float32),
+                                pltpu.VMEM((block_k, d), jnp.float32)]),
+            out_shape=[jax.ShapeDtypeStruct((b * h, lq, d), q.dtype),
+                       jax.ShapeDtypeStruct((b * h, lk, d), k.dtype),
+                       jax.ShapeDtypeStruct((b * h, lk, d), v.dtype)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=max(need, _VMEM_DEFAULT_LIMIT)),
+            name="mxnet_flash_attention_bwd",
+        )(jnp.asarray(pairs), flat(q), flat(k), flat(v), flat(g), rows(lse),
+          rows(delta))
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
+
+
+def _use_pallas_bwd(q, k):
+    """Static gate for the Pallas backward: where ``_use_pallas`` takes the
+    forward kernel, the inputs are bf16, both lengths divide into its tiles
+    and a head's ``dq`` row fits VMEM beside the tiles.  float32 inputs take
+    the scan: their products are several MXU passes either way, and at that
+    the kernel is the slower of the two on a v5e (2.18 against 0.86 ms a
+    call at (16, 12, 512, 512, 64); PERF.md section 6, PR 27)."""
+    import jax.numpy as jnp
+
+    lq, lk, d = q.shape[2], k.shape[2], q.shape[3]
+    block_q, block_k = _fa_bwd_block_sizes(lq, lk)
+    if not _use_pallas(q) or q.dtype != jnp.bfloat16:
+        return False
+    if block_q is None or block_k is None:
+        return False
+    return _fa_bwd_vmem_bytes(lq, d, q.dtype.itemsize, block_q,
+                              block_k) <= _VMEM_MOST
+
+
+def _fa_backward(q, k, v, o, lse, g, causal, sm_scale, mask=None,
+                 sharded=None):
+    """The backward of ``flash_attention``: the Pallas kernel where
+    ``_use_pallas_bwd`` takes it (per batch shard under ``sharded``, as
+    ``_fa_forward``), the blockwise scan for everything else.  Which one a
+    call took is counted once a trace in
+    ``mxnet_flash_attention_bwd_calls_total{path}``."""
+    from .. import telemetry
+
+    pallas = _use_pallas_bwd(q, k)
+    telemetry.counter(
+        "mxnet_flash_attention_bwd_calls_total",
+        "flash_attention backward calls traced, by the path they took",
+        ("path",)).labels(path="pallas" if pallas else "blockwise").inc()
+    if not pallas:
+        return _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
+                                      mask=mask)
+    bwd = functools.partial(_fa_backward_pallas, causal=causal,
+                            sm_scale=sm_scale, mask=mask)
+    return _per_batch_shard(bwd, sharded)(q, k, v, o, lse, g)
+
+
+# --------------------------------------------------------------------------
+# blockwise backward (jax, O(L) memory via scan recompute): the fallback
 # --------------------------------------------------------------------------
 def _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
                            block_k=512, mask=None, block_q=512):
     """Gradients of q, k and v, recomputing the probabilities a K block at a
-    time from the saved log-sum-exp: float32 operands, O(L) memory.
+    time from the saved log-sum-exp, in plain jax: the path of every call
+    the Pallas backward does not take (``_use_pallas_bwd``).  O(L) memory.
+    As in the kernels, the five products take their operands in the input's
+    dtype and accumulate in float32 (``p`` and ``ds`` are cast to it for
+    theirs); float32 inputs keep float32 operands at the process's
+    precision; ``s``, ``p``, ``dp``, ``ds``, ``delta`` and the three sums
+    are float32 and the results are cast once, at the end.
 
     Where no tile of the score matrix is wholly masked (no mask, or a row
     of one q tile), one scan over the K blocks takes every query row at
@@ -450,46 +724,43 @@ def _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
         live = _live_tiles(causal, mask, lq, lk, block_q, block_k)
 
         acc_t = jnp.result_type(q.dtype, jnp.float32)
+        product = functools.partial(jnp.einsum, preferred_element_type=acc_t,
+                                    precision=_operand_precision(q.dtype))
+        delta = jnp.sum(o.astype(acc_t) * g.astype(acc_t), axis=-1)  # (b,h,lq)
 
         def tile_grads(qt, gt, delta_t, lse_t, kt, vt, q_pos, k_pos):
-            """One tile's ``(dq part, dk part, dv part)``; operands float32,
+            """One tile's ``(dq part, dk part, dv part)`` in float32;
             ``q_pos`` a column and ``k_pos`` a row of positions."""
-            s = jnp.einsum("bhqd,bhkd->bhqk", qt, kt) * sm_scale
+            s = product("bhqd,bhkd->bhqk", qt, kt) * sm_scale
             # same diagonal offset as the forward (q_i attends keys up to
             # i + lk - lq when lengths differ, e.g. decode)
             seen = _visible(jnp, q_pos, k_pos, causal, mask, lq, lk)
             if seen is not None:
                 s = jnp.where(seen, s, NEG_INF)
             p = jnp.exp(s - lse_t[..., None])                  # (b,h,q,bk)
-            dv = jnp.einsum("bhqk,bhqd->bhkd", p, gt)
-            dp = jnp.einsum("bhqd,bhkd->bhqk", gt, vt)
-            ds = p * (dp - delta_t[..., None]) * sm_scale
-            dk = jnp.einsum("bhqk,bhqd->bhkd", ds, qt)
-            return jnp.einsum("bhqk,bhkd->bhqd", ds, kt), dk, dv
+            dv = product("bhqk,bhqd->bhkd", p.astype(gt.dtype), gt)
+            dp = product("bhqd,bhkd->bhqk", gt, vt)
+            ds = (p * (dp - delta_t[..., None]) * sm_scale).astype(qt.dtype)
+            dk = product("bhqk,bhqd->bhkd", ds, qt)
+            return product("bhqk,bhkd->bhqd", ds, kt), dk, dv
 
         if live.all():
-            qf = q.astype(acc_t)
-            gf = g.astype(acc_t)
-            of = o.astype(acc_t)
-            delta = jnp.sum(of * gf, axis=-1)                  # (b,h,lq)
-
-            kb = k.reshape(b, h, nkb, block_k, d).astype(acc_t)
-            vb = v.reshape(b, h, nkb, block_k, d).astype(acc_t)
+            kb = k.reshape(b, h, nkb, block_k, d)
+            vb = v.reshape(b, h, nkb, block_k, d)
             q_pos = jnp.arange(lq)[:, None]
 
             def step(dq, idx):
                 k_pos = idx * block_k + jnp.arange(block_k)[None, :]
                 dq_part, dk, dv = tile_grads(
-                    qf, gf, delta, lse, kb[:, :, idx], vb[:, :, idx],
+                    q, g, delta, lse, kb[:, :, idx], vb[:, :, idx],
                     q_pos, k_pos)
                 return dq + dq_part, (dk, dv)
 
-            dq0 = jnp.zeros_like(qf)
+            dq0 = jnp.zeros(q.shape, acc_t)
             dq, (dks, dvs) = jax.lax.scan(step, dq0, jnp.arange(nkb))
             dk = jnp.moveaxis(dks, 0, 2).reshape(b, h, lk, d)
             dv = jnp.moveaxis(dvs, 0, 2).reshape(b, h, lk, d)
         else:
-            delta = jnp.sum(o.astype(acc_t) * g.astype(acc_t), axis=-1)
             # K tile by K tile, so that a K tile's gradient is finished
             # before the next one's begins
             pairs = jnp.asarray(_np.argwhere(live.T)[:, ::-1], jnp.int32)
@@ -506,11 +777,9 @@ def _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
                 dq, dk, dv = carry
                 q0, k0 = pair[0] * block_q, pair[1] * block_k
                 dq_part, dk_part, dv_part = tile_grads(
-                    rows(q, q0, block_q).astype(acc_t),
-                    rows(g, q0, block_q).astype(acc_t),
+                    rows(q, q0, block_q), rows(g, q0, block_q),
                     rows(delta, q0, block_q), rows(lse, q0, block_q),
-                    rows(k, k0, block_k).astype(acc_t),
-                    rows(v, k0, block_k).astype(acc_t),
+                    rows(k, k0, block_k), rows(v, k0, block_k),
                     q0 + jnp.arange(block_q)[:, None],
                     k0 + jnp.arange(block_k)[None, :])
                 return (add_rows(dq, dq_part, q0), add_rows(dk, dk_part, k0),
@@ -526,7 +795,11 @@ def _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
 # public op with custom vjp
 # --------------------------------------------------------------------------
 @functools.lru_cache(maxsize=None)
-def _make_flash(causal, sm_scale_key, mask=None):
+def _make_flash(causal, sm_scale_key, mask=None, sharded=None):
+    """The op for one static configuration.  ``sharded`` is the
+    ``batch_sharded`` scope the call was traced under: the backward is
+    traced after that scope has closed (``value_and_grad`` transposes once
+    the forward has returned), so it is kept here and not read again."""
     import jax
     import jax.numpy as jnp
 
@@ -538,7 +811,7 @@ def _make_flash(causal, sm_scale_key, mask=None):
 
     def _dispatch_fwd(q, k, v):
         if _use_pallas(q):
-            o, lse = _fa_forward(q, k, v, causal, sm_scale, mask)
+            o, lse = _fa_forward(q, k, v, causal, sm_scale, mask, sharded)
         else:
             o, lse = _mha_with_lse(q, k, v, causal, sm_scale, mask)
         return o, (q, k, v, o, lse)
@@ -549,8 +822,8 @@ def _make_flash(causal, sm_scale_key, mask=None):
 
     def bwd(res, g):
         q, k, v, o, lse = res
-        return _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
-                                      mask=mask)
+        return _fa_backward(q, k, v, o, lse, g, causal, sm_scale, mask,
+                            sharded)
 
     flash.defvjp(fwd, bwd)
     return flash
@@ -577,7 +850,8 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, mask=None,
         rep = hq // hkv
         k = jnp.repeat(k, rep, axis=1)
         v = jnp.repeat(v, rep, axis=1)
-    fn = _make_flash(bool(causal), float(sm_scale), mask)
+    fn = _make_flash(bool(causal), float(sm_scale), mask,
+                     getattr(_SCOPE, "value", None))
     return fn(q, k, v)
 
 

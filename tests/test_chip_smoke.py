@@ -209,6 +209,54 @@ def test_sharded_step_runs_the_kernel_per_batch_shard(toy_bert, monkeypatch):
         o, fa._mha_reference(q, k, v, False, 0.125), atol=2e-6)
 
 
+def test_sharded_backward_runs_the_kernel_per_batch_shard(monkeypatch):
+    """The backward is traced after ``batch_sharded`` has closed (the
+    transpose follows the forward's return), so the op keeps the scope it
+    was called under: lowered for the TPU over a dp mesh, the gradient
+    holds both kernels inside shard_maps; interpreted on the virtual mesh,
+    the sharded backward kernel agrees with autodiff through the plain
+    path and is the path counted."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.ops import flash_attention as fa
+    from mxnet_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(fa, "_use_pallas", lambda q: q.shape[-2] >= 256)
+    mesh = make_mesh(devices=jax.devices()[:4])
+    rs = np.random.RandomState(1)
+    q, k, v, g = (jnp.asarray(rs.randn(4, 2, 256, 64).astype("f")).astype(
+        "bfloat16") for _ in range(4))
+
+    def grads(attend):
+        return jax.jit(jax.grad(
+            lambda q, k, v: jnp.sum(attend(q, k, v) * g), (0, 1, 2)))
+
+    def sharded(q, k, v):
+        with fa.batch_sharded(mesh, ("dp",)):
+            return fa.flash_attention(q, k, v, causal=True)
+
+    def taken():
+        return {s["labels"]["path"]: s["value"] for s in telemetry.snapshot()[
+            "metrics"]["mxnet_flash_attention_bwd_calls_total"]["samples"]}
+
+    text = grads(sharded).trace(q, k, v).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("sdy.manual_computation") == 2
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == 2
+    before = taken()
+    with pltpu.force_tpu_interpret_mode():
+        got = grads(sharded)(q, k, v)
+    assert taken()["pallas"] == before["pallas"] + 1
+    want = grads(lambda q, k, v: fa._mha_reference(q, k, v, True, 0.125))(
+        *(x.astype("float32") for x in (q, k, v)))
+    # bf16 against the float32 answer on the same inputs: p, ds and the
+    # results are rounded (tests/test_flash_attention_bwd.py)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a.astype("float32"), b, rtol=2 ** -6,
+                                   atol=2 ** -5)
+
+
 def test_compile_cache_placement(monkeypatch, tmp_path):
     import os
 

@@ -1,7 +1,7 @@
-"""The Pallas attention forward, compiled at real widths for a TPU v5e that
-is described and not attached: what the chip's compiler refuses (an operand
-type, a slice off the tiling, too much VMEM) the TPU interpreter of
-``test_chip_smoke.py`` lets through.  Nothing runs, so nothing here is a
+"""The Pallas attention forward and backward, compiled at real widths for a
+TPU v5e that is described and not attached: what the chip's compiler refuses
+(an operand type, a slice off the tiling, too much VMEM) the TPU interpreter
+of ``test_chip_smoke.py`` lets through.  Nothing runs, so nothing here is a
 time or a result.  All of these stay in this one file: the worker that is
 given it is the only one that loads the TPU's library."""
 import functools
@@ -99,4 +99,37 @@ def test_masked_flash_backward_compiles_for_v5e(one_chip):
                              sharding=one_chip)
     lse = jax.ShapeDtypeStruct((2, 32, 8192), "float32", sharding=one_chip)
     compiled = jax.jit(bwd).lower(x, x, x, x, lse, x).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("shape,dim,dtype,causal,block", [
+    # BERT-base as the benchmark's cell runs it: a head is one tile pair
+    ((16, 12, 512, 512), 64, "bfloat16", False, None),
+    ((16, 12, 512, 512), 64, "float32", False, None),
+    # the decoder cell's own call: 80 live pairs of 256 a head, the row of
+    # dq (4 MiB in float32) resident, the VMEM limit stated by the call
+    ((2, 32, 8192, 8192), 128, "bfloat16", False, 4),
+    # causal: the lower triangle's pairs, and lq < lk with its offset
+    ((2, 16, 2048, 2048), 128, "bfloat16", True, None),
+    ((2, 16, 256, 512), 128, "bfloat16", True, None),
+])
+def test_flash_backward_kernel_compiles_for_v5e(one_chip, shape, dim, dtype,
+                                                causal, block):
+    """The Pallas backward: a transposed narrow operand, a transposed
+    float32 accumulator, a branch on a prefetched flag and bf16 operands at
+    ``DEFAULT`` are all the chip's compiler's to accept or refuse.  Under
+    the mask it needs no more scratch in HBM than the scan's gigabyte."""
+    from mxnet_tpu.ops.flash_attention import (BLOCK_DIFFUSION,
+                                               _fa_backward_pallas)
+
+    b, h, lq, lk = shape
+    bwd = functools.partial(
+        _fa_backward_pallas, causal=causal, sm_scale=dim ** -0.5,
+        mask=(BLOCK_DIFFUSION, block) if block else None)
+    q = jax.ShapeDtypeStruct((b, h, lq, dim), dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, h, lk, dim), dtype, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((b, h, lq), "float32", sharding=one_chip)
+    compiled = jax.jit(bwd).lower(q, kv, kv, q, lse, q).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "mxnet_flash_attention_bwd" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

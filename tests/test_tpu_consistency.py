@@ -284,8 +284,9 @@ def test_flash_attention_pallas_block_diffusion_forward(length, block, heads,
 ])
 def test_flash_attention_pallas_block_diffusion_grads(length, heads, kv_heads,
                                                       dim, dtype):
-    """Kernel forward + blockwise backward against autodiff through the
-    plain path under the same mask."""
+    """Kernel forward + kernel backward (bf16: one tile pair a head at
+    length 128, the live pairs of 16 at 1024; float32 takes the scan)
+    against autodiff through the plain path under the same mask."""
     import jax
     import jax.numpy as jnp
 
@@ -319,6 +320,53 @@ def test_flash_attention_pallas_block_diffusion_grads(length, heads, kv_heads,
         error = np.linalg.norm(gf - gr) / np.linalg.norm(gr)
         print(f"d{name}: relative error {error:.4g}")
         assert error < 2e-2, (name, error)
+
+
+# the backward kernel at the shapes it has to hold: full attention as BERT
+# runs it (a head is one tile pair, head 64), causal over several tiles at
+# head 128, causal with lq < lk (the diagonal moved), full over 16 pairs.
+# Called by itself: the gate sends float32 inputs to the scan (the slower
+# path for them is the kernel), and the kernel still has to be right there
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("lq,lk,heads,dim,causal", [
+    (512, 512, 12, 64, False),
+    (2048, 2048, 4, 128, True),
+    (256, 512, 4, 128, True),
+    (2048, 2048, 2, 64, False),
+])
+def test_flash_attention_pallas_backward_kernel(lq, lk, heads, dim, causal,
+                                                dtype):
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.flash_attention import (_fa_backward_pallas,
+                                               _fa_forward_pallas,
+                                               _mha_reference,
+                                               _use_pallas_bwd)
+
+    q, k, v = _flash_inputs(dtype, (2, heads, lq, dim), (2, heads, lk, dim))
+    assert _use_pallas_bwd(q, k) == (dtype != "float32")
+    g = jnp.asarray(_R.randn(2, heads, lq, dim).astype("f")).astype(dtype)
+    scale = 1.0 / np.sqrt(dim)
+
+    @jax.jit
+    def kernels(q, k, v, g):
+        o, lse = _fa_forward_pallas(q, k, v, causal, scale)
+        return _fa_backward_pallas(q, k, v, o, lse, g, causal, scale)
+
+    g_flash = kernels(q, k, v, g)
+    g_ref = jax.grad(lambda q, k, v: (
+        _mha_reference(q, k, v, causal, scale).astype(jnp.float32)
+        * g.astype(jnp.float32)).sum(), argnums=(0, 1, 2))(q, k, v)
+    # the norm of the difference against the norm: float32 inputs keep
+    # float32 operands at the process's precision (1e-4: sums in another
+    # order over up to 2,048 keys); bf16 rounds p, ds and the results (2%)
+    for name, gf, gr in zip("qkv", g_flash, g_ref):
+        assert gf.dtype == q.dtype
+        gf, gr = np.asarray(gf, "f"), np.asarray(gr, "f")
+        error = np.linalg.norm(gf - gr) / np.linalg.norm(gr)
+        print(f"d{name}: relative error {error:.4g}")
+        assert error < (1e-4 if dtype == "float32" else 2e-2), (name, error)
 
 
 def test_trainstep_bf16_on_tpu():
