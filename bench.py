@@ -202,54 +202,6 @@ def bench_bert(on_tpu):
 
 
 def bench_llama(on_tpu):
-    """On TPU (and unless MXNET_BENCH_SWEEP=0) this sweeps flash-attention
-    block sizes — the tune PERF_NOTES flagged as needing a chip run — and
-    headlines the best (block config reported in extras)."""
-    import os
-    import sys
-
-    sweep = os.environ.get("MXNET_BENCH_SWEEP", "1") != "0"
-    force = os.environ.get("MXNET_BENCH_FORCE_SWEEP", "0") == "1"
-    explicit = ("MXNET_FLASH_BLOCK_Q" in os.environ
-                or "MXNET_FLASH_BLOCK_KV" in os.environ)
-    if explicit:
-        # user pinned a config: measure EXACTLY that, touch nothing
-        bq = int(os.environ.get("MXNET_FLASH_BLOCK_Q", 128))
-        bkv = int(os.environ.get("MXNET_FLASH_BLOCK_KV", 128))
-        tok, mfu = _bench_llama_once(on_tpu)
-        key = f"q{bq}_kv{bkv}"
-        return tok, mfu, {"flash_blocks": {key: {
-            "value": round(tok, 2), "mfu": round(mfu, 4)}}, "best": key}
-    grid = [(128, 128)]
-    if (on_tpu or force) and sweep:
-        grid += [(256, 256), (256, 512), (512, 512)]
-    results, errors = {}, {}
-    last_exc = None
-    for bq, bkv in grid:
-        os.environ["MXNET_FLASH_BLOCK_Q"] = str(bq)
-        os.environ["MXNET_FLASH_BLOCK_KV"] = str(bkv)
-        try:
-            results[f"q{bq}_kv{bkv}"] = _bench_llama_once(on_tpu)
-        except Exception as e:
-            print(f"bench: llama blocks ({bq},{bkv}) failed ({e!r})",
-                  file=sys.stderr)
-            errors[f"q{bq}_kv{bkv}"] = repr(e)[:200]
-            last_exc = e
-    os.environ.pop("MXNET_FLASH_BLOCK_Q", None)
-    os.environ.pop("MXNET_FLASH_BLOCK_KV", None)
-    if not results:
-        raise last_exc  # the real root cause reaches BENCH.json's error
-    best = max(results, key=lambda k: results[k][0])
-    tok, mfu = results[best]
-    cfgs = {k: {"value": round(v[0], 2), "mfu": round(v[1], 4)}
-            for k, v in results.items()}
-    # failed configs stay visible, distinguishable from never-swept ones
-    for k, err in errors.items():
-        cfgs[k] = {"error": err}
-    return tok, mfu, {"flash_blocks": cfgs, "best": best}
-
-
-def _bench_llama_once(on_tpu):
     import mxnet_tpu as mx
     from mxnet_tpu.gluon.model_zoo.language import llama
     from mxnet_tpu.parallel.data_parallel import TrainStep
@@ -564,129 +516,6 @@ def _bench_zero_optimizer_bytes(dp):
             os.environ.pop("MXNET_ZERO", None)
         else:
             os.environ["MXNET_ZERO"] = prev
-
-
-def bench_graph():
-    """Graph compiler (ISSUE 11): pass-pipeline one-time cost, measured
-    fused-op count, and step-time A/B (pipeline on vs off) on (a) the
-    llama proxy through TrainStep and (b) a deep elementwise-chain
-    microbench — the workload whose dispatch graph the fusion pass
-    collapses hardest."""
-    import time
-
-    import jax
-    import numpy as np
-
-    import mxnet_tpu as mx
-    from mxnet_tpu import autograd, nd
-    from mxnet_tpu import graph as G
-    from mxnet_tpu.gluon import HybridBlock, nn
-    from mxnet_tpu.gluon.model_zoo.language import llama
-    from mxnet_tpu.parallel.data_parallel import TrainStep
-
-    out = {}
-
-    # -- (a) deep elementwise-chain microbench ----------------------------
-    class Chain(HybridBlock):
-        def __init__(self, depth=24, **kw):
-            super().__init__(**kw)
-            self.depth = depth
-            with self.name_scope():
-                self.fc = nn.Dense(128, in_units=64)
-
-        def hybrid_forward(self, F, x):
-            h = self.fc(x)
-            for _ in range(self.depth):
-                h = F.tanh(h * 0.5 + 0.125)
-            return h
-
-    def chain_arm(flag, prefix, iters=60):
-        mx.random.seed(0)
-        np.random.seed(0)
-        net = Chain(prefix=prefix)
-        net.initialize()
-        net.hybridize()
-        x = nd.array(np.random.RandomState(1).randn(16, 64).astype("f"))
-        with G.override_enabled(flag):
-            t0 = time.perf_counter()
-            net(x).asnumpy()                      # build
-            build_s = time.perf_counter() - t0
-            for _ in range(5):
-                net(x).asnumpy()                  # warm
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                y = net(x)
-            y.asnumpy()
-            step_ms = (time.perf_counter() - t0) / iters * 1e3
-        fused = 0
-        for ir in getattr(net, "_cached_graph_ir", {}).values():
-            fused += ir.fused_op_count()
-        return {"build_s": round(build_s, 3),
-                "forward_ms": round(step_ms, 3), "fused_ops": fused}
-
-    G.reset_stats()
-    raw = chain_arm(False, "graw_")
-    opt = chain_arm(True, "gopt_")
-    stats = G.stats_snapshot()
-    pipeline_s = sum(p["seconds"] for p in stats["passes"].values())
-    out["elemwise_chain"] = {
-        "optimized": opt, "raw": raw,
-        "pipeline_one_time_s": round(pipeline_s, 4),
-        "speedup": round(raw["forward_ms"] / opt["forward_ms"], 3)
-        if opt["forward_ms"] else 0.0,
-    }
-
-    # -- (b) llama proxy through TrainStep (the functionalize seam) -------
-    cfg = dict(vocab_size=512, hidden_size=128, num_layers=2, num_heads=4,
-               num_kv_heads=2, intermediate_size=256, max_seq_len=64)
-    ids = np.random.RandomState(0).randint(
-        0, cfg["vocab_size"], (2, 64)).astype("int32")
-    labels = np.random.RandomState(1).randint(
-        0, cfg["vocab_size"], (2, 64)).astype("int32")
-
-    def loss_fn(logits, y):
-        import jax.numpy as jnp
-
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        return -jnp.take_along_axis(logp, y[..., None], axis=-1)
-
-    def llama_arm(flag, iters=12):
-        mx.random.seed(0)
-        np.random.seed(0)
-        net = llama.LlamaForCausalLM(llama.LlamaConfig(**cfg))
-        net.initialize()
-        net(mx.nd.zeros((1, 64), dtype="int32"))
-        step = TrainStep(net, loss_fn, optimizer="adam",
-                         optimizer_params={"learning_rate": 3e-4})
-        G.reset_stats()
-        with G.override_enabled(flag):
-            t0 = time.perf_counter()
-            step(ids, labels)                     # build
-            build_s = time.perf_counter() - t0
-            for _ in range(3):
-                float(step(ids, labels))          # warm (sync each)
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                loss = step(ids, labels)
-            float(loss)
-            step_ms = (time.perf_counter() - t0) / iters * 1e3
-        snap = G.stats_snapshot()
-        return {"build_s": round(build_s, 2),
-                "step_ms": round(step_ms, 2),
-                "fused_ops": snap["fused_ops_created"],
-                "pipeline_one_time_s": round(
-                    sum(p["seconds"] for p in snap["passes"].values()), 4),
-                "fallbacks": snap["fallbacks"]}
-
-    l_raw = llama_arm(False)
-    l_opt = llama_arm(True)
-    out["llama_proxy"] = {
-        "optimized": l_opt, "raw": l_raw,
-        "speedup": round(l_raw["step_ms"] / l_opt["step_ms"], 3)
-        if l_opt["step_ms"] else 0.0,
-    }
-    out["fused_op_count"] = opt["fused_ops"] + l_opt["fused_ops"]
-    return out
 
 
 def bench_planner():
@@ -1565,9 +1394,6 @@ def bench_tune(workloads=None, rungs=2, budget0=2, serving=False):
       tensors x 32KiB), the measured win/loss crossover from
       bench_overlap: per-key (cap 0) pays 16 collective launches where
       one fused bucket pays 1.
-    - ``graph_fuse_cap`` — the deep elementwise-chain microbench from
-      bench_graph, rebuilt per trial so the pass pipeline re-runs
-      under the candidate cap.
     - ``prefetch_buffer`` — an input-bound producer/consumer pipeline
       (~1 ms host work per side); depth overlaps them.
 
@@ -1580,10 +1406,7 @@ def bench_tune(workloads=None, rungs=2, budget0=2, serving=False):
     import numpy as np
 
     import jax
-    import mxnet_tpu as mx
     from mxnet_tpu import nd, telemetry, tuning
-    from mxnet_tpu import graph as G
-    from mxnet_tpu.gluon import HybridBlock, nn
     from mxnet_tpu.parallel import bucketing
     from mxnet_tpu.parallel.collectives import allreduce_hosts
 
@@ -1625,45 +1448,6 @@ def bench_tune(workloads=None, rungs=2, budget0=2, serving=False):
         once()                              # warm every jit path
         return timed_step(once, budget)
 
-    # -- graph_fuse_cap: deep elementwise chain ---------------------------
-    class Chain(HybridBlock):
-        def __init__(self, depth=24, **kw):
-            super().__init__(**kw)
-            self.depth = depth
-            with self.name_scope():
-                self.fc = nn.Dense(128, in_units=64)
-
-        def hybrid_forward(self, F, x):
-            h = self.fc(x)
-            for _ in range(self.depth):
-                h = F.tanh(h * 0.5 + 0.125)
-            return h
-
-    chain_seq = [0]
-
-    def measure_fuse(value, budget):
-        # a fresh net per trial: the fusion pass reads the cap at
-        # pipeline time, and a cached optimized graph would measure
-        # the previous trial's cap
-        chain_seq[0] += 1
-        mx.random.seed(0)
-        np.random.seed(0)
-        net = Chain(prefix=f"tunechain{chain_seq[0]}_")
-        net.initialize()
-        net.hybridize()
-        x = nd.array(np.random.RandomState(1).randn(16, 64).astype("f"))
-        with G.override_enabled(True):
-            net(x).asnumpy()                # build under the trial cap
-            for _ in range(3):
-                net(x).asnumpy()
-
-            def once():
-                for _ in range(10):
-                    y = net(x)
-                y.asnumpy()
-
-            return timed_step(once, budget)
-
     # -- prefetch_buffer: input-bound producer/consumer pipeline ----------
     def measure_prefetch(value, budget):
         from mxnet_tpu.gluon.data.prefetcher import PrefetchIterator
@@ -1686,8 +1470,6 @@ def bench_tune(workloads=None, rungs=2, budget0=2, serving=False):
 
     measures = {
         "allreduce_bucket_mb": (measure_bucket, bucket_sig, "s/step"),
-        "graph_fuse_cap": (measure_fuse,
-                           ("elemwise_chain", 24, 16, 64), "s/step"),
         "prefetch_buffer": (measure_prefetch,
                             ("prefetch_pipeline", 8), "s/batch"),
     }
@@ -1822,10 +1604,10 @@ def main():
     except Exception as e:  # keep the headline alive
         extra["bert_base_pretrain"] = {"error": repr(e)[:200]}
     try:
-        llama_s, llama_mfu, llama_cfgs = bench_llama(on_tpu=True)
+        llama_s, llama_mfu = bench_llama(on_tpu=True)
         extra["llama_proxy_train"] = {
             "value": round(llama_s, 2), "unit": "tokens/s/chip",
-            "mfu": round(llama_mfu, 4), **llama_cfgs}
+            "mfu": round(llama_mfu, 4)}
     except Exception as e:
         extra["llama_proxy_train"] = {"error": repr(e)[:200]}
     try:
@@ -1856,13 +1638,6 @@ def main():
         extra["planner"] = bench_planner()
     except Exception as e:
         extra["planner"] = {"error": repr(e)[:200]}
-    try:
-        # graph compiler (ISSUE 11): pass-pipeline one-time cost,
-        # measured fused-op count, and optimized-vs-raw step time on
-        # the llama proxy + a deep elementwise-chain microbench
-        extra["graph"] = bench_graph()
-    except Exception as e:
-        extra["graph"] = {"error": repr(e)[:200]}
     try:
         # zero-downtime elasticity (ISSUE 13): restart-to-first-step
         # cold vs warm (compile cache), live ZeRO reshard vs checkpoint

@@ -37,10 +37,6 @@
 #               budgets, visualize_sharding round trip through the
 #               telemetry snapshot, planner-vs-legacy TrainStep
 #               trajectory bit-identity) + the planner unit suite
-#   graph       graph-compiler smoke (pipeline idempotence across
-#               processes, bit-parity on the CPU mesh with the pipeline
-#               on vs off, fused-op count asserted, raw-vs-optimized
-#               trace counts) + the graph unit suite
 #   serving     inference-engine smoke (AOT warmup, 100 concurrent
 #               mixed-length HTTP requests with ZERO fresh traces,
 #               completions bit-matching the full-context forward,
@@ -189,19 +185,6 @@ case "$LANE" in
     #    its own (~30s)
     JAX_PLATFORMS=cpu python -m pytest -q tests/test_planner.py
     ;;
-  graph)
-    # 1) end-to-end smoke through the PUBLIC surface (ISSUE 11): deep
-    #    elementwise-chain model fuses (count asserted), optimized
-    #    5-step trajectory bit-matches raw, optimized-graph digest is
-    #    identical across two fresh processes, steady state performs
-    #    zero fresh traces
-    JAX_PLATFORMS=cpu python ci/graph_smoke.py
-    # 2) the unit suite (IR round trips, per-pass bit-parity fixtures,
-    #    knobs, fallback, serving/export integration).  The unit lane
-    #    also runs this file; the repeat is deliberate — the graph
-    #    stage must stay green/triagable on its own (~15s)
-    JAX_PLATFORMS=cpu python -m pytest -q tests/test_graph.py
-    ;;
   serving)
     # 1) end-to-end smoke through the PUBLIC surface: engine + HTTP on a
     #    free port, 4 concurrent clients x 25 mixed-length requests with
@@ -238,7 +221,7 @@ case "$LANE" in
     python bench.py | tee BENCH.json
     ;;
   *)
-    echo "unknown lane: $LANE (lint|unit|tpu|dist|chaos|telemetry|overlap|planner|graph|serving|tuning|sanity|nightly|bench)" >&2
+    echo "unknown lane: $LANE (lint|unit|tpu|dist|chaos|telemetry|overlap|planner|serving|tuning|sanity|nightly|bench)" >&2
     exit 2
     ;;
 esac

@@ -34,8 +34,8 @@ Wired vars (read at ``import mxnet_tpu``):
   (default ``spawn``; ``fork`` is an explicit opt-in — the parent is
   always multi-threaded and fork can deadlock children on inherited
   locks).
-- ``MXNET_BENCH_FORCE_SWEEP``: run the TPU-gated bench sweep branches
-  (resnet config sweep, flash-block grid) on CPU too, so the sweep and
+- ``MXNET_BENCH_FORCE_SWEEP``: run the TPU-gated bench sweep branch
+  (resnet config sweep) on CPU too, so the sweep and
   headline-selection code paths are exercised before first chip contact.
 - ``MXNET_FAULT_SPEC``: deterministic fault injection —
   ``<seam>:fail[:times[:Error]]``, comma-separated (e.g.
@@ -198,10 +198,6 @@ Wired vars (read at ``import mxnet_tpu``):
   jax upgrade" item is now this one flag).
 - ``MXNET_PLANNER_REPORT``: print the planner's ``visualize_sharding``
   report whenever a plan is computed (default 0).
-- ``MXNET_GRAPH_PIPELINE``: graph-compiler pass pipeline between the
-  traced (hybridized) graph and jit lowering (default 1; see
-  :mod:`mxnet_tpu.graph` and README "Graph compiler").  0 = every
-  consumer runs the raw traced program.
 - ``MXNET_GRAPH_PASSES``: comma-separated graph-pass selection; plain
   names replace the default list, ``-name`` entries subtract from it
   (unset = the default catalog).
@@ -469,12 +465,6 @@ def planner_report():
     return get_bool("MXNET_PLANNER_REPORT", False)
 
 
-def graph_pipeline():
-    """Graph-compiler pass pipeline on the hybridize/TrainStep/serving
-    trace seam (MXNET_GRAPH_PIPELINE, default on; mxnet_tpu/graph)."""
-    return get_bool("MXNET_GRAPH_PIPELINE", True)
-
-
 def graph_passes():
     """Graph-pass selection spec (MXNET_GRAPH_PASSES; unset = default
     catalog, "-name" subtracts — parsed by graph.selected_pass_names)."""
@@ -733,10 +723,6 @@ def describe():
         ("MXNET_CPU_WORKER_NTHREADS", "decode/augment pool width"),
         ("MXNET_PROFILER_AUTOSTART", "start profiler at import"),
         ("MXNET_KVSTORE_BIGARRAY_BOUND", "dist kvstore bucket threshold"),
-        ("MXNET_FLASH_BLOCK_Q", "flash-attention q tile (unset: from the "
-         "call's shape)"),
-        ("MXNET_FLASH_BLOCK_KV", "flash-attention kv tile (unset: from the "
-         "call's shape)"),
         ("MXNET_COORDINATOR_ADDRESS", "jax.distributed coordinator"),
         ("MXNET_TEST_TPU", "real-chip test lane"),
         ("MXNET_EAGER_JIT", "eager jit-cache fast path (default 1; "
@@ -881,9 +867,6 @@ def describe():
          "workaround (default 0; flip after a jax upgrade)"),
         ("MXNET_PLANNER_REPORT", "print the visualize_sharding report "
          "at plan time (default 0)"),
-        ("MXNET_GRAPH_PIPELINE", "graph-compiler pass pipeline on the "
-         "hybridize/TrainStep/serving trace seam (default 1; "
-         "mxnet_tpu/graph)"),
         ("MXNET_GRAPH_PASSES", "graph-pass selection (csv; \"-name\" "
          "subtracts from the default catalog; unset = defaults)"),
         ("MXNET_GRAPH_FUSE_CAP", "max ops per fused elementwise chain "

@@ -486,28 +486,17 @@ class HybridBlock(Block):
             return tuple(outs + state_vals)
 
         jitted = jax.jit(fn, static_argnums=())
-        # run an abstract trace to discover state updates & output arity
+        # the jit's own abstract trace discovers state updates and output
+        # arity, and the first call finds it cached: forward runs once
         in_vals = [a._get() if isinstance(a, NDArray) else a for a in args]
         param_vals = [p.data()._get() for p in params_list]
         from jax import random as _jr
 
-        ref_avals = jax.eval_shape(fn, param_vals, _jr.PRNGKey(0), *in_vals)
+        jitted.eval_shape(param_vals, _jr.PRNGKey(0), *in_vals)
         state_params = state_params_box[0]
         n_state = len(state_params)
         self._cached_state_params[key] = state_params
         self._cached_single[key] = single_box[0]
-
-        # graph-compiler tier (ISSUE 11): re-trace forward into the typed
-        # graph IR, run the pass pipeline, and jit the optimized replay
-        # instead of the raw op-by-op program.  Any trace/validation
-        # failure falls back to the imperative jit above — correctness
-        # never depends on the optimizer.
-        graph_kind = "raw"
-        opt_jitted = self._build_graph_entry(
-            params_list, args, state_params, single_box[0], ref_avals, key)
-        if opt_jitted is not None:
-            jitted = opt_jitted
-            graph_kind = "optimized"
         entry = (jitted, params_list, n_state)
         self._cached_graph[key] = entry
         from .. import telemetry as _telemetry
@@ -515,66 +504,8 @@ class HybridBlock(Block):
         _telemetry.compile_event(
             "block", getattr(self, "name", type(self).__name__) or
             type(self).__name__,
-            _time.perf_counter() - _compile_t0, _compile_cause,
-            graph=graph_kind)
+            _time.perf_counter() - _compile_t0, _compile_cause)
         return entry
-
-    def _build_graph_entry(self, params_list, args, state_params, single,
-                           ref_avals, key):
-        """Trace -> optimize -> validate -> jit.  Returns the jitted
-        optimized executor, or None (with a ``graph:fallback`` compile
-        event) when this forward cannot ride the graph tier."""
-        import time as _time
-
-        import jax
-        import numpy as _np2
-
-        from .. import graph as _graph
-        from .. import telemetry as _telemetry
-
-        if not _graph.enabled() or \
-                not all(isinstance(a, NDArray) for a in args):
-            return None
-        t0 = _time.perf_counter()
-        try:
-            plist = sorted(self.collect_params().items())
-            if [p for _, p in plist] != list(params_list):
-                raise MXNetError("graph tier: parameter order drifted")
-            input_avals = [jax.ShapeDtypeStruct(
-                tuple(a.shape), _np2.dtype(a.dtype)) for a in args]
-            g = _graph.trace_block(self, plist, input_avals,
-                                   train_mode=_ag.is_training())
-            # the traced state heads must target the SAME parameters, in
-            # the same order, as the imperative trace discovered
-            name_of = {id(p): n for n, p in plist}
-            if [name_of[id(p)] for p in state_params] != \
-                    [n for n, _ in g.state]:
-                raise MXNetError("graph tier: state write-back mismatch")
-            if g.single != single:
-                raise MXNetError("graph tier: output arity mismatch")
-            opt = _graph.default_pipeline().run(g)
-            gfn = _graph.make_block_fn(opt)
-            param_vals = [p.data()._get() for p in params_list]
-            in_vals = [a._get() for a in args]
-            got = jax.eval_shape(gfn, param_vals, jax.random.PRNGKey(0),
-                                 *in_vals)
-            ref = ref_avals if isinstance(ref_avals, (tuple, list)) \
-                else (ref_avals,)
-            if [(tuple(a.shape), str(a.dtype)) for a in got] != \
-                    [(tuple(a.shape), str(a.dtype)) for a in ref]:
-                raise MXNetError("graph tier: output aval mismatch")
-            if not hasattr(self, "_cached_graph_ir"):
-                self._cached_graph_ir = {}
-            self._cached_graph_ir[key] = opt
-            return jax.jit(gfn)
-        except Exception as e:
-            _graph.record_fallback()
-            _telemetry.compile_event(
-                "graph", getattr(self, "name", type(self).__name__) or
-                type(self).__name__,
-                _time.perf_counter() - t0, "fallback",
-                reason=repr(e)[:200])
-            return None
 
     def _trace_to_symbol(self, *args):
         """Trace ``forward`` with SymbolTracer proxies → (Symbol, arg_params,
@@ -718,12 +649,9 @@ class SymbolBlock(HybridBlock):
     def _optimized_heads(self):
         """Graph-tier heads: the loaded symbol run through the pass
         pipeline once per cache version (serving's SymbolBlock path runs
-        the optimized graph too).  Pipeline off or unoptimizable -> the
-        raw heads."""
+        the optimized graph too).  Unoptimizable -> the raw heads."""
         from .. import graph as _graph
 
-        if not _graph.enabled():
-            return self._sym._heads
         ent = getattr(self, "_opt_heads_entry", None)
         if ent is not None and ent[0] == self._cache_version:
             return ent[1]

@@ -244,8 +244,8 @@ class LlamaDecoderLayer(HybridBlock):
 
             xv = x._get() if isinstance(x, NDArray) else x
             if isinstance(xv, jax.core.Tracer):
-                # under a jax trace (TrainStep's fused step, or any
-                # jax.jit/grad over the functionalized net): checkpoint the
+                # under a jax trace (TrainStep's fused step, hybridize()'s
+                # cached op, any jax.jit/grad over the net): checkpoint the
                 # whole layer — closed-over parameter tracers differentiate
                 # normally, activations are recomputed in backward
                 # what the layer gives to telemetry.step_scalar leaves the
@@ -263,29 +263,19 @@ class LlamaDecoderLayer(HybridBlock):
                 for name, values in scalars.items():
                     _telemetry.step_scalar(name, values)
                 return NDArray._from_jax(out, getattr(x, "context", None))
-            # eager tape (autograd.record) and hybridize() both lack a
-            # remat node — warn rather than silently skipping the memory
-            # saving the user asked for
+            # the eager tape (autograd.record) and export()'s symbolic
+            # trace have no remat node: warn rather than silently skipping
+            # the memory saving the user asked for
             from .... import autograd as _ag
-            from ....symbol.symbol import _TRACE_OBSERVER
 
-            if type(x).__name__ == "SymbolTracer" \
-                    and _TRACE_OBSERVER[0] is not None:
-                # the graph tier's trace (functionalize, the cached op): a
-                # flat list of ops has no node for a checkpoint.  Raising
-                # sends the caller to its imperative jit trace, where the
-                # branch above applies
-                raise MXNetError(
-                    "LlamaConfig(remat=True): the graph tier cannot express "
-                    "a per-layer checkpoint; the imperative trace can")
             if type(x).__name__ == "SymbolTracer" or _ag.is_recording():
                 import warnings
 
                 warnings.warn(
-                    "LlamaConfig(remat=True) has no effect under "
-                    "hybridize() or the eager autograd tape; use "
-                    "parallel.data_parallel.TrainStep (or jax.jit over "
-                    "the functionalized net) for rematerialized training",
+                    "LlamaConfig(remat=True) has no effect under the eager "
+                    "autograd tape or export(); hybridize() the net or use "
+                    "parallel.data_parallel.TrainStep (any jax trace of the "
+                    "net) for rematerialized training",
                     stacklevel=2)
         return self._body(x)
 

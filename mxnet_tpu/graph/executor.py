@@ -1,16 +1,15 @@
 """Replay an optimized Graph as a pure jax function.
 
-``make_block_fn(graph)`` returns the cached-op contract function
+``make_block_fn(graph)`` returns a function of the cached op's contract
 
     fn(param_vals, rng_key, *input_vals) -> tuple(outputs + state_vals)
 
-that ``HybridBlock._call_cached_op`` and ``functionalize`` jit.  The
-replay mirrors ``ndarray.invoke`` exactly — same op fns, same attr
+The replay mirrors ``ndarray.invoke`` exactly — same op fns, same attr
 filtering, the same AMP cast wrap per op, and RNG keys derived with the
-same ``fold_in(base, counter)`` scheme using the counters stamped at
-trace time — so a pipeline with no enabled passes produces a jaxpr
-numerically identical to the imperative jit trace (the bit-parity
-floor every pass builds on).
+same ``fold_in(base, counter)`` scheme, numbered in node order — so a
+pipeline with no enabled passes computes what evaluating the Symbol
+would (the bit-parity floor every pass builds on; the pass tests replay
+a graph before and after a pass through it).
 """
 from __future__ import annotations
 
@@ -26,13 +25,13 @@ def make_block_fn(graph):
     from ..ops.registry import get_op
     from ..symbol.symbol import _clean_attrs
 
-    steps = []           # (node_id, od, attrs, input_edges, rng_index)
+    steps = []           # (node_id, od, attrs, input_edges)
     for nid, node in enumerate(graph.nodes):
         if node.op is None:
             continue
         od = get_op(node.op)     # raises MXNetError for unknown ops
         steps.append((nid, od, _clean_attrs(node.attrs),
-                      tuple(node.inputs), node.rng_index))
+                      tuple(node.inputs)))
     param_ids = [nid for nid, _ in graph.params]
     input_ids = list(graph.inputs)
     out_edges = list(graph.outputs) + [e for _, e in graph.state]
@@ -59,21 +58,15 @@ def make_block_fn(graph):
         for nid, v in consts.items():
             vals[(nid, 0)] = jnp.asarray(v)
         amp_wrap = _AMP["wrap"] if _AMP["on"] else None
-        fallback_rng = 0
-        for nid, od, attrs, in_edges, rng_index in steps:
+        rng_ops = 0
+        for nid, od, attrs, in_edges in steps:
             f = functools.partial(_call_with_attrs, od.fn, attrs)
             if amp_wrap is not None:
                 f = amp_wrap(od, f)
             args = [vals[e] for e in in_edges]
             if od.needs_rng:
-                if rng_index is None:
-                    # graphs built without a trace (from_symbol) carry no
-                    # stamped counters — number sequentially in node order
-                    # (trace_block stamps every rng node, so a graph never
-                    # mixes stamped and sequential numbering)
-                    fallback_rng += 1
-                    rng_index = fallback_rng
-                args = [jax.random.fold_in(rng_key, rng_index)] + args
+                rng_ops += 1      # keys numbered in node order
+                args = [jax.random.fold_in(rng_key, rng_ops)] + args
             out = f(*args)
             outs = out if isinstance(out, (tuple, list)) else (out,)
             for i, v in enumerate(outs):

@@ -30,25 +30,21 @@ class Node:
     or an embedded constant (op=None, value=ndarray).
 
     ``inputs`` are ``(node_id, out_index)`` edges into earlier nodes.
-    ``rng_index`` is the trace-time fold_in counter for needs_rng ops —
-    pinned at trace so passes that drop or reorder nodes can never shift
-    another op's key stream.  ``avals`` is the per-output
-    ``(shape, dtype_str)`` tuple captured at trace time (None when built
-    from a shape-oblivious Symbol).
+    ``avals`` is the per-output ``(shape, dtype_str)`` tuple where whoever
+    built the graph knew it (None when built from a shape-oblivious
+    Symbol).
     """
 
-    __slots__ = ("op", "name", "attrs", "inputs", "nout", "value",
-                 "rng_index", "avals")
+    __slots__ = ("op", "name", "attrs", "inputs", "nout", "value", "avals")
 
     def __init__(self, op, name, attrs=None, inputs=(), nout=1, value=None,
-                 rng_index=None, avals=None):
+                 avals=None):
         self.op = op
         self.name = name
         self.attrs = dict(attrs or {})
         self.inputs = list(inputs)
         self.nout = nout
         self.value = value
-        self.rng_index = rng_index
         self.avals = avals
 
     @property
@@ -61,7 +57,7 @@ class Node:
 
     def clone(self):
         return Node(self.op, self.name, dict(self.attrs), list(self.inputs),
-                    self.nout, self.value, self.rng_index, self.avals)
+                    self.nout, self.value, self.avals)
 
     def __repr__(self):
         kind = self.op or ("const" if self.is_const else "var")
@@ -188,7 +184,7 @@ class Graph:
             h.update(repr((op_key, n.name if n.is_var else None,
                            sorted((k, repr(v)) for k, v in n.attrs.items()
                                   if not k.startswith("__")),
-                           n.inputs, n.nout, n.rng_index,
+                           n.inputs, n.nout,
                            None if n.value is None else
                            (n.value.shape, str(n.value.dtype),
                             _np.asarray(n.value).tobytes()))).encode())

@@ -8,9 +8,6 @@ before/after — so pipeline wins are read off telemetry, not asserted.
 
 Knobs (env.py / README "Graph compiler"):
 
-- ``MXNET_GRAPH_PIPELINE``: master switch (default 1).  Off = every
-  consumer (hybridized blocks, TrainStep, serving export) runs the
-  raw traced program.
 - ``MXNET_GRAPH_PASSES``: comma-separated pass selection.  Plain names
   replace the default list; ``-name`` entries subtract from it.
 - ``MXNET_GRAPH_FUSE_CAP``: max ops per fused elementwise chain
@@ -21,15 +18,13 @@ from __future__ import annotations
 import threading
 import time
 from collections import OrderedDict
-from contextlib import contextmanager
 
 from .. import env as _env
 from ..base import MXNetError
 
 __all__ = ["graph_pass", "list_passes", "PassPipeline", "default_pipeline",
-           "enabled", "override_enabled", "selected_pass_names",
-           "DEFAULT_PASSES", "stats_snapshot", "reset_stats",
-           "record_fallback"]
+           "selected_pass_names", "DEFAULT_PASSES", "stats_snapshot",
+           "reset_stats", "record_fallback"]
 
 PASS_REGISTRY: "OrderedDict[str, object]" = OrderedDict()
 
@@ -65,33 +60,6 @@ def list_passes():
     """Registered pass names, registration order."""
     _ensure_builtins()
     return list(PASS_REGISTRY)
-
-
-# --------------------------------------------------------------------------
-# enable / selection knobs
-# --------------------------------------------------------------------------
-_OVERRIDE = threading.local()
-
-
-def enabled():
-    """Pipeline master switch: thread-local override (tests/bench A/B)
-    over ``MXNET_GRAPH_PIPELINE`` (default on)."""
-    ov = getattr(_OVERRIDE, "value", None)
-    if ov is not None:
-        return ov
-    return _env.graph_pipeline()
-
-
-@contextmanager
-def override_enabled(flag):
-    """Force the pipeline on/off for this thread (the bench/test A/B
-    seam — flipping os.environ mid-process would race other threads)."""
-    prev = getattr(_OVERRIDE, "value", None)
-    _OVERRIDE.value = bool(flag)
-    try:
-        yield
-    finally:
-        _OVERRIDE.value = prev
 
 
 def selected_pass_names():
@@ -138,9 +106,9 @@ def _record_pass(name, before, after, dt):
 
 
 def record_fallback():
-    """A consumer tried the graph path and fell back to the imperative
-    trace (counted so 'pipeline on' that silently never runs is
-    visible in the snapshot)."""
+    """A consumer handed its Symbol to the pipeline and kept the raw one
+    (counted so a pipeline that silently never runs is visible in the
+    snapshot)."""
     with _SLOCK:
         _STATS["fallbacks"] += 1
 
@@ -148,7 +116,6 @@ def record_fallback():
 def stats_snapshot():
     with _SLOCK:
         return {
-            "enabled": enabled(),
             "pipeline_runs": _STATS["pipeline_runs"],
             "fallbacks": _STATS["fallbacks"],
             "fused_ops_created": _STATS["fused_ops_created"],

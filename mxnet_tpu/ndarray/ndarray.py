@@ -647,12 +647,6 @@ def invoke(opname, nd_args, attrs, out=None, ctx=None):
 
         if any(isinstance(a, SymbolTracer) for a in nd_args if a is not None):
             return trace_invoke(opname, nd_args, attrs)
-        if _SYMTRACE.get("rng_ops") and od.needs_rng:
-            # graph-tier trace (mxnet_tpu.graph.trace): an rng op with no
-            # tracer inputs (standalone random creation in forward) must
-            # become a graph node drawing from the per-call trace key, not
-            # execute eagerly and bake one fixed draw in as a constant
-            return trace_invoke(opname, nd_args, attrs)
     nd_args = [a for a in nd_args if a is not None]  # optional inputs omitted
     in_vals = []
     out_ctx = ctx

@@ -358,38 +358,22 @@ def _largest_tile(length):
 
 
 def _fa_block_sizes(lq, lk, d, itemsize):
-    """Forward kernel tile sizes.  The tuning funnel's answer where it has
-    one (MXNET_FLASH_BLOCK_Q / MXNET_FLASH_BLOCK_KV pins > MXNET_TUNE=1
-    stored winners; values must divide the padded sequence length), and
-    where it answers ``default``, from what the call shows.  Measured on a
+    """Forward kernel tile sizes, from what the call shows.  Measured on a
     v5e (PERF.md section 6, PR 25): the larger q tile wins up to 512, and
     one pass over the whole K row beats the online update while the score
     tile stays small.  So ``block_q`` is the largest of 512 / 256 / 128
     that divides ``lq`` (else ``lq``), and ``block_k`` the whole K row
     where one grid step's float32 score tile and operand tiles fit
     ``_TILE_VMEM_BUDGET``, else the largest of 512 / 256 / 128 that
-    divides ``lk`` and fits.  What was chosen is set in
-    ``mxnet_tuning_chosen_value{knob}`` either way.  Re-read per call on
-    purpose — the op is jit_safe=False exactly so sweeps/trials can vary
-    the tile between calls."""
-    from .. import tuning as _tuning
+    divides ``lk`` and fits."""
+    block_q = _largest_tile(lq) or lq
 
-    block_q, q_from = _tuning.resolve_info("flash_block_q")
-    block_k, k_from = _tuning.resolve_info("flash_block_kv")
-    if q_from == "default":
-        block_q = _largest_tile(lq) or lq
-    block_q = min(int(block_q), lq)
-    if k_from == "default":
-        def fits(bk):
-            return (block_q * bk * 4 + (block_q + 2 * bk) * d * itemsize
-                    <= _TILE_VMEM_BUDGET)
+    def fits(bk):
+        return (block_q * bk * 4 + (block_q + 2 * bk) * d * itemsize
+                <= _TILE_VMEM_BUDGET)
 
-        k_tiles = [lk] + [b for b in (512, 256, 128)
-                          if b < lk and lk % b == 0]
-        block_k = next((b for b in k_tiles if fits(b)), k_tiles[-1])
-    block_k = min(int(block_k), lk)
-    _tuning.note_chosen("flash_block_q", block_q)
-    _tuning.note_chosen("flash_block_kv", block_k)
+    k_tiles = [lk] + [b for b in (512, 256, 128) if b < lk and lk % b == 0]
+    block_k = next((b for b in k_tiles if fits(b)), k_tiles[-1])
     return block_q, block_k
 
 
@@ -859,11 +843,7 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, mask=None,
 from .registry import register
 
 
-# jit_safe=False: the op re-reads MXNET_FLASH_BLOCK_{Q,KV} per call (the
-# bench block sweep depends on that), so it must not be frozen into a cached
-# eager executable; per-call overhead is irrelevant at attention sizes
-@register("_contrib_flash_attention", aliases=("flash_attention",),
-          jit_safe=False)
+@register("_contrib_flash_attention", aliases=("flash_attention",))
 def flash_attention_op(q, k, v, causal=False, sm_scale=None, mask=None,
                        mask_block=0):
     """Fused scaled-dot-product attention (net-new vs reference; the TPU

@@ -11,8 +11,6 @@ from __future__ import annotations
 
 from collections import OrderedDict
 
-from ..base import MXNetError
-
 __all__ = ["functionalize"]
 
 
@@ -47,7 +45,7 @@ def functionalize(net, train_mode=False, with_state=False):
     names = [name for name, _ in plist]
     name_of = {id(p): name for name, p in plist}
 
-    def imperative_apply(params_dict, rng_key, *input_vals):
+    def apply_fn(params_dict, rng_key, *input_vals):
         pmap = {}
         for name, pobj in zip(names, param_objs):
             pmap[pobj] = NDArray._from_jax(params_dict[name], None)
@@ -74,93 +72,5 @@ def functionalize(net, train_mode=False, with_state=False):
         state = OrderedDict(
             (name_of[id(p)], v) for p, v in tc.state_updates if id(p) in name_of)
         return out, state
-
-    # graph-compiler tier (ISSUE 11): trace once per signature into the
-    # typed graph IR, run the pass pipeline, and replay the OPTIMIZED
-    # graph — TrainStep, pipeline_apply, and the serving export/AOT path
-    # all lower this function, so they all run the optimized program.
-    # Validation pins the graph replay's avals to the imperative trace's;
-    # any mismatch (or an untraceable forward) falls back.
-    graph_cache = {}
-
-    def _graph_entry(params_dict, input_vals):
-        import time as _time
-
-        import jax
-
-        from .. import graph as _graph
-        from .. import telemetry as _telemetry
-        from ..ndarray.ndarray import _AMP
-
-        if not _graph.enabled():
-            return None
-        try:
-            input_avals = [jax.ShapeDtypeStruct(tuple(v.shape), v.dtype)
-                           for v in input_vals]
-            param_avals = {n: jax.ShapeDtypeStruct(
-                tuple(params_dict[n].shape), params_dict[n].dtype)
-                for n in names}
-        except Exception:
-            return None
-        sig = (tuple((tuple(a.shape), str(a.dtype)) for a in input_avals),
-               tuple((tuple(param_avals[n].shape), str(param_avals[n].dtype))
-                     for n in names),
-               _AMP["epoch"] if _AMP["on"] else None,
-               getattr(net, "_cache_version", 0))
-        if sig in graph_cache:
-            return graph_cache[sig]
-        t0 = _time.perf_counter()
-        entry = None
-        try:
-            g = _graph.trace_block(net, plist, input_avals,
-                                   train_mode=train_mode)
-            if not with_state:
-                # the imperative path drops state updates from the trace
-                # (XLA DCEs them); drop the heads so the DCE pass does too
-                g = g.copy()
-                g.state = []
-            opt = _graph.default_pipeline().run(g)
-            gfn = _graph.make_block_fn(opt)
-            key_aval = jax.eval_shape(lambda: jax.random.PRNGKey(0))
-            got = jax.eval_shape(
-                gfn, [param_avals[n] for n in names], key_aval,
-                *input_avals)
-            ref = jax.eval_shape(imperative_apply, param_avals, key_aval,
-                                 *input_avals)
-            n_state = len(opt.state)
-            got_out = list(got[:len(got) - n_state] if n_state else got)
-            ref_out = ref[0] if with_state else ref
-            ref_flat = jax.tree_util.tree_leaves(ref_out)
-            if [(tuple(a.shape), str(a.dtype)) for a in got_out] != \
-                    [(tuple(a.shape), str(a.dtype)) for a in ref_flat]:
-                raise MXNetError("graph tier: output aval mismatch")
-            if with_state:
-                ref_state = ref[1]
-                if sorted(ref_state) != sorted(n for n, _ in opt.state):
-                    raise MXNetError("graph tier: state name mismatch")
-            entry = (gfn, [n for n, _ in opt.state], opt.single)
-        except Exception as e:
-            _graph.record_fallback()
-            _telemetry.compile_event(
-                "graph", getattr(net, "name", type(net).__name__) or
-                type(net).__name__,
-                _time.perf_counter() - t0, "fallback",
-                reason=repr(e)[:200])
-        graph_cache[sig] = entry
-        return entry
-
-    def apply_fn(params_dict, rng_key, *input_vals):
-        entry = _graph_entry(params_dict, input_vals)
-        if entry is None:
-            return imperative_apply(params_dict, rng_key, *input_vals)
-        gfn, state_names, single = entry
-        flat = gfn([params_dict[n] for n in names], rng_key, *input_vals)
-        n_state = len(state_names)
-        real = flat[:len(flat) - n_state] if n_state else flat
-        out = real[0] if single else tuple(real)
-        if not with_state:
-            return out
-        state_vals = flat[len(flat) - n_state:] if n_state else ()
-        return out, OrderedDict(zip(state_names, state_vals))
 
     return apply_fn, params

@@ -947,13 +947,6 @@ def _tracer_for(node, idx, in_avals_or_shape):
     return SymbolTracer((node, idx), in_avals_or_shape)
 
 
-# trace observer: while a graph-tier trace is active (mxnet_tpu.graph.trace)
-# the callback sees every op node IN CREATION ORDER — the graph IR keeps
-# that order so its replay draws RNG keys and writes state updates in the
-# exact sequence the imperative jit path would (bit-parity contract)
-_TRACE_OBSERVER = [None]
-
-
 def trace_invoke(opname, args, attrs):
     """Build a graph node from NDArray/SymbolTracer inputs during export
     tracing, propagating concrete avals via jax.eval_shape."""
@@ -989,9 +982,6 @@ def trace_invoke(opname, args, attrs):
     multi = isinstance(out_aval, (tuple, list))
     nout = len(out_aval) if multi else 1
     node = _Node(od.name, name, attrs, in_heads, nout=nout)
-    obs = _TRACE_OBSERVER[0]
-    if obs is not None:
-        obs(node, out_aval if multi else (out_aval,))
     if not multi:
         return SymbolTracer((node, 0), out_aval)
     return [SymbolTracer((node, i), av) for i, av in enumerate(out_aval)]

@@ -17,8 +17,8 @@ existing layers:
   miss.
 
 Every consumer — ``TrainStep``/kvstore bucketing, the graph
-``PassPipeline``, flash-attention tiles, the prefetcher, the
-``ServingEngine`` — resolves its value through ONE funnel::
+``PassPipeline``, the prefetcher, the ``ServingEngine`` — resolves its
+value through ONE funnel::
 
     value = tuning.resolve("allreduce_bucket_mb", signature=sig)
 
@@ -34,8 +34,7 @@ Telemetry: ``mxnet_tuning_trials_total{knob}`` (search measurements),
 ``mxnet_tuning_db_{hits,misses,stores}_total`` (DB traffic), and
 ``mxnet_tuning_chosen_value{knob}`` (the numeric value each knob
 resolved to, by source precedence — string-grid knobs export their
-grid index; a consumer that derives its value from its call where the
-funnel answers ``default`` reports it through :func:`note_chosen`).
+grid index).
 """
 from __future__ import annotations
 
@@ -52,7 +51,7 @@ from .search import schedule, successive_halving, tune_knob
 
 __all__ = ["Knob", "TuningDB", "all_knobs", "default_db",
            "device_kind", "effective_config", "enabled", "get_knob",
-           "knob_names", "note_chosen", "register_knob", "reset", "resolve",
+           "knob_names", "register_knob", "reset", "resolve",
            "resolve_db", "resolve_info", "schedule",
            "successive_halving", "trial_override", "tune_knob"]
 
@@ -141,14 +140,6 @@ def resolve_info(name, signature=None, plan_digest=None, db=None):
                 _CHOSEN.labels(knob=name).set(_gauge_value(knob, value))
                 return value, "tuned"
     return knob.default, "default"
-
-
-def note_chosen(name, value):
-    """Set ``mxnet_tuning_chosen_value{knob}`` from a consumer that, where
-    the funnel answered ``default``, worked the value out from its own
-    call (the flash tiles, from the shape): a scrape then says what the
-    step was traced with, not the knob's nominal default."""
-    _CHOSEN.labels(knob=name).set(_gauge_value(get_knob(name), value))
 
 
 def resolve(name, signature=None, plan_digest=None, db=None):

@@ -136,22 +136,6 @@ register_knob(Knob(
     "max ops per fused elementwise chain (< 2 disables the pass; "
     "graph/passes.py)"))
 register_knob(Knob(
-    "flash_block_q", "MXNET_FLASH_BLOCK_Q", int, 128,
-    (128, 256, 512), "training",
-    "flash-attention forward q tile (must divide the padded sequence; "
-    "ops/flash_attention.py).  Unpinned and untuned, the kernel takes "
-    "the largest of 512 / 256 / 128 that divides the q length, not "
-    "this nominal default.  The backward kernel is not pinned by this: "
-    "it takes its tiles from the call's shape"))
-register_knob(Knob(
-    "flash_block_kv", "MXNET_FLASH_BLOCK_KV", int, 128,
-    (128, 256, 512), "training",
-    "flash-attention forward kv tile (ops/flash_attention.py).  "
-    "Unpinned and untuned, the kernel takes the whole K row while the "
-    "score tile fits its VMEM budget (one-pass softmax), else the "
-    "largest of 512 / 256 / 128 that divides it, not this nominal "
-    "default.  The forward's alone, as flash_block_q"))
-register_knob(Knob(
     "prefetch_buffer", "MXNET_PREFETCH_BUFFER", int, 2,
     (0, 1, 2, 4, 8), "training",
     "device-prefetch queue depth (0 = serial staging; "

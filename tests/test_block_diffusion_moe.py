@@ -376,8 +376,8 @@ def test_program_matches_the_reference_loss_and_every_gradient(amp,
 
 
 def test_remat_is_real_in_the_fused_step_and_scopes_are_in_its_table():
-    """``LlamaConfig(remat=True)`` recomputes under ``TrainStep`` (the graph
-    tier steps aside), and the expert layer's scopes reach the op table."""
+    """``LlamaConfig(remat=True)`` recomputes under ``TrainStep``, and the
+    expert layer's scopes reach the op table."""
     from mxnet_tpu import profiler
     from mxnet_tpu.parallel.data_parallel import TrainStep
 

@@ -62,38 +62,11 @@ def test_main_refuses_without_a_chip(capsys):
     assert out == "" and "no TPU" in err
 
 
-def _gauge_of(knob):
-    from mxnet_tpu import telemetry
-
-    samples = telemetry.snapshot()["metrics"][
-        "mxnet_tuning_chosen_value"]["samples"]
-    return {s["labels"].get("knob"): s["value"] for s in samples}[knob]
-
-
-@pytest.fixture
-def flash_pins(monkeypatch):
-    """Pin the tiles as an operator would, through the environment."""
-    from mxnet_tpu import tuning
-
-    def pin(block_q=None, block_kv=None):
-        for var, value in (("MXNET_FLASH_BLOCK_Q", block_q),
-                           ("MXNET_FLASH_BLOCK_KV", block_kv)):
-            if value is None:
-                monkeypatch.delenv(var, raising=False)
-            else:
-                monkeypatch.setenv(var, str(value))
-        tuning.reset()
-
-    pin()
-    yield pin
-    tuning.reset()
-
-
 # float32 inputs keep float32 operands: the one-pass softmax where the K
-# row is one block, and under a pinned tile the online update, unrolled
-# and (causal) skipping.  bf16 inputs feed the MXU as they are, against the
-# plain path on the same bf16 inputs.
-@pytest.mark.parametrize("lq,lk,dim,causal,dtype,pin_kv", [
+# row is one block, and with a smaller K tile handed in the online update,
+# unrolled and (causal) skipping.  bf16 inputs feed the MXU as they are,
+# against the plain path on the same bf16 inputs.
+@pytest.mark.parametrize("lq,lk,dim,causal,dtype,block_k", [
     (256, 256, 64, False, "float32", None),
     (512, 512, 64, True, "float32", None),
     (256, 512, 128, True, "float32", None),
@@ -106,24 +79,24 @@ def flash_pins(monkeypatch):
     (512, 512, 128, False, "bfloat16", None),
     (256, 512, 128, True, "bfloat16", None)])
 def test_flash_forward_interpreted_matches_reference(lq, lk, dim, causal,
-                                                     dtype, pin_kv,
-                                                     flash_pins):
+                                                     dtype, block_k):
     from jax.experimental.pallas import tpu as pltpu
 
-    from mxnet_tpu.ops.flash_attention import (_fa_forward_pallas,
+    from mxnet_tpu.ops.flash_attention import (_fa_block_sizes,
+                                               _fa_forward_pallas,
                                                _mha_with_lse)
 
-    flash_pins(block_kv=pin_kv)
     rs = np.random.RandomState(0)
     q = jnp.asarray(rs.randn(1, 1, lq, dim).astype("f")).astype(dtype)
     k = jnp.asarray(rs.randn(1, 1, lk, dim).astype("f")).astype(dtype)
     v = jnp.asarray(rs.randn(1, 1, lk, dim).astype("f")).astype(dtype)
     scale = 1.0 / np.sqrt(dim)
+    if block_k is None:      # these shapes take the whole K row by the rule
+        assert _fa_block_sizes(lq, lk, dim, q.dtype.itemsize)[1] == lk
     with pltpu.force_tpu_interpret_mode():
-        o, lse = _fa_forward_pallas(q, k, v, causal, scale)
+        o, lse = _fa_forward_pallas(q, k, v, causal, scale, block_k=block_k)
     ref_o, ref_lse = _mha_with_lse(q, k, v, causal, scale)
     assert o.dtype == q.dtype and lse.dtype == jnp.float32
-    assert _gauge_of("flash_block_kv") == (pin_kv or lk)
     if dtype == "float32":
         np.testing.assert_allclose(o, ref_o, atol=2e-6)
         np.testing.assert_allclose(lse, ref_lse, atol=2e-6)
@@ -135,7 +108,7 @@ def test_flash_forward_interpreted_matches_reference(lq, lk, dim, causal,
         np.testing.assert_allclose(lse, ref_lse, atol=1e-2)
 
 
-@pytest.mark.parametrize("lq,lk,dim,dtype,pins,tiles", [
+@pytest.mark.parametrize("lq,lk,dim,dtype,given,tiles", [
     # from the shape: the q tile as large as divides, the whole K row while
     # the score tile fits the budget, else the largest that divides and fits
     (512, 512, 64, "bfloat16", {}, (512, 512)),
@@ -144,30 +117,34 @@ def test_flash_forward_interpreted_matches_reference(lq, lk, dim, causal,
     (2048, 2048, 128, "bfloat16", {}, (512, 512)),
     (256, 512, 128, "bfloat16", {}, (256, 512)),
     (384, 384, 64, "bfloat16", {}, (128, 384)),
-    # a pin wins, each knob by itself
+    # a tile the caller hands in wins, each by itself
     (512, 512, 64, "bfloat16", {"block_q": 128}, (128, 512)),
-    (512, 512, 64, "bfloat16", {"block_kv": 256}, (512, 256)),
-    (512, 512, 64, "bfloat16", {"block_q": 128, "block_kv": 128},
+    (512, 512, 64, "bfloat16", {"block_k": 256}, (512, 256)),
+    (512, 512, 64, "bfloat16", {"block_q": 128, "block_k": 128},
      (128, 128))])
-def test_flash_tiles_come_from_the_shape_unless_pinned(lq, lk, dim, dtype,
-                                                       pins, tiles,
-                                                       flash_pins):
-    from mxnet_tpu import tuning
-    from mxnet_tpu.ops.flash_attention import (_fa_block_sizes,
-                                               _fa_forward_pallas)
+def test_flash_tiles_come_from_the_shape_unless_handed_in(lq, lk, dim, dtype,
+                                                          given, tiles,
+                                                          monkeypatch):
+    from mxnet_tpu.ops import flash_attention as fa
 
-    flash_pins(**pins)
-    assert _fa_block_sizes(lq, lk, dim, jnp.dtype(dtype).itemsize) == tiles
-    # what the kernel is traced with is what a scrape of the gauge says
+    if not given:
+        assert fa._fa_block_sizes(lq, lk, dim,
+                                  jnp.dtype(dtype).itemsize) == tiles
+    # what the kernel is traced with: its q block and its K tile
+    traced = []
+    kernel = fa._fa_fwd_kernel
+
+    def spy(q_ref, *refs, block_k, **kw):
+        traced.append((q_ref.shape[0], block_k))
+        return kernel(q_ref, *refs, block_k=block_k, **kw)
+
+    monkeypatch.setattr(fa, "_fa_fwd_kernel", spy)
     q = jax.ShapeDtypeStruct((1, 2, lq, dim), dtype)
     k = jax.ShapeDtypeStruct((1, 2, lk, dim), dtype)
-    with tuning.trial_override("flash_block_q", 128):
-        jax.eval_shape(lambda q, k: _fa_forward_pallas(q, k, k, False, 0.125),
-                       q, k)
-        assert _gauge_of("flash_block_q") == 128    # a trial wins over both
     o, lse = jax.eval_shape(
-        lambda q, k: _fa_forward_pallas(q, k, k, False, 0.125), q, k)
-    assert (_gauge_of("flash_block_q"), _gauge_of("flash_block_kv")) == tiles
+        lambda q, k: fa._fa_forward_pallas(q, k, k, False, 0.125, **given),
+        q, k)
+    assert traced == [tiles]
     assert o.shape == q.shape and lse.shape == (1, 2, lq)
 
 

@@ -1,16 +1,16 @@
-"""Graph compiler tier (ISSUE 11): IR, passes, pipeline, integration.
+"""Graph compiler tier (ISSUE 11): IR, passes, pipeline, Symbol surface.
 
-Every pass ships with a seeded fixture graph + a BIT-parity assertion
-(optimized output ``np.array_equal`` unoptimized — the fp32 contract),
-plus the end-to-end pins: a 5-step hybridized training trajectory
-bit-identical with the pipeline on vs off, and the serving artifact
-path steady-state zero-fresh-trace with the optimized graph.
+Every pass ships with a seeded fixture graph built from Symbols + a
+BIT-parity assertion (optimized output ``np.array_equal`` unoptimized —
+the fp32 contract), plus the pins of the surface the tier serves: a
+loaded ``SymbolBlock`` runs the optimized heads and ``optimize_for``
+rides the pipeline; and a served artifact traces nothing in steady state.
 """
 import numpy as np
 import pytest
 
 import mxnet_tpu as mx
-from mxnet_tpu import autograd, nd, telemetry
+from mxnet_tpu import nd, telemetry
 from mxnet_tpu import graph as G
 from mxnet_tpu.gluon import HybridBlock, nn
 
@@ -30,6 +30,24 @@ def _exec(g, feed):
     ivals = [feed[g.nodes[i].name] for i in g.inputs]
     return [np.asarray(v)
             for v in fn(pvals, jax.random.PRNGKey(0), *ivals)]
+
+
+def _stamp_avals(g, *inputs):
+    """Give every node its ``(shape, dtype)`` a port: what a front end
+    that knows its types stamps, and what place_amp_casts reads."""
+    import itertools
+
+    import jax
+
+    every = g.copy()
+    every.outputs = [(nid, i) for nid, n in enumerate(g.nodes)
+                     for i in range(n.nout)]
+    avals = iter(jax.eval_shape(G.make_block_fn(every), [],
+                                jax.random.PRNGKey(0), *inputs))
+    for n in g.nodes:
+        n.avals = tuple((tuple(a.shape), str(a.dtype))
+                        for a in itertools.islice(avals, n.nout))
+    return g
 
 
 # -- IR ---------------------------------------------------------------------
@@ -98,17 +116,12 @@ def test_cse_merges_duplicates_parity():
 def test_cse_never_merges_rng_ops():
     from mxnet_tpu.graph.passes import eliminate_common_subexpr
 
-    class TwoDrops(HybridBlock):
-        def hybrid_forward(self, F, x):
-            return F.Dropout(x, p=0.5, training=True) + \
-                F.Dropout(x, p=0.5, training=True)
-
     import jax
 
-    net = TwoDrops()
-    net.initialize()
-    g = G.trace_block(net, [], [jax.ShapeDtypeStruct((4, 4), np.float32)],
-                      train_mode=True)
+    x = mx.sym.var("data")
+    y = mx.sym.Dropout(x, p=0.5, training=True) + \
+        mx.sym.Dropout(x, p=0.5, training=True)
+    g = G.Graph.from_symbol(y, input_names=["data"])
     n_drop = sum(1 for n in g.nodes if n.op == "Dropout")
     assert n_drop == 2
     opt = eliminate_common_subexpr(g)
@@ -139,93 +152,60 @@ def test_dead_node_elimination_keeps_signature():
 def test_fuse_elemwise_chains_parity_and_cap(monkeypatch):
     from mxnet_tpu.graph.passes import fuse_elemwise_chains
 
-    class Chain(HybridBlock):
-        def hybrid_forward(self, F, x):
-            h = x
-            for _ in range(4):
-                h = F.tanh(h * 0.5 + 1.0)
-            return h
-
-    import jax
-
-    net = Chain()
-    net.initialize()
-    g = G.trace_block(net, [], [jax.ShapeDtypeStruct((3, 4), np.float32)])
+    h = mx.sym.var("data")
+    for _ in range(4):
+        h = mx.sym.tanh(h * 0.5 + 1.0)
+    g = G.Graph.from_symbol(h, input_names=["data"])
     assert g.n_ops == 12
-    x = np.random.RandomState(3).randn(3, 4).astype("f")
-    ref = np.asarray(G.make_block_fn(g)([], jax.random.PRNGKey(0), x)[0])
+    feed = {"data": np.random.RandomState(3).randn(3, 4).astype("f")}
+    ref = _exec(g, feed)[0]
     opt = fuse_elemwise_chains(g)
     assert opt.fused_op_count() == 1 and opt.n_ops == 1
-    out = np.asarray(G.make_block_fn(opt)([], jax.random.PRNGKey(0), x)[0])
-    assert np.array_equal(out, ref)
+    assert np.array_equal(_exec(opt, feed)[0], ref)
     # the chain cap splits long chains into bounded fused segments
     monkeypatch.setenv("MXNET_GRAPH_FUSE_CAP", "4")
     capped = fuse_elemwise_chains(g)
     assert capped.fused_op_count() > 1
     assert all(n.attrs.get("__n_fused__", 0) <= 4 for n in capped.nodes)
-    out2 = np.asarray(G.make_block_fn(capped)([], jax.random.PRNGKey(0),
-                                              x)[0])
-    assert np.array_equal(out2, ref)
+    assert np.array_equal(_exec(capped, feed)[0], ref)
     monkeypatch.setenv("MXNET_GRAPH_FUSE_CAP", "0")
     assert fuse_elemwise_chains(g).fused_op_count() == 0
 
 
 def test_amp_cast_placement_parity():
-    from mxnet_tpu.graph.passes import place_amp_casts
+    from mxnet_tpu.graph.passes import eliminate_dead_nodes, place_amp_casts
 
-    class Casty(HybridBlock):
-        def hybrid_forward(self, F, x):
-            # identity cast + widen->narrow round trip + cast after
-            # movement (hoistable) — all bit-exact removals/moves
-            h = x.astype("float32")                    # identity (x is f32)
-            h = h.astype("float16").astype("float32")  # NOT collapsible
-            w = x.astype("float16")
-            w = w.astype("float32").astype("float16")  # collapses to w
-            r = x.reshape((4, 3)).astype("float16")    # hoists above move
-            return h.sum() + w.astype("float32").sum() + \
-                r.astype("float32").sum()
+    def cast(s, dtype):
+        return mx.sym.cast(s, dtype=dtype)
 
-    import jax
-
-    net = Casty()
-    net.initialize()
-    g = G.trace_block(net, [], [jax.ShapeDtypeStruct((3, 4), np.float32)])
-    x = np.random.RandomState(4).randn(3, 4).astype("f")
-    ref = [np.asarray(v)
-           for v in G.make_block_fn(g)([], jax.random.PRNGKey(0), x)]
+    # identity cast + widen->narrow round trip + cast after movement
+    # (hoistable) — all bit-exact removals/moves
+    x = mx.sym.var("data")
+    h = cast(x, "float32")                              # identity (x is f32)
+    h = cast(cast(h, "float16"), "float32")             # NOT collapsible
+    w = cast(x, "float16")
+    w = cast(cast(w, "float32"), "float16")             # collapses to w
+    r = cast(mx.sym.reshape(x, shape=(4, 3)), "float16")  # hoists above move
+    y = mx.sym.sum(h) + mx.sym.sum(cast(w, "float32")) + \
+        mx.sym.sum(cast(r, "float32"))
+    feed = {"data": np.random.RandomState(4).randn(3, 4).astype("f")}
+    g = _stamp_avals(G.Graph.from_symbol(y, input_names=["data"]),
+                     feed["data"])
+    ref = _exec(g, feed)
     n_casts = sum(1 for n in g.nodes if n.op == "cast")
     assert n_casts >= 7
-    opt = place_amp_casts(g)
-    from mxnet_tpu.graph.passes import eliminate_dead_nodes
-
-    opt = eliminate_dead_nodes(opt)
+    opt = eliminate_dead_nodes(place_amp_casts(g))
     assert sum(1 for n in opt.nodes if n.op == "cast") < n_casts
-    out = [np.asarray(v)
-           for v in G.make_block_fn(opt)([], jax.random.PRNGKey(0), x)]
+    out = _exec(opt, feed)
     assert all(np.array_equal(a, b) for a, b in zip(out, ref))
 
 
 # -- pipeline ---------------------------------------------------------------
 def test_pipeline_idempotent_and_telemetry():
-    class Deep(HybridBlock):
-        def __init__(self):
-            super().__init__()
-            with self.name_scope():
-                self.fc = nn.Dense(8, in_units=8)
-
-        def hybrid_forward(self, F, x):
-            h = self.fc(x)
-            for _ in range(4):
-                h = F.sigmoid(h + 0.25)
-            return h
-
-    import jax
-
-    net = Deep()
-    net.initialize()
-    plist = sorted(net.collect_params().items())
-    g = G.trace_block(net, plist, [jax.ShapeDtypeStruct((2, 8),
-                                                        np.float32)])
+    h = mx.sym.FullyConnected(mx.sym.var("data"), num_hidden=8, name="fc")
+    for _ in range(4):
+        h = mx.sym.sigmoid(h + 0.25)
+    g = G.Graph.from_symbol(h, input_names=["data"])
     pipe = G.default_pipeline()
     opt1 = pipe.run(g)
     opt2 = G.default_pipeline().run(opt1)
@@ -257,17 +237,6 @@ def test_pass_selection_knob(monkeypatch):
         G.selected_pass_names()
 
 
-def test_pipeline_disable_knob(monkeypatch):
-    monkeypatch.setenv("MXNET_GRAPH_PIPELINE", "0")
-    assert not G.enabled()
-    with G.override_enabled(True):
-        assert G.enabled()
-    monkeypatch.delenv("MXNET_GRAPH_PIPELINE")
-    assert G.enabled()                    # default on
-    with G.override_enabled(False):
-        assert not G.enabled()
-
-
 def test_registering_duplicate_pass_name_raises():
     from mxnet_tpu.base import MXNetError
 
@@ -281,182 +250,11 @@ def test_registering_duplicate_pass_name_raises():
             return graph.copy()
 
 
-# -- hybridized integration --------------------------------------------------
-def _mlp(prefix):
-    net = nn.HybridSequential(prefix=prefix)
-    with net.name_scope():
-        net.add(nn.Dense(16, activation="relu", in_units=8))
-        net.add(nn.BatchNorm(in_channels=16))
-        net.add(nn.Dropout(0.25))
-        net.add(nn.Dense(4, in_units=16))
-    return net
-
-
-def _strip(d, prefix):
-    return {k[len(prefix):]: v for k, v in d.items()}
-
-
-def test_hybridized_trajectory_bit_identical_pipeline_on_off():
-    """5 SGD steps through a hybridized MLP (BatchNorm state + dropout
-    RNG in play): parameters, outputs and running stats bit-match with
-    the pipeline on vs off (the ISSUE 11 acceptance pin)."""
-    from mxnet_tpu.gluon import Trainer
-
-    results = {}
-    for flag, prefix in ((True, "on_"), (False, "off_")):
-        mx.random.seed(7)
-        np.random.seed(7)
-        net = _mlp(prefix)
-        net.initialize()
-        net.hybridize()
-        trainer = Trainer(net.collect_params(), "sgd",
-                          {"learning_rate": 0.1})
-        rs = np.random.RandomState(11)
-        with G.override_enabled(flag):
-            losses = []
-            for _ in range(5):
-                x = nd.array(rs.randn(6, 8).astype("f"))
-                with autograd.record():
-                    y = net(x)
-                    loss = (y * y).mean()
-                loss.backward()
-                trainer.step(6)
-                losses.append(float(loss.asnumpy()))
-        results[flag] = (losses,
-                         _strip({k: p.data().asnumpy() for k, p in
-                                 net.collect_params().items()}, prefix))
-    assert results[True][0] == results[False][0]
-    pa, pb = results[True][1], results[False][1]
-    assert set(pa) == set(pb)
-    for k in pa:
-        assert np.array_equal(pa[k], pb[k]), k
-
-
-def test_hybridized_block_records_optimized_graph():
-    class Deep(HybridBlock):
-        def __init__(self):
-            super().__init__()
-            with self.name_scope():
-                self.fc = nn.Dense(8, in_units=8)
-
-        def hybrid_forward(self, F, x):
-            h = self.fc(x)
-            for _ in range(5):
-                h = F.tanh(h * 0.5)
-            return h
-
-    net = Deep()
-    net.initialize()
-    net.hybridize()
-    with G.override_enabled(True):
-        net(nd.zeros((2, 8)))
-    irs = list(net._cached_graph_ir.values())
-    assert irs and irs[0].fused_op_count() >= 1
-    assert G.stats_snapshot()["pipeline_runs"] >= 1
-
-
-def test_untraceable_forward_falls_back():
-    """apply_fn composites (the fused-RNN-scan escape hatch) can't ride
-    the graph tier: the cached-op path must fall back to the imperative
-    jit, stay correct, and record the fallback."""
-    from mxnet_tpu.ndarray.ndarray import apply_fn
-
-    class Escape(HybridBlock):
-        def hybrid_forward(self, F, x):
-            return apply_fn(lambda v: v * 2.0, [x], name="escape") + 1.0
-
-    net = Escape()
-    net.initialize()
-    x = nd.array(np.random.RandomState(0).randn(4, 3).astype("f"))
-    y_eager = net(x).asnumpy()
-    net.hybridize()
-    with G.override_enabled(True):
-        y_hyb = net(x).asnumpy()
-    assert np.array_equal(y_hyb, y_eager)
-    assert G.stats_snapshot()["fallbacks"] >= 1
-    assert any(e["kind"] == "graph" and e["cause"] == "fallback"
-               for e in telemetry.compile_events())
-
-
-def test_train_step_trajectory_bit_identical_pipeline_on_off():
-    """5 TrainStep steps (functionalize path — the seam TrainStep,
-    pipeline_apply and serving lowering share) bit-identical on vs
-    off."""
-    from mxnet_tpu.parallel.data_parallel import TrainStep
-
-    def _ce(logits, labels):
-        import jax
-        import jax.numpy as jnp
-
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        return -jnp.take_along_axis(logp, labels[:, None], axis=-1)
-
-    out = {}
-    for flag, prefix in ((True, "ton_"), (False, "toff_")):
-        mx.random.seed(5)
-        np.random.seed(5)
-        net = _mlp(prefix)
-        net.initialize()
-        net(nd.zeros((2, 8)))
-        step = TrainStep(net, _ce, optimizer="sgd",
-                         optimizer_params={"learning_rate": 0.2})
-        rs = np.random.RandomState(9)
-        with G.override_enabled(flag):
-            losses = []
-            for _ in range(5):
-                x = rs.randn(8, 8).astype("f")
-                y = (x.sum(axis=1) > 0).astype("int32")
-                losses.append(float(step(x, y)))
-        out[flag] = (losses, _strip({k: np.asarray(v) for k, v in
-                                     step.params.items()}, prefix))
-    assert out[True][0] == out[False][0]
-    for k in out[True][1]:
-        assert np.array_equal(out[True][1][k], out[False][1][k]), k
-
-
-def test_llama_proxy_train_step_bit_identical_pipeline_on_off():
-    """The llama proxy (flash attention, RoPE, RMSNorm, SwiGLU — all
-    registered ops) rides the graph tier end to end: 3 Adam steps
-    bit-identical on vs off, and the optimized path really ran."""
-    import jax
-    import jax.numpy as jnp
-
-    from mxnet_tpu.gluon.model_zoo.language import llama
-    from mxnet_tpu.parallel.data_parallel import TrainStep
-
-    cfg = dict(vocab_size=64, hidden_size=32, num_layers=2, num_heads=4,
-               num_kv_heads=2, intermediate_size=64, max_seq_len=16)
-
-    def loss_fn(logits, y):
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        return -jnp.take_along_axis(logp, y[..., None], axis=-1)
-
-    ids = np.random.RandomState(0).randint(0, 64, (2, 8)).astype("int32")
-    labels = np.random.RandomState(1).randint(0, 64, (2, 8)).astype("int32")
-    out = {}
-    for flag in (True, False):
-        mx.random.seed(3)
-        np.random.seed(3)
-        net = llama.LlamaForCausalLM(llama.LlamaConfig(**cfg))
-        net.initialize()
-        net(mx.nd.zeros((1, 8), dtype="int32"))
-        step = TrainStep(net, loss_fn, optimizer="adam",
-                         optimizer_params={"learning_rate": 1e-3})
-        G.reset_stats()
-        with G.override_enabled(flag):
-            losses = [float(step(ids, labels)) for _ in range(3)]
-        snap = G.stats_snapshot()
-        if flag:
-            assert snap["pipeline_runs"] >= 1 and snap["fallbacks"] == 0
-        out[flag] = losses
-    assert out[True] == out[False]
-
-
 # -- serving / export integration --------------------------------------------
 def test_serving_artifact_optimized_zero_fresh_traces(tmp_path):
-    """Export -> load_artifact with the pipeline on: outputs bit-match
-    the pipeline-off forward, and steady state performs ZERO fresh
-    traces with the optimized executables (the ISSUE 11 serving pin)."""
+    """Export -> load_artifact: the artifact bit-matches the hybridized
+    forward, and steady state performs ZERO fresh traces (the ISSUE 11
+    serving pin)."""
     from mxnet_tpu import serving
 
     class Deep(HybridBlock):
@@ -476,28 +274,24 @@ def test_serving_artifact_optimized_zero_fresh_traces(tmp_path):
     net.initialize()
     net.hybridize()
     x = nd.array(np.random.RandomState(0).randn(4, 16).astype("f"))
-    with G.override_enabled(False):
-        y_raw = net(x).asnumpy()
-    net.hybridize()  # clear caches; re-trace optimized
-    with G.override_enabled(True):
-        y_opt = net(x).asnumpy()
-        assert np.array_equal(y_opt, y_raw)
-        path = str(tmp_path / "deep")
-        net.export(path)
-        art = serving.load_artifact(path)
-        assert np.array_equal(art(x).asnumpy(), y_raw)
-        # steady state: repeat calls at a warmed signature trace nothing
-        before = telemetry.snapshot()["compile"]["count"]
-        for _ in range(3):
-            art(x)
-        assert telemetry.snapshot()["compile"]["count"] == before
+    y_raw = net(x).asnumpy()
+    path = str(tmp_path / "deep")
+    net.export(path)
+    art = serving.load_artifact(path)
+    assert np.array_equal(art(x).asnumpy(), y_raw)
+    # steady state: repeat calls at a warmed signature trace nothing
+    before = telemetry.snapshot()["compile"]["count"]
+    for _ in range(3):
+        art(x)
+    assert telemetry.snapshot()["compile"]["count"] == before
 
 
 def test_symbol_block_runs_optimized_heads(tmp_path):
     """SymbolBlock (the load_artifact reconstruction path) runs the
-    optimized heads: fused chain present, outputs bit-match raw."""
+    optimized heads: fused chain present, outputs bit-match ``evaluate``
+    of the raw heads."""
     from mxnet_tpu.gluon import SymbolBlock
-    from mxnet_tpu.symbol.symbol import _topo
+    from mxnet_tpu.symbol.symbol import _topo, evaluate
 
     class Deep(HybridBlock):
         def __init__(self):
@@ -518,12 +312,12 @@ def test_symbol_block_runs_optimized_heads(tmp_path):
     net.export(prefix, 0, xv, manifest=False)
     blk = SymbolBlock.imports(f"{prefix}-symbol.json", ["data"],
                               f"{prefix}-0000.params")
-    with G.override_enabled(False):
-        y_raw = blk(xv).asnumpy()
-    with G.override_enabled(True):
-        blk._opt_heads_entry = None      # force re-derivation
-        y_opt = blk(xv).asnumpy()
-        heads = blk._optimized_heads()
+    feed = {"data": xv._get()}
+    feed.update({n: blk.params.get(n).data()._get()
+                 for n in blk._sym_param_names})
+    y_raw = np.asarray(evaluate(blk._sym._heads, feed)[0][0])
+    y_opt = blk(xv).asnumpy()
+    heads = blk._optimized_heads()
     assert np.array_equal(y_opt, y_raw)
     ops = [n.op for n in _topo(heads) if n.op is not None]
     assert any(op.startswith("_gfused_chain") for op in ops), ops

@@ -499,3 +499,105 @@ def test_ulysses_attention_rejects_indivisible_heads():
     q = jnp.zeros((1, 4, 16, 8), "f")  # 4 heads, 8-way sp
     with pytest.raises(ValueError, match="divisible"):
         ulysses_context_parallel_attention(q, q, q, mesh)
+
+
+# --------------------------------------------------------------------------
+# one tracer for a Gluon net: NDArray over jax tracers, whoever asks
+# --------------------------------------------------------------------------
+def _toy_bert():
+    from mxnet_tpu.gluon.model_zoo.language import bert
+
+    net = bert.BertForPretraining(bert.BertConfig(
+        vocab_size=64, hidden_size=32, num_layers=2, num_heads=2,
+        intermediate_size=64, max_position=16, dropout=0.0))
+    ids = np.random.RandomState(0).randint(0, 64, (2, 16)).astype("int32")
+    return net, ids, lambda outs, y: (outs[0] ** 2).mean() + outs[1].mean()
+
+
+def _toy_llama(**extra):
+    from mxnet_tpu.gluon.model_zoo.language import llama
+
+    net = llama.LlamaForCausalLM(llama.LlamaConfig(
+        vocab_size=64, hidden_size=32, num_layers=2, num_heads=4,
+        num_kv_heads=2, intermediate_size=64, max_seq_len=16, **extra))
+    ids = np.random.RandomState(0).randint(0, 64, (2, 16)).astype("int32")
+    return net, ids, lambda logits, y: (logits ** 2).mean()
+
+
+def _toy_llama_remat_experts_block_diffusion():
+    return _toy_llama(remat=True, num_experts=4, moe_capacity_factor=None,
+                      moe_top_k=2, moe_renormalize=True,
+                      moe_experts_held=(1, 2), moe_intermediate_size=16,
+                      block_diffusion=4)
+
+
+def _toy_resnet():
+    net = gluon.model_zoo.vision.get_model("resnet18_v1", classes=4)
+    x = np.random.RandomState(0).randn(2, 3, 32, 32).astype("float32")
+    return net, x, lambda logits, y: (logits ** 2).mean()
+
+
+@pytest.mark.parametrize("how", ["train_step", "hybridize"])
+@pytest.mark.parametrize("make", [_toy_bert, _toy_llama,
+                                  _toy_llama_remat_experts_block_diffusion,
+                                  _toy_resnet])
+def test_one_python_forward_a_compile_and_no_graph_tier(make, how,
+                                                        monkeypatch):
+    """``TrainStep`` and ``hybridize()`` trace a net the same way, once a
+    compile: its Python ``forward`` runs one time, and nothing of the
+    graph pipeline (no ``graph`` fallback, no ``graph_pass``) is recorded."""
+    from mxnet_tpu import telemetry
+
+    net, x, loss = make()
+    net.initialize()
+    net(nd.array(x, dtype=x.dtype))          # settle deferred shapes
+    forwards = []
+    hybrid_forward = type(net).hybrid_forward
+
+    def counted(self, *args, **kwargs):
+        forwards.append(self)
+        return hybrid_forward(self, *args, **kwargs)
+
+    monkeypatch.setattr(type(net), "hybrid_forward", counted)
+    telemetry.reset()
+    if how == "train_step":
+        step = TrainStep(net, loss, optimizer="sgd",
+                         optimizer_params={"learning_rate": 0.01})
+        y = np.zeros((x.shape[0],), "int32")
+        assert np.isfinite(float(step(x, y)))
+        assert np.isfinite(float(step(x, y)))
+    else:
+        net.hybridize()
+        for _ in range(2):                    # same signature: no retrace
+            with autograd.record():
+                out = net(nd.array(x, dtype=x.dtype))
+            out = out[0] if isinstance(out, tuple) else out
+            out.backward()
+            assert np.isfinite(out.asnumpy()).all()
+    assert forwards == [net]
+    assert not [e for e in telemetry.compile_events()
+                if e["kind"].startswith("graph")]
+
+
+def test_hybridized_remat_llama_checkpoints_its_layers():
+    """Under ``hybridize()`` as under ``TrainStep``, a
+    ``LlamaConfig(remat=True)`` layer is a ``jax.checkpoint``: the cached
+    op's jaxpr holds one a layer, and no warning says it has no effect."""
+    import warnings
+
+    import jax
+
+    net, ids, _ = _toy_llama(remat=True)
+    net.initialize()
+    net(nd.array(ids, dtype="int32"))
+    net.hybridize()
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        net(nd.array(ids, dtype="int32"))
+    (jitted, params_list, _), = net._cached_graph.values()
+    jaxpr = jax.make_jaxpr(jitted)(
+        [p.data()._get() for p in params_list], jax.random.PRNGKey(0), ids)
+    assert str(jaxpr).count("remat2[") >= 2
+    apply_fn, params = functionalize(net)
+    fused = jax.make_jaxpr(apply_fn)(params, jax.random.PRNGKey(0), ids)
+    assert str(fused).count("remat2[") >= 2

@@ -21,8 +21,7 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _KNOB_ENV = ("MXNET_TUNE", "MXNET_TUNE_DB_DIR",
              "MXNET_ALLREDUCE_BUCKET_MB", "MXNET_GRAPH_FUSE_CAP",
-             "MXNET_PREFETCH_BUFFER", "MXNET_FLASH_BLOCK_Q",
-             "MXNET_FLASH_BLOCK_KV")
+             "MXNET_PREFETCH_BUFFER")
 
 
 @pytest.fixture(autouse=True)
@@ -48,11 +47,10 @@ def _counter(name):
 # --------------------------------------------------------------------------
 def test_registry_population_and_lookup():
     names = tuning.knob_names()
-    for expected in ("allreduce_bucket_mb", "graph_fuse_cap",
-                     "flash_block_q", "flash_block_kv",
-                     "prefetch_buffer", "serving_batch_buckets",
-                     "serving_prefill_buckets", "serving_page_size"):
-        assert expected in names
+    assert sorted(names) == sorted((
+        "allreduce_bucket_mb", "graph_fuse_cap", "prefetch_buffer",
+        "serving_batch_buckets", "serving_prefill_buckets",
+        "serving_page_size"))
     k = tuning.get_knob("allreduce_bucket_mb")
     assert k.env_var == "MXNET_ALLREDUCE_BUCKET_MB"
     assert k.default == 32 and 0 in k.grid and 64 in k.grid
