@@ -152,8 +152,9 @@ def moe_swiglu(x, router_weight, gate_proj, up_proj, down_proj,
     sorted by expert (``parallel.expert_parallel.moe_apply``).  Router
     logits and gates are float32 whatever the products' dtype, which is the
     expert weights' (under AMP the target dtype: ``x`` and the router are
-    exempt from the cast, contrib/amp/lists.py).  The layer's routed pairs
-    and load imbalance go to ``telemetry.step_scalar``."""
+    exempt from the cast, contrib/amp/lists.py).  The layer's routed pairs,
+    the rows its routing walked and its load imbalance go to
+    ``telemetry.step_scalar``."""
     from jax import lax, nn
 
     from .. import telemetry
@@ -186,6 +187,8 @@ def moe_swiglu(x, router_weight, gate_proj, up_proj, down_proj,
             held=(int(experts_first), gate_proj.shape[0]))
         telemetry.step_scalar(telemetry.MOE_ROUTED_PAIRS.name,
                               aux["routed_pairs"])
+        telemetry.step_scalar(telemetry.MOE_WALKED_ROWS.name,
+                              aux["walked_rows"])
         telemetry.step_scalar(telemetry.MOE_LOAD_MAX_OVER_MEAN.name,
                               aux["load_max_over_mean"])
         return out.reshape(b, l, h)

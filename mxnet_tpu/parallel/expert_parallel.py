@@ -16,24 +16,43 @@ Capability upgrade over the reference (MXNet 1.x has no MoE).
   and group sizes, ``jax.lax.ragged_dot`` inside it).  No pair is ever
   dropped: the sorted rows are walked in parts of ``_PART_ROWS``, the
   groups of a part are the pairs it holds and nothing else, and a part past
-  the last pair is skipped, so the work follows the load while every shape
-  stays static.  What the experts held elsewhere would add is left out; the
-  exchange that brings it in is not written yet.
+  the last pair is skipped.  Within a part the gathers follow the pairs
+  too: ``dispatch`` gathers the tokens' rows into the sorted order a
+  granule of ``_GRANULE`` at a time and stops at the last pair, and so does
+  ``combine``'s backward, for the rows and for their gates.  The way back
+  (``combine``, ``dispatch``'s backward) is one scatter-add of the whole
+  part with the rows past the last pair as zeros: XLA's scatter-add on a
+  TPU pays a pass over the indices and the target before its first row, so
+  a granule at a time it costs more than the part at once.  Each of the two
+  is the other's transpose, written by hand (``jax.custom_vjp``) because
+  the walk's trip count is a device number.
+  What the experts held elsewhere would add is left out; the exchange that
+  brings it in is not written yet.
 """
 from __future__ import annotations
+
+import functools
 
 from ..base import MXNetError
 from ..profiler import SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTE
 
-__all__ = ["moe_apply", "stack_expert_params", "inject_aux_loss"]
+__all__ = ["moe_apply", "stack_expert_params", "inject_aux_loss",
+           "dispatch", "combine"]
 
 # Rows of the sorted (token, expert) pairs that the dropless path computes at
-# once: what is live of one part (its tokens, the experts' hidden rows, its
-# float32 result) is about 0.8 GiB at hidden 2048 and width 768.  The
-# products follow the pairs; the gather, the selects and the scatter-add are
-# of a whole part, so a load a few pairs over a multiple of this pays them
-# for one part more.
+# once, the static bound on what one grouped product sees: what is live of
+# one part (its tokens, the experts' hidden rows, its result) is about
+# 0.8 GiB at hidden 2048 and width 768.  The products and the gathers follow
+# the pairs, the scatter-add is of a whole part: a load a few pairs over a
+# multiple of this pays one granule's gathers and one part's scatter-add
+# more.
 _PART_ROWS = 32768
+# Rows of a part that one step of its sorted walk gathers.  On a v5e a
+# step costs what its rows cost (16 gathers of 2,048 rows take what one of
+# 32,768 takes; 4,096 times the same in every load tried), so the granule is
+# as small as keeps the rows walked past the last pair, at most one granule
+# a part, a few percent of a load of 16,384.  It divides _PART_ROWS.
+_GRANULE = 2048
 
 
 def stack_expert_params(per_expert):
@@ -60,7 +79,9 @@ def moe_apply(expert_fn, expert_params, router_weight, x, mesh=None,
     ``first .. first + count - 1`` of the router's ``E``.  Gates are the
     softmax over all ``E`` in float32, the ``top_k`` largest, divided by
     their sum when ``renormalize``.  aux: ``routed_pairs`` (pairs computed
-    here), ``expert_load`` (count,), ``load_max_over_mean``, ``dropped`` 0.
+    here), ``walked_rows`` (rows that the sorted walks covered to gather
+    them: whole granules), ``expert_load`` (count,),
+    ``load_max_over_mean``, ``dropped`` 0.
     """
     if capacity_factor is None:
         if mesh is not None:
@@ -78,6 +99,144 @@ def moe_apply(expert_fn, expert_params, router_weight, x, mesh=None,
                        capacity_factor)
 
 
+def _sorted_walk(part, n_live, body, init):
+    """``carry = body(lo, live, carry)`` over the granules of a part's
+    sorted rows that hold one of its first ``n_live``, in order: the granule
+    starts at row ``lo`` and ``live (granule,)`` says which of its rows are
+    among the ``n_live``.  The trip count is a device number, which JAX
+    cannot differentiate and need not: ``dispatch`` and ``combine`` are each
+    other's transpose, by hand."""
+    import jax
+    import jax.numpy as jnp
+
+    granule = min(_GRANULE, part)
+    if part % granule:
+        raise MXNetError(f"a part of {part} rows is no whole number of "
+                         f"granules of {granule}")
+
+    def step(i, carry):
+        lo = i * granule
+        return body(lo, lo + jnp.arange(granule, dtype=jnp.int32) < n_live,
+                    carry)
+
+    return jax.lax.fori_loop(0, (n_live + granule - 1) // granule, step,
+                             init)
+
+
+def _cut(a, lo, live):
+    """The granule of ``a`` that starts at row ``lo``."""
+    import jax
+
+    return jax.lax.dynamic_slice_in_dim(a, lo, live.shape[0])
+
+
+def _put(whole, lo, live, granule):
+    """``whole`` with the granule at ``lo`` set to ``granule``'s live rows
+    and to zero in the others."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.lax.dynamic_update_slice_in_dim(
+        whole, jnp.where(live[:, None], granule, 0).astype(whole.dtype), lo,
+        0)
+
+
+@functools.lru_cache(maxsize=None)
+def _walks():
+    """``dispatch`` and ``combine`` with their hand-written backwards."""
+    import jax
+    import jax.numpy as jnp
+
+    def add_rows(out, rows, tokens, n_live):
+        """``out`` with the first ``n_live`` of ``rows`` added to their
+        tokens' rows: one scatter-add of the whole part, the rows past
+        ``n_live`` as zeros (module docstring)."""
+        live = (jnp.arange(tokens.shape[0]) < n_live)[:, None]
+        return out.at[tokens].add(jnp.where(live, rows, 0).astype(out.dtype))
+
+    def dispatch_rows(tokens_count, x, tokens, n_live):
+        """``tokens_count`` is ``x``'s, static, for the backward: the
+        residuals hold nothing of ``x``'s shape."""
+        def body(lo, live, rows):
+            return _put(rows, lo, live, x[_cut(tokens, lo, live)])
+
+        with jax.named_scope(SCOPE_MOE_ROUTE):
+            return _sorted_walk(
+                tokens.shape[0], n_live, body,
+                jnp.zeros(tokens.shape + x.shape[1:], x.dtype))
+
+    def dispatch_fwd(tokens_count, x, tokens, n_live):
+        return dispatch_rows(tokens_count, x, tokens, n_live), (tokens,
+                                                                n_live)
+
+    def dispatch_bwd(tokens_count, res, g):
+        tokens, n_live = res
+        with jax.named_scope(SCOPE_MOE_ROUTE):
+            dx = add_rows(jnp.zeros((tokens_count,) + g.shape[1:], g.dtype),
+                          g, tokens, n_live)
+        return dx, None, None
+
+    def combine_rows(out, y, gates, order, n_live):
+        top_k = gates.shape[1]
+
+        def add(out):
+            return add_rows(
+                out, y.astype(out.dtype) * gates.reshape(-1)[order][:, None],
+                order // top_k, n_live)
+
+        with jax.named_scope(SCOPE_MOE_ROUTE):
+            return jax.lax.cond(n_live > 0, add, lambda out: out, out)
+
+    def combine_fwd(out, y, gates, order, n_live):
+        return combine_rows(out, y, gates, order, n_live), (y, gates, order,
+                                                            n_live)
+
+    def combine_bwd(res, g):
+        y, gates, order, n_live = res
+        top_k = gates.shape[1]
+
+        def body(lo, live, carry):
+            dy, dgates = carry
+            pair = _cut(order, lo, live)
+            got = g[pair // top_k]
+            per_row = jnp.sum(_cut(y, lo, live).astype(g.dtype) * got, axis=1)
+            return (_put(dy, lo, live, got * gates.reshape(-1)[pair][:, None]),
+                    dgates.at[pair].add(jnp.where(live, per_row, 0)))
+
+        with jax.named_scope(SCOPE_MOE_ROUTE):
+            dy, dgates = _sorted_walk(
+                order.shape[0], n_live, body,
+                (jnp.zeros_like(y), jnp.zeros(gates.size, gates.dtype)))
+        return g, dy, dgates.reshape(gates.shape), None, None
+
+    dispatch = jax.custom_vjp(dispatch_rows, nondiff_argnums=(0,))
+    combine = jax.custom_vjp(combine_rows)
+    dispatch.defvjp(dispatch_fwd, dispatch_bwd)
+    combine.defvjp(combine_fwd, combine_bwd)
+    return dispatch, combine
+
+
+def dispatch(x, tokens, n_live):
+    """The tokens of a part's pairs, in the sorted order: ``rows[i] =
+    x[tokens[i]]`` for ``i < n_live`` and zero past it, ``(part, d)`` in
+    ``x``'s dtype; ``tokens (part,)`` int32, ``n_live`` a traced count.
+    Only the granules that hold a row before ``n_live`` are gathered.  The
+    backward is one scatter-add of the whole part, whatever ``n_live``: call
+    it where the part holds a pair."""
+    return _walks()[0](x.shape[0], x, tokens, n_live)
+
+
+def combine(out, y, gates, order, n_live):
+    """``out (T, d)`` (float32) with ``gates[pair] * y[i]`` added to the
+    token of each sorted row ``i < n_live`` of the part, by one scatter-add
+    of the whole part that a part with no pair skips.  ``order (part,)``
+    holds the rows' pairs (token * top_k + choice), ``gates (T, top_k)``
+    every pair's gate; the rows of ``y`` past ``n_live`` may hold anything,
+    NaN included.  The backward walks the granules that hold a row before
+    ``n_live``, for ``y`` and the gates alike."""
+    return _walks()[1](out, y, gates, order, n_live)
+
+
 def _moe_dropless(expert_fn, expert_params, router_weight, x, top_k,
                   renormalize, held):
     import jax
@@ -90,7 +249,9 @@ def _moe_dropless(expert_fn, expert_params, router_weight, x, top_k,
         raise MXNetError(f"held {held} / top_k {top_k} do not fit a router "
                          f"of {E} experts")
     pairs = T * top_k
-    part = min(_PART_ROWS, pairs)
+    # a part is a whole number of granules
+    granule = min(_GRANULE, pairs)
+    part = -(-min(_PART_ROWS, pairs) // granule) * granule
     n_parts = -(-pairs // part)
 
     with jax.named_scope(SCOPE_MOE_ROUTE):
@@ -103,63 +264,57 @@ def _moe_dropless(expert_fn, expert_params, router_weight, x, top_k,
         # pairs held elsewhere get the key ``count`` and sort past the end
         local = chosen.reshape(-1) - first
         key = jnp.where((local >= 0) & (local < count), local, count)
-        order = jnp.argsort(key, stable=True)
-        token_of = (order // top_k).astype(jnp.int32)
-        gate_of = gates.reshape(-1)[order]
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
         load = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
                        dtype=jnp.int32)                           # (count,)
         ends = jnp.cumsum(load)
         total = ends[-1]
-        pad = n_parts * part - pairs
-        token_of = jnp.pad(token_of, (0, pad)).reshape(n_parts, part)
-        gate_of = jnp.pad(gate_of, (0, pad)).reshape(n_parts, part)
+        order = jnp.pad(order, (0, n_parts * part - pairs)) \
+            .reshape(n_parts, part)
         starts = jnp.arange(n_parts, dtype=jnp.int32) * part
+        # the rows of each part that hold a pair, and the whole granules
+        # of them that its sorted walk covers
+        n_live = jnp.clip(total - starts, 0, part)
+        walked = jnp.sum(-(-n_live // granule) * granule)
 
-    def live(lo):
-        """Does the part at ``lo`` hold a pair?"""
-        return lo < total
-
-    def one_part(x, params, tokens, gate, lo):
-        """The rows ``[lo, lo + part)`` of the sorted pairs: each pair's
-        expert output times its gate, float32, zero past the last pair.
-
-        The groups are the pairs the part holds.  A grouped product leaves
-        the rows past its last group as they were in memory, NaN included,
-        forward and backward: the two selects keep them out of the result
-        and, transposed, out of the tokens' and the gates' gradients."""
+    def products(x, params, order, lo, n_live):
+        """The experts' outputs for the rows ``[lo, lo + part)`` of the
+        sorted pairs.  The groups are the pairs the part holds.  A grouped
+        product leaves the rows past its last group as they were in memory,
+        NaN included, forward and backward: ``combine`` and ``dispatch``'s
+        backward take those rows as zeros."""
         with jax.named_scope(SCOPE_MOE_ROUTE):
             sizes = (jnp.clip(ends, lo, lo + part)
                      - jnp.clip(ends - load, lo, lo + part))
-            valid = (lo + jnp.arange(part) < total)[:, None]
-            rows = jnp.where(valid, x[tokens], 0)
+            rows = dispatch(x, order // top_k, n_live)
         with jax.named_scope(SCOPE_MOE_EXPERTS):
-            y = expert_fn(params, rows, sizes)
-        with jax.named_scope(SCOPE_MOE_ROUTE):
-            return jnp.where(valid, y.astype(jnp.float32), 0.0) \
-                * gate[:, None]
+            return expert_fn(params, rows, sizes)
 
-    # A part past the last pair is skipped.  The skip lies inside the
-    # checkpoint: what the backward keeps of a part is then the
-    # checkpoint's inputs, of which the scan stacks the part's own (tokens,
-    # gates) and hoists the tokens and the weights, which every part
-    # shares; a cond's own residuals it would stack whole, part by part.
+    # A part past the last pair is skipped: its dispatch and products by
+    # the cond, its combine by a cond of its own forward and by walking no
+    # step backward.  All of it lies inside the checkpoint: what the
+    # backward keeps of a part is then the checkpoint's inputs, of which
+    # the scan stacks the part's own (its slice of the order) and hoists
+    # the tokens, the gates and the weights, which every part shares; a
+    # cond's own residuals, or combine's, it would stack whole, part by
+    # part.  ``out`` the backward never reads.
+    skipped = jax.eval_shape(
+        expert_fn, expert_params, jax.ShapeDtypeStruct((part, d), x.dtype),
+        jax.ShapeDtypeStruct((count,), jnp.int32))
+
     @jax.checkpoint
-    def part_rows_of(x, params, tokens, gate, lo):
-        return jax.lax.cond(
-            live(lo), lambda: one_part(x, params, tokens, gate, lo),
-            lambda: jnp.zeros((part, d), jnp.float32))
+    def add_part(out, x, params, gates, order, lo, n_live):
+        y = jax.lax.cond(
+            n_live > 0, lambda: products(x, params, order, lo, n_live),
+            lambda: jnp.zeros(skipped.shape, skipped.dtype))
+        return combine(out, y, gates, order, n_live)
 
     def step(out, part_in):
-        tokens, gate, lo = part_in
-        y = part_rows_of(x, expert_params, tokens, gate, lo)
-        with jax.named_scope(SCOPE_MOE_ROUTE):
-            out = jax.lax.cond(live(lo), lambda: out.at[tokens].add(y),
-                               lambda: out)
-        return out, None
+        return add_part(out, x, expert_params, gates, *part_in), None
 
     out, _ = jax.lax.scan(step, jnp.zeros((T, d), jnp.float32),
-                          (token_of, gate_of, starts))
-    aux = {"routed_pairs": total, "expert_load": load,
+                          (order, starts, n_live))
+    aux = {"routed_pairs": total, "walked_rows": walked, "expert_load": load,
            "load_max_over_mean": jnp.max(load) * count
            / jnp.maximum(total, 1).astype(jnp.float32),
            "dropped": jnp.zeros((), jnp.int32)}

@@ -407,6 +407,11 @@ MOE_ROUTED_PAIRS = counter(
     "mxnet_moe_routed_pairs_total",
     "(token, expert) pairs the dropless expert layers of this process "
     "computed: those routed to the experts held here (step scalar)")
+MOE_WALKED_ROWS = counter(
+    "mxnet_moe_walked_rows_total",
+    "rows that the sorted walks of the dropless expert layers covered to "
+    "gather their pairs, whole granules (step scalar): over "
+    "mxnet_moe_routed_pairs_total, 1.0 is no row gathered in vain")
 MOE_LOAD_MAX_OVER_MEAN = histogram(
     "mxnet_moe_expert_load_max_over_mean",
     "fullest held expert's pairs over the mean of the held experts, one "

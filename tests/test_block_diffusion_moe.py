@@ -5,6 +5,7 @@ mask; dropless top-k routing over the experts held (the shares add up, the
 worst imbalance loses nothing); and the program's loss and gradients against
 the configuration's plain reference at a small size of the same shape of
 layer.  All on the CPU, seeded random weights."""
+import functools
 import json
 import os
 
@@ -16,7 +17,9 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import nd, telemetry
 from mxnet_tpu.ops import flash_attention as fa
-from mxnet_tpu.parallel.expert_parallel import _PART_ROWS, moe_apply
+from mxnet_tpu.parallel import expert_parallel
+from mxnet_tpu.parallel.expert_parallel import (_PART_ROWS, combine, dispatch,
+                                                moe_apply)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -256,6 +259,176 @@ def test_dropless_under_the_worst_imbalance(tokens):
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
 
 
+# a granule of 8 rows in a part of 32: the edges of the walk at a size where
+# twelve cases cost nothing
+WALK_GRANULE, WALK_PART = 8, 32
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("n_live", [0, 1, WALK_GRANULE - 1, WALK_GRANULE,
+                                    WALK_GRANULE + 1, WALK_PART])
+def test_dispatch_and_combine_walk_live_rows_like_the_plain_forms(
+        monkeypatch, n_live, dtype):
+    """``dispatch`` and ``combine`` against a gather, two selects and a
+    scatter-add of the whole part: values, and the gradients of the tokens,
+    the experts' rows and the gates.  12 tokens choose 4: a token comes back
+    in several groups of the part, which is the sorted rows 8 to 40 of 48;
+    the rows past ``n_live`` hold NaN, as the TPU's grouped product may
+    leave them, and ``n_live`` is a traced number."""
+    monkeypatch.setattr(expert_parallel, "_GRANULE", WALK_GRANULE)
+    rs = np.random.RandomState(7)
+    tokens, top_k, d, lo = 12, 4, 6, 8
+    sorted_pairs = rs.permutation(tokens * top_k).astype("i4")
+    order = jnp.asarray(sorted_pairs[lo:lo + WALK_PART])
+    token_of = order // top_k
+    x = jnp.asarray(rs.randn(tokens, d).astype("f")).astype(dtype)
+    out = jnp.asarray(rs.randn(tokens, d).astype("f"))
+    valid = (jnp.arange(WALK_PART) < n_live)
+    y = jnp.asarray(rs.randn(WALK_PART, d).astype("f")).astype(dtype)
+    dirty = jnp.where(valid[:, None], y, jnp.nan)
+    gates = jnp.asarray(rs.rand(tokens, top_k).astype("f"))
+    w = jnp.asarray(rs.randn(WALK_PART, d).astype("f"))
+
+    def plain_dispatch(x):
+        return jnp.where(valid[:, None], x[token_of], 0)
+
+    def plain_combine(out, y, gates):
+        return out.at[token_of].add(
+            jnp.where(valid[:, None], y.astype(jnp.float32), 0.0)
+            * gates.reshape(-1)[order][:, None])
+
+    got = jax.jit(lambda x, n: dispatch(x, token_of, n))(x, n_live)
+    assert got.dtype == x.dtype
+    np.testing.assert_array_equal(got, plain_dispatch(x))
+    got = jax.jit(lambda n: combine(out, dirty, gates, order, n))(n_live)
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(got, plain_combine(out, y, gates), atol=1e-6)
+
+    # float32 sums in another order; bf16 rounds every term of one
+    tol = 1e-5 if dtype == "float32" else 0.05
+    dx = jax.jit(jax.grad(lambda x, n: jnp.sum(
+        dispatch(x, token_of, n).astype(jnp.float32) * w)))(
+            x, n_live)
+    want = jax.grad(lambda x: jnp.sum(
+        plain_dispatch(x).astype(jnp.float32) * w))(x)
+    assert dx.dtype == x.dtype
+    np.testing.assert_allclose(dx.astype(jnp.float32),
+                               want.astype(jnp.float32), atol=tol)
+
+    def through(fn):
+        return lambda out, y, gates, *n: jnp.sum(
+            jnp.sin(fn(out, y, gates, *n)) * w[:tokens])
+
+    got = jax.jit(jax.grad(through(
+        lambda out, y, gates, n: combine(out, y, gates, order, n)),
+        (0, 1, 2)))(
+        out, dirty, gates, n_live)
+    want = jax.grad(through(plain_combine), (0, 1, 2))(out, y, gates)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and np.isfinite(
+            np.asarray(a, dtype="f")).all()
+        np.testing.assert_allclose(a.astype(jnp.float32),
+                                   b.astype(jnp.float32), atol=tol)
+
+
+def _whole_part_dropless(expert_fn, params, router, x, top_k, held, part):
+    """The layer as it stood before ISSUE 29, kept here as the plain form:
+    the same router, key and sort, then the gates gathered for every pair
+    and, a part at a time, a gather, two selects and a scatter-add of the
+    whole part, differentiated by JAX."""
+    first, count = held
+    pairs = x.shape[0] * top_k
+    n_parts = -(-pairs // part)
+    logits = jnp.dot(x, router, precision=jax.lax.Precision.HIGHEST)
+    gates, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    local = chosen.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < count), local, count)
+    order = jnp.argsort(key, stable=True)
+    load = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
+                   dtype=jnp.int32)
+    ends = jnp.cumsum(load)
+    pad = n_parts * part - pairs
+    tokens = jnp.pad(order // top_k, (0, pad)).reshape(n_parts, part)
+    gate_of = jnp.pad(gates.reshape(-1)[order], (0, pad)) \
+        .reshape(n_parts, part)
+    out = jnp.zeros(x.shape, jnp.float32)
+    for i in range(n_parts):
+        lo = i * part
+        sizes = (jnp.clip(ends, lo, lo + part)
+                 - jnp.clip(ends - load, lo, lo + part))
+        valid = (lo + jnp.arange(part) < ends[-1])[:, None]
+        y = expert_fn(params, jnp.where(valid, x[tokens[i]], 0), sizes)
+        out = out.at[tokens[i]].add(
+            jnp.where(valid, y.astype(jnp.float32), 0.0)
+            * gate_of[i][:, None])
+    return out.astype(x.dtype)
+
+
+def test_a_held_share_under_checkpoint_and_jit_is_the_whole_part_form(
+        monkeypatch):
+    """``moe_apply`` with a share held, inside ``jax.checkpoint`` inside
+    ``jit`` (custom VJPs with a traced trip count, in a cond, in the part's
+    checkpoint, in the scan, in the layer's checkpoint): the result and the
+    gradients of the tokens, the router and the experts are those of the
+    form that moves whole parts.  Three parts of 64 rows in granules of 16,
+    the pairs held ending inside the second."""
+    monkeypatch.setattr(expert_parallel, "_GRANULE", 16)
+    monkeypatch.setattr(expert_parallel, "_PART_ROWS", 64)
+    rs = np.random.RandomState(11)
+    x = jnp.asarray(rs.randn(48, 12).astype("f"))
+    router = jnp.asarray(rs.randn(12, 8).astype("f"))
+    p = _expert_weights(rs, 4, 12, 6)
+
+    @jax.checkpoint
+    def layer(x, router, p):
+        return moe_apply(_grouped, p, router, x, capacity_factor=None,
+                         top_k=4, renormalize=True, held=(2, 4))
+
+    def loss(fn):
+        return lambda x, router, p: jnp.sum(jnp.sin(fn(x, router, p)))
+
+    out, aux = jax.jit(layer)(x, router, p)
+    pairs = int(aux["routed_pairs"])
+    assert 64 < pairs < 128                   # the second part ends the load
+    assert pairs <= int(aux["walked_rows"]) < pairs + 16
+    plain = functools.partial(_whole_part_dropless, _grouped, top_k=4,
+                              held=(2, 4), part=64)
+
+    def plain_layer(x, router, p):
+        return plain(p, router, x)
+
+    np.testing.assert_allclose(out, plain_layer(x, router, p), atol=1e-6)
+    got = jax.jit(jax.grad(loss(lambda *a: layer(*a)[0]), (0, 1, 2)))(
+        x, router, p)
+    want = jax.grad(loss(plain_layer), (0, 1, 2))(x, router, p)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, atol=6e-6)
+
+
+@pytest.mark.parametrize("held,part_rows", [((0, 8), 256), ((2, 3), 32)])
+def test_walked_rows_follow_the_routed_pairs(monkeypatch, held, part_rows):
+    """``walked_rows`` is what the layer's sorted walks cover: whole
+    granules of the parts' live rows.  With every expert held, in one part
+    that divides, that is the routed pairs exactly; on a share in parts of
+    32, less than a granule more, in the last part that holds a pair."""
+    monkeypatch.setattr(expert_parallel, "_GRANULE", 16)
+    monkeypatch.setattr(expert_parallel, "_PART_ROWS", part_rows)
+    rs = np.random.RandomState(3)
+    x = jnp.asarray(rs.randn(80, 12).astype("f"))
+    router = jnp.asarray(rs.randn(12, 8).astype("f"))
+    p = _expert_weights(rs, held[1], 12, 6)
+    _, aux = moe_apply(_grouped, p, router, x, capacity_factor=None, top_k=2,
+                       renormalize=True, held=held)
+    pairs, walked = int(aux["routed_pairs"]), int(aux["walked_rows"])
+    if held == (0, 8):
+        assert walked == pairs == 160
+    else:
+        assert 32 < pairs < 160 and pairs % 16
+        assert walked == -(-pairs // 16) * 16
+
+
 def test_switch_routing_is_the_same_function_with_a_capacity():
     rs = np.random.RandomState(2)
     x = jnp.asarray(rs.randn(32, 8).astype("f"))
@@ -373,6 +546,10 @@ def test_program_matches_the_reference_loss_and_every_gradient(amp,
     load = metrics["mxnet_moe_expert_load_max_over_mean"]["samples"][0]
     assert load["count"] == 4 and load["sum"] / 4 >= 1.0
     assert 0.6 < pairs / (4 * 2 * 64 * 2 * 4 / 8) < 1.4
+    # 128 rows choose 2: a layer's sorted walk covers its one part of 256
+    # rows, which is one granule
+    walked = metrics["mxnet_moe_walked_rows_total"]["samples"][0]["value"]
+    assert walked == 4 * 256
 
 
 def test_remat_is_real_in_the_fused_step_and_scopes_are_in_its_table():

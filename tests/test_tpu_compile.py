@@ -133,3 +133,80 @@ def test_flash_backward_kernel_compiles_for_v5e(one_chip, shape, dim, dtype,
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "mxnet_flash_attention_bwd" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def _gathered_and_scattered(text):
+    """From a compiled program's text: the shapes of what its gathers
+    produce and of what its scatters are given to put."""
+    import re
+
+    dims = {name: tuple(int(n) for n in shape.split(",") if n)
+            for name, shape in re.findall(
+                r"%?([\w.-]+) = \w+\[([\d,]*)\]", text)}
+    gathered = [dims[name] for name in re.findall(
+        r"%?([\w.-]+) = \S+ gather\(", text)]
+    scattered = [dims[updates] for updates in re.findall(
+        r" scatter\(%?[\w.-]+, %?[\w.-]+, %?([\w.-]+)\)", text)]
+    return gathered, scattered
+
+
+@pytest.mark.parametrize("backward", [False, True])
+def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
+        one_chip, backward):
+    """The decoder cell's expert layer (16,384 rows of 2,048 choose 8 of 128
+    experts, 16 of width 768 held in bf16) under a layer's checkpoint:
+    loops with a traced trip count inside custom VJPs, a cond, a checkpoint
+    and a scan are the chip's compiler's to accept.  What it compiled
+    gathers rows a granule of the sorted rows at a time and no gate for
+    each of the 131,072 pairs; a part's 32,768 rows move at once only in
+    the scatter-add (``combine`` forward, ``dispatch`` backward), which XLA
+    does as a sort of the indices, a gather of the rows into that order and
+    a sorted scatter."""
+    from mxnet_tpu.parallel.expert_parallel import (_GRANULE, _PART_ROWS,
+                                                    moe_apply)
+
+    tokens, hidden, experts, held, width, top_k = 16384, 2048, 128, 16, 768, 8
+
+    def grouped(p, rows, sizes):
+        rows = rows.astype(p["g"].dtype)
+        dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
+                                precision=jax.lax.Precision.DEFAULT)
+        return dot(jax.nn.silu(dot(rows, p["g"])) * dot(rows, p["u"]),
+                   p["d"])
+
+    @jax.checkpoint
+    def layer(x, router, p):
+        out, aux = moe_apply(grouped, p, router, x, capacity_factor=None,
+                             top_k=top_k, renormalize=True, held=(16, held))
+        return out, aux["walked_rows"]
+
+    def loss(x, router, p):
+        out, walked = layer(x, router, p)
+        return jnp.sum(jnp.sin(out)), walked
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (spec((tokens, hidden), "float32"),
+            spec((hidden, experts), "float32"),
+            {"g": spec((held, hidden, width), "bfloat16"),
+             "u": spec((held, hidden, width), "bfloat16"),
+             "d": spec((held, width, hidden), "bfloat16")})
+    fn = jax.value_and_grad(loss, (0, 1, 2), has_aux=True) if backward \
+        else layer
+    gathered, scattered = _gathered_and_scattered(
+        jax.jit(fn).lower(*args).compile().as_text())
+    # besides the whole part's, the router's top-k scatters a token's 8
+    # gates back and a granule's gates' gradients go to their pairs:
+    # scalars both
+    whole = [shape for shape in scattered if shape == (_PART_ROWS, hidden)]
+    assert len(whole) == (2 if backward else 1)
+    assert all(hidden not in shape[1:] for shape in scattered
+               if shape not in whole)
+    # forward: the tokens' rows; backward: those again, the cotangent's
+    # rows and a granule's gates
+    rows = [shape[0] for shape in gathered if shape[-1] == hidden]
+    assert rows.count(_GRANULE) >= (3 if backward else 1)
+    assert rows.count(_PART_ROWS) == len(whole)
+    assert set(rows) == {_GRANULE, _PART_ROWS}
+    assert all(tokens * top_k not in shape for shape in gathered)
