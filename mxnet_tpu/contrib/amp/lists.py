@@ -30,11 +30,11 @@ TARGET_DTYPE_OPS = [
 ]
 
 # inputs (by position) of a TARGET_DTYPE_OPS op that keep their dtype: the
-# expert layer's tokens and router weight, so that its router logits, its
-# softmax and the choice of experts are float32; the op casts the tokens it
-# gathers to the expert weights' dtype itself
+# expert layer's tokens, router weight and selection bias, so that its
+# router logits, its scores and the choice of experts are float32; the op
+# casts the tokens it gathers to the expert weights' dtype itself
 KEEP_DTYPE_INPUTS = {
-    "_contrib_moe_swiglu": (0, 1),
+    "_contrib_moe_swiglu": (0, 1, 5),
 }
 
 # numerically sensitive ops pinned to fp32

@@ -14,6 +14,7 @@ import math
 import numpy as _np
 
 from ....base import MXNetError
+from ....profiler import SCOPE_MOE_SHARED
 from ...block import HybridBlock
 from ...parameter import Parameter
 from ... import nn
@@ -31,10 +32,17 @@ class LlamaConfig:
                  num_experts=0, moe_capacity_factor=1.25,
                  moe_aux_loss_weight=0.01, head_dim=None, qk_norm=False,
                  moe_top_k=1, moe_renormalize=False, moe_experts_held=None,
-                 moe_intermediate_size=None, block_diffusion=0):
+                 moe_intermediate_size=None, block_diffusion=0,
+                 attention_types=None, attention_window=0,
+                 rope_attention_types=("full", "window"),
+                 attention_gate=False, post_norms=False, embed_scale=1.0,
+                 num_dense_layers=0, moe_shared_intermediate_size=0,
+                 moe_score="softmax", moe_route_scale=1.0,
+                 moe_renorm_eps=0.0, moe_select_bias=False):
         # num_experts > 0: an MoE FFN (parallel.expert_parallel) replaces
-        # the dense SwiGLU MLP in every layer; num_experts is the router's
-        # width.  moe_capacity_factor=<number>: switch top-1 routing with
+        # the dense SwiGLU MLP in every layer after the first
+        # num_dense_layers; num_experts is the router's width.
+        # moe_capacity_factor=<number>: switch top-1 routing with
         # capacity dropping (Mixtral-style; shard the expert dim over the
         # 'ep' mesh axis in TrainStep specs).  moe_capacity_factor=None:
         # dropless routing of moe_top_k experts a token (gates divided by
@@ -55,6 +63,46 @@ class LlamaConfig:
         # via parallel.expert_parallel.inject_aux_loss (0 disables; the
         # dropless path has none)
         self.moe_aux_loss_weight = moe_aux_loss_weight
+        # the dropless router beyond softmax top-k: moe_score "sigmoid"
+        # scores each output by its own sigmoid; moe_select_bias gives the
+        # layer a vector of the router's width (a parameter no gradient
+        # reaches, grad_req "null": in checkpoints, out of the optimizer)
+        # that is added to the scores for the choice of experts and never
+        # to a gate; gates are divided by their sum plus moe_renorm_eps
+        # (moe_renormalize) and multiplied by moe_route_scale.
+        # moe_shared_intermediate_size > 0: a dense SwiGLU of that width
+        # that every token passes, added to the routed part (on a share of
+        # an expert-parallel deployment it is computed whole).
+        self.num_dense_layers = num_dense_layers
+        self.moe_score = moe_score
+        self.moe_route_scale = moe_route_scale
+        self.moe_renorm_eps = moe_renorm_eps
+        self.moe_select_bias = moe_select_bias
+        self.moe_shared_intermediate_size = moe_shared_intermediate_size
+        # a layer's attention is "full" (causal) or "window" (causal over
+        # the last attention_window keys): attention_types names each
+        # layer's, default all full; RoPE turns the q and k of the kinds in
+        # rope_attention_types.  attention_gate: o * sigmoid(x Wz) before
+        # the output projection.  post_norms: an RMSNorm on each sublayer's
+        # output before it joins the residual, beside the one on its
+        # input.  embed_scale multiplies the embeddings.
+        self.attention_types = tuple(attention_types) \
+            if attention_types is not None else ("full",) * num_layers
+        self.attention_window = attention_window
+        self.rope_attention_types = tuple(rope_attention_types)
+        self.attention_gate = attention_gate
+        self.post_norms = post_norms
+        self.embed_scale = embed_scale
+        if len(self.attention_types) != num_layers or set(
+                self.attention_types) - {"full", "window"}:
+            raise MXNetError(
+                f"attention_types names each of the {num_layers} layers "
+                f"'full' or 'window'; got {self.attention_types}")
+        if "window" in self.attention_types and (
+                attention_window < 1 or block_diffusion):
+            raise MXNetError(
+                "a window layer needs attention_window >= 1 and the causal "
+                "layout (block_diffusion=0)")
         # remat: rematerialize each decoder layer's activations in backward
         # (jax.checkpoint) — trades ~1/3 more FLOPs for O(num_layers) less
         # activation HBM, the standard lever for bigger per-chip batches
@@ -90,6 +138,17 @@ class LlamaConfig:
                 f"({num_heads}) for GQA")
         self.head_dim = head_dim or hidden_size // num_heads
 
+    def sparse_layer(self, i):
+        """Is layer ``i``'s FFN the expert layer?"""
+        return self.num_experts > 0 and i >= self.num_dense_layers
+
+    def layers_alike(self):
+        """Are all layers of one kind (what a scan over stacked layers or a
+        pipeline of equal stages needs)?"""
+        n = self.num_layers
+        return len(set(self.attention_types)) <= 1 and len(
+            {self.sparse_layer(i) for i in range(n)}) <= 1
+
 
 class RMSNorm(HybridBlock):
     def __init__(self, dim, eps=1e-5, **kwargs):
@@ -102,10 +161,11 @@ class RMSNorm(HybridBlock):
 
 
 class LlamaAttention(HybridBlock):
-    def __init__(self, cfg, **kwargs):
+    def __init__(self, cfg, kind="full", **kwargs):
         super().__init__(**kwargs)
         d, hd = cfg.hidden_size, cfg.head_dim
         self._cfg = cfg
+        self._kind = kind
         # child names matter: parallel.tensor_parallel's Megatron rules key
         # on the q/k/v/o_proj suffixes to pick column- vs row-parallel specs
         with self.name_scope():
@@ -124,6 +184,10 @@ class LlamaAttention(HybridBlock):
             if cfg.qk_norm:
                 self.q_norm = RMSNorm(hd, cfg.rms_eps, prefix="q_norm_")
                 self.k_norm = RMSNorm(hd, cfg.rms_eps, prefix="k_norm_")
+            if cfg.attention_gate:
+                self.gate_proj = nn.Dense(cfg.num_heads * hd, use_bias=False,
+                                          flatten=False, in_units=d,
+                                          prefix="gate_proj_")
 
     def hybrid_forward(self, F, x):
         cfg = self._cfg
@@ -147,27 +211,35 @@ class LlamaAttention(HybridBlock):
                                   mask_block=cfg.block_diffusion,
                                   sm_scale=1.0 / math.sqrt(hd))
         else:
-            q = F.rope(q, base=cfg.rope_base)
-            k = F.rope(k, base=cfg.rope_base)
-            o = F.flash_attention(q, k, v, causal=True,
-                                  sm_scale=1.0 / math.sqrt(hd))
+            if self._kind in cfg.rope_attention_types:
+                q = F.rope(q, base=cfg.rope_base)
+                k = F.rope(k, base=cfg.rope_base)
+            if self._kind == "window":
+                o = F.flash_attention(q, k, v, mask="window",
+                                      window=cfg.attention_window,
+                                      sm_scale=1.0 / math.sqrt(hd))
+            else:
+                o = F.flash_attention(q, k, v, causal=True,
+                                      sm_scale=1.0 / math.sqrt(hd))
         o = o.transpose((0, 2, 1, 3)).reshape((b, l, cfg.num_heads * hd))
+        if cfg.attention_gate:
+            o = o * F.sigmoid(self.gate_proj(x))
         return self.o_proj(o)
 
 
 class LlamaMLP(HybridBlock):
-    def __init__(self, cfg, **kwargs):
+    def __init__(self, cfg, width=None, **kwargs):
         super().__init__(**kwargs)
+        width = width or cfg.intermediate_size
         with self.name_scope():
-            self.gate_proj = nn.Dense(cfg.intermediate_size, use_bias=False,
+            self.gate_proj = nn.Dense(width, use_bias=False,
                                       flatten=False, in_units=cfg.hidden_size,
                                       prefix="gate_proj_")
-            self.up_proj = nn.Dense(cfg.intermediate_size, use_bias=False,
+            self.up_proj = nn.Dense(width, use_bias=False,
                                     flatten=False, in_units=cfg.hidden_size,
                                     prefix="up_proj_")
             self.down_proj = nn.Dense(cfg.hidden_size, use_bias=False,
-                                      flatten=False,
-                                      in_units=cfg.intermediate_size,
+                                      flatten=False, in_units=width,
                                       prefix="down_proj_")
 
     def hybrid_forward(self, F, x):
@@ -176,7 +248,8 @@ class LlamaMLP(HybridBlock):
 
 class LlamaMoEMLP(HybridBlock):
     """MoE SwiGLU FFN (net-new vs the reference): switch top-1 with a
-    capacity, or dropless top-k over the experts held (``LlamaConfig``).
+    capacity, or dropless top-k over the experts held, with a shared expert
+    beside them where the config gives one a width (``LlamaConfig``).
 
     Expert weights are stacked with a leading expert axis, one entry an
     expert held, so parallel.expert_parallel's dispatch/combine einsums
@@ -189,11 +262,13 @@ class LlamaMoEMLP(HybridBlock):
         E, H, I = cfg.num_experts, cfg.hidden_size, cfg.moe_intermediate_size
         N = cfg.moe_experts_held[1]
         if cfg.moe_capacity_factor is not None and (
-                N != E or cfg.moe_top_k != 1):
+                N != E or cfg.moe_top_k != 1 or cfg.moe_score != "softmax"
+                or cfg.moe_select_bias or cfg.moe_route_scale != 1.0):
             raise MXNetError(
                 "a moe_capacity_factor is the switch top-1 layer over every "
-                "expert; moe_top_k > 1 and moe_experts_held route dropless "
-                "(moe_capacity_factor=None)")
+                "expert, softmax gates; moe_top_k > 1, moe_experts_held, "
+                "moe_score, moe_select_bias and moe_route_scale route "
+                "dropless (moe_capacity_factor=None)")
         with self.name_scope():
             self.router = self.params.get("router_weight", shape=(H, E))
             self.gate_proj = self.params.get("gate_proj_weight",
@@ -201,40 +276,72 @@ class LlamaMoEMLP(HybridBlock):
             self.up_proj = self.params.get("up_proj_weight", shape=(N, H, I))
             self.down_proj = self.params.get("down_proj_weight",
                                              shape=(N, I, H))
+            if cfg.moe_select_bias:
+                self.select_bias = self.params.get(
+                    "select_bias", shape=(E,), init="zeros", grad_req="null")
+            if cfg.moe_shared_intermediate_size:
+                self.shared = LlamaMLP(
+                    cfg, width=cfg.moe_shared_intermediate_size,
+                    prefix="shared_")
 
-    def hybrid_forward(self, F, x, router, gate_proj, up_proj, down_proj):
+    def hybrid_forward(self, F, x, router, gate_proj, up_proj, down_proj,
+                       select_bias=None):
         # a registered op (not a raw apply_fn), so the block traces to
         # Symbol and exports/imports like the rest of the zoo
         cfg = self._cfg
-        if cfg.moe_capacity_factor is None:
+        if cfg.moe_capacity_factor is not None:
             return F.moe_swiglu(x, router, gate_proj, up_proj, down_proj,
-                                capacity_factor=0.0, top_k=cfg.moe_top_k,
-                                renormalize=cfg.moe_renormalize,
-                                experts_first=cfg.moe_experts_held[0])
-        return F.moe_swiglu(x, router, gate_proj, up_proj, down_proj,
-                            capacity_factor=cfg.moe_capacity_factor,
-                            aux_loss_weight=cfg.moe_aux_loss_weight)
+                                capacity_factor=cfg.moe_capacity_factor,
+                                aux_loss_weight=cfg.moe_aux_loss_weight)
+        out = F.moe_swiglu(x, router, gate_proj, up_proj, down_proj,
+                           select_bias, capacity_factor=0.0,
+                           top_k=cfg.moe_top_k,
+                           renormalize=cfg.moe_renormalize,
+                           experts_first=cfg.moe_experts_held[0],
+                           score=cfg.moe_score,
+                           route_scale=cfg.moe_route_scale,
+                           renorm_eps=cfg.moe_renorm_eps)
+        if cfg.moe_shared_intermediate_size:
+            import jax
+
+            with jax.named_scope(SCOPE_MOE_SHARED):
+                out = out + self.shared(x)
+        return out
 
 
 class LlamaDecoderLayer(HybridBlock):
-    def __init__(self, cfg, **kwargs):
+    """Layer ``index`` of the decoder: its attention of the kind
+    ``cfg.attention_types`` names, its FFN dense or the expert layer
+    (``cfg.sparse_layer``)."""
+
+    def __init__(self, cfg, index=0, **kwargs):
         super().__init__(**kwargs)
         self._remat = cfg.remat
+        self._post_norms = cfg.post_norms
         with self.name_scope():
             self.input_layernorm = RMSNorm(cfg.hidden_size, cfg.rms_eps,
                                            prefix="input_layernorm_")
-            self.self_attn = LlamaAttention(cfg, prefix="self_attn_")
+            self.self_attn = LlamaAttention(
+                cfg, kind=cfg.attention_types[index], prefix="self_attn_")
             self.post_attention_layernorm = RMSNorm(
                 cfg.hidden_size, cfg.rms_eps,
                 prefix="post_attention_layernorm_")
-            if cfg.num_experts > 0:
+            if cfg.sparse_layer(index):
                 self.mlp = LlamaMoEMLP(cfg, prefix="mlp_")
             else:
                 self.mlp = LlamaMLP(cfg, prefix="mlp_")
+            if cfg.post_norms:
+                self.attn_out_layernorm = RMSNorm(
+                    cfg.hidden_size, cfg.rms_eps,
+                    prefix="attn_out_layernorm_")
+                self.mlp_out_layernorm = RMSNorm(
+                    cfg.hidden_size, cfg.rms_eps, prefix="mlp_out_layernorm_")
 
     def _body(self, x):
-        x = x + self.self_attn(self.input_layernorm(x))
-        return x + self.mlp(self.post_attention_layernorm(x))
+        a = self.self_attn(self.input_layernorm(x))
+        x = x + (self.attn_out_layernorm(a) if self._post_norms else a)
+        m = self.mlp(self.post_attention_layernorm(x))
+        return x + (self.mlp_out_layernorm(m) if self._post_norms else m)
 
     def hybrid_forward(self, F, x):
         if self._remat:
@@ -290,11 +397,13 @@ class LlamaModel(HybridBlock):
             self.layers = nn.HybridSequential(prefix="layers_")
             with self.layers.name_scope():
                 for i in range(cfg.num_layers):
-                    self.layers.add(LlamaDecoderLayer(cfg, prefix=f"{i}_"))
+                    self.layers.add(LlamaDecoderLayer(cfg, i, prefix=f"{i}_"))
             self.norm = RMSNorm(cfg.hidden_size, cfg.rms_eps, prefix="norm_")
 
     def hybrid_forward(self, F, input_ids):
         h = self.embed_tokens(input_ids)
+        if self._cfg.embed_scale != 1.0:
+            h = h * self._cfg.embed_scale
         h = self.layers(h)
         return self.norm(h)
 
@@ -412,6 +521,11 @@ class LlamaForCausalLM(HybridBlock):
 
         cfg = self._cfg
         L = cfg.num_layers
+        if not cfg.layers_alike():
+            raise MXNetError(
+                "pipeline_decompose streams equal stages of like layers; "
+                "this net's layers are of several kinds (attention_types, "
+                "num_dense_layers)")
         if L % n_stages:
             raise MXNetError(
                 f"num_layers {L} not divisible by pipeline stages "
@@ -564,6 +678,20 @@ def _embed(params, cfg, ids):
     return jnp.take(params["model.embed_tokens.weight"], idx, axis=0)
 
 
+def _refuse_unserved(cfg):
+    """The serving forwards know the plain decoder alone: every layer full
+    causal attention with RoPE and a dense FFN."""
+    if cfg.num_experts > 0:
+        raise MXNetError("incremental decode does not support MoE FFNs yet")
+    if "window" in cfg.attention_types \
+            or "full" not in cfg.rope_attention_types \
+            or cfg.attention_gate or cfg.post_norms or cfg.embed_scale != 1.0:
+        raise MXNetError(
+            "incremental decode does not support window layers, layers "
+            "without RoPE, the attention gate, post norms or an embedding "
+            "scale yet")
+
+
 def prefill_apply(params, cfg, ids):
     """Full-context forward that also returns every layer's roped k/v.
 
@@ -573,8 +701,7 @@ def prefill_apply(params, cfg, ids):
     prompt never changes the logits at real positions: causal attention
     means position i only sees j <= i), and the k/v stacks seed a decode
     cache."""
-    if cfg.num_experts > 0:
-        raise MXNetError("incremental decode does not support MoE FFNs yet")
+    _refuse_unserved(cfg)
     jnp = _jnp()
     from ....ops.attention_ops import rms_norm as _rms
     from ....ops.flash_attention import flash_attention as _fa
@@ -611,8 +738,7 @@ def decode_apply(params, cfg, ids, positions, kv_join):
     count (``positions + 1``).  Dense caches (``decode_step``) and the
     serving paged pool both plug in here, so there is exactly one copy of
     the decode math.  Returns logits (B, vocab)."""
-    if cfg.num_experts > 0:
-        raise MXNetError("incremental decode does not support MoE FFNs yet")
+    _refuse_unserved(cfg)
     jnp = _jnp()
     from ....ops.attention_ops import rms_norm as _rms
 

@@ -131,8 +131,9 @@ def swiglu(gate, up):
 
 @register("_contrib_moe_swiglu", aliases=("moe_swiglu",))
 def moe_swiglu(x, router_weight, gate_proj, up_proj, down_proj,
-               capacity_factor=1.25, aux_loss_weight=0.0, top_k=1,
-               renormalize=False, experts_first=0):
+               select_bias=None, capacity_factor=1.25, aux_loss_weight=0.0,
+               top_k=1, renormalize=False, experts_first=0, score="softmax",
+               route_scale=1.0, renorm_eps=0.0):
     """MoE SwiGLU FFN over stacked expert weights (net-new vs the
     reference).  Registered as a first-class op so MoE models trace to
     Symbol and export/SymbolBlock-import like any other graph (fused RNN
@@ -146,15 +147,17 @@ def moe_swiglu(x, router_weight, gate_proj, up_proj, down_proj,
     aux_loss_weight > 0 (Switch Transformer eq. 4).
 
     ``capacity_factor = 0``: dropless ``top_k`` routing over the router's
-    ``E`` outputs (gates divided by their sum when ``renormalize``), of
-    which this layer holds the ``N`` experts from ``experts_first`` on and
-    computes their part of the result, by grouped products over the pairs
-    sorted by expert (``parallel.expert_parallel.moe_apply``).  Router
-    logits and gates are float32 whatever the products' dtype, which is the
-    expert weights' (under AMP the target dtype: ``x`` and the router are
-    exempt from the cast, contrib/amp/lists.py).  The layer's routed pairs,
-    the rows its routing walked and its load imbalance go to
-    ``telemetry.step_scalar``."""
+    ``E`` outputs, of which this layer holds the ``N`` experts from
+    ``experts_first`` on and computes their part of the result, by grouped
+    products over the pairs sorted by expert
+    (``parallel.expert_parallel.moe_apply``, which says how ``score``
+    (``"softmax"`` or ``"sigmoid"``), ``select_bias (E,)``, ``renormalize``
+    with ``renorm_eps`` and ``route_scale`` make the gates).  Router logits,
+    scores, bias and gates are float32 whatever the products' dtype, which
+    is the expert weights' (under AMP the target dtype: ``x``, the router
+    and the bias are exempt from the cast, contrib/amp/lists.py).  The
+    layer's routed pairs, the rows its routing walked and its load
+    imbalance go to ``telemetry.step_scalar``."""
     from jax import lax, nn
 
     from .. import telemetry
@@ -184,7 +187,9 @@ def moe_swiglu(x, router_weight, gate_proj, up_proj, down_proj,
         out, aux = moe_apply(
             grouped_fn, params, router_weight, toks, capacity_factor=None,
             top_k=int(top_k), renormalize=bool(renormalize),
-            held=(int(experts_first), gate_proj.shape[0]))
+            held=(int(experts_first), gate_proj.shape[0]), score=score,
+            select_bias=select_bias, scale=float(route_scale),
+            renorm_eps=float(renorm_eps))
         telemetry.step_scalar(telemetry.MOE_ROUTED_PAIRS.name,
                               aux["routed_pairs"])
         telemetry.step_scalar(telemetry.MOE_WALKED_ROWS.name,

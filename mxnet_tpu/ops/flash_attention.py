@@ -68,19 +68,26 @@ def _use_pallas(q):
 # and the backward; no (lq, lk) array leaves the trace of the first and last
 # --------------------------------------------------------------------------
 BLOCK_DIFFUSION = "block_diffusion"
+WINDOW = "window"
 
 
-def _mask_key(mask, mask_block, causal):
-    """The static mask of a call: None, or ``(BLOCK_DIFFUSION, B)``."""
+def _mask_key(mask, mask_block, causal, window=0):
+    """The static mask of a call: None, ``(BLOCK_DIFFUSION, B)`` or
+    ``(WINDOW, W)``."""
     from ..base import MXNetError
 
     if mask is None:
         return None
-    if mask != BLOCK_DIFFUSION:
+    if mask not in (BLOCK_DIFFUSION, WINDOW):
         raise MXNetError(f"flash_attention: unknown mask {mask!r}; known: "
-                         f"{BLOCK_DIFFUSION!r}")
+                         f"{BLOCK_DIFFUSION!r}, {WINDOW!r}")
     if causal:
         raise MXNetError("flash_attention: a mask takes the place of causal")
+    if mask == WINDOW:
+        if int(window) < 1:
+            raise MXNetError("flash_attention: mask='window' needs window, "
+                             "the number of keys a query sees")
+        return (WINDOW, int(window))
     if int(mask_block) < 1:
         raise MXNetError("flash_attention: mask='block_diffusion' needs "
                          "mask_block, the block length")
@@ -103,7 +110,13 @@ def _visible(xp, q_pos, k_pos, causal, mask, lq, lk):
     block diffusion (Arriola et al. 2025) over a row of a noised copy
     followed by the clean copy, blocks of ``B``: a noised query sees the
     noised keys of its own block and the clean keys of earlier blocks; a
-    clean query sees the clean keys of its own and earlier blocks."""
+    clean query sees the clean keys of its own and earlier blocks.
+    ``(WINDOW, W)``: causal, and of the keys up to its own position a query
+    sees the last ``W`` alone (``i - W < j <= i``, the diagonal moved as
+    ``causal`` moves it)."""
+    if mask is not None and mask[0] == WINDOW:
+        last = q_pos + (lk - lq)
+        return (k_pos <= last) & (k_pos > last - mask[1])
     if mask is not None:
         half = lk // 2
         qb, q_noised = _block_of(xp, q_pos, half, mask[1])
@@ -150,7 +163,12 @@ def _live_tiles(causal, mask, lq, lk, block_q, block_k):
 def _check_mask_shape(mask, lq, lk):
     from ..base import MXNetError
 
-    if mask is not None and (lq != lk or lk % (2 * mask[1])):
+    if mask is not None and mask[0] == WINDOW:
+        if lq > lk:
+            raise MXNetError(f"flash_attention: mask {mask} wants the "
+                             f"queries to be the last of the keys; got lq "
+                             f"{lq}, lk {lk}")
+    elif mask is not None and (lq != lk or lk % (2 * mask[1])):
         raise MXNetError(
             f"flash_attention: mask {mask} wants a row of a noised and a "
             f"clean copy, each a whole number of blocks; got lq {lq}, lk {lk}")
@@ -244,6 +262,25 @@ def _bd_tile_ranges(r0, r1, half, block, block_k):
     return a_lo, a_hi, jnp.maximum(c_lo, a_hi), c_hi
 
 
+def _window_tile_range(r0, r1, offset, window, block_k, num_kb):
+    """K tiles ``[lo, hi)`` a q tile of rows ``[r0, r1)`` can see under a
+    window of ``window`` keys, the diagonal moved by ``offset``: from the
+    tile of its first row's first key to that of its last row's own key.
+    Holds for Python ints and traced scalars."""
+    import jax.numpy as jnp
+
+    lo = jnp.maximum(r0 + offset - window + 1, 0) // block_k
+    hi = jnp.minimum((r1 + offset + block_k - 1) // block_k, num_kb)
+    return lo, hi
+
+
+def _kernel_name(base, mask):
+    """A kernel's name in a trace: a window call's tells it from a full
+    call's; the others keep ``base``."""
+    return base + "_window" if mask is not None and mask[0] == WINDOW \
+        else base
+
+
 def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
                    sm_scale, seq_k, diag_offset=0, mask=None):
     """One q block against its head's whole K/V row.
@@ -263,7 +300,9 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
     update, unrolled when nothing is masked, and skipping the K blocks no
     row of the q block can see when causal or under ``mask`` (``_visible``
     is the predicate, evaluated here from a column of row positions and a
-    row of column positions).
+    row of column positions): under a window the one range of
+    ``_window_tile_range``, under block diffusion the two of
+    ``_bd_tile_ranges``.
     """
     import jax
     import jax.numpy as jnp
@@ -298,8 +337,8 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
                 jnp.int32, (block_q, 1), 0)
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (1, block_k), 1)
-            s = jnp.where(_visible(jnp, q_pos, k_pos, False, mask, seq_k,
-                                   seq_k), s, NEG_INF)
+            s = jnp.where(_visible(jnp, q_pos, k_pos, False, mask,
+                                   seq_k - diag_offset, seq_k), s, NEG_INF)
         return s
 
     def weighted_v(p, kb):
@@ -332,6 +371,11 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
                 ((qi + 1) * block_q + diag_offset + block_k - 1) // block_k,
                 num_kb)
             carry = jax.lax.fori_loop(0, max_kb, body, carry)
+        elif mask is not None and mask[0] == WINDOW:
+            carry = jax.lax.fori_loop(
+                *_window_tile_range(qi * block_q, (qi + 1) * block_q,
+                                    diag_offset, mask[1], block_k, num_kb),
+                body, carry)
         elif mask is not None:
             a_lo, a_hi, c_lo, c_hi = _bd_tile_ranges(
                 qi * block_q, (qi + 1) * block_q, seq_k // 2, mask[1],
@@ -418,7 +462,7 @@ def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q=None, block_k=None,
             jax.ShapeDtypeStruct((b * h, lq, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, 8, lq), jnp.float32),
         ],
-        name="mxnet_flash_attention_fwd",
+        name=_kernel_name("mxnet_flash_attention_fwd", mask),
     )(qf, kf, vf)
     return o.reshape(b, h, lq, d), lse[:, 0, :].reshape(b, h, lq)
 
@@ -482,10 +526,13 @@ def _fa_bwd_pairs(causal, mask, lq, lk, block_q, block_k):
     tile so that a K tile's ``dk`` and ``dv`` are finished before the next
     one's begin.  Flags: first and last pair of their K tile, and whether
     the mask hides some pair of the tile (``_visible`` is evaluated in those
-    alone).  Every K tile is in it (under each mask here the last query
-    sees every key), so every tile of ``dk`` and ``dv`` is written."""
+    alone).  Every K tile is in it, so every tile of ``dk`` and ``dv`` is
+    written: one that no query sees (a window over ``lq < lk`` leaves the
+    first keys to none) is walked once, with the first q tile and wholly
+    hidden, and so written as zeros."""
     some, every = _tile_visibility(causal, mask, lq, lk, block_q, block_k)
-    assert some.any(axis=0).all(), "a K tile that no query sees"
+    some = some.copy()
+    some[0, ~some.any(axis=0)] = True
     pairs = _np.argwhere(some.T)[:, ::-1]
     turn = pairs[1:, 1] != pairs[:-1, 1]
     flags = (_FIRST_OF_K * _np.r_[True, turn] + _LAST_OF_K * _np.r_[turn, True]
@@ -624,7 +671,7 @@ def _fa_backward_pallas(q, k, v, o, lse, g, causal, sm_scale, mask=None):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary"),
                 vmem_limit_bytes=max(need, _VMEM_DEFAULT_LIMIT)),
-            name="mxnet_flash_attention_bwd",
+            name=_kernel_name("mxnet_flash_attention_bwd", mask),
         )(jnp.asarray(pairs), flat(q), flat(k), flat(v), flat(g), rows(lse),
           rows(delta))
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
@@ -794,7 +841,17 @@ def _make_flash(causal, sm_scale_key, mask=None, sharded=None):
         return _dispatch_fwd(q, k, v)[0]
 
     def _dispatch_fwd(q, k, v):
-        if _use_pallas(q):
+        from .. import telemetry
+
+        pallas = _use_pallas(q)
+        telemetry.counter(
+            "mxnet_flash_attention_fwd_calls_total",
+            "flash_attention forward calls traced, by the path they took "
+            "and the mask they ran under",
+            ("path", "mask")).labels(
+                path="pallas" if pallas else "plain",
+                mask=mask[0] if mask else "causal" if causal else "none").inc()
+        if pallas:
             o, lse = _fa_forward(q, k, v, causal, sm_scale, mask, sharded)
         else:
             o, lse = _mha_with_lse(q, k, v, causal, sm_scale, mask)
@@ -814,15 +871,17 @@ def _make_flash(causal, sm_scale_key, mask=None, sharded=None):
 
 
 def flash_attention(q, k, v, causal=False, sm_scale=None, mask=None,
-                    mask_block=0):
+                    mask_block=0, window=0):
     """q (B,Hq,Lq,D); k,v (B,Hkv,Lk,D) with Hq % Hkv == 0 (GQA).
 
     ``mask="block_diffusion"`` with ``mask_block`` the block length: the
     training mask of block diffusion over rows of a noised copy followed by
-    the clean copy (``_visible``); it takes the place of ``causal``."""
+    the clean copy.  ``mask="window"`` with ``window`` = W: causal attention
+    in which a query sees the last W keys up to its own position.  A mask
+    takes the place of ``causal`` (``_visible`` has the predicates)."""
     import jax.numpy as jnp
 
-    mask = _mask_key(mask, mask_block, causal)
+    mask = _mask_key(mask, mask_block, causal, window)
     _check_mask_shape(mask, q.shape[2], k.shape[2])
     d = q.shape[-1]
     if sm_scale is None:
@@ -845,8 +904,8 @@ from .registry import register
 
 @register("_contrib_flash_attention", aliases=("flash_attention",))
 def flash_attention_op(q, k, v, causal=False, sm_scale=None, mask=None,
-                       mask_block=0):
+                       mask_block=0, window=0):
     """Fused scaled-dot-product attention (net-new vs reference; the TPU
     answer to contrib/transformer.cc's unfused attention path)."""
     return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                           mask=mask, mask_block=mask_block)
+                           mask=mask, mask_block=mask_block, window=window)

@@ -65,7 +65,8 @@ def stack_expert_params(per_expert):
 
 def moe_apply(expert_fn, expert_params, router_weight, x, mesh=None,
               axis="ep", capacity_factor=1.25, top_k=1, renormalize=False,
-              held=None):
+              held=None, score="softmax", select_bias=None, scale=1.0,
+              renorm_eps=0.0):
     """One MoE layer over tokens ``x (T, d)`` with ``router_weight (d, E)``.
 
     With a ``capacity_factor`` (switch top-1): ``expert_fn(params_one_expert,
@@ -76,9 +77,13 @@ def moe_apply(expert_fn, expert_params, router_weight, x, mesh=None,
     With ``capacity_factor=None`` (dropless top-k): ``expert_fn(params,
     rows (R, d), group_sizes (count,)) -> (R, d)`` over rows sorted by
     expert, ``expert_params`` leaves ``(count, ...)``: the experts
-    ``first .. first + count - 1`` of the router's ``E``.  Gates are the
-    softmax over all ``E`` in float32, the ``top_k`` largest, divided by
-    their sum when ``renormalize``.  aux: ``routed_pairs`` (pairs computed
+    ``first .. first + count - 1`` of the router's ``E``.  Scores are the
+    softmax over all ``E`` in float32, or with ``score="sigmoid"`` each
+    output's own sigmoid; the experts chosen are the ``top_k`` largest of
+    the scores plus ``select_bias (E,)`` where one is given, and the gates
+    their scores (the bias moves the choice and never a gate), divided by
+    their sum plus ``renorm_eps`` when ``renormalize``, times ``scale``.
+    aux: ``routed_pairs`` (pairs computed
     here), ``walked_rows`` (rows that the sorted walks covered to gather
     them: whole granules), ``expert_load`` (count,),
     ``load_max_over_mean``, ``dropped`` 0.
@@ -89,11 +94,17 @@ def moe_apply(expert_fn, expert_params, router_weight, x, mesh=None,
                 "dropless routing over an ep mesh needs the token exchange, "
                 "which is not written yet; pass held=(first, count) and run "
                 "one share a chip")
+        if score not in ("softmax", "sigmoid"):
+            raise MXNetError(f"unknown router score {score!r}; known: "
+                             "'softmax', 'sigmoid'")
         return _moe_dropless(expert_fn, expert_params, router_weight, x,
-                             int(top_k), bool(renormalize), held)
-    if top_k != 1 or held is not None:
+                             int(top_k), bool(renormalize), held, score,
+                             select_bias, float(scale), float(renorm_eps))
+    if top_k != 1 or held is not None or score != "softmax" \
+            or select_bias is not None or scale != 1.0:
         raise MXNetError("a capacity_factor is the switch top-1 path over "
-                         "every expert; top_k > 1 and held= route dropless "
+                         "every expert, softmax gates; top_k > 1, held=, "
+                         "score=, select_bias= and scale= route dropless "
                          "(capacity_factor=None)")
     return _moe_switch(expert_fn, expert_params, router_weight, x, mesh, axis,
                        capacity_factor)
@@ -238,7 +249,7 @@ def combine(out, y, gates, order, n_live):
 
 
 def _moe_dropless(expert_fn, expert_params, router_weight, x, top_k,
-                  renormalize, held):
+                  renormalize, held, score, select_bias, scale, renorm_eps):
     import jax
     import jax.numpy as jnp
 
@@ -258,9 +269,19 @@ def _moe_dropless(expert_fn, expert_params, router_weight, x, top_k,
         logits = jnp.dot(x.astype(jnp.float32),
                          router_weight.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)    # (T, E)
-        gates, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        scores = jax.nn.softmax(logits, axis=-1) if score == "softmax" \
+            else jax.nn.sigmoid(logits)
+        if select_bias is None:
+            gates, chosen = jax.lax.top_k(scores, top_k)
+        else:
+            _, chosen = jax.lax.top_k(
+                scores + select_bias.astype(jnp.float32), top_k)
+            gates = jnp.take_along_axis(scores, chosen, axis=-1)
         if renormalize:
-            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+            norm = jnp.sum(gates, axis=-1, keepdims=True)
+            gates = gates / (norm + renorm_eps if renorm_eps else norm)
+        if scale != 1.0:
+            gates = gates * scale
         # pairs held elsewhere get the key ``count`` and sort past the end
         local = chosen.reshape(-1) - first
         key = jnp.where((local >= 0) & (local < count), local, count)

@@ -44,6 +44,9 @@ SCOPE_ATTENTION_PLAIN_FWD = "mxnet_attention_plain_fwd"
 # experts held.  Backward ops carry transpose(jvp(<scope>))
 SCOPE_MOE_ROUTE = "mx_moe_route"
 SCOPE_MOE_EXPERTS = "mx_moe_experts"
+# the shared expert beside them (model_zoo/language/llama.py::LlamaMoEMLP):
+# a dense SwiGLU that every token passes
+SCOPE_MOE_SHARED = "mx_moe_shared"
 
 _CONFIG = {"filename": "profile.json", "profile_all": False,
            "profile_imperative": False, "dir": None, "jax_trace": True,

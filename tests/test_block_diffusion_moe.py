@@ -454,7 +454,7 @@ def test_amp_keeps_the_router_float32_and_feeds_the_experts_bf16():
     as they are and the expert weights in bf16."""
     from mxnet_tpu.contrib.amp import _cast_scope, lists
 
-    assert lists.KEEP_DTYPE_INPUTS["_contrib_moe_swiglu"] == (0, 1)
+    assert lists.KEEP_DTYPE_INPUTS["_contrib_moe_swiglu"] == (0, 1, 5)
     seen = {}
 
     def spy(expert_fn, params, router, x, **kw):

@@ -135,6 +135,53 @@ def test_flash_backward_kernel_compiles_for_v5e(one_chip, shape, dim, dtype,
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+# the window cell's own call (one sample of 8,192 rows, the 4 key-value
+# heads repeated to the 32 query heads, head 128, a window of 2,048: four
+# K tiles of 512 wide), a window narrower than a tile, and ``lq < lk`` with
+# K tiles that no query sees
+WINDOW_SHAPES = [((1, 32, 8192, 8192), 128, "bfloat16", 2048),
+                 ((2, 4, 1024, 1024), 128, "bfloat16", 96),
+                 ((2, 4, 256, 1024), 64, "bfloat16", 300)]
+
+
+@pytest.mark.parametrize("shape,dim,dtype,window", WINDOW_SHAPES)
+def test_window_flash_forward_compiles_for_v5e(one_chip, shape, dim, dtype,
+                                               window):
+    """The forward under ``mask="window"``: the band's one range of K tiles
+    as a loop with traced bounds, the predicate's two comparisons joined,
+    under a name that tells the call from a full one."""
+    from mxnet_tpu.ops.flash_attention import WINDOW, _fa_forward_pallas
+
+    b, h, lq, lk = shape
+    fwd = functools.partial(_fa_forward_pallas, causal=False,
+                            sm_scale=dim ** -0.5, mask=(WINDOW, window))
+    q = jax.ShapeDtypeStruct((b, h, lq, dim), dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, h, lk, dim), dtype, sharding=one_chip)
+    text = jax.jit(fwd).lower(q, kv, kv).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "mxnet_flash_attention_fwd_window" in text
+
+
+@pytest.mark.parametrize("shape,dim,dtype,window", WINDOW_SHAPES)
+def test_window_flash_backward_kernel_compiles_for_v5e(one_chip, shape, dim,
+                                                       dtype, window):
+    """The backward kernel over the band's tile pairs alone (at the cell's
+    shape 70 of the causal 136), a K tile that no query sees walked once."""
+    from mxnet_tpu.ops.flash_attention import WINDOW, _fa_backward_pallas
+
+    b, h, lq, lk = shape
+    bwd = functools.partial(_fa_backward_pallas, causal=False,
+                            sm_scale=dim ** -0.5, mask=(WINDOW, window))
+    q = jax.ShapeDtypeStruct((b, h, lq, dim), dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, h, lk, dim), dtype, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((b, h, lq), "float32", sharding=one_chip)
+    compiled = jax.jit(bwd).lower(q, kv, kv, q, lse, q).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "mxnet_flash_attention_bwd_window" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 def _gathered_and_scattered(text):
     """From a compiled program's text: the shapes of what its gathers
     produce and of what its scatters are given to put."""
