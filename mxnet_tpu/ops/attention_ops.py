@@ -156,8 +156,8 @@ def moe_swiglu(x, router_weight, gate_proj, up_proj, down_proj,
     scores, bias and gates are float32 whatever the products' dtype, which
     is the expert weights' (under AMP the target dtype: ``x``, the router
     and the bias are exempt from the cast, contrib/amp/lists.py).  The
-    layer's routed pairs, the rows its routing walked and its load
-    imbalance go to ``telemetry.step_scalar``."""
+    layer's routed pairs, the rows and the parts its routing walked and its
+    load imbalance go to ``telemetry.step_scalar``."""
     from jax import lax, nn
 
     from .. import telemetry
@@ -194,6 +194,9 @@ def moe_swiglu(x, router_weight, gate_proj, up_proj, down_proj,
                               aux["routed_pairs"])
         telemetry.step_scalar(telemetry.MOE_WALKED_ROWS.name,
                               aux["walked_rows"])
+        telemetry.step_scalar(telemetry.MOE_LIVE_PARTS.name,
+                              aux["live_parts"])
+        telemetry.step_scalar(telemetry.MOE_PARTS.name, aux["parts"])
         telemetry.step_scalar(telemetry.MOE_LOAD_MAX_OVER_MEAN.name,
                               aux["load_max_over_mean"])
         return out.reshape(b, l, h)

@@ -14,18 +14,26 @@ Capability upgrade over the reference (MXNet 1.x has no MoE).
   the result only.  The (token, expert) pairs that land here are sorted by
   expert and go through grouped matrix products (``expert_fn`` over rows
   and group sizes, ``jax.lax.ragged_dot`` inside it).  No pair is ever
-  dropped: the sorted rows are walked in parts of ``_PART_ROWS``, the
-  groups of a part are the pairs it holds and nothing else, and a part past
-  the last pair is skipped.  Within a part the gathers follow the pairs
-  too: ``dispatch`` gathers the tokens' rows into the sorted order a
-  granule of ``_GRANULE`` at a time and stops at the last pair, and so does
-  ``combine``'s backward, for the rows and for their gates.  The way back
-  (``combine``, ``dispatch``'s backward) is one scatter-add of the whole
-  part with the rows past the last pair as zeros: XLA's scatter-add on a
-  TPU pays a pass over the indices and the target before its first row, so
-  a granule at a time it costs more than the part at once.  Each of the two
-  is the other's transpose, written by hand (``jax.custom_vjp``) because
-  the walk's trip count is a device number.
+  dropped: the sorted rows are cut into parts of ``_PART_ROWS``, the groups
+  of a part are the pairs it holds and nothing else, and the layer walks
+  the parts that hold a pair, ``ceil(pairs held / part)`` of them, a device
+  number: one loop forward, and one loop backward that takes the ``jax.vjp``
+  of a part (its forward computed again there) and adds what it gives into
+  the gradients of the tokens, the weights and the gates.  A part past the
+  last pair costs nothing either way.  JAX cannot reverse a loop whose trip
+  count is a device number, so the loop's backward is written by hand
+  (``_walk_live_parts``); it keeps the loop's inputs and nothing else.  Every
+  shape takes the loop, a shape of one part too: its trips are then 0 or 1.
+  Within a part the gathers follow the pairs too: ``dispatch`` gathers the
+  tokens' rows into the sorted order a granule of ``_GRANULE`` at a time
+  and stops at the last pair, and so does ``combine``'s backward, for the
+  rows and for their gates.  The way back (``combine``, ``dispatch``'s
+  backward) is one scatter-add of the whole part with the rows past the
+  last pair as zeros: XLA's scatter-add on a TPU pays a pass over the
+  indices and the target before its first row, so a granule at a time it
+  costs more than the part at once.  Each of the two is the other's
+  transpose, written by hand (``jax.custom_vjp``) because the walk's trip
+  count is a device number.
   What the experts held elsewhere would add is left out; the exchange that
   brings it in is not written yet.
 """
@@ -42,10 +50,11 @@ __all__ = ["moe_apply", "stack_expert_params", "inject_aux_loss",
 # Rows of the sorted (token, expert) pairs that the dropless path computes at
 # once, the static bound on what one grouped product sees: what is live of
 # one part (its tokens, the experts' hidden rows, its result) is about
-# 0.8 GiB at hidden 2048 and width 768.  The products and the gathers follow
-# the pairs, the scatter-add is of a whole part: a load a few pairs over a
-# multiple of this pays one granule's gathers and one part's scatter-add
-# more.
+# 0.8 GiB at hidden 2048 and width 768.  ``tokens x top_k`` rows are a static
+# number of parts; the walk takes the first ``ceil(pairs held / part)`` of
+# them.  The products and the gathers follow the pairs, the scatter-add is
+# of a whole part: a load a few pairs over a multiple of this pays one
+# granule's gathers and one part's scatter-add more.
 _PART_ROWS = 32768
 # Rows of a part that one step of its sorted walk gathers.  On a v5e a
 # step costs what its rows cost (16 gathers of 2,048 rows take what one of
@@ -85,7 +94,9 @@ def moe_apply(expert_fn, expert_params, router_weight, x, mesh=None,
     their sum plus ``renorm_eps`` when ``renormalize``, times ``scale``.
     aux: ``routed_pairs`` (pairs computed
     here), ``walked_rows`` (rows that the sorted walks covered to gather
-    them: whole granules), ``expert_load`` (count,),
+    them: whole granules), ``live_parts`` of ``parts`` (the parts of the
+    sorted rows that hold a pair, which the layer walks, of the static
+    number that the shape allows), ``expert_load`` (count,),
     ``load_max_over_mean``, ``dropped`` 0.
     """
     if capacity_factor is None:
@@ -188,15 +199,10 @@ def _walks():
         return dx, None, None
 
     def combine_rows(out, y, gates, order, n_live):
-        top_k = gates.shape[1]
-
-        def add(out):
+        with jax.named_scope(SCOPE_MOE_ROUTE):
             return add_rows(
                 out, y.astype(out.dtype) * gates.reshape(-1)[order][:, None],
-                order // top_k, n_live)
-
-        with jax.named_scope(SCOPE_MOE_ROUTE):
-            return jax.lax.cond(n_live > 0, add, lambda out: out, out)
+                order // gates.shape[1], n_live)
 
     def combine_fwd(out, y, gates, order, n_live):
         return combine_rows(out, y, gates, order, n_live), (y, gates, order,
@@ -218,7 +224,7 @@ def _walks():
             dy, dgates = _sorted_walk(
                 order.shape[0], n_live, body,
                 (jnp.zeros_like(y), jnp.zeros(gates.size, gates.dtype)))
-        return g, dy, dgates.reshape(gates.shape), None, None
+            return g, dy, dgates.reshape(gates.shape), None, None
 
     dispatch = jax.custom_vjp(dispatch_rows, nondiff_argnums=(0,))
     combine = jax.custom_vjp(combine_rows)
@@ -240,7 +246,8 @@ def dispatch(x, tokens, n_live):
 def combine(out, y, gates, order, n_live):
     """``out (T, d)`` (float32) with ``gates[pair] * y[i]`` added to the
     token of each sorted row ``i < n_live`` of the part, by one scatter-add
-    of the whole part that a part with no pair skips.  ``order (part,)``
+    of the whole part, whatever ``n_live``: call it where the part holds a
+    pair.  ``order (part,)``
     holds the rows' pairs (token * top_k + choice), ``gates (T, top_k)``
     every pair's gate; the rows of ``y`` past ``n_live`` may hold anything,
     NaN included.  The backward walks the granules that hold a row before
@@ -293,53 +300,107 @@ def _moe_dropless(expert_fn, expert_params, router_weight, x, top_k,
         order = jnp.pad(order, (0, n_parts * part - pairs)) \
             .reshape(n_parts, part)
         starts = jnp.arange(n_parts, dtype=jnp.int32) * part
-        # the rows of each part that hold a pair, and the whole granules
-        # of them that its sorted walk covers
+        # the rows of each part that hold a pair, the whole granules of
+        # them that its sorted walk covers, the parts that hold a pair at
+        # all, and each part's groups: the pairs of each expert in it
         n_live = jnp.clip(total - starts, 0, part)
         walked = jnp.sum(-(-n_live // granule) * granule)
+        live_parts = -(-total // part)
+        lo, hi = starts[:, None], starts[:, None] + part
+        sizes = jnp.clip(ends, lo, hi) - jnp.clip(ends - load, lo, hi)
+        out = jnp.zeros((T, d), jnp.float32)
 
-    def products(x, params, order, lo, n_live):
-        """The experts' outputs for the rows ``[lo, lo + part)`` of the
-        sorted pairs.  The groups are the pairs the part holds.  A grouped
-        product leaves the rows past its last group as they were in memory,
-        NaN included, forward and backward: ``combine`` and ``dispatch``'s
-        backward take those rows as zeros."""
+    def add_part(out, x, params, gates, order, sizes, n_live):
+        """``out`` with one part's experts' outputs added to their tokens.
+        A grouped product leaves the rows past its last group as they were
+        in memory, NaN included, forward and backward: ``combine`` and
+        ``dispatch``'s backward take those rows as zeros."""
         with jax.named_scope(SCOPE_MOE_ROUTE):
-            sizes = (jnp.clip(ends, lo, lo + part)
-                     - jnp.clip(ends - load, lo, lo + part))
             rows = dispatch(x, order // top_k, n_live)
         with jax.named_scope(SCOPE_MOE_EXPERTS):
-            return expert_fn(params, rows, sizes)
-
-    # A part past the last pair is skipped: its dispatch and products by
-    # the cond, its combine by a cond of its own forward and by walking no
-    # step backward.  All of it lies inside the checkpoint: what the
-    # backward keeps of a part is then the checkpoint's inputs, of which
-    # the scan stacks the part's own (its slice of the order) and hoists
-    # the tokens, the gates and the weights, which every part shares; a
-    # cond's own residuals, or combine's, it would stack whole, part by
-    # part.  ``out`` the backward never reads.
-    skipped = jax.eval_shape(
-        expert_fn, expert_params, jax.ShapeDtypeStruct((part, d), x.dtype),
-        jax.ShapeDtypeStruct((count,), jnp.int32))
-
-    @jax.checkpoint
-    def add_part(out, x, params, gates, order, lo, n_live):
-        y = jax.lax.cond(
-            n_live > 0, lambda: products(x, params, order, lo, n_live),
-            lambda: jnp.zeros(skipped.shape, skipped.dtype))
+            y = expert_fn(params, rows, sizes)
         return combine(out, y, gates, order, n_live)
 
-    def step(out, part_in):
-        return add_part(out, x, expert_params, gates, *part_in), None
-
-    out, _ = jax.lax.scan(step, jnp.zeros((T, d), jnp.float32),
-                          (order, starts, n_live))
-    aux = {"routed_pairs": total, "walked_rows": walked, "expert_load": load,
+    out = _walk_live_parts(add_part, out, x, expert_params, gates,
+                           (order, sizes, n_live), live_parts)
+    aux = {"routed_pairs": total, "walked_rows": walked,
+           "live_parts": live_parts,
+           "parts": jnp.asarray(n_parts, jnp.int32), "expert_load": load,
            "load_max_over_mean": jnp.max(load) * count
            / jnp.maximum(total, 1).astype(jnp.float32),
            "dropped": jnp.zeros((), jnp.int32)}
     return out.astype(x.dtype), aux
+
+
+def _over_live_parts(body, init, parts, live_parts, last_first=False):
+    """``carry = body(carry, *part_i)`` for ``i < live_parts``, a device
+    number, ``part_i`` the ``i``-th of each array of ``parts``.  The loop's
+    own ops (the counter, which part) carry the routing's scope inside cond
+    and body: a scope around the loop would name its whole body, the
+    products too."""
+    import jax
+    import jax.numpy as jnp
+
+    def cond(at):
+        with jax.named_scope(SCOPE_MOE_ROUTE):
+            return at[0] < live_parts
+
+    def step(at):
+        done, carry = at
+        with jax.named_scope(SCOPE_MOE_ROUTE):
+            i = live_parts - 1 - done if last_first else done
+            mine = tuple(a[i] for a in parts)
+            done = done + 1
+        return done, body(carry, *mine)
+
+    return jax.lax.while_loop(cond, step,
+                              (jnp.zeros_like(live_parts), init))[1]
+
+
+def _walk_live_parts(add_part, out, x, params, gates, parts, live_parts):
+    """``out = add_part(out, x, params, gates, *part_i)`` over the first
+    ``live_parts`` parts.  ``live_parts`` is a device number, so the
+    backward is by hand: a loop over the same parts, the last first as JAX
+    would take them, that adds each part's cotangents (``jax.vjp`` of
+    ``add_part``, whose forward is computed again there) into those of
+    ``x``, ``params`` and ``gates``; ``out`` enters ``add_part`` by an
+    addition, so its cotangent passes through.  The residuals are the
+    loop's inputs.  The accumulators start as zeros: the first part's
+    cotangents in their place would take a second copy of the part in the
+    program, outside the loop."""
+    import jax
+    import jax.numpy as jnp
+
+    def forward(out, x, params, gates, parts, live_parts):
+        return _over_live_parts(
+            lambda out, *mine: add_part(out, x, params, gates, *mine), out,
+            parts, live_parts)
+
+    def walk_fwd(out, x, params, gates, parts, live_parts):
+        return (forward(out, x, params, gates, parts, live_parts),
+                (x, params, gates, parts, live_parts))
+
+    def walk_bwd(res, g):
+        x, params, gates, parts, live_parts = res
+
+        def add_cotangents(acc, *mine):
+            # add_part's out is dead here: only its cotangent, g, is read
+            _, pull = jax.vjp(lambda *of: add_part(g, *of, *mine),
+                              x, params, gates)
+            got = pull(g)
+            with jax.named_scope(SCOPE_MOE_ROUTE):
+                return jax.tree_util.tree_map(jnp.add, acc, got)
+
+        with jax.named_scope(SCOPE_MOE_ROUTE):
+            zeros = jax.tree_util.tree_map(jnp.zeros_like,
+                                           (x, params, gates))
+        return (g, *_over_live_parts(add_cotangents, zeros, parts,
+                                     live_parts, last_first=True),
+                None, None)
+
+    walk = jax.custom_vjp(forward)
+    walk.defvjp(walk_fwd, walk_bwd)
+    return walk(out, x, params, gates, parts, live_parts)
 
 
 def _moe_switch(expert_fn, expert_params, router_weight, x, mesh, axis,

@@ -412,6 +412,16 @@ MOE_WALKED_ROWS = counter(
     "rows that the sorted walks of the dropless expert layers covered to "
     "gather their pairs, whole granules (step scalar): over "
     "mxnet_moe_routed_pairs_total, 1.0 is no row gathered in vain")
+MOE_LIVE_PARTS = counter(
+    "mxnet_moe_live_parts_total",
+    "parts of the sorted rows that held a pair, which the dropless expert "
+    "layers walked (step scalar): over mxnet_moe_parts_total, 1.0 is a load "
+    "that fills every part, where walking the live ones alone saves nothing")
+MOE_PARTS = counter(
+    "mxnet_moe_parts_total",
+    "parts that the shapes of the dropless expert layers allow, tokens x "
+    "top_k rows in parts of a fixed size, one count a layer a step (step "
+    "scalar)")
 MOE_LOAD_MAX_OVER_MEAN = histogram(
     "mxnet_moe_expert_load_max_over_mean",
     "fullest held expert's pairs over the mean of the held experts, one "

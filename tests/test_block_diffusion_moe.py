@@ -184,6 +184,32 @@ def _grouped(p, rows, sizes):
     return jax.lax.ragged_dot(hidden, p["d"], sizes)
 
 
+def _past(rows):
+    return jnp.all(rows == 0, axis=1, keepdims=True)
+
+
+@jax.custom_vjp
+def _dirty_grouped(p, rows, sizes):
+    """``_grouped`` that answers NaN in the rows past the last pair (they
+    enter as zeros), in its output and in its rows' cotangent, as the TPU's
+    grouped product may leave them."""
+    return _dirty_fwd(p, rows, sizes)[0]
+
+
+def _dirty_fwd(p, rows, sizes):
+    y, vjp = jax.vjp(lambda p, rows: _grouped(p, rows, sizes), p, rows)
+    return jnp.where(_past(rows), jnp.nan, y), (vjp, _past(rows))
+
+
+def _dirty_bwd(res, g):
+    vjp, was_past = res
+    dp, drows = vjp(jnp.where(was_past, 0, g))
+    return dp, jnp.where(was_past, jnp.nan, drows), None
+
+
+_dirty_grouped.defvjp(_dirty_fwd, _dirty_bwd)
+
+
 def _dense_moe(x, router, p, top_k, renormalize, experts):
     """Every expert of ``experts`` on every token, weighed by its gate."""
     probs = jax.nn.softmax(x @ router, -1)
@@ -368,8 +394,8 @@ def _whole_part_dropless(expert_fn, params, router, x, top_k, held, part):
 def test_a_held_share_under_checkpoint_and_jit_is_the_whole_part_form(
         monkeypatch):
     """``moe_apply`` with a share held, inside ``jax.checkpoint`` inside
-    ``jit`` (custom VJPs with a traced trip count, in a cond, in the part's
-    checkpoint, in the scan, in the layer's checkpoint): the result and the
+    ``jit`` (custom VJPs with a traced trip count, in the loop over live
+    parts, in the layer's checkpoint): the result and the
     gradients of the tokens, the router and the experts are those of the
     form that moves whole parts.  Three parts of 64 rows in granules of 16,
     the pairs held ending inside the second."""
@@ -427,6 +453,120 @@ def test_walked_rows_follow_the_routed_pairs(monkeypatch, held, part_rows):
     else:
         assert 32 < pairs < 160 and pairs % 16
         assert walked == -(-pairs // 16) * 16
+
+
+# 64 tokens choose 4 of 16 experts: 256 sorted rows, four parts of 64 in
+# granules of 16.  The experts held, the part's size and the parts that then
+# hold a pair, of the static number
+LOOP_CASES = {
+    "0 of 4": ((15, 1), 64, 0, 4),       # an expert no token chooses
+    "1 of 4": ((5, 2), 64, 1, 4),        # 50 pairs
+    "2 of 4": ((5, 4), 64, 2, 4),        # 113: the second part partly full
+    "4 of 4": ((0, 16), 64, 4, 4),       # every expert held: every part full
+    "1 of 1": ((5, 4), 256, 1, 1),       # one part by the shape: one trip
+    "0 of 1": ((15, 1), 256, 0, 1),      # one part, and no pair in it: none
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(LOOP_CASES))
+def test_the_loop_over_live_parts_is_the_scan_over_every_part(
+        monkeypatch, case, dtype):
+    """``moe_apply`` on a share, under ``jax.checkpoint`` and ``jit`` (the
+    loop over the parts that hold a pair, its hand-written backward with a
+    traced trip count, inside the layer's checkpoint), against the form that
+    walks every part and is differentiated by JAX: the result and the
+    gradients of the tokens, the experts' weights and the router, which the
+    gates' gradient reaches the router through.  The program's expert
+    function answers NaN past a part's last pair, the plain form's does not.
+    In float32 bit for bit (the sums are the same, in the same order: a
+    gradient that was ``0 + g`` is ``g``), but for the tokens' gradient
+    where two parts or more add into it, or where the plain form's one part
+    lies open to XLA beside the router's share of that gradient: XLA makes
+    of a part's scatter-add into zeros and the add that follows one
+    scatter-add into the sum so far, in either form as it sees fit (one unit
+    in the last place measured); in bf16 to the tolerance of the walks' own
+    test."""
+    held, part, live, parts = LOOP_CASES[case]
+    monkeypatch.setattr(expert_parallel, "_GRANULE", 16)
+    monkeypatch.setattr(expert_parallel, "_PART_ROWS", part)
+    rs = np.random.RandomState(11)
+    x = jnp.asarray(np.abs(rs.randn(64, 12)).astype("f")).astype(dtype)
+    router = rs.randn(12, 16).astype("f")
+    router[:, 15] = -5.0          # positive tokens never choose expert 15
+    router = jnp.asarray(router)
+    p = jax.tree_util.tree_map(lambda a: a.astype(dtype),
+                               _expert_weights(rs, held[1], 12, 6))
+
+    @jax.checkpoint
+    def layer(x, router, p):
+        return moe_apply(_dirty_grouped, p, router, x, capacity_factor=None,
+                         top_k=4, renormalize=True, held=held)
+
+    def plain_layer(x, router, p):
+        return _whole_part_dropless(_grouped, p, router, x, top_k=4,
+                                    held=held, part=part)
+
+    def loss(fn):
+        return lambda *a: jnp.sum(jnp.sin(fn(*a).astype(jnp.float32)))
+
+    out, aux = jax.jit(layer)(x, router, p)
+    assert (int(aux["live_parts"]), int(aux["parts"])) == (live, parts)
+    assert int(aux["live_parts"]) == -(-int(aux["routed_pairs"]) // part)
+    if case == "2 of 4":
+        assert int(aux["routed_pairs"]) % part
+    want_out = jax.jit(plain_layer)(x, router, p)
+    got = jax.jit(jax.grad(loss(lambda *a: layer(*a)[0]), (0, 1, 2)))(
+        x, router, p)
+    want = jax.jit(jax.grad(loss(plain_layer), (0, 1, 2)))(x, router, p)
+    if live == 0:
+        assert not np.asarray(out, "f").any()
+        assert not any(np.asarray(leaf, "f").any()
+                       for leaf in jax.tree_util.tree_leaves(got))
+    for i, (a, b) in enumerate(zip(
+            jax.tree_util.tree_leaves((out, got)),
+            jax.tree_util.tree_leaves((want_out, want)))):
+        assert a.dtype == b.dtype
+        a, b = np.asarray(a, "f"), np.asarray(b, "f")
+        assert np.isfinite(a).all()
+        if dtype == "bfloat16":
+            np.testing.assert_allclose(a, b, atol=0.05)
+        elif (live > 1 or parts == 1) and i == 1:   # the tokens' gradient
+            np.testing.assert_allclose(a, b, atol=1e-6)
+        else:
+            np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("cell,shares,part_rows,parts", [
+    # 16,384 tokens choose 8: four parts of 32,768; a share of 8 holds one
+    # pair a token, half a part
+    ("block diffusion", 8, 2 * 64, 4),
+    # 8,192 tokens choose 8: two parts; a share of 16 (the bias keeps the
+    # choice on the first 8) holds one pair a token, a quarter part
+    ("window", 16, 4 * 64, 2),
+])
+def test_live_parts_are_the_parts_that_hold_a_pair(monkeypatch, cell, shares,
+                                                   part_rows, parts):
+    """``live_parts`` of ``parts`` at the two decoder cells' ratios, on
+    routers made as theirs: the held experts' columns, the same for every
+    share, so that a token's 8 largest are the copies of one column and this
+    share is routed one pair a token.  One part of four and one of two hold
+    a pair."""
+    monkeypatch.setattr(expert_parallel, "_GRANULE", 16)
+    monkeypatch.setattr(expert_parallel, "_PART_ROWS", part_rows)
+    rs = np.random.RandomState(5)
+    held = 4
+    x = jnp.asarray(rs.randn(64, 12).astype("f"))
+    router = jnp.asarray(np.tile(rs.randn(12, held).astype("f"), (1, shares)))
+    bias = jnp.asarray(np.repeat([1.0, 0.0], held * shares // 2).astype("f"))
+    p = _expert_weights(rs, held, 12, 6)
+    _, aux = moe_apply(
+        _grouped, p, router, x, capacity_factor=None, top_k=8,
+        renormalize=True, held=(held, held),
+        **({"score": "sigmoid", "select_bias": bias} if cell == "window"
+           else {}))
+    assert int(aux["routed_pairs"]) == 64
+    assert (int(aux["live_parts"]), int(aux["parts"])) == (1, parts)
 
 
 def test_switch_routing_is_the_same_function_with_a_capacity():
@@ -502,22 +642,34 @@ def _small_sdar():
     return (cfg, *mods, _module(BENCH_ROOT, "drivers", "fused_step"))
 
 
-@pytest.mark.parametrize("amp,tolerance", [
-    # float32 against float32: the gap is the order of the sums (the
-    # program sorts pairs by expert, the reference runs every expert on
-    # every token): a few 1e-7 measured, 1e-5 allowed
-    (None, {"loss_gap": 1e-5, "first_gradient_gap": 1e-5,
-            "first_gradient_error": 1e-5, "change_gap": 1e-3}),
-    # bf16 operands: three decimal digits a product, and a router near-tie
-    # may pick another expert for a token: 1.2e-4 / 0.003 / 0.011 / 0.004
-    # measured at seed 5; a float32 result would read a hundred times less
-    ("bfloat16", {"loss_gap": 2e-3, "first_gradient_gap": 0.03,
-                  "first_gradient_error": 0.06, "change_gap": 0.03}),
+# float32 against float32: the gap is the order of the sums (the program sorts
+# pairs by expert, the reference runs every expert on every token): a few
+# 1e-7 measured, 1e-5 allowed
+FLOAT32_GAPS = {"loss_gap": 1e-5, "first_gradient_gap": 1e-5,
+                "first_gradient_error": 1e-5, "change_gap": 1e-3}
+# bf16 operands: three decimal digits a product, and a router near-tie may
+# pick another expert for a token: 1.2e-4 / 0.003 / 0.011 / 0.004 measured at
+# seed 5; a float32 result would read a hundred times less
+BF16_GAPS = {"loss_gap": 2e-3, "first_gradient_gap": 0.03,
+             "first_gradient_error": 0.06, "change_gap": 0.03}
+
+
+@pytest.mark.parametrize("amp,part_rows,tolerance", [
+    (None, None, FLOAT32_GAPS),
+    ("bfloat16", None, BF16_GAPS),
+    # a layer's 256 sorted rows in four parts of 64 (granules of 16), of
+    # which its 128 pairs fill two: the loop over live parts and its
+    # backward through the fused step, AMP and the layers' checkpoints
+    (None, 64, FLOAT32_GAPS),
+    ("bfloat16", 64, BF16_GAPS),
 ])
-def test_program_matches_the_reference_loss_and_every_gradient(amp,
-                                                               tolerance):
+def test_program_matches_the_reference_loss_and_every_gradient(
+        monkeypatch, amp, part_rows, tolerance):
     from chipbench.harness import check, loop
 
+    if part_rows:
+        monkeypatch.setattr(expert_parallel, "_GRANULE", 16)
+        monkeypatch.setattr(expert_parallel, "_PART_ROWS", part_rows)
     cfg, build, reference, driver = _small_sdar()
     spec = {"batch": 2, "seq": 32, "optimizer": "adam", "amp_dtype": amp,
             "optimizer_params": {"learning_rate": 1e-6}}
@@ -547,9 +699,20 @@ def test_program_matches_the_reference_loss_and_every_gradient(amp,
     assert load["count"] == 4 and load["sum"] / 4 >= 1.0
     assert 0.6 < pairs / (4 * 2 * 64 * 2 * 4 / 8) < 1.4
     # 128 rows choose 2: a layer's sorted walk covers its one part of 256
-    # rows, which is one granule
-    walked = metrics["mxnet_moe_walked_rows_total"]["samples"][0]["value"]
-    assert walked == 4 * 256
+    # rows, which is one granule; in parts of 64, the two that the share's
+    # 128 pairs fill (the assumed routers: one pair a token) of four
+    def total(name):
+        return metrics[name]["samples"][0]["value"]
+
+    if part_rows:
+        assert pairs == 4 * 128
+        assert total("mxnet_moe_walked_rows_total") == 4 * 128
+        assert total("mxnet_moe_live_parts_total") == 4 * 2
+        assert total("mxnet_moe_parts_total") == 4 * 4
+    else:
+        assert total("mxnet_moe_walked_rows_total") == 4 * 256
+        assert total("mxnet_moe_live_parts_total") == 4
+        assert total("mxnet_moe_parts_total") == 4
 
 
 def test_remat_is_real_in_the_fused_step_and_scopes_are_in_its_table():
@@ -600,29 +763,11 @@ def test_rows_past_the_last_pair_may_hold_anything(tokens):
     function that answers NaN there, in its output and in its rows'
     cotangent, changes nothing: not the result, not the tokens' gradient,
     not the gates'.  With 16,400 tokens the sorted rows are two parts, and
-    the second, which holds no pair, is skipped."""
+    the second, which holds no pair, is never walked."""
     rs = np.random.RandomState(4)
     x = jnp.asarray(rs.randn(tokens, 16).astype("f"))
     router = jnp.asarray(rs.randn(16, 8).astype("f"))
     p = _expert_weights(rs, 2, 16, 8)
-
-    def past(rows):
-        return jnp.all(rows == 0, axis=1, keepdims=True)
-
-    @jax.custom_vjp
-    def dirty(p, rows, sizes):
-        return _dirty_fwd(p, rows, sizes)[0]
-
-    def _dirty_fwd(p, rows, sizes):
-        y, vjp = jax.vjp(lambda p, rows: _grouped(p, rows, sizes), p, rows)
-        return jnp.where(past(rows), jnp.nan, y), (vjp, past(rows))
-
-    def _dirty_bwd(res, g):
-        vjp, was_past = res
-        dp, drows = vjp(jnp.where(was_past, 0.0, g))
-        return dp, jnp.where(was_past, jnp.nan, drows), None
-
-    dirty.defvjp(_dirty_fwd, _dirty_bwd)
 
     def loss(fn, x, p, router):
         out, aux = moe_apply(fn, p, router, x, capacity_factor=None, top_k=2,
@@ -630,7 +775,7 @@ def test_rows_past_the_last_pair_may_hold_anything(tokens):
         return jnp.sum(jnp.sin(out)), aux["routed_pairs"]
 
     (got, pairs), got_g = jax.value_and_grad(loss, (1, 2, 3), has_aux=True)(
-        dirty, x, p, router)
+        _dirty_grouped, x, p, router)
     (want, _), want_g = jax.value_and_grad(loss, (1, 2, 3), has_aux=True)(
         _grouped, x, p, router)
     # a part with slack rows, and for two parts none in the second

@@ -197,22 +197,77 @@ def _gathered_and_scattered(text):
     return gathered, scattered
 
 
+# the two decoder cells' expert layers: rows, hidden, the router's width, the
+# experts held and their width, experts a token, and how the router scores
+EXPERT_LAYERS = {
+    # 16,384 rows choose 8 of 128 by softmax, 16 of width 768 held: four
+    # parts of 32,768 sorted rows, of which the share's pairs fill half of one
+    "block diffusion": (16384, 2048, 128, 16, 768, 8, {}),
+    # 8,192 rows choose 8 of 128 by sigmoid plus a bias, 8 of width 1,024
+    # held: two parts, a quarter of one filled
+    "window": (8192, 2048, 128, 8, 1024, 8,
+               {"score": "sigmoid", "scale": 2.826, "renorm_eps": 1e-20}),
+}
+
+
+def _loops_over_parts(text):
+    """From a compiled program's text: ``[(own op_name, condition's
+    instructions, body's instructions)]`` of the ``while`` loops that are
+    under neither of the expert layer's scopes themselves and hold a grouped
+    product: the loops over parts (the sorted walks are under the
+    routing's).  An instruction is ``(name, own op_name, rest of line)``."""
+    import re
+
+    from mxnet_tpu import profiler
+
+    _, computations = profiler._hlo_computations(text)
+    found = []
+    for instructions in computations.values():
+        for _, own, rest in instructions:
+            if " while(" not in rest or profiler.SCOPE_MOE_ROUTE in own:
+                continue
+            cond, body = (computations[re.search(
+                rf"\b{key}=%?([\w.\-]+)", rest).group(1)]
+                for key in ("condition", "body"))
+            if any("ragged-dot" in line or "ragged_dot" in line
+                   for _, _, line in body):
+                found.append((own, cond, body))
+    return found
+
+
 @pytest.mark.parametrize("backward", [False, True])
+@pytest.mark.parametrize("cell", list(EXPERT_LAYERS))
 def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
-        one_chip, backward):
-    """The decoder cell's expert layer (16,384 rows of 2,048 choose 8 of 128
-    experts, 16 of width 768 held in bf16) under a layer's checkpoint:
-    loops with a traced trip count inside custom VJPs, a cond, a checkpoint
-    and a scan are the chip's compiler's to accept.  What it compiled
-    gathers rows a granule of the sorted rows at a time and no gate for
-    each of the 131,072 pairs; a part's 32,768 rows move at once only in
-    the scatter-add (``combine`` forward, ``dispatch`` backward), which XLA
-    does as a sort of the indices, a gather of the rows into that order and
-    a sorted scatter."""
+        one_chip, cell, backward):
+    """Both decoder cells' expert layers, the experts held in bf16, under a
+    layer's checkpoint and the step's forward scope: loops with a traced
+    trip count (over the parts that hold a pair, and inside them the walks
+    of ``dispatch`` and ``combine``, custom VJPs all) are the chip's
+    compiler's to accept.
+
+    What it compiled gathers rows a granule of the sorted rows at a time
+    and no gate for each of the ``rows x 8`` pairs; a part's 32,768 rows
+    move at once only in the scatter-add (``combine`` forward, ``dispatch``
+    backward), which XLA does as a sort of the indices, a gather of the
+    rows into that order and a sorted scatter.
+
+    The loops over parts: one forward and, backward, one more (what the
+    layer's checkpoint computes again is the loop's inputs, so its second
+    forward loop is dead).  Their trip count is a number they carry, not
+    the static number of parts.  Every instruction of theirs that the
+    program named (the counter and the bound's compare, which part, the
+    accumulators' adds, the walks, the products' element-wise ops) is under
+    exactly one of ``mx_moe_route`` and ``mx_moe_experts``, the grouped
+    products under XLA's own name, and the backward loop's are of the class
+    ``backward`` by their own names; the ``while`` instruction itself carries its caller's
+    scope (one around it would name its body's products too), and what the
+    compiler adds without a name (copies, a buffer's fill sunk into the
+    body) carries none."""
+    from mxnet_tpu import profiler
     from mxnet_tpu.parallel.expert_parallel import (_GRANULE, _PART_ROWS,
                                                     moe_apply)
 
-    tokens, hidden, experts, held, width, top_k = 16384, 2048, 128, 16, 768, 8
+    tokens, hidden, experts, held, width, top_k, scoring = EXPERT_LAYERS[cell]
 
     def grouped(p, rows, sizes):
         rows = rows.astype(p["g"].dtype)
@@ -222,13 +277,15 @@ def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
                    p["d"])
 
     @jax.checkpoint
-    def layer(x, router, p):
+    def layer(x, router, p, bias):
         out, aux = moe_apply(grouped, p, router, x, capacity_factor=None,
-                             top_k=top_k, renormalize=True, held=(16, held))
+                             top_k=top_k, renormalize=True, held=(16, held),
+                             select_bias=bias, **scoring)
         return out, aux["walked_rows"]
 
-    def loss(x, router, p):
-        out, walked = layer(x, router, p)
+    @jax.named_scope(profiler.SCOPE_FORWARD)
+    def loss(x, router, p, bias):
+        out, walked = layer(x, router, p, bias)
         return jnp.sum(jnp.sin(out)), walked
 
     def spec(shape, dtype):
@@ -238,11 +295,13 @@ def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
             spec((hidden, experts), "float32"),
             {"g": spec((held, hidden, width), "bfloat16"),
              "u": spec((held, hidden, width), "bfloat16"),
-             "d": spec((held, width, hidden), "bfloat16")})
+             "d": spec((held, width, hidden), "bfloat16")},
+            spec((experts,), "float32") if scoring else None)
     fn = jax.value_and_grad(loss, (0, 1, 2), has_aux=True) if backward \
-        else layer
-    gathered, scattered = _gathered_and_scattered(
-        jax.jit(fn).lower(*args).compile().as_text())
+        else loss
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    gathered, scattered = _gathered_and_scattered(text)
     # besides the whole part's, the router's top-k scatters a token's 8
     # gates back and a granule's gates' gradients go to their pairs:
     # scalars both
@@ -257,3 +316,27 @@ def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
     assert rows.count(_PART_ROWS) == len(whole)
     assert set(rows) == {_GRANULE, _PART_ROWS}
     assert all(tokens * top_k not in shape for shape in gathered)
+
+    loops = _loops_over_parts(text)
+    assert len(loops) == (2 if backward else 1)
+    table = profiler.scopes_of(compiled)
+    for own, cond, body in loops:
+        assert profiler.SCOPE_MOE_EXPERTS not in own
+        # the bound is carried: no constant to compare the counter with
+        assert not any(" constant(" in rest for _, _, rest in cond)
+        # (a constant, or an element of the loop's carry, keeps the name of
+        # whoever made it first, and is no work)
+        named = [(name, scope) for name, scope, rest in cond + body
+                 if scope and profiler._HLO_OPCODE.search(rest).group(1)
+                 not in ("constant", "get-tuple-element")]
+        assert len(named) > 20
+        for name, scope in named:
+            if scope.startswith("ragged-dot"):
+                continue
+            assert (profiler.SCOPE_MOE_ROUTE in scope) \
+                != (profiler.SCOPE_MOE_EXPERTS in scope), (name, scope)
+            # (a fusion may take in an op that the forward named as well)
+            want = "backward" if "transpose(" in own else "forward"
+            assert profiler._scope_classes([scope]) == [want], (name, scope)
+            assert want in table[name]["classes"], name
+    assert sum("transpose(" in own for own, _, _ in loops) == int(backward)

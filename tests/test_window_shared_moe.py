@@ -348,6 +348,14 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_block():
                                atol=2e-5)
 
 
+@pytest.fixture
+def flash_calls_from_nothing():
+    """``telemetry.reset`` zeroes a label and keeps it, so the forward's calls
+    by mask would list the masks of the process's earlier tests: the family
+    goes, and the next call registers it anew."""
+    telemetry._FAMILIES.pop("mxnet_flash_attention_fwd_calls_total", None)
+
+
 @pytest.mark.parametrize("amp,tolerance", [
     # float32 against float32: the gap is the order of the sums: 0 / 8e-8 /
     # 6e-7 / 1.2e-5 measured at seed 5, 1e-5 allowed (the change's gap is of
@@ -363,8 +371,8 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_block():
     ("bfloat16", {"loss_gap": 2e-3, "first_gradient_gap": 0.1,
                   "first_gradient_error": 0.4, "change_gap": 0.05}),
 ])
-def test_program_matches_the_reference_loss_and_every_gradient(amp,
-                                                               tolerance):
+def test_program_matches_the_reference_loss_and_every_gradient(
+        flash_calls_from_nothing, amp, tolerance):
     from chipbench.harness import check, loop
     from mxnet_tpu import profiler
 
@@ -395,6 +403,10 @@ def test_program_matches_the_reference_loss_and_every_gradient(amp,
     metrics = telemetry.snapshot()["metrics"]
     pairs = metrics["mxnet_moe_routed_pairs_total"]["samples"][0]["value"]
     assert pairs == 2 * 4 * 64
+    # 64 tokens choose 8: one part of 512 sorted rows a layer, and it holds
+    # pairs
+    for name in ("mxnet_moe_live_parts_total", "mxnet_moe_parts_total"):
+        assert metrics[name]["samples"][0]["value"] == 2 * 4
     # the forward's calls by mask (the trace's and the checkpoints' of four
     # window layers and one full), and the shared expert's scope in the
     # step's table
