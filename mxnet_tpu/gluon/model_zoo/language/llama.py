@@ -38,7 +38,8 @@ class LlamaConfig:
                  attention_gate=False, post_norms=False, embed_scale=1.0,
                  num_dense_layers=0, moe_shared_intermediate_size=0,
                  moe_score="softmax", moe_route_scale=1.0,
-                 moe_renorm_eps=0.0, moe_select_bias=False):
+                 moe_renorm_eps=0.0, moe_select_bias=False,
+                 rope_parameters=None):
         # num_experts > 0: an MoE FFN (parallel.expert_parallel) replaces
         # the dense SwiGLU MLP in every layer after the first
         # num_dense_layers; num_experts is the router's width.
@@ -93,6 +94,26 @@ class LlamaConfig:
         self.attention_gate = attention_gate
         self.post_norms = post_norms
         self.embed_scale = embed_scale
+        # rope_parameters: RoPE by attention kind, {"full" | "window":
+        # {"rope_theta": base, and for YaRN "rope_type": "yarn", "factor",
+        # "original_max_position_embeddings", "beta_fast", "beta_slow",
+        # "attention_factor", "truncate"}} (the keys of a published
+        # config's rope_parameters); a kind it does not name turns by
+        # rope_base, and rope_attention_types still says which kinds turn
+        self.rope_parameters = {k: dict(v) for k, v in
+                                (rope_parameters or {}).items()}
+        for kind, given in self.rope_parameters.items():
+            if kind not in ("full", "window") or given.get(
+                    "rope_type", "default") not in ("default", "yarn"):
+                raise MXNetError(
+                    "rope_parameters names the kinds 'full' and 'window' "
+                    f"and the rope_type 'default' or 'yarn'; got {kind!r}: "
+                    f"{given}")
+        if self.rope_parameters and block_diffusion:
+            raise MXNetError(
+                "the block-diffusion layout (block_diffusion > 0) turns both "
+                "halves of its row by rope_base; it takes no rope_parameters "
+                "by kind")
         if len(self.attention_types) != num_layers or set(
                 self.attention_types) - {"full", "window"}:
             raise MXNetError(
@@ -137,6 +158,22 @@ class LlamaConfig:
                 f"num_kv_heads ({num_kv_heads}) must divide num_heads "
                 f"({num_heads}) for GQA")
         self.head_dim = head_dim or hidden_size // num_heads
+
+    def rope_kwargs(self, kind):
+        """What ``F.rope`` takes for a layer of ``kind``: ``base`` alone,
+        or YaRN's inverse frequencies and magnitude."""
+        given = self.rope_parameters.get(kind, {})
+        base = float(given.get("rope_theta", self.rope_base))
+        if given.get("rope_type", "default") == "default":
+            return {"base": base}
+        from ....ops.attention_ops import yarn_rope_parameters
+
+        inv_freq, magnitude = yarn_rope_parameters(
+            self.head_dim, base, given["factor"],
+            given["original_max_position_embeddings"],
+            given.get("beta_fast", 32.0), given.get("beta_slow", 1.0),
+            given.get("attention_factor"), given.get("truncate", True))
+        return {"inv_freq": inv_freq, "magnitude": magnitude}
 
     def sparse_layer(self, i):
         """Is layer ``i``'s FFN the expert layer?"""
@@ -189,7 +226,10 @@ class LlamaAttention(HybridBlock):
                                           flatten=False, in_units=d,
                                           prefix="gate_proj_")
 
-    def hybrid_forward(self, F, x):
+    def hybrid_forward(self, F, x, segment_ids=None, positions=None):
+        """``segment_ids`` (batch, L) with the ``positions`` that start
+        again at each document (``LlamaModel`` derives them): RoPE turns by
+        those, and a query sees the keys of its own document alone."""
         cfg = self._cfg
         b, l = x.shape[0], x.shape[1]
         hd = cfg.head_dim
@@ -207,19 +247,21 @@ class LlamaAttention(HybridBlock):
             pos = F.concat(half, half, dim=0)
             q = F.rope(q, pos, base=cfg.rope_base)
             k = F.rope(k, pos, base=cfg.rope_base)
-            o = F.flash_attention(q, k, v, mask="block_diffusion",
+            o = F.flash_attention(q, k, v, segment_ids,
+                                  mask="block_diffusion",
                                   mask_block=cfg.block_diffusion,
                                   sm_scale=1.0 / math.sqrt(hd))
         else:
             if self._kind in cfg.rope_attention_types:
-                q = F.rope(q, base=cfg.rope_base)
-                k = F.rope(k, base=cfg.rope_base)
+                turn = cfg.rope_kwargs(self._kind)
+                q = F.rope(q, positions, **turn)
+                k = F.rope(k, positions, **turn)
             if self._kind == "window":
-                o = F.flash_attention(q, k, v, mask="window",
+                o = F.flash_attention(q, k, v, segment_ids, mask="window",
                                       window=cfg.attention_window,
                                       sm_scale=1.0 / math.sqrt(hd))
             else:
-                o = F.flash_attention(q, k, v, causal=True,
+                o = F.flash_attention(q, k, v, segment_ids, causal=True,
                                       sm_scale=1.0 / math.sqrt(hd))
         o = o.transpose((0, 2, 1, 3)).reshape((b, l, cfg.num_heads * hd))
         if cfg.attention_gate:
@@ -337,13 +379,15 @@ class LlamaDecoderLayer(HybridBlock):
                 self.mlp_out_layernorm = RMSNorm(
                     cfg.hidden_size, cfg.rms_eps, prefix="mlp_out_layernorm_")
 
-    def _body(self, x):
-        a = self.self_attn(self.input_layernorm(x))
+    def _body(self, x, *packed):
+        a = self.self_attn(self.input_layernorm(x), *packed)
         x = x + (self.attn_out_layernorm(a) if self._post_norms else a)
         m = self.mlp(self.post_attention_layernorm(x))
         return x + (self.mlp_out_layernorm(m) if self._post_norms else m)
 
-    def hybrid_forward(self, F, x):
+    def hybrid_forward(self, F, x, *packed):
+        """``packed``: nothing, or the row's segment ids and positions,
+        which go on to the attention."""
         if self._remat:
             import jax
 
@@ -359,14 +403,15 @@ class LlamaDecoderLayer(HybridBlock):
                 # checkpoint as an output and is given again outside
                 from .... import telemetry as _telemetry
 
-                def body_pure(v):
+                def body_pure(*values):
+                    ctx = getattr(x, "context", None)
                     with _telemetry.collect_step_scalars() as scalars:
-                        out = self._body(
-                            NDArray._from_jax(v, getattr(x, "context", None))
-                        )._get()
+                        out = self._body(*(NDArray._from_jax(v, ctx)
+                                           for v in values))._get()
                     return out, scalars.stacked()
 
-                out, scalars = jax.checkpoint(body_pure)(xv)
+                out, scalars = jax.checkpoint(body_pure)(
+                    xv, *(p._get() for p in packed))
                 for name, values in scalars.items():
                     _telemetry.step_scalar(name, values)
                 return NDArray._from_jax(out, getattr(x, "context", None))
@@ -384,7 +429,7 @@ class LlamaDecoderLayer(HybridBlock):
                     "parallel.data_parallel.TrainStep (any jax trace of the "
                     "net) for rematerialized training",
                     stacklevel=2)
-        return self._body(x)
+        return self._body(x, *packed)
 
 
 class LlamaModel(HybridBlock):
@@ -400,11 +445,20 @@ class LlamaModel(HybridBlock):
                     self.layers.add(LlamaDecoderLayer(cfg, i, prefix=f"{i}_"))
             self.norm = RMSNorm(cfg.hidden_size, cfg.rms_eps, prefix="norm_")
 
-    def hybrid_forward(self, F, input_ids):
+    def hybrid_forward(self, F, input_ids, segment_ids=None):
+        """``segment_ids`` (batch, L) integers: the row is documents packed
+        end to end, a document a run of equal ids.  Positions then start
+        again at each document and every layer's attention is confined to
+        the query's own (``F.flash_attention``'s ``segment_ids``)."""
         h = self.embed_tokens(input_ids)
         if self._cfg.embed_scale != 1.0:
             h = h * self._cfg.embed_scale
-        h = self.layers(h)
+        # what a packed row brings to every layer: nothing, or its ids and
+        # the positions that start again at each document
+        packed = () if segment_ids is None else (
+            segment_ids, F.segment_positions(segment_ids))
+        for layer in self.layers:
+            h = layer(h, *packed)
         return self.norm(h)
 
 
@@ -418,8 +472,8 @@ class LlamaForCausalLM(HybridBlock):
                                     flatten=False, in_units=cfg.hidden_size,
                                     prefix="lm_head_")
 
-    def hybrid_forward(self, F, input_ids):
-        h = self.model(input_ids)
+    def hybrid_forward(self, F, input_ids, segment_ids=None):
+        h = self.model(input_ids, segment_ids)
         if self._cfg.block_diffusion:
             # rows are [xt ; x0]: logits over the noised half only
             h = F.slice_axis(h, axis=1, begin=0, end=h.shape[1] // 2)
@@ -690,6 +744,10 @@ def _refuse_unserved(cfg):
             "incremental decode does not support window layers, layers "
             "without RoPE, the attention gate, post norms or an embedding "
             "scale yet")
+    if cfg.rope_parameters:
+        raise MXNetError(
+            "incremental decode does not support rope_parameters by "
+            "attention kind or YaRN yet: it turns every layer by rope_base")
 
 
 def prefill_apply(params, cfg, ids):

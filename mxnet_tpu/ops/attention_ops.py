@@ -97,28 +97,95 @@ def rms_norm(x, gamma, eps=1e-6):
     return (y * gamma.astype(jnp.float32)).astype(x.dtype)
 
 
+def yarn_rope_parameters(head_dim, base, factor, original_max_position,
+                         beta_fast=32.0, beta_slow=1.0, attention_factor=None,
+                         truncate=True):
+    """``(inv_freq, magnitude)`` of YaRN (Peng et al. 2023, as
+    ``transformers``' ``_compute_yarn_parameters`` has it): ``head_dim / 2``
+    inverse frequencies, a blend of the interpolated ones (``base``'s
+    divided by ``factor``) and the extrapolated ones (``base``'s own) by a
+    ramp over the rotated dimensions, and the factor that scales cos and
+    sin (``0.1 ln(factor) + 1`` where ``attention_factor`` is None).  The
+    ramp runs from the dimension that turns ``beta_fast`` times over
+    ``original_max_position`` positions to the one that turns ``beta_slow``
+    times, rounded outwards to whole dimensions when ``truncate``.  Computed
+    on the host in float64; ``inv_freq`` is a tuple of Python floats, so
+    that it can be a static attribute of ``rope``."""
+    import math
+
+    def dim_of(rotations):
+        return (head_dim * math.log(original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low, high = dim_of(beta_fast), dim_of(beta_slow)
+    if truncate:
+        low, high = math.floor(low), math.ceil(high)
+    low, high = max(low, 0), min(high, head_dim - 1)
+    if low == high:
+        high += 0.001   # the ramp's width may not be 0
+    n = _np.arange(head_dim // 2, dtype=_np.float64)
+    extrapolated = base ** (-2.0 * n / head_dim)
+    interpolated = extrapolated / factor
+    ramp = _np.clip((n - low) / (high - low), 0.0, 1.0)
+    inv_freq = interpolated * ramp + extrapolated * (1.0 - ramp)
+    if attention_factor is None:
+        attention_factor = 0.1 * math.log(factor) + 1.0 if factor > 1 else 1.0
+    return tuple(float(f) for f in inv_freq), float(attention_factor)
+
+
 @register("rope")
-def rope(x, positions=None, base=10000.0, scale=1.0):
+def rope(x, positions=None, base=10000.0, scale=1.0, inv_freq=None,
+         magnitude=1.0):
     """Rotary position embedding over the last dim.
 
     x (B, H, L, D) with D even; positions (L,) or (B, L) (defaults to
-    arange).  Half-split convention (Llama)."""
+    arange).  Half-split convention (Llama).  ``inv_freq`` (D / 2 numbers)
+    takes the place of ``base``'s ``base ** (-2n / D)`` where a scaling of
+    the frequencies gives its own (``yarn_rope_parameters``); ``magnitude``
+    multiplies cos and sin."""
     jnp = _jnp()
     b, h, l, d = x.shape
     if positions is None:
         positions = jnp.arange(l)
     positions = jnp.asarray(positions) * scale
-    freqs = base ** (-jnp.arange(0, d // 2, dtype=jnp.float32) / (d // 2))
+    if inv_freq is None:
+        freqs = base ** (-jnp.arange(0, d // 2, dtype=jnp.float32) / (d // 2))
+    else:
+        freqs = jnp.asarray(inv_freq, dtype=jnp.float32)
+        if freqs.shape != (d // 2,):
+            from ..base import MXNetError
+
+            raise MXNetError(f"rope: inv_freq holds {freqs.shape} numbers "
+                             f"for a head of {d}; wants {d // 2}")
     angles = positions[..., None] * freqs                  # (..., L, d/2)
     if angles.ndim == 2:        # (L, d/2): shared across batch and heads
         angles = angles[None, None]
     elif angles.ndim == 3:      # (B, L, d/2): per-batch, broadcast over heads
         angles = angles[:, None]
-    cos = jnp.cos(angles).astype(x.dtype)
-    sin = jnp.sin(angles).astype(x.dtype)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if magnitude != 1.0:
+        cos, sin = cos * magnitude, sin * magnitude
+    cos, sin = cos.astype(x.dtype), sin.astype(x.dtype)
     x1, x2 = x[..., : d // 2], x[..., d // 2:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1)
+
+
+@register("segment_positions", differentiable=False)
+def segment_positions(segment_ids):
+    """Positions that start again at each document of a packed row:
+    ``segment_ids`` (B, L) integers, a document a run of equal ids;
+    ``pos[i] = i - (index of the first token of i's run)``, int32.  The
+    first index of a run is the running maximum of the boundaries'."""
+    import jax
+
+    jnp = _jnp()
+    ids = jnp.asarray(segment_ids)
+    index = jnp.arange(ids.shape[-1], dtype=jnp.int32)
+    starts = jnp.where(ids[..., 1:] != ids[..., :-1], index[1:], 0)
+    starts = jnp.concatenate([jnp.zeros_like(starts[..., :1]), starts], -1)
+    return index - jax.lax.cummax(starts, axis=ids.ndim - 1)
 
 
 @register("swiglu")

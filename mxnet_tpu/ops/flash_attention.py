@@ -25,7 +25,9 @@ import threading
 
 import numpy as _np
 
-from ..profiler import SCOPE_ATTENTION_BWD, SCOPE_ATTENTION_PLAIN_FWD
+from ..profiler import (KERNEL_ATTENTION_FWD, KERNEL_SUFFIX_SEGMENTS,
+                        KERNEL_SUFFIX_WINDOW, SCOPE_ATTENTION_BWD,
+                        SCOPE_ATTENTION_PLAIN_FWD)
 
 NEG_INF = -1e30
 
@@ -101,9 +103,12 @@ def _block_of(xp, pos, half, block):
     return xp.where(noised, pos, pos - half) // block, noised
 
 
-def _visible(xp, q_pos, k_pos, causal, mask, lq, lk):
+def _visible(xp, q_pos, k_pos, causal, mask, lq, lk, ids=None):
     """May query row ``q_pos`` see key column ``k_pos``?  Broadcasts; None
-    where every pair is visible.  ``xp`` is numpy or jax.numpy.
+    where every pair is visible.  ``xp`` is numpy or jax.numpy.  With
+    ``ids`` = (the queries' segment ids, the keys'), shaped as the
+    positions are, a pair is seen only inside one document: the mask's
+    predicate and the ids' equality.
 
     ``causal``: keys up to the query's own position, the diagonal moved by
     ``lk - lq`` (decode).  ``(BLOCK_DIFFUSION, B)``: the training mask of
@@ -114,6 +119,9 @@ def _visible(xp, q_pos, k_pos, causal, mask, lq, lk):
     ``(WINDOW, W)``: causal, and of the keys up to its own position a query
     sees the last ``W`` alone (``i - W < j <= i``, the diagonal moved as
     ``causal`` moves it)."""
+    if ids is not None:
+        return _visible(xp, q_pos, k_pos, causal, mask, lq, lk) \
+            & (ids[0] == ids[1])
     if mask is not None and mask[0] == WINDOW:
         last = q_pos + (lk - lq)
         return (k_pos <= last) & (k_pos > last - mask[1])
@@ -160,6 +168,70 @@ def _live_tiles(causal, mask, lq, lk, block_q, block_k):
     return _tile_visibility(causal, mask, lq, lk, block_q, block_k)[0]
 
 
+class _Mask:
+    """A call's mask as every path takes it, built once in
+    ``flash_attention`` and handed on whole: ``key``, the static part
+    (``_mask_key``: None, ``(BLOCK_DIFFUSION, B)`` or ``(WINDOW, W)``), and
+    ``seg``, the operand a packed row brings or None: (batch, lk) int32
+    segment ids, a document a run of equal ids, the queries being the last
+    ``lq`` keys.  ``_visible`` is the one predicate over both.  A path
+    called on its own (tests, ``context_parallel``) may be given the key
+    alone."""
+
+    __slots__ = ("key", "seg")
+
+    def __init__(self, key=None, seg=None):
+        self.key, self.seg = key, seg
+
+    @classmethod
+    def of(cls, mask):
+        return mask if isinstance(mask, cls) else cls(mask)
+
+    @property
+    def operands(self):
+        """What of the description is traced: ``()`` or ``(seg,)``."""
+        return () if self.seg is None else (self.seg,)
+
+    def over(self, *operands):
+        """The description over ``operands`` in place of its own: a batch
+        shard's, or those a ``custom_vjp`` hands back."""
+        return _Mask(self.key, *operands)
+
+    def ids(self, lq):
+        """``(queries' ids (b, 1, lq, 1), keys' ids (b, 1, 1, lk))``, shaped
+        to broadcast against the scores, or None without ``seg``."""
+        if self.seg is None:
+            return None
+        seg = self.seg
+        return seg[:, None, seg.shape[1] - lq:, None], seg[:, None, None, :]
+
+    def label(self, causal):
+        """The value of a counter's ``mask`` label."""
+        return (self.key[0] if self.key else "causal" if causal else "none") \
+            + (KERNEL_SUFFIX_SEGMENTS if self.seg is not None else "")
+
+    def check(self, causal, batch, lk):
+        """Segment ids go with ``causal`` and with the window (every query
+        then sees itself, so no row is empty): one id a key."""
+        from ..base import MXNetError
+
+        if self.seg is None:
+            return
+        if self.key is not None and self.key[0] != WINDOW:
+            raise MXNetError(f"flash_attention: segment_ids do not go with "
+                             f"mask {self.key[0]!r}; they confine causal=True "
+                             f"and mask={WINDOW!r}")
+        if self.key is None and not causal:
+            raise MXNetError("flash_attention: segment_ids confine "
+                             f"causal=True and mask={WINDOW!r}; a call that "
+                             "is neither has no diagonal for a query to see "
+                             "itself on")
+        if tuple(self.seg.shape) != (batch, lk):
+            raise MXNetError(f"flash_attention: segment_ids are (batch, lk) "
+                             f"= ({batch}, {lk}); got "
+                             f"{tuple(self.seg.shape)}")
+
+
 def _check_mask_shape(mask, lq, lk):
     from ..base import MXNetError
 
@@ -181,6 +253,7 @@ def _mha_with_lse(q, k, v, causal, sm_scale, mask=None):
     import jax
     import jax.numpy as jnp
 
+    mask = _Mask.of(mask)
     with jax.named_scope(SCOPE_ATTENTION_PLAIN_FWD):
         b, hq, lq, d = q.shape
         hkv = k.shape[1]
@@ -192,7 +265,7 @@ def _mha_with_lse(q, k, v, causal, sm_scale, mask=None):
                             k.astype(jnp.float32)) * sm_scale
         lk = k.shape[2]
         seen = _visible(jnp, jnp.arange(lq)[:, None], jnp.arange(lk)[None, :],
-                        causal, mask, lq, lk)
+                        causal, mask.key, lq, lk, mask.ids(lq))
         if seen is not None:
             scores = jnp.where(seen, scores, NEG_INF)
         m = scores.max(axis=-1, keepdims=True)
@@ -276,14 +349,22 @@ def _window_tile_range(r0, r1, offset, window, block_k, num_kb):
 
 def _kernel_name(base, mask):
     """A kernel's name in a trace: a window call's tells it from a full
-    call's; the others keep ``base``."""
-    return base + "_window" if mask is not None and mask[0] == WINDOW \
-        else base
+    call's, a call's under segment ids from one without; the others keep
+    ``base``."""
+    mask = _Mask.of(mask)
+    if mask.key is not None and mask.key[0] == WINDOW:
+        base += KERNEL_SUFFIX_WINDOW
+    return base + KERNEL_SUFFIX_SEGMENTS if mask.seg is not None else base
 
 
 def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
-                   sm_scale, seq_k, diag_offset=0, mask=None):
-    """One q block against its head's whole K/V row.
+                   sm_scale, seq_k, diag_offset=0, mask=None, qseg_ref=None,
+                   kseg_ref=None):
+    """One q block against its head's whole K/V row.  Under segment ids
+    (``_fa_fwd_kernel_segments``) ``qseg_ref`` (block_q, 1) holds the q
+    block's ids as a column and ``kseg_ref`` (8, seq_k) the row's along the
+    lanes (8 sublanes alike): every tile walked is then masked by
+    ``_visible`` with them; which tiles are walked does not change.
 
     Grid: (batch*heads, num_q_blocks).  Block shapes:
       q_ref (block_q, d) VMEM; k_ref/v_ref (seq_k, d) VMEM (whole K/V row
@@ -311,6 +392,7 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
     block_q, d = q_ref.shape
     qi = pl.program_id(1)
     num_kb = seq_k // block_k
+    segmented = qseg_ref is not None
 
     q = q_ref[:]
     precision = _operand_precision(q.dtype)
@@ -326,19 +408,22 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
                                 preferred_element_type=jnp.float32)
         if not scale_q:
             s = s * sm_scale
-        if causal:
+        if causal and not segmented:
             q_pos = diag_offset + qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        elif mask is not None:
+        elif causal or mask is not None:
             q_pos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, 1), 0)
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (1, block_k), 1)
-            s = jnp.where(_visible(jnp, q_pos, k_pos, False, mask,
-                                   seq_k - diag_offset, seq_k), s, NEG_INF)
+            ids = (qseg_ref[...], kseg_ref[:1, pl.ds(kb * block_k, block_k)]) \
+                if segmented else None
+            s = jnp.where(_visible(jnp, q_pos, k_pos, causal, mask,
+                                   seq_k - diag_offset, seq_k, ids), s,
+                          NEG_INF)
         return s
 
     def weighted_v(p, kb):
@@ -396,6 +481,14 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
     lse_ref[:] = jnp.broadcast_to(lse.reshape(1, block_q), lse_ref.shape)
 
 
+def _fa_fwd_kernel_segments(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref,
+                            lse_ref, **static):
+    """``_fa_fwd_kernel`` of a call under segment ids: its two refs of ids
+    come after the operands, as ``_fa_forward_pallas`` lists them."""
+    _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qseg_ref=qseg_ref,
+                   kseg_ref=kseg_ref, **static)
+
+
 def _largest_tile(length):
     """The largest of 512 / 256 / 128 that divides ``length``, or None."""
     return next((b for b in (512, 256, 128) if length % b == 0), None)
@@ -421,12 +514,37 @@ def _fa_block_sizes(lq, lk, d, itemsize):
     return block_q, block_k
 
 
+# Mosaic's scoped VMEM limit where a call states none, and the most a kernel
+# may state for itself (a v5e core has 128 MiB)
+_VMEM_DEFAULT_LIMIT = 16 << 20
+_VMEM_MOST = 96 << 20
+
+
+def _fa_fwd_vmem_limit(lk, d, itemsize, block_q, segmented):
+    """The scoped VMEM limit a forward call states, or None.  One grid step
+    may hold two buffers of the head's whole K and V rows, twice
+    ``_TILE_VMEM_BUDGET`` (what the tile choice may spend on a step's score
+    and operand tiles, and as much again for their copies and second
+    buffers), and under segment ids two buffers of the keys' ids (8
+    sublanes) and of the q tile's column (a lane tile wide in VMEM).  A K
+    row too long for Mosaic's default limit states its own, as the backward
+    does (16,384 keys of 128 in bf16 are 16 MiB in their two buffers);
+    every call under the default states none and is compiled as it always
+    was (8,192 keys of 128 in bf16 stand exactly at it: tested)."""
+    ids = 2 * 4 * (8 * lk + 128 * block_q) if segmented else 0
+    need = 4 * lk * d * itemsize + 2 * _TILE_VMEM_BUDGET + ids
+    return None if need <= _VMEM_DEFAULT_LIMIT else min(need, _VMEM_MOST)
+
+
 def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q=None, block_k=None,
                        mask=None):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
+    mask = _Mask.of(mask)
+    seg = mask.seg
     b, h, lq, d = q.shape
     lk = k.shape[2]
     if block_q is None or block_k is None:
@@ -443,17 +561,33 @@ def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q=None, block_k=None,
     kf = k.reshape(b * h, lk, d)
     vf = v.reshape(b * h, lk, d)
 
-    kernel = functools.partial(_fa_fwd_kernel, block_k=block_k,
-                               causal=causal, sm_scale=sm_scale, seq_k=lk,
-                               diag_offset=lk - lq, mask=mask)
+    kernel = functools.partial(
+        _fa_fwd_kernel if seg is None else _fa_fwd_kernel_segments,
+        block_k=block_k, causal=causal, sm_scale=sm_scale, seq_k=lk,
+        diag_offset=lk - lq, mask=mask.key)
+    in_specs = [
+        pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0)),
+        pl.BlockSpec((None, lk, d), lambda bh, qi: (bh, 0, 0)),
+        pl.BlockSpec((None, lk, d), lambda bh, qi: (bh, 0, 0)),
+    ]
+    operands = [qf, kf, vf]
+    limit = _fa_fwd_vmem_limit(lk, d, q.dtype.itemsize, block_q,
+                               seg is not None)
+    params = {} if limit is None else {
+        "compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)}
+    if seg is not None:
+        # a sample's ids serve its h heads: the queries' as a column a q
+        # block, the keys' whole along the lanes
+        in_specs += [
+            pl.BlockSpec((None, block_q, 1), lambda bh, qi: (bh // h, qi, 0)),
+            pl.BlockSpec((None, 8, lk), lambda bh, qi: (bh // h, 0, 0)),
+        ]
+        operands += [seg[:, lk - lq:, None],
+                     jnp.broadcast_to(seg[:, None, :], (b, 8, lk))]
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((None, lk, d), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((None, lk, d), lambda bh, qi: (bh, 0, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((None, 8, block_q), lambda bh, qi: (bh, 0, qi)),
@@ -462,8 +596,9 @@ def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q=None, block_k=None,
             jax.ShapeDtypeStruct((b * h, lq, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, 8, lq), jnp.float32),
         ],
-        name=_kernel_name("mxnet_flash_attention_fwd", mask),
-    )(qf, kf, vf)
+        name=_kernel_name(KERNEL_ATTENTION_FWD, mask),
+        **params,
+    )(*operands)
     return o.reshape(b, h, lq, d), lse[:, 0, :].reshape(b, h, lq)
 
 
@@ -478,10 +613,15 @@ def _per_batch_shard(fn, sharded):
 
 
 def _fa_forward(q, k, v, causal, sm_scale, mask=None, sharded=None):
-    """The Pallas forward, per batch shard under ``sharded``."""
-    fwd = functools.partial(_fa_forward_pallas, causal=causal,
-                            sm_scale=sm_scale, mask=mask)
-    return _per_batch_shard(fwd, sharded)(q, k, v)
+    """The Pallas forward, per batch shard under ``sharded`` (the mask's
+    operands, where it has any, sharded with the batch)."""
+    mask = _Mask.of(mask)
+
+    def fwd(q, k, v, *operands):
+        return _fa_forward_pallas(q, k, v, causal, sm_scale,
+                                  mask=mask.over(*operands))
+
+    return _per_batch_shard(fwd, sharded)(q, k, v, *mask.operands)
 
 
 # --------------------------------------------------------------------------
@@ -492,11 +632,6 @@ _TN_DIMS = (((0,), (0,)), ((), ()))
 
 # flags of a row of the backward's table of tile pairs
 _FIRST_OF_K, _LAST_OF_K, _PARTLY_SEEN = 1, 2, 4
-
-# Mosaic's scoped VMEM limit where a call states none, and the most the
-# backward may state for itself (a v5e core has 128 MiB)
-_VMEM_DEFAULT_LIMIT = 16 << 20
-_VMEM_MOST = 96 << 20
 
 
 def _fa_bwd_block_sizes(lq, lk):
@@ -542,8 +677,13 @@ def _fa_bwd_pairs(causal, mask, lq, lk, block_q, block_k):
 
 def _fa_bwd_kernel(pairs_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, causal,
-                   sm_scale, seq_q, seq_k, mask):
-    """One live tile pair: its five products, nothing recomputed.
+                   sm_scale, seq_q, seq_k, mask, qseg_ref=None,
+                   kseg_ref=None):
+    """One live tile pair: its five products, nothing recomputed.  Under
+    segment ids (``_fa_bwd_kernel_segments``) ``qseg_ref`` (1, block_q)
+    holds the q tile's ids as a row and ``kseg_ref`` (block_k, 1) the K
+    tile's as a column: every pair walked is then masked (a tile the mask
+    shows whole may still hold two documents); the table does not change.
 
     Grid: (batch*heads, live tile pairs), the pairs K tile by K tile
     (``_fa_bwd_pairs``, prefetched to SMEM; the block index maps read it, so
@@ -570,6 +710,7 @@ def _fa_bwd_kernel(pairs_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     block_k = k_ref.shape[0]
     t = pl.program_id(1)
     qi, ki, flags = pairs_ref[0, t], pairs_ref[1, t], pairs_ref[2, t]
+    ids = None if qseg_ref is None else (qseg_ref[...], kseg_ref[...])
 
     @pl.when(t == 0)
     def _():
@@ -598,9 +739,10 @@ def _fa_bwd_kernel(pairs_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             k_pos = ki * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_k, 1), 0)
             return jnp.where(_visible(jnp, q_pos, k_pos, causal, mask, seq_q,
-                                      seq_k), s, NEG_INF)
+                                      seq_k, ids), s, NEG_INF)
 
-        s = jax.lax.cond((flags & _PARTLY_SEEN) != 0, hide, lambda s: s, s)
+        s = hide(s) if ids is not None else jax.lax.cond(
+            (flags & _PARTLY_SEEN) != 0, hide, lambda s: s, s)
     p = jnp.exp(s - lse_ref[...])
     dv_acc[...] += dot(p.astype(g.dtype), g, _NN_DIMS)
     dp = dot(v, g, _NT_DIMS)
@@ -623,6 +765,16 @@ def _fa_bwd_kernel(pairs_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         jax.lax.fori_loop(0, seq_q // block_q, put, None)
 
 
+def _fa_bwd_kernel_segments(pairs_ref, q_ref, k_ref, v_ref, g_ref, lse_ref,
+                            delta_ref, qseg_ref, kseg_ref, *outputs_and_scratch,
+                            **static):
+    """``_fa_bwd_kernel`` of a call under segment ids: its two refs of ids
+    come after the operands, as ``_fa_backward_pallas`` lists them."""
+    _fa_bwd_kernel(pairs_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
+                   *outputs_and_scratch, qseg_ref=qseg_ref,
+                   kseg_ref=kseg_ref, **static)
+
+
 def _fa_backward_pallas(q, k, v, o, lse, g, causal, sm_scale, mask=None):
     """Gradients of q, k and v from one Pallas call over the live tile
     pairs (``_fa_bwd_kernel``); ``delta = rowsum(o * g)`` is the one
@@ -632,11 +784,13 @@ def _fa_backward_pallas(q, k, v, o, lse, g, causal, sm_scale, mask=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    mask = _Mask.of(mask)
+    seg = mask.seg
     with jax.named_scope(SCOPE_ATTENTION_BWD):
         b, h, lq, d = q.shape
         lk = k.shape[2]
         block_q, block_k = _fa_bwd_block_sizes(lq, lk)
-        pairs = _fa_bwd_pairs(causal, mask, lq, lk, block_q, block_k)
+        pairs = _fa_bwd_pairs(causal, mask.key, lq, lk, block_q, block_k)
         nq = lq // block_q
 
         delta = jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
@@ -650,15 +804,30 @@ def _fa_backward_pallas(q, k, v, o, lse, g, causal, sm_scale, mask=None):
         q_row = pl.BlockSpec((None, None, 1, block_q),
                              lambda bh, t, pairs: (bh, pairs[0, t], 0, 0))
         need = _fa_bwd_vmem_bytes(lq, d, q.dtype.itemsize, block_q, block_k)
-        kernel = functools.partial(_fa_bwd_kernel, causal=causal,
-                                   sm_scale=sm_scale, seq_q=lq, seq_k=lk,
-                                   mask=mask)
+        kernel = functools.partial(
+            _fa_bwd_kernel if seg is None else _fa_bwd_kernel_segments,
+            causal=causal, sm_scale=sm_scale, seq_q=lq, seq_k=lk,
+            mask=mask.key)
+        in_specs = [q_tile, k_tile, k_tile, q_tile, q_row, q_row]
+        operands = [jnp.asarray(pairs), flat(q), flat(k), flat(v), flat(g),
+                    rows(lse), rows(delta)]
+        if seg is not None:
+            # a sample's ids serve its h heads: the q tile's as a row (the
+            # scores are held transposed), the K tile's as a column
+            in_specs += [
+                pl.BlockSpec((None, None, 1, block_q), lambda bh, t, pairs:
+                             (bh // h, pairs[0, t], 0, 0)),
+                pl.BlockSpec((None, block_k, 1), lambda bh, t, pairs:
+                             (bh // h, pairs[1, t], 0)),
+            ]
+            operands += [seg[:, lk - lq:].reshape(b, nq, 1, block_q),
+                         seg[:, :, None]]
         dq, dk, dv = pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
                 grid=(b * h, pairs.shape[1]),
-                in_specs=[q_tile, k_tile, k_tile, q_tile, q_row, q_row],
+                in_specs=in_specs,
                 out_specs=[pl.BlockSpec((None, lq, d),
                                         lambda bh, t, pairs: (bh, 0, 0)),
                            k_tile, k_tile],
@@ -671,9 +840,8 @@ def _fa_backward_pallas(q, k, v, o, lse, g, causal, sm_scale, mask=None):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary"),
                 vmem_limit_bytes=max(need, _VMEM_DEFAULT_LIMIT)),
-            name=_kernel_name("mxnet_flash_attention_bwd", mask),
-        )(jnp.asarray(pairs), flat(q), flat(k), flat(v), flat(g), rows(lse),
-          rows(delta))
+            name=_kernel_name(SCOPE_ATTENTION_BWD, mask),
+        )(*operands)
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
 
@@ -705,17 +873,23 @@ def _fa_backward(q, k, v, o, lse, g, causal, sm_scale, mask=None,
     ``mxnet_flash_attention_bwd_calls_total{path}``."""
     from .. import telemetry
 
+    mask = _Mask.of(mask)
     pallas = _use_pallas_bwd(q, k)
     telemetry.counter(
         "mxnet_flash_attention_bwd_calls_total",
         "flash_attention backward calls traced, by the path they took",
-        ("path",)).labels(path="pallas" if pallas else "blockwise").inc()
+        ("path",)).labels(path=("pallas" if pallas else "blockwise")
+                          + (KERNEL_SUFFIX_SEGMENTS if mask.operands else "")
+                          ).inc()
     if not pallas:
         return _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
                                       mask=mask)
-    bwd = functools.partial(_fa_backward_pallas, causal=causal,
-                            sm_scale=sm_scale, mask=mask)
-    return _per_batch_shard(bwd, sharded)(q, k, v, o, lse, g)
+
+    def bwd(q, k, v, o, lse, g, *operands):
+        return _fa_backward_pallas(q, k, v, o, lse, g, causal, sm_scale,
+                                   mask=mask.over(*operands))
+
+    return _per_batch_shard(bwd, sharded)(q, k, v, o, lse, g, *mask.operands)
 
 
 # --------------------------------------------------------------------------
@@ -742,6 +916,8 @@ def _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
     import jax
     import jax.numpy as jnp
 
+    how = _Mask.of(mask)
+    mask, ids = how.key, how.ids(q.shape[2])
     with jax.named_scope(SCOPE_ATTENTION_BWD):
         b, h, lq, d = q.shape
         lk = k.shape[2]
@@ -759,13 +935,22 @@ def _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
                                     precision=_operand_precision(q.dtype))
         delta = jnp.sum(o.astype(acc_t) * g.astype(acc_t), axis=-1)  # (b,h,lq)
 
-        def tile_grads(qt, gt, delta_t, lse_t, kt, vt, q_pos, k_pos):
+        def ids_of(q0, k0, rows_q):
+            """The segment ids of ``rows_q`` query rows from ``q0`` and of
+            the K tile from ``k0``, or None."""
+            if ids is None:
+                return None
+            return (jax.lax.dynamic_slice_in_dim(ids[0], q0, rows_q, axis=2),
+                    jax.lax.dynamic_slice_in_dim(ids[1], k0, block_k, axis=3))
+
+        def tile_grads(qt, gt, delta_t, lse_t, kt, vt, q_pos, k_pos, ids):
             """One tile's ``(dq part, dk part, dv part)`` in float32;
-            ``q_pos`` a column and ``k_pos`` a row of positions."""
+            ``q_pos`` a column and ``k_pos`` a row of positions, ``ids``
+            the tile's segment ids shaped likewise, or None."""
             s = product("bhqd,bhkd->bhqk", qt, kt) * sm_scale
             # same diagonal offset as the forward (q_i attends keys up to
             # i + lk - lq when lengths differ, e.g. decode)
-            seen = _visible(jnp, q_pos, k_pos, causal, mask, lq, lk)
+            seen = _visible(jnp, q_pos, k_pos, causal, mask, lq, lk, ids)
             if seen is not None:
                 s = jnp.where(seen, s, NEG_INF)
             p = jnp.exp(s - lse_t[..., None])                  # (b,h,q,bk)
@@ -781,10 +966,11 @@ def _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
             q_pos = jnp.arange(lq)[:, None]
 
             def step(dq, idx):
-                k_pos = idx * block_k + jnp.arange(block_k)[None, :]
+                k0 = idx * block_k
+                k_pos = k0 + jnp.arange(block_k)[None, :]
                 dq_part, dk, dv = tile_grads(
                     q, g, delta, lse, kb[:, :, idx], vb[:, :, idx],
-                    q_pos, k_pos)
+                    q_pos, k_pos, ids_of(0, k0, lq))
                 return dq + dq_part, (dk, dv)
 
             dq0 = jnp.zeros(q.shape, acc_t)
@@ -812,7 +998,8 @@ def _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
                     rows(delta, q0, block_q), rows(lse, q0, block_q),
                     rows(k, k0, block_k), rows(v, k0, block_k),
                     q0 + jnp.arange(block_q)[:, None],
-                    k0 + jnp.arange(block_k)[None, :])
+                    k0 + jnp.arange(block_k)[None, :],
+                    ids_of(q0, k0, block_q))
                 return (add_rows(dq, dq_part, q0), add_rows(dk, dk_part, k0),
                         add_rows(dv, dv_part, k0)), None
 
@@ -827,58 +1014,96 @@ def _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
 # --------------------------------------------------------------------------
 @functools.lru_cache(maxsize=None)
 def _make_flash(causal, sm_scale_key, mask=None, sharded=None):
-    """The op for one static configuration.  ``sharded`` is the
-    ``batch_sharded`` scope the call was traced under: the backward is
-    traced after that scope has closed (``value_and_grad`` transposes once
-    the forward has returned), so it is kept here and not read again."""
+    """The op for one static configuration: ``flash(q, k, v, *operands)``,
+    the operands those of the call's ``_Mask`` (none, or the segment ids:
+    integers, so no gradient goes back to them), of which ``mask`` is the
+    static key.  ``sharded`` is the ``batch_sharded`` scope the call was
+    traced under: the backward is traced after that scope has closed
+    (``value_and_grad`` transposes once the forward has returned), so it is
+    kept here and not read again."""
     import jax
-    import jax.numpy as jnp
 
     sm_scale = float(sm_scale_key)
 
     @jax.custom_vjp
-    def flash(q, k, v):
-        return _dispatch_fwd(q, k, v)[0]
+    def flash(q, k, v, *operands):
+        return _dispatch_fwd(q, k, v, *operands)[0]
 
-    def _dispatch_fwd(q, k, v):
+    def _dispatch_fwd(q, k, v, *operands):
         from .. import telemetry
 
+        how = _Mask(mask, *operands)
         pallas = _use_pallas(q)
         telemetry.counter(
             "mxnet_flash_attention_fwd_calls_total",
             "flash_attention forward calls traced, by the path they took "
-            "and the mask they ran under",
+            "and the mask they ran under (with _segments where segment ids "
+            "confine it)",
             ("path", "mask")).labels(
                 path="pallas" if pallas else "plain",
-                mask=mask[0] if mask else "causal" if causal else "none").inc()
+                mask=how.label(causal)).inc()
         if pallas:
-            o, lse = _fa_forward(q, k, v, causal, sm_scale, mask, sharded)
+            o, lse = _fa_forward(q, k, v, causal, sm_scale, how, sharded)
         else:
-            o, lse = _mha_with_lse(q, k, v, causal, sm_scale, mask)
-        return o, (q, k, v, o, lse)
-
-    def fwd(q, k, v):
-        o, res = _dispatch_fwd(q, k, v)
-        return o, res
+            o, lse = _mha_with_lse(q, k, v, causal, sm_scale, how)
+        return o, (q, k, v, o, lse, operands)
 
     def bwd(res, g):
-        q, k, v, o, lse = res
-        return _fa_backward(q, k, v, o, lse, g, causal, sm_scale, mask,
-                            sharded)
+        q, k, v, o, lse, operands = res
+        grads = _fa_backward(q, k, v, o, lse, g, causal, sm_scale,
+                             _Mask(mask, *operands), sharded)
+        return grads + (None,) * len(operands)
 
-    flash.defvjp(fwd, bwd)
+    flash.defvjp(_dispatch_fwd, bwd)
     return flash
 
 
+def _count_pairs(q, k, causal, mask):
+    """Gives the step's two counts of a call under segment ids (``mask``
+    its ``_Mask``) to ``telemetry.step_scalar`` (which keeps nothing
+    outside a fused step's trace, and the compiler then drops the sum), a
+    sample counted once whatever its heads: the pairs the mask and the ids
+    show, computed on the device from the ids (a query at position ``p`` of
+    its document sees ``p + 1`` keys, or the window's ``W`` if that is
+    fewer; a document a run of equal ids), and the pairs of the tiles the
+    forward walks, from the shape alone (every pair on the plain path)."""
+    import jax.numpy as jnp
+
+    from .. import telemetry
+    from .attention_ops import segment_positions
+
+    b, lq, lk = q.shape[0], q.shape[2], k.shape[2]
+    seen = segment_positions(mask.seg)[:, lk - lq:] + 1
+    if mask.key is not None:
+        seen = jnp.minimum(seen, mask.key[1])
+    walked = lq * lk
+    if _use_pallas(q):
+        block_q, block_k = _fa_block_sizes(lq, lk, q.shape[3],
+                                           q.dtype.itemsize)
+        walked = block_q * block_k * int(_live_tiles(
+            causal, mask.key, lq, lk, block_q, block_k).sum())
+    telemetry.step_scalar(telemetry.ATTENTION_VISIBLE_PAIRS.name,
+                          jnp.sum(seen.astype(jnp.float32)))
+    telemetry.step_scalar(telemetry.ATTENTION_WALKED_PAIRS.name,
+                          jnp.float32(b * walked))
+
+
 def flash_attention(q, k, v, causal=False, sm_scale=None, mask=None,
-                    mask_block=0, window=0):
+                    mask_block=0, window=0, segment_ids=None):
     """q (B,Hq,Lq,D); k,v (B,Hkv,Lk,D) with Hq % Hkv == 0 (GQA).
 
     ``mask="block_diffusion"`` with ``mask_block`` the block length: the
     training mask of block diffusion over rows of a noised copy followed by
     the clean copy.  ``mask="window"`` with ``window`` = W: causal attention
     in which a query sees the last W keys up to its own position.  A mask
-    takes the place of ``causal`` (``_visible`` has the predicates)."""
+    takes the place of ``causal`` (``_visible`` has the predicates).
+
+    ``segment_ids`` (B, Lk) integers, with ``causal=True`` or
+    ``mask="window"``: documents packed into one row, a document a run of
+    equal ids; a query sees the keys of its own document alone (the queries
+    are the last Lq keys).  The ids are an operand: rows whose boundaries
+    move from batch to batch run one program, which walks the tiles the
+    mask alone would and hides the pairs of two documents in them."""
     import jax.numpy as jnp
 
     mask = _mask_key(mask, mask_block, causal, window)
@@ -893,9 +1118,13 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, mask=None,
         rep = hq // hkv
         k = jnp.repeat(k, rep, axis=1)
         v = jnp.repeat(v, rep, axis=1)
-    fn = _make_flash(bool(causal), float(sm_scale), mask,
-                     getattr(_SCOPE, "value", None))
-    return fn(q, k, v)
+    how = _Mask(mask, None if segment_ids is None
+                else jnp.asarray(segment_ids).astype(jnp.int32))
+    how.check(causal, q.shape[0], k.shape[2])
+    if how.seg is not None:
+        _count_pairs(q, k, bool(causal), how)
+    return _make_flash(bool(causal), float(sm_scale), mask,
+                       getattr(_SCOPE, "value", None))(q, k, v, *how.operands)
 
 
 # registry entry --------------------------------------------------------------
@@ -903,9 +1132,12 @@ from .registry import register
 
 
 @register("_contrib_flash_attention", aliases=("flash_attention",))
-def flash_attention_op(q, k, v, causal=False, sm_scale=None, mask=None,
-                       mask_block=0, window=0):
+def flash_attention_op(q, k, v, segment_ids=None, causal=False, sm_scale=None,
+                       mask=None, mask_block=0, window=0):
     """Fused scaled-dot-product attention (net-new vs reference; the TPU
-    answer to contrib/transformer.cc's unfused attention path)."""
+    answer to contrib/transformer.cc's unfused attention path).
+    ``segment_ids``, a fourth array, confines causal and window attention
+    to the documents of a packed row (``flash_attention``)."""
     return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                           mask=mask, mask_block=mask_block, window=window)
+                           mask=mask, mask_block=mask_block, window=window,
+                           segment_ids=segment_ids)

@@ -411,13 +411,16 @@ class TrainStep:
                 # step beside the loss (an expert layer's routed pairs)
                 with amp_scope(), attention_scope(), \
                         _telemetry.collect_step_scalars() as scalars:
+                    # a batch's x of several arrays (ids and segment ids) is
+                    # the net's inputs in order
+                    xs = x if isinstance(x, tuple) else (x,)
                     if pipeline_cfg is not None:
                         out = pipelined_forward(p, rng, x)
                         state = {}
                     elif with_state:
-                        out, state = apply_fn(p, rng, x)
+                        out, state = apply_fn(p, rng, *xs)
                     else:
-                        out = apply_fn(p, rng, x)
+                        out = apply_fn(p, rng, *xs)
                         state = {}
                 if dtype is not None:
                     out = jax.tree_util.tree_map(
@@ -493,7 +496,11 @@ class TrainStep:
         the shared staging decision tree (``prefetcher.stage_leaf``): an
         array the prefetcher already put with the right sharding passes
         through untouched — the overlap path must add zero work here (and
-        must NOT round-trip device arrays through numpy)."""
+        must NOT round-trip device arrays through numpy).  A tuple or list
+        of arrays (a net of several inputs) is staged leaf by leaf and
+        comes back a tuple."""
+        if isinstance(v, (tuple, list)):
+            return tuple(self._stage_batch(leaf) for leaf in v)
         v = getattr(v, "_get", lambda: v)()
         if self._batch_shard is None:
             return v
@@ -533,22 +540,19 @@ class TrainStep:
             jax.tree_util.tree_map(aval, self.train_params),
             jax.tree_util.tree_map(aval, self.rest_params),
             jax.tree_util.tree_map(aval, self.opt_state),
-            aval(rng),
-            jax.ShapeDtypeStruct(tuple(x.shape), x.dtype,
-                                 sharding=getattr(x, "sharding", None)),
-            jax.ShapeDtypeStruct(tuple(y.shape), y.dtype,
-                                 sharding=getattr(y, "sharding",
-                                                  None))))
+            aval(rng), jax.tree_util.tree_map(aval, x), aval(y)))
 
     def _cc_lookup(self, sig, rng, x, y):
         """Resolve the cached executable for one batch signature (once
         per sig): a hit replaces self._step for that sig; a miss
         schedules an export right after the first (tracing) call."""
+        import jax
         import jax.numpy as jnp
 
         from .. import compile_cache as _ccache
 
-        x = x if hasattr(x, "shape") else jnp.asarray(x)
+        x = jax.tree_util.tree_map(
+            lambda v: v if hasattr(v, "shape") else jnp.asarray(v), x)
         y = y if hasattr(y, "shape") else jnp.asarray(y)
         avals = self._cc_avals(rng, x, y)
         key = self._cc.key(
@@ -564,8 +568,14 @@ class TrainStep:
         return fn
 
     def __call__(self, x, y):
+        """One step on the batch ``(x, y)``.  ``x`` is the net's input, or a
+        tuple or list of its inputs in order (token ids and segment ids):
+        each is staged and is part of the signature a step compiles for."""
         from jax import random as jr
 
+        if self._pipeline is not None and isinstance(x, (tuple, list)):
+            raise MXNetError("a pipelined step streams one array through its "
+                             "stages; x is a tuple of several")
         with _telemetry.phase(PHASE_PREPARE):
             x = self._stage_batch(x)
             y = self._stage_batch(y)
@@ -577,10 +587,11 @@ class TrainStep:
             # variable-shape workload must not leak memory proportional to
             # distinct sigs (past the cap fresh compiles simply go
             # unrecorded)
-            sig = (tuple(getattr(x, "shape", ())),
-                   str(getattr(x, "dtype", "")),
-                   tuple(getattr(y, "shape", ())),
-                   str(getattr(y, "dtype", "")))
+            # over every leaf: one array's signature is what it always was
+            sig = tuple(part for v in (x if isinstance(x, tuple) else (x,))
+                        + (y,)
+                        for part in (tuple(getattr(v, "shape", ())),
+                                     str(getattr(v, "dtype", ""))))
             step_fn = self._step
             flops = None
             if self._cc is not None:

@@ -39,6 +39,13 @@ SCOPE_FORWARD = "mx_forward"
 SCOPE_OPTIMIZER = "mx_optimizer"
 SCOPE_ATTENTION_BWD = "mxnet_flash_attention_bwd"
 SCOPE_ATTENTION_PLAIN_FWD = "mxnet_attention_plain_fwd"
+# the Pallas attention kernels' own names (ops/flash_attention.py): the
+# forward's, then "_window" under the window, then "_segments" under segment
+# ids (mxnet_flash_attention_fwd_window_segments); the backward kernel's
+# is the backward's scope with the same suffixes
+KERNEL_ATTENTION_FWD = "mxnet_flash_attention_fwd"
+KERNEL_SUFFIX_WINDOW = "_window"
+KERNEL_SUFFIX_SEGMENTS = "_segments"
 # the expert layer (parallel/expert_parallel.py, dropless routing): router,
 # top-k, sort, gather and scatter; and the grouped products over the
 # experts held.  Backward ops carry transpose(jvp(<scope>))

@@ -429,6 +429,20 @@ MOE_LOAD_MAX_OVER_MEAN = histogram(
     buckets=exponential_buckets(1.0, 1.1, 30))
 
 
+ATTENTION_VISIBLE_PAIRS = counter(
+    "mxnet_attention_visible_pairs_total",
+    "(query, key) pairs that the mask and the segment ids show, over the "
+    "flash_attention calls under segment ids of this process's fused steps, "
+    "a sample counted once whatever its heads; computed on the device from "
+    "the ids (step scalar)")
+ATTENTION_WALKED_PAIRS = counter(
+    "mxnet_attention_walked_pairs_total",
+    "(query, key) pairs of the tiles those calls' forwards walk, from their "
+    "shapes alone (step scalar): mxnet_attention_visible_pairs_total over "
+    "it is the share of the walked pairs that counts, and 1 minus it what "
+    "skipping tiles from the ids could save at most")
+
+
 def goodput_note(bucket, seconds):
     """Charge ``seconds`` of wall time to a goodput ``bucket``
     (``checkpoint`` / ``restart`` / ``reshard`` / ``stall`` /

@@ -182,6 +182,64 @@ def test_window_flash_backward_kernel_compiles_for_v5e(one_chip, shape, dim,
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+# under segment ids: the packed cell's own calls (1 sample of 16,384 rows of
+# 13 documents, 32 heads of 128; causal on the full layers, a window of
+# 1,024 on the others), then lq < lk, two samples to a grid and float32
+SEGMENT_SHAPES = [((1, 32, 16384, 16384), 128, "bfloat16", 0),
+                  ((1, 32, 16384, 16384), 128, "bfloat16", 1024),
+                  ((2, 4, 256, 1024), 128, "bfloat16", 300),
+                  ((2, 4, 512, 512), 64, "float32", 0)]
+
+
+def _segment_call(one_chip, path, shape, dim, dtype, window):
+    from mxnet_tpu.ops.flash_attention import WINDOW
+
+    b, h, lq, lk = shape
+    fn = functools.partial(path, causal=not window, sm_scale=dim ** -0.5)
+    key = (WINDOW, window) if window else None
+    q = jax.ShapeDtypeStruct((b, h, lq, dim), dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, h, lk, dim), dtype, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((b, lk), "int32", sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((b, h, lq), "float32", sharding=one_chip)
+    return fn, key, q, kv, seg, lse
+
+
+@pytest.mark.parametrize("shape,dim,dtype,window", SEGMENT_SHAPES)
+def test_segment_flash_forward_compiles_for_v5e(one_chip, shape, dim, dtype,
+                                                window):
+    """The forward with segment ids as blocked operands beside q and k (a
+    column of the q block's, the row's whole along the lanes), causal and
+    under the window, each under its own name."""
+    from mxnet_tpu.ops.flash_attention import _Mask, _fa_forward_pallas
+
+    fn, key, q, kv, seg, _ = _segment_call(one_chip, _fa_forward_pallas, shape,
+                                      dim, dtype, window)
+    text = jax.jit(lambda q, k, v, seg: fn(q, k, v, mask=_Mask(key, seg))
+                   ).lower(q, kv, kv, seg).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert ("mxnet_flash_attention_fwd_window_segments" if window
+            else "mxnet_flash_attention_fwd_segments") in text
+
+
+@pytest.mark.parametrize("shape,dim,dtype,window", SEGMENT_SHAPES[:3])
+def test_segment_flash_backward_kernel_compiles_for_v5e(one_chip, shape, dim,
+                                                        dtype, window):
+    """The backward kernel with the ids of the pair's tiles (the q tile's a
+    row, the K tile's a column), every pair walked masked."""
+    from mxnet_tpu.ops.flash_attention import _Mask, _fa_backward_pallas
+
+    fn, key, q, kv, seg, lse = _segment_call(one_chip, _fa_backward_pallas, shape,
+                                        dim, dtype, window)
+    compiled = jax.jit(lambda q, k, v, o, lse, g, seg: fn(
+        q, k, v, o, lse, g, mask=_Mask(key, seg))).lower(
+            q, kv, kv, q, lse, q, seg).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("mxnet_flash_attention_bwd_window_segments" if window
+            else "mxnet_flash_attention_bwd_segments") in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 def _gathered_and_scattered(text):
     """From a compiled program's text: the shapes of what its gathers
     produce and of what its scatters are given to put."""
