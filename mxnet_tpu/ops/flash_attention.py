@@ -8,7 +8,9 @@ score matrix out of HBM, fed to the MXU in the input's dtype with tiles
 chosen from the call's shape (`_fa_block_sizes`), and a backward kernel that
 recomputes the probabilities a tile at a time in VMEM from the saved
 log-sum-exp (`_fa_bwd_kernel`: the live tile pairs alone, five products a
-pair on operands in the input's dtype, float32 accumulation).  Where the
+pair on operands in the input's dtype, float32 accumulation).  Under
+segment ids both kernels walk the tiles the batch's documents show, by a
+table built on the device from the ids (`_segment_tiles`).  Where the
 kernels do not apply (no TPU, rows under 256, a length that does not divide
 into tiles) the forward is plain jax and the backward a blockwise lax.scan
 over the same tiles on the same operands (`_fa_backward_blockwise`, O(L)
@@ -166,6 +168,57 @@ def _live_tiles(causal, mask, lq, lk, block_q, block_k):
     """numpy bool ``(lq / block_q, lk / block_k)``: tiles in which some pair
     is visible."""
     return _tile_visibility(causal, mask, lq, lk, block_q, block_k)[0]
+
+
+def _sample_of(bh, heads):
+    """The sample of row ``bh`` of the flattened (batch x heads): a
+    truncating divide (``bh`` is never negative), one instruction where
+    ``//`` lowers to a floor divide's compares and selects, which Mosaic
+    lowers anew in every block spec that holds one (PERF.md section 6,
+    PR 33)."""
+    import jax
+
+    return jax.lax.div(bh, heads)
+
+
+def _segment_tiles(seg, causal, mask, lq, lk, block_q, block_k):
+    """The live tiles of a call under segment ids, from that batch's ids on
+    the device: bool ``(batch, lq / block_q, lk / block_k)``.  A tile is
+    live iff the static mask shows some pair in it (``_live_tiles``) and the
+    id ranges of its queries and of its keys meet.  Where the ids are runs
+    in rising order that is exactly "some pair of one document is seen";
+    for any other ids (runs out of order, a document in two places) it
+    holds more, never less: no tile with a visible pair is dropped, and
+    ``_visible`` alone decides a pair.  ``seg`` is the ``(batch, lk)``
+    operand, so one program serves every batch."""
+    import jax.numpy as jnp
+
+    b = seg.shape[0]
+
+    def ends(ids, block):
+        tiles = ids.reshape(b, -1, block)
+        return tiles.min(axis=-1), tiles.max(axis=-1)
+
+    (qmin, qmax), (kmin, kmax) = ends(seg[:, lk - lq:], block_q), ends(seg,
+                                                                      block_k)
+    meet = (jnp.maximum(qmin[:, :, None], kmin[:, None, :])
+            <= jnp.minimum(qmax[:, :, None], kmax[:, None, :]))
+    return meet & _live_tiles(causal, mask, lq, lk, block_q, block_k)
+
+
+def _fa_fwd_bounds(live):
+    """int32 ``(2, batch * q tiles)``: the first live K tile of every q
+    tile's row of ``live`` (``_segment_tiles``) and one past the last, a
+    sample after the other: what the forward kernel under ids walks.  The
+    hull holds every live tile (and, where the ids are not rising runs, dead
+    ones between them); a row without a live tile walks none."""
+    import jax.numpy as jnp
+
+    nk = live.shape[2]
+    lo = jnp.argmax(live, axis=2)
+    hi = jnp.where(live.any(axis=2), nk - jnp.argmax(live[:, :, ::-1], axis=2),
+                   lo)
+    return jnp.stack([lo, hi]).astype(jnp.int32).reshape(2, -1)
 
 
 class _Mask:
@@ -359,12 +412,20 @@ def _kernel_name(base, mask):
 
 def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
                    sm_scale, seq_k, diag_offset=0, mask=None, qseg_ref=None,
-                   kseg_ref=None):
+                   kseg_ref=None, bounds_ref=None, heads=None):
     """One q block against its head's whole K/V row.  Under segment ids
     (``_fa_fwd_kernel_segments``) ``qseg_ref`` (block_q, 1) holds the q
     block's ids as a column and ``kseg_ref`` (8, seq_k) the row's along the
     lanes (8 sublanes alike): every tile walked is then masked by
-    ``_visible`` with them; which tiles are walked does not change.
+    ``_visible`` with them.  Which K tiles are walked comes from that
+    batch's ids too: ``bounds_ref`` (2, samples x q blocks) in SMEM
+    (``_fa_fwd_bounds``) holds the first live K tile of the q block's row of
+    the table (``_segment_tiles``) and one past the last, in place of the
+    causal bound and the window's range; a sample's bounds serve its
+    ``heads`` heads.  A tile left out held no visible pair, and such a tile
+    adds exact zeros (walked before the row's first visible key, the
+    rescaling wipes it; after it, ``exp`` gives 0): the results are those of
+    walking every tile the mask alone shows, bit for bit.
 
     Grid: (batch*heads, num_q_blocks).  Block shapes:
       q_ref (block_q, d) VMEM; k_ref/v_ref (seq_k, d) VMEM (whole K/V row
@@ -450,7 +511,12 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
         carry = (jnp.full((block_q, 1), NEG_INF, dtype=jnp.float32),
                  jnp.zeros((block_q, 1), dtype=jnp.float32),
                  jnp.zeros((block_q, d), dtype=jnp.float32))
-        if causal:
+        if segmented:
+            # the live K tiles of this sample's ids (a superset: their hull)
+            at = _sample_of(pl.program_id(0), heads) * pl.num_programs(1) + qi
+            carry = jax.lax.fori_loop(bounds_ref[0, at], bounds_ref[1, at],
+                                      body, carry)
+        elif causal:
             # skip fully-masked K blocks beyond this q block (offset-aware)
             max_kb = jnp.minimum(
                 ((qi + 1) * block_q + diag_offset + block_k - 1) // block_k,
@@ -481,12 +547,13 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
     lse_ref[:] = jnp.broadcast_to(lse.reshape(1, block_q), lse_ref.shape)
 
 
-def _fa_fwd_kernel_segments(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref,
-                            lse_ref, **static):
-    """``_fa_fwd_kernel`` of a call under segment ids: its two refs of ids
-    come after the operands, as ``_fa_forward_pallas`` lists them."""
+def _fa_fwd_kernel_segments(bounds_ref, q_ref, k_ref, v_ref, qseg_ref,
+                            kseg_ref, o_ref, lse_ref, **static):
+    """``_fa_fwd_kernel`` of a call under segment ids: the bounds prefetched
+    to SMEM come first and its two refs of ids after the operands, as
+    ``_fa_forward_pallas`` lists them."""
     _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qseg_ref=qseg_ref,
-                   kseg_ref=kseg_ref, **static)
+                   kseg_ref=kseg_ref, bounds_ref=bounds_ref, **static)
 
 
 def _largest_tile(length):
@@ -561,37 +628,47 @@ def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q=None, block_k=None,
     kf = k.reshape(b * h, lk, d)
     vf = v.reshape(b * h, lk, d)
 
-    kernel = functools.partial(
-        _fa_fwd_kernel if seg is None else _fa_fwd_kernel_segments,
-        block_k=block_k, causal=causal, sm_scale=sm_scale, seq_k=lk,
-        diag_offset=lk - lq, mask=mask.key)
+    static = dict(block_k=block_k, causal=causal, sm_scale=sm_scale, seq_k=lk,
+                  diag_offset=lk - lq, mask=mask.key)
+    # the index maps take the grid's indices and, under ids, the prefetched
+    # bounds after them
     in_specs = [
-        pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0)),
-        pl.BlockSpec((None, lk, d), lambda bh, qi: (bh, 0, 0)),
-        pl.BlockSpec((None, lk, d), lambda bh, qi: (bh, 0, 0)),
+        pl.BlockSpec((None, block_q, d), lambda bh, qi, *_: (bh, qi, 0)),
+        pl.BlockSpec((None, lk, d), lambda bh, qi, *_: (bh, 0, 0)),
+        pl.BlockSpec((None, lk, d), lambda bh, qi, *_: (bh, 0, 0)),
+    ]
+    out_specs = [
+        pl.BlockSpec((None, block_q, d), lambda bh, qi, *_: (bh, qi, 0)),
+        pl.BlockSpec((None, 8, block_q), lambda bh, qi, *_: (bh, 0, qi)),
     ]
     operands = [qf, kf, vf]
     limit = _fa_fwd_vmem_limit(lk, d, q.dtype.itemsize, block_q,
                                seg is not None)
     params = {} if limit is None else {
         "compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)}
-    if seg is not None:
+    if seg is None:
+        kernel = functools.partial(_fa_fwd_kernel, **static)
+        params.update(grid=grid, in_specs=in_specs, out_specs=out_specs)
+    else:
         # a sample's ids serve its h heads: the queries' as a column a q
-        # block, the keys' whole along the lanes
+        # block, the keys' whole along the lanes, and the K tiles its q
+        # blocks walk, from the table of its live tiles
+        kernel = functools.partial(_fa_fwd_kernel_segments, heads=h, **static)
         in_specs += [
-            pl.BlockSpec((None, block_q, 1), lambda bh, qi: (bh // h, qi, 0)),
-            pl.BlockSpec((None, 8, lk), lambda bh, qi: (bh // h, 0, 0)),
+            pl.BlockSpec((None, block_q, 1),
+                         lambda bh, qi, *_: (_sample_of(bh, h), qi, 0)),
+            pl.BlockSpec((None, 8, lk),
+                         lambda bh, qi, *_: (_sample_of(bh, h), 0, 0)),
         ]
-        operands += [seg[:, lk - lq:, None],
-                     jnp.broadcast_to(seg[:, None, :], (b, 8, lk))]
+        live = _segment_tiles(seg, causal, mask.key, lq, lk, block_q, block_k)
+        operands = [_fa_fwd_bounds(live)] + operands + [
+            seg[:, lk - lq:, None],
+            jnp.broadcast_to(seg[:, None, :], (b, 8, lk))]
+        params.update(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs))
     o, lse = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((None, 8, block_q), lambda bh, qi: (bh, 0, qi)),
-        ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, lq, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, 8, lq), jnp.float32),
@@ -630,8 +707,10 @@ def _fa_forward(q, k, v, causal, sm_scale, mask=None, sharded=None):
 # a.T @ b as one dot_general contracting both first dims
 _TN_DIMS = (((0,), (0,)), ((), ()))
 
-# flags of a row of the backward's table of tile pairs
-_FIRST_OF_K, _LAST_OF_K, _PARTLY_SEEN = 1, 2, 4
+# flags of a row of the backward's table of tile pairs; under segment ids a
+# dead row (past a sample's live pairs) carries that flag alone, and
+# ``_PARTLY_SEEN`` is not read (every pair walked is compared there)
+_FIRST_OF_K, _LAST_OF_K, _PARTLY_SEEN, _DEAD = 1, 2, 4, 8
 
 
 def _fa_bwd_block_sizes(lq, lk):
@@ -675,19 +754,66 @@ def _fa_bwd_pairs(causal, mask, lq, lk, block_q, block_k):
     return _np.stack([pairs[:, 0], pairs[:, 1], flags]).astype(_np.int32)
 
 
+def _fa_bwd_pairs_under_ids(pairs, live):
+    """The table of a call under segment ids and the grid steps it takes:
+    int32 ``(3, batch x rows)``, a sample's ``rows = pairs.shape[1]`` after
+    the other, and the longest sample's count of live pairs.  Of the static
+    table's ``pairs`` (``_fa_bwd_pairs``) those whose tile is ``live`` for
+    the sample (``_segment_tiles`` at the backward's tiles), packed to the
+    front in the static order, K tile by K tile, with the first and last
+    flags of what is left.  A K tile none of whose pairs is live keeps its
+    first, which the ids then hide wholly, so that every tile of ``dk`` and
+    ``dv`` is written (as zeros).  The rows past a sample's live pairs are
+    ``_DEAD`` and repeat the last live pair's tiles: those the grid still
+    reaches (a batch's shorter samples) copy nothing and compute nothing.
+    A few XLA ops on arrays of ``rows`` (one compare of rows x rows a sample
+    packs them: no sort, no scatter)."""
+    import jax.numpy as jnp
+
+    qi, ki, flags = pairs
+    rows = pairs.shape[1]
+    keep = live[:, qi, ki] | (((flags & _FIRST_OF_K) != 0)
+                              & ~live.any(axis=1)[:, ki])
+    count = keep.sum(axis=1, keepdims=True)
+    t = jnp.arange(rows)[None, :]
+    # row t takes the static row of the (t + 1)-th pair kept: as many rows
+    # lie before it as have kept fewer than that
+    nth = jnp.minimum(t, count - 1) + 1
+    src = (jnp.cumsum(keep, axis=1)[:, None, :] < nth[:, :, None]).sum(axis=-1)
+    q_t, k_t = jnp.asarray(qi)[src], jnp.asarray(ki)[src]
+    turn = k_t[:, 1:] != k_t[:, :-1]
+    edge = jnp.ones((keep.shape[0], 1), bool)
+    flags = jnp.where(
+        t < count,
+        _FIRST_OF_K * jnp.concatenate([edge, turn], axis=1)
+        + _LAST_OF_K * (jnp.concatenate([turn, edge], axis=1)
+                        | (t == count - 1)), _DEAD)
+    table = jnp.stack([q_t, k_t, flags]).astype(jnp.int32).reshape(3, -1)
+    return table, jnp.max(count).astype(jnp.int32)
+
+
 def _fa_bwd_kernel(pairs_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, causal,
-                   sm_scale, seq_q, seq_k, mask, qseg_ref=None,
+                   sm_scale, seq_q, seq_k, mask, table_row, qseg_ref=None,
                    kseg_ref=None):
     """One live tile pair: its five products, nothing recomputed.  Under
     segment ids (``_fa_bwd_kernel_segments``) ``qseg_ref`` (1, block_q)
     holds the q tile's ids as a row and ``kseg_ref`` (block_k, 1) the K
-    tile's as a column: every pair walked is then masked (a tile the mask
-    shows whole may still hold two documents); the table does not change.
+    tile's as a column, and the table is the sample's own, built on the
+    device from its ids (``_fa_bwd_pairs_under_ids``; ``table_row`` gives
+    the table's row of a grid step): the pairs whose tiles hold a pair of
+    one document, every one masked by ``_visible`` with the ids (a tile the
+    mask shows whole may still hold two documents, and a ``cond`` around the
+    compare costs more than the compare: PERF.md section 6, PR 33), then
+    ``_DEAD`` rows, whose steps do nothing.  A pair left out would have
+    added exact zeros to ``dq``, ``dk`` and ``dv``: the gradients are those
+    of walking every pair the mask alone shows, bit for bit.
 
     Grid: (batch*heads, live tile pairs), the pairs K tile by K tile
     (``_fa_bwd_pairs``, prefetched to SMEM; the block index maps read it, so
-    a dead tile costs no step and no copy).  Blocks: q_ref / g_ref
+    a dead tile costs no step and no copy); under ids the second length is
+    the batch's longest table, a number the device computes (a dynamic grid
+    bound).  Blocks: q_ref / g_ref
     (block_q, d) and lse_ref / delta_ref (1, block_q) of the pair's q tile;
     k_ref / v_ref and dk_ref / dv_ref (block_k, d) of its K tile; dq_ref the
     head's whole (seq_q, d) row.  Scratch, float32: dq_acc (q tiles, d,
@@ -709,51 +835,58 @@ def _fa_bwd_kernel(pairs_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     block_q, d = q_ref.shape
     block_k = k_ref.shape[0]
     t = pl.program_id(1)
-    qi, ki, flags = pairs_ref[0, t], pairs_ref[1, t], pairs_ref[2, t]
     ids = None if qseg_ref is None else (qseg_ref[...], kseg_ref[...])
+    row = t if ids is None else table_row(pl.program_id(0), t)
+    qi, ki, flags = pairs_ref[0, row], pairs_ref[1, row], pairs_ref[2, row]
 
     @pl.when(t == 0)
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    @pl.when((flags & _FIRST_OF_K) != 0)
-    def _():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
+    def pair():
+        @pl.when((flags & _FIRST_OF_K) != 0)
+        def _():
+            dk_acc[...] = jnp.zeros_like(dk_acc)
+            dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q, k, v, g = q_ref[...], k_ref[...], v_ref[...], g_ref[...]
-    dot = functools.partial(jax.lax.dot_general,
-                            precision=_operand_precision(q.dtype),
-                            preferred_element_type=jnp.float32)
-    # a power of two scales q exactly, as in the forward
-    scale_q = _np.frexp(sm_scale)[0] == 0.5
-    if scale_q:
-        s = dot(k, (q.astype(jnp.float32) * sm_scale).astype(q.dtype),
-                _NT_DIMS)
+        q, k, v, g = q_ref[...], k_ref[...], v_ref[...], g_ref[...]
+        dot = functools.partial(jax.lax.dot_general,
+                                precision=_operand_precision(q.dtype),
+                                preferred_element_type=jnp.float32)
+        # a power of two scales q exactly, as in the forward
+        scale_q = _np.frexp(sm_scale)[0] == 0.5
+        if scale_q:
+            s = dot(k, (q.astype(jnp.float32) * sm_scale).astype(q.dtype),
+                    _NT_DIMS)
+        else:
+            s = dot(k, q, _NT_DIMS) * sm_scale
+        if causal or mask is not None:
+            def hide(s):
+                q_pos = qi * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, block_q), 1)
+                k_pos = ki * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_k, 1), 0)
+                return jnp.where(_visible(jnp, q_pos, k_pos, causal, mask,
+                                          seq_q, seq_k, ids), s, NEG_INF)
+
+            s = hide(s) if ids is not None else jax.lax.cond(
+                (flags & _PARTLY_SEEN) != 0, hide, lambda s: s, s)
+        p = jnp.exp(s - lse_ref[...])
+        dv_acc[...] += dot(p.astype(g.dtype), g, _NN_DIMS)
+        dp = dot(v, g, _NT_DIMS)
+        ds = (p * (dp - delta_ref[...])).astype(q.dtype)
+        dk_acc[...] += dot(ds, q, _NN_DIMS)
+        dq_acc[qi] += dot(k, ds, _TN_DIMS)
+
+        @pl.when((flags & _LAST_OF_K) != 0)
+        def _():
+            dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+            dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+    if ids is None:
+        pair()
     else:
-        s = dot(k, q, _NT_DIMS) * sm_scale
-    if causal or mask is not None:
-        def hide(s):
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_q), 1)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, 1), 0)
-            return jnp.where(_visible(jnp, q_pos, k_pos, causal, mask, seq_q,
-                                      seq_k, ids), s, NEG_INF)
-
-        s = hide(s) if ids is not None else jax.lax.cond(
-            (flags & _PARTLY_SEEN) != 0, hide, lambda s: s, s)
-    p = jnp.exp(s - lse_ref[...])
-    dv_acc[...] += dot(p.astype(g.dtype), g, _NN_DIMS)
-    dp = dot(v, g, _NT_DIMS)
-    ds = (p * (dp - delta_ref[...])).astype(q.dtype)
-    dk_acc[...] += dot(ds, q, _NN_DIMS)
-    dq_acc[qi] += dot(k, ds, _TN_DIMS)
-
-    @pl.when((flags & _LAST_OF_K) != 0)
-    def _():
-        dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
-        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+        pl.when((flags & _DEAD) == 0)(pair)
 
     @pl.when(t == pl.num_programs(1) - 1)
     def _():
@@ -777,8 +910,10 @@ def _fa_bwd_kernel_segments(pairs_ref, q_ref, k_ref, v_ref, g_ref, lse_ref,
 
 def _fa_backward_pallas(q, k, v, o, lse, g, causal, sm_scale, mask=None):
     """Gradients of q, k and v from one Pallas call over the live tile
-    pairs (``_fa_bwd_kernel``); ``delta = rowsum(o * g)`` is the one
-    reduction left to XLA.  No score-shaped array reaches HBM."""
+    pairs (``_fa_bwd_kernel``): those of the static table, or under segment
+    ids those of the table built here from the ids; ``delta = rowsum(o *
+    g)`` is the one reduction left to XLA.  No score-shaped array reaches
+    HBM."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -797,36 +932,46 @@ def _fa_backward_pallas(q, k, v, o, lse, g, causal, sm_scale, mask=None):
         rows = lambda x: x.astype(jnp.float32).reshape(b * h, nq, 1, block_q)
         flat = lambda x: x.reshape(b * h, x.shape[2], d)
 
-        q_tile = pl.BlockSpec((None, block_q, d),
-                              lambda bh, t, pairs: (bh, pairs[0, t], 0))
-        k_tile = pl.BlockSpec((None, block_k, d),
-                              lambda bh, t, pairs: (bh, pairs[1, t], 0))
-        q_row = pl.BlockSpec((None, None, 1, block_q),
-                             lambda bh, t, pairs: (bh, pairs[0, t], 0, 0))
+        # the table's row of grid step (bh, t): row t, or under ids row t
+        # of the sample's own table
+        n_rows = pairs.shape[1]
+        at = (lambda bh, t: t) if seg is None else (
+            lambda bh, t: _sample_of(bh, h) * n_rows + t)
+        q_tile = pl.BlockSpec((None, block_q, d), lambda bh, t, pairs:
+                              (bh, pairs[0, at(bh, t)], 0))
+        k_tile = pl.BlockSpec((None, block_k, d), lambda bh, t, pairs:
+                              (bh, pairs[1, at(bh, t)], 0))
+        q_row = pl.BlockSpec((None, None, 1, block_q), lambda bh, t, pairs:
+                             (bh, pairs[0, at(bh, t)], 0, 0))
         need = _fa_bwd_vmem_bytes(lq, d, q.dtype.itemsize, block_q, block_k)
-        kernel = functools.partial(
-            _fa_bwd_kernel if seg is None else _fa_bwd_kernel_segments,
-            causal=causal, sm_scale=sm_scale, seq_q=lq, seq_k=lk,
-            mask=mask.key)
+        static = dict(causal=causal, sm_scale=sm_scale, seq_q=lq, seq_k=lk,
+                      mask=mask.key, table_row=at)
         in_specs = [q_tile, k_tile, k_tile, q_tile, q_row, q_row]
-        operands = [jnp.asarray(pairs), flat(q), flat(k), flat(v), flat(g),
-                    rows(lse), rows(delta)]
-        if seg is not None:
+        operands = [flat(q), flat(k), flat(v), flat(g), rows(lse),
+                    rows(delta)]
+        if seg is None:
+            kernel = functools.partial(_fa_bwd_kernel, **static)
+            table, steps = jnp.asarray(pairs), n_rows
+        else:
             # a sample's ids serve its h heads: the q tile's as a row (the
-            # scores are held transposed), the K tile's as a column
+            # scores are held transposed), the K tile's as a column, and
+            # the table of the pairs its documents show
+            kernel = functools.partial(_fa_bwd_kernel_segments, **static)
             in_specs += [
                 pl.BlockSpec((None, None, 1, block_q), lambda bh, t, pairs:
-                             (bh // h, pairs[0, t], 0, 0)),
+                             (_sample_of(bh, h), pairs[0, at(bh, t)], 0, 0)),
                 pl.BlockSpec((None, block_k, 1), lambda bh, t, pairs:
-                             (bh // h, pairs[1, t], 0)),
+                             (_sample_of(bh, h), pairs[1, at(bh, t)], 0)),
             ]
             operands += [seg[:, lk - lq:].reshape(b, nq, 1, block_q),
                          seg[:, :, None]]
+            table, steps = _fa_bwd_pairs_under_ids(pairs, _segment_tiles(
+                seg, causal, mask.key, lq, lk, block_q, block_k))
         dq, dk, dv = pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
-                grid=(b * h, pairs.shape[1]),
+                grid=(b * h, steps),
                 in_specs=in_specs,
                 out_specs=[pl.BlockSpec((None, lq, d),
                                         lambda bh, t, pairs: (bh, 0, 0)),
@@ -841,7 +986,7 @@ def _fa_backward_pallas(q, k, v, o, lse, g, causal, sm_scale, mask=None):
                 dimension_semantics=("parallel", "arbitrary"),
                 vmem_limit_bytes=max(need, _VMEM_DEFAULT_LIMIT)),
             name=_kernel_name(SCOPE_ATTENTION_BWD, mask),
-        )(*operands)
+        )(table, *operands)
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
 
@@ -1066,7 +1211,9 @@ def _count_pairs(q, k, causal, mask):
     show, computed on the device from the ids (a query at position ``p`` of
     its document sees ``p + 1`` keys, or the window's ``W`` if that is
     fewer; a document a run of equal ids), and the pairs of the tiles the
-    forward walks, from the shape alone (every pair on the plain path)."""
+    forward walks: on the kernel's path the K tiles of every q tile's
+    bounds (``_fa_fwd_bounds``), summed on the device from the same ids; on
+    the plain path every pair of the square."""
     import jax.numpy as jnp
 
     from .. import telemetry
@@ -1076,16 +1223,16 @@ def _count_pairs(q, k, causal, mask):
     seen = segment_positions(mask.seg)[:, lk - lq:] + 1
     if mask.key is not None:
         seen = jnp.minimum(seen, mask.key[1])
-    walked = lq * lk
+    walked = jnp.float32(b * lq * lk)
     if _use_pallas(q):
         block_q, block_k = _fa_block_sizes(lq, lk, q.shape[3],
                                            q.dtype.itemsize)
-        walked = block_q * block_k * int(_live_tiles(
-            causal, mask.key, lq, lk, block_q, block_k).sum())
+        lo, hi = _fa_fwd_bounds(_segment_tiles(
+            mask.seg, causal, mask.key, lq, lk, block_q, block_k))
+        walked = jnp.sum(hi - lo).astype(jnp.float32) * (block_q * block_k)
     telemetry.step_scalar(telemetry.ATTENTION_VISIBLE_PAIRS.name,
                           jnp.sum(seen.astype(jnp.float32)))
-    telemetry.step_scalar(telemetry.ATTENTION_WALKED_PAIRS.name,
-                          jnp.float32(b * walked))
+    telemetry.step_scalar(telemetry.ATTENTION_WALKED_PAIRS.name, walked)
 
 
 def flash_attention(q, k, v, causal=False, sm_scale=None, mask=None,
@@ -1102,8 +1249,14 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, mask=None,
     ``mask="window"``: documents packed into one row, a document a run of
     equal ids; a query sees the keys of its own document alone (the queries
     are the last Lq keys).  The ids are an operand: rows whose boundaries
-    move from batch to batch run one program, which walks the tiles the
-    mask alone would and hides the pairs of two documents in them."""
+    move from batch to batch run one program.  Of the tiles the mask alone
+    shows, the kernels walk those whose queries' and keys' id ranges meet,
+    by a table built on the device from each batch's ids
+    (``_segment_tiles``), and hide the pairs of two documents in them.
+    Where the ids are runs in rising order (what a packer writes) those are
+    exactly the tiles that hold a pair of one document; any other ids (runs
+    out of order, a document in two places) are computed rightly and skip
+    less."""
     import jax.numpy as jnp
 
     mask = _mask_key(mask, mask_block, causal, window)
