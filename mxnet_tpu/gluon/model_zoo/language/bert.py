@@ -9,6 +9,8 @@ from __future__ import annotations
 
 import math
 
+from ....profiler import (SCOPE_ATTENTION_PROJ, SCOPE_EMBED, SCOPE_FFN,
+                          SCOPE_HEAD, SCOPE_NORM)
 from ...block import HybridBlock
 from ... import nn
 
@@ -51,11 +53,18 @@ class BertSelfAttention(HybridBlock):
         def heads(t):
             return t.reshape((b, l, cfg.num_heads, hd)).transpose((0, 2, 1, 3))
 
-        q, k, v = heads(self.query(x)), heads(self.key(x)), heads(self.value(x))
+        import jax
+
+        # the parts are named at the call sites (profiler.py): the attention
+        # op between the projections names its own kernels and backward
+        with jax.named_scope(SCOPE_ATTENTION_PROJ):
+            q, k, v = (heads(self.query(x)), heads(self.key(x)),
+                       heads(self.value(x)))
         o = F.flash_attention(q, k, v, causal=False,
                               sm_scale=1.0 / math.sqrt(hd))
-        o = o.transpose((0, 2, 1, 3)).reshape((b, l, cfg.hidden_size))
-        return self.dropout(self.out(o))
+        with jax.named_scope(SCOPE_ATTENTION_PROJ):
+            o = o.transpose((0, 2, 1, 3)).reshape((b, l, cfg.hidden_size))
+            return self.dropout(self.out(o))
 
 
 class BertLayer(HybridBlock):
@@ -71,9 +80,16 @@ class BertLayer(HybridBlock):
         self.dropout = nn.Dropout(cfg.dropout)
 
     def hybrid_forward(self, F, x):
-        x = self.attn_norm(x + self.attention(x))
-        h = F.gelu(self.intermediate(x))
-        return self.out_norm(x + self.dropout(self.output(h)))
+        import jax
+
+        a = x + self.attention(x)
+        with jax.named_scope(SCOPE_NORM):
+            x = self.attn_norm(a)
+        with jax.named_scope(SCOPE_FFN):
+            h = self.dropout(self.output(F.gelu(self.intermediate(x))))
+        h = x + h
+        with jax.named_scope(SCOPE_NORM):
+            return self.out_norm(h)
 
 
 class BertModel(HybridBlock):
@@ -94,17 +110,22 @@ class BertModel(HybridBlock):
                                flatten=False, in_units=cfg.hidden_size)
 
     def hybrid_forward(self, F, input_ids, token_types=None):
+        import jax
+
         b, l = input_ids.shape[0], input_ids.shape[1]
-        pos = F.arange(0, l, dtype="int32")
-        h = self.word_embed(input_ids)
-        positions = self.position_embed(pos)
-        h = h + positions.reshape((1, l, -1))
-        if token_types is not None:
-            h = h + self.token_type_embed(token_types)
-        h = self.embed_dropout(self.embed_norm(h))
-        h = self.encoder(h)
-        pooled = self.pooler(h.slice_axis(axis=1, begin=0, end=1)
-                             .reshape((b, -1)))
+        with jax.named_scope(SCOPE_EMBED):
+            pos = F.arange(0, l, dtype="int32")
+            h = self.word_embed(input_ids)
+            positions = self.position_embed(pos)
+            h = h + positions.reshape((1, l, -1))
+            if token_types is not None:
+                h = h + self.token_type_embed(token_types)
+        with jax.named_scope(SCOPE_NORM):
+            h = self.embed_norm(h)
+        h = self.encoder(self.embed_dropout(h))
+        with jax.named_scope(SCOPE_HEAD):
+            pooled = self.pooler(h.slice_axis(axis=1, begin=0, end=1)
+                                 .reshape((b, -1)))
         return h, pooled
 
 
@@ -124,9 +145,12 @@ class BertForPretraining(HybridBlock):
         self.nsp = nn.Dense(2, flatten=False, in_units=cfg.hidden_size)
 
     def hybrid_forward(self, F, input_ids, token_types=None):
+        import jax
+
         seq, pooled = self.bert(input_ids, token_types)
-        mlm = self.mlm_decoder(self.mlm_norm(F.gelu(self.mlm_dense(seq))))
-        return mlm, self.nsp(pooled)
+        with jax.named_scope(SCOPE_HEAD):   # its norm is the head's
+            mlm = self.mlm_decoder(self.mlm_norm(F.gelu(self.mlm_dense(seq))))
+            return mlm, self.nsp(pooled)
 
     def pipeline_decompose(self, n_stages, train_mode=True):
         """Split BertForPretraining for TrainStep(pipeline=...): embeddings

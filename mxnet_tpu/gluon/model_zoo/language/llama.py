@@ -14,7 +14,9 @@ import math
 import numpy as _np
 
 from ....base import MXNetError
-from ....profiler import SCOPE_MOE_SHARED
+from ....profiler import (SCOPE_ATTENTION_PROJ, SCOPE_EMBED, SCOPE_FFN,
+                          SCOPE_HEAD, SCOPE_MOE_SHARED, SCOPE_NORM,
+                          SCOPE_ROPE)
 from ...block import HybridBlock
 from ...parameter import Parameter
 from ... import nn
@@ -230,23 +232,30 @@ class LlamaAttention(HybridBlock):
         """``segment_ids`` (batch, L) with the ``positions`` that start
         again at each document (``LlamaModel`` derives them): RoPE turns by
         those, and a query sees the keys of its own document alone."""
+        import jax
+
         cfg = self._cfg
         b, l = x.shape[0], x.shape[1]
         hd = cfg.head_dim
-        q = self.q_proj(x).reshape((b, l, cfg.num_heads, hd)).transpose(
-            (0, 2, 1, 3))
-        k = self.k_proj(x).reshape((b, l, cfg.num_kv_heads, hd)).transpose(
-            (0, 2, 1, 3))
-        v = self.v_proj(x).reshape((b, l, cfg.num_kv_heads, hd)).transpose(
-            (0, 2, 1, 3))
+        # the parts are named here, one after the other (profiler.py): the
+        # attention op between them names its own kernels and backward
+        with jax.named_scope(SCOPE_ATTENTION_PROJ):
+            q = self.q_proj(x).reshape(
+                (b, l, cfg.num_heads, hd)).transpose((0, 2, 1, 3))
+            k = self.k_proj(x).reshape(
+                (b, l, cfg.num_kv_heads, hd)).transpose((0, 2, 1, 3))
+            v = self.v_proj(x).reshape(
+                (b, l, cfg.num_kv_heads, hd)).transpose((0, 2, 1, 3))
         if cfg.qk_norm:
-            q, k = self.q_norm(q), self.k_norm(k)
+            with jax.named_scope(SCOPE_NORM):
+                q, k = self.q_norm(q), self.k_norm(k)
         if cfg.block_diffusion:
-            # [xt ; x0]: both halves of the row carry positions 0..L-1
-            half = F.arange(0, l // 2, dtype="int32")
-            pos = F.concat(half, half, dim=0)
-            q = F.rope(q, pos, base=cfg.rope_base)
-            k = F.rope(k, pos, base=cfg.rope_base)
+            with jax.named_scope(SCOPE_ROPE):
+                # [xt ; x0]: both halves of the row carry positions 0..L-1
+                half = F.arange(0, l // 2, dtype="int32")
+                pos = F.concat(half, half, dim=0)
+                q = F.rope(q, pos, base=cfg.rope_base)
+                k = F.rope(k, pos, base=cfg.rope_base)
             o = F.flash_attention(q, k, v, segment_ids,
                                   mask="block_diffusion",
                                   mask_block=cfg.block_diffusion,
@@ -254,8 +263,9 @@ class LlamaAttention(HybridBlock):
         else:
             if self._kind in cfg.rope_attention_types:
                 turn = cfg.rope_kwargs(self._kind)
-                q = F.rope(q, positions, **turn)
-                k = F.rope(k, positions, **turn)
+                with jax.named_scope(SCOPE_ROPE):
+                    q = F.rope(q, positions, **turn)
+                    k = F.rope(k, positions, **turn)
             if self._kind == "window":
                 o = F.flash_attention(q, k, v, segment_ids, mask="window",
                                       window=cfg.attention_window,
@@ -263,10 +273,12 @@ class LlamaAttention(HybridBlock):
             else:
                 o = F.flash_attention(q, k, v, segment_ids, causal=True,
                                       sm_scale=1.0 / math.sqrt(hd))
-        o = o.transpose((0, 2, 1, 3)).reshape((b, l, cfg.num_heads * hd))
-        if cfg.attention_gate:
-            o = o * F.sigmoid(self.gate_proj(x))
-        return self.o_proj(o)
+        with jax.named_scope(SCOPE_ATTENTION_PROJ):
+            o = o.transpose((0, 2, 1, 3)).reshape(
+                (b, l, cfg.num_heads * hd))
+            if cfg.attention_gate:
+                o = o * F.sigmoid(self.gate_proj(x))
+            return self.o_proj(o)
 
 
 class LlamaMLP(HybridBlock):
@@ -380,10 +392,26 @@ class LlamaDecoderLayer(HybridBlock):
                     cfg.hidden_size, cfg.rms_eps, prefix="mlp_out_layernorm_")
 
     def _body(self, x, *packed):
-        a = self.self_attn(self.input_layernorm(x), *packed)
-        x = x + (self.attn_out_layernorm(a) if self._post_norms else a)
-        m = self.mlp(self.post_attention_layernorm(x))
-        return x + (self.mlp_out_layernorm(m) if self._post_norms else m)
+        import jax
+
+        with jax.named_scope(SCOPE_NORM):
+            h = self.input_layernorm(x)
+        a = self.self_attn(h, *packed)
+        if self._post_norms:
+            with jax.named_scope(SCOPE_NORM):
+                a = self.attn_out_layernorm(a)
+        x = x + a
+        with jax.named_scope(SCOPE_NORM):
+            h = self.post_attention_layernorm(x)
+        if isinstance(self.mlp, LlamaMLP):
+            with jax.named_scope(SCOPE_FFN):
+                m = self.mlp(h)
+        else:   # the expert layer names its own parts
+            m = self.mlp(h)
+        if self._post_norms:
+            with jax.named_scope(SCOPE_NORM):
+                m = self.mlp_out_layernorm(m)
+        return x + m
 
     def hybrid_forward(self, F, x, *packed):
         """``packed``: nothing, or the row's segment ids and positions,
@@ -450,16 +478,22 @@ class LlamaModel(HybridBlock):
         end to end, a document a run of equal ids.  Positions then start
         again at each document and every layer's attention is confined to
         the query's own (``F.flash_attention``'s ``segment_ids``)."""
-        h = self.embed_tokens(input_ids)
-        if self._cfg.embed_scale != 1.0:
-            h = h * self._cfg.embed_scale
+        import jax
+
+        with jax.named_scope(SCOPE_EMBED):
+            h = self.embed_tokens(input_ids)
+            if self._cfg.embed_scale != 1.0:
+                h = h * self._cfg.embed_scale
         # what a packed row brings to every layer: nothing, or its ids and
         # the positions that start again at each document
-        packed = () if segment_ids is None else (
-            segment_ids, F.segment_positions(segment_ids))
+        packed = ()
+        if segment_ids is not None:
+            with jax.named_scope(SCOPE_ROPE):
+                packed = (segment_ids, F.segment_positions(segment_ids))
         for layer in self.layers:
             h = layer(h, *packed)
-        return self.norm(h)
+        with jax.named_scope(SCOPE_NORM):
+            return self.norm(h)
 
 
 class LlamaForCausalLM(HybridBlock):
@@ -473,11 +507,14 @@ class LlamaForCausalLM(HybridBlock):
                                     prefix="lm_head_")
 
     def hybrid_forward(self, F, input_ids, segment_ids=None):
+        import jax
+
         h = self.model(input_ids, segment_ids)
-        if self._cfg.block_diffusion:
-            # rows are [xt ; x0]: logits over the noised half only
-            h = F.slice_axis(h, axis=1, begin=0, end=h.shape[1] // 2)
-        return self.lm_head(h)
+        with jax.named_scope(SCOPE_HEAD):
+            if self._cfg.block_diffusion:
+                # rows are [xt ; x0]: logits over the noised half only
+                h = F.slice_axis(h, axis=1, begin=0, end=h.shape[1] // 2)
+            return self.lm_head(h)
 
     @property
     def config(self):

@@ -394,11 +394,12 @@ class TrainStep:
                 in_jit_sharding=pipe_in_jit)
             return d["post_fn"]({k: p[k] for k in d["post_names"]}, rng, h)
 
-        # Named ``train_step`` so that a trace shows the module as
-        # ``jit_train_step``.  The name is also part of JAX's persistent
-        # compile-cache key, which the scopes below are not: an executable
-        # cached under another name by a build without them is not loaded
-        # in place of this one (it would carry that build's op names).
+        # Named ``train_step_<digest of the scope names>`` below, so that a
+        # trace shows the module as ``jit_train_step_...``.  The name is
+        # part of JAX's persistent compile-cache key, which the scopes are
+        # not: an executable cached by a build with other scope names is
+        # not loaded in place of this one (it would carry that build's op
+        # names), whoever adds or renames a scope.
         def train_step(train_params, rest_params, opt_state, rng, x, y):
             # the scopes only name the ops (profiler.scopes_of reads them
             # back); the backward needs none, JAX derives its ops' names
@@ -422,11 +423,15 @@ class TrainStep:
                     else:
                         out = apply_fn(p, rng, *xs)
                         state = {}
-                if dtype is not None:
-                    out = jax.tree_util.tree_map(
-                        lambda o: o.astype(jnp.float32)
-                        if jnp.issubdtype(o.dtype, jnp.floating) else o, out)
-                return jnp.mean(loss_fn(out, y)), (state, scalars.stacked())
+                # the loss is the caller's function: its call is named
+                with jax.named_scope(_profiler.SCOPE_LOSS):
+                    if dtype is not None:
+                        out = jax.tree_util.tree_map(
+                            lambda o: o.astype(jnp.float32)
+                            if jnp.issubdtype(o.dtype, jnp.floating) else o,
+                            out)
+                    loss = jnp.mean(loss_fn(out, y))
+                return loss, (state, scalars.stacked())
 
             (loss, (state, scalars)), grads = jax.value_and_grad(
                 loss_of, has_aux=True)(train_params)
@@ -438,6 +443,8 @@ class TrainStep:
                     new_rest[k] = v
             return loss, new_tp, new_rest, new_opt, scalars
 
+        train_step.__name__ = train_step.__qualname__ = \
+            f"train_step_{_profiler.scope_digest()}"
         donate_argnums = (0, 1, 2) if donate else ()
         self._step = jax.jit(train_step, donate_argnums=donate_argnums)
         self._rng_seed = 0

@@ -16,6 +16,7 @@ Reference: ``python/mxnet/profiler.py`` + ``src/profiler/`` (SURVEY.md
 """
 from __future__ import annotations
 
+import hashlib
 import json
 import os
 import re
@@ -28,7 +29,7 @@ from .base import MXNetError
 
 __all__ = ["set_config", "start", "stop", "dump", "dumps", "pause", "resume",
            "Task", "Frame", "Marker", "Counter", "Domain", "Scope",
-           "scopes_of", "register_executable", "op_scopes"]
+           "scopes_of", "register_executable", "op_scopes", "scope_digest"]
 
 # ``jax.named_scope`` names inside the compiled programs: they land in
 # every HLO instruction's ``op_name`` metadata, which is how a device trace
@@ -54,6 +55,31 @@ SCOPE_MOE_EXPERTS = "mx_moe_experts"
 # the shared expert beside them (model_zoo/language/llama.py::LlamaMoEMLP):
 # a dense SwiGLU that every token passes
 SCOPE_MOE_SHARED = "mx_moe_shared"
+# the transformer block's own parts (model_zoo/language/llama.py, bert.py;
+# the loss in parallel/data_parallel.py::TrainStep), entered at the call
+# sites one after the other, so that no op's own name holds two of them:
+# the q/k/v/o projections with the gate's and the head reshapes beside them;
+# a dense layer's FFN; every norm of a block; RoPE and its positions; the
+# embedding gathers; the head (BERT's MLM transform, pooler and NSP with
+# it); the cast of the outputs to float32 and the caller's loss function
+SCOPE_ATTENTION_PROJ = "mx_attn_proj"
+SCOPE_FFN = "mx_ffn"
+SCOPE_NORM = "mx_norm"
+SCOPE_ROPE = "mx_rope"
+SCOPE_EMBED = "mx_embed"
+SCOPE_HEAD = "mx_head"
+SCOPE_LOSS = "mx_loss"
+
+
+def scope_digest():
+    """Eight hex digits over every ``SCOPE_*`` / ``KERNEL_*`` constant of
+    this module.  ``TrainStep`` names its jitted function with it: JAX's
+    persistent compile-cache key holds a program's name and leaves its
+    metadata out, so an executable cached before a scope was added or
+    renamed would come back with the old op names."""
+    named = sorted((k, v) for k, v in globals().items()
+                   if k.startswith(("SCOPE_", "KERNEL_")))
+    return hashlib.sha1(repr(named).encode()).hexdigest()[:8]
 
 _CONFIG = {"filename": "profile.json", "profile_all": False,
            "profile_imperative": False, "dir": None, "jax_trace": True,
@@ -314,6 +340,38 @@ def _scope_classes(op_names):
     return sorted(found)
 
 
+# What an instruction is by its own name, whatever its metadata says: the
+# TPU's expansion of a grouped product drops the program's scope
+# (``ragged-dot-*`` custom calls, named so in ``op_name`` too), and GSPMD
+# hands an all-reduce the name of the gradient it sums.
+PART_COLLECTIVES = "collectives"
+PART_OPTIMIZER = "optimizer"
+PART_MIXED = "mixed"
+_PART_BY_INSTRUCTION = (("ragged-dot", SCOPE_MOE_EXPERTS),
+                        ("all-reduce", PART_COLLECTIVES))
+
+
+def _part_finder():
+    """``innermost(name)``: the last part name in ``name`` (an ``op_name``
+    or an instruction's name), or ``""``.  The part names are the scopes
+    above but ``mx_forward`` and ``mx_optimizer`` (classes, not parts) and
+    the attention forward kernel's name; a step's few hundred distinct
+    names are looked at once each."""
+    names = {v for k, v in globals().items() if k.startswith("SCOPE_")}
+    names = names - {SCOPE_FORWARD, SCOPE_OPTIMIZER} | {KERNEL_ATTENTION_FWD}
+    findall = re.compile("|".join(
+        map(re.escape, sorted(names, key=len, reverse=True)))).findall
+    memo = {}
+
+    def innermost(name):
+        if name not in memo:
+            found = findall(name)
+            memo[name] = found[-1] if found else ""
+        return memo[name]
+
+    return innermost
+
+
 def _hlo_computations(text):
     """``(entry, {computation: [(instruction, own op_name, what follows the
     name on its line)]})`` of an HLO module's text."""
@@ -341,9 +399,10 @@ def _hlo_computations(text):
 
 
 def scopes_of(compiled):
-    """``{instruction: {"scope": op_name, "classes": [...]}}`` for every
-    instruction of a compiled executable that a device trace can show: the
-    entry computation's, and those of loop bodies, branches and calls.
+    """``{instruction: {"scope": op_name, "classes": [...], "part": name}}``
+    for every instruction of a compiled executable that a device trace can
+    show: the entry computation's, and those of loop bodies, branches and
+    calls.
 
     ``scope`` is the instruction's ``op_name`` metadata verbatim (the
     ``jax.named_scope`` path it was traced under); ``classes`` is the
@@ -351,9 +410,24 @@ def scopes_of(compiled):
     and, for a fusion, the names of the instructions it fused (those of
     nested fusions too).  One class: cleanly attributed.  Two or more: a
     fusion the compiler made across the parts.  None: unscoped (copies,
-    infeed).  Parsed once from ``compiled.as_text()``; empty where the
-    text cannot be had or carries no metadata, so that a reader finds
-    nothing rather than something wrong."""
+    infeed).
+
+    ``part`` resolves every instruction to one part of the model by one
+    rule.  (i) What the instruction is by itself: an attention kernel, a
+    grouped product or an all-reduce by its instruction name, else the
+    innermost (last) part scope in its own ``op_name`` (names the compiler
+    joined by ``;`` each by itself).  Else (ii), for a fusion, the one part
+    among the ``op_name``s of what it fused, nested fusions too;
+    ``mx_optimizer`` is no part, so a weight gradient's matmul with Adam's
+    update as epilogue is the part of its matmul.  Else (iii) ``"mixed"``
+    where it fused several parts, ``"optimizer"`` where ``mx_optimizer``
+    alone names it, ``""`` where nothing does (the compiler's copies and
+    fills).  A ``while`` / ``call`` / ``conditional`` keeps the part of its
+    own name; the ops of its body are rows of their own.
+
+    Parsed once from ``compiled.as_text()``; empty where the text cannot
+    be had or carries no metadata, so that a reader finds nothing rather
+    than something wrong."""
     try:
         text = compiled.as_text()
     except Exception:   # a runtime that keeps no text for this executable
@@ -361,6 +435,7 @@ def scopes_of(compiled):
     if not text:
         return {}
     entry, computations = _hlo_computations(text)
+    innermost = _part_finder()
 
     def fused_names(comp, seen):
         if comp in seen:
@@ -372,6 +447,24 @@ def scopes_of(compiled):
             for called in _HLO_CALLS.findall(rest):
                 out += fused_names(called, seen)
         return out
+
+    def part_of(name, names):
+        for prefix, part in _PART_BY_INSTRUCTION:
+            if name.startswith(prefix) or names[0].startswith(prefix):
+                return part
+        kernel = innermost(name.split(".", 1)[0])
+        if kernel:
+            return kernel
+        # where the compiler merged instructions it joined their names by
+        # ";": the own name is then several, each looked at by itself
+        own = set(map(innermost, names[0].split(";"))) - {""}
+        if len(own) == 1:
+            return own.pop()
+        fused = own | set(map(innermost, names[1:])) - {""}
+        if fused:
+            return fused.pop() if len(fused) == 1 else PART_MIXED
+        return PART_OPTIMIZER if any(
+            SCOPE_OPTIMIZER in n for n in names) else ""
 
     table = {}
     todo, shown = [entry], set()
@@ -385,7 +478,8 @@ def scopes_of(compiled):
             for called in _HLO_CALLS.findall(rest):
                 names += fused_names(called, set())
             table[name] = {"scope": sys.intern(own),
-                           "classes": _scope_classes(names)}
+                           "classes": _scope_classes(names),
+                           "part": part_of(name, names)}
             for one, many in _HLO_SHOWN.findall(rest):
                 todo += [one] if one else [
                     c.strip().lstrip("%") for c in many.split(",")]
