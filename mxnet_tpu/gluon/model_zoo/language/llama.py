@@ -198,6 +198,11 @@ class RMSNorm(HybridBlock):
     def hybrid_forward(self, F, x, weight):
         return F.rms_norm(x, weight, eps=self._eps)
 
+    def scale(self):
+        """The weight as ``hybrid_forward`` gets it (under a trace its
+        stand-in), for a caller whose own op holds the norm."""
+        return self._resolve_params()["weight"]
+
 
 class LlamaAttention(HybridBlock):
     def __init__(self, cfg, kind="full", **kwargs):
@@ -240,39 +245,50 @@ class LlamaAttention(HybridBlock):
         # the parts are named here, one after the other (profiler.py): the
         # attention op between them names its own kernels and backward
         with jax.named_scope(SCOPE_ATTENTION_PROJ):
-            q = self.q_proj(x).reshape(
-                (b, l, cfg.num_heads, hd)).transpose((0, 2, 1, 3))
-            k = self.k_proj(x).reshape(
-                (b, l, cfg.num_kv_heads, hd)).transpose((0, 2, 1, 3))
+            q, k = self.q_proj(x), self.k_proj(x)
             v = self.v_proj(x).reshape(
                 (b, l, cfg.num_kv_heads, hd)).transpose((0, 2, 1, 3))
-        if cfg.qk_norm:
-            with jax.named_scope(SCOPE_NORM):
-                q, k = self.q_norm(q), self.k_norm(k)
+        turn = None     # what F.rope takes here, or no turn at all
         if cfg.block_diffusion:
+            turn = {"base": cfg.rope_base}
             with jax.named_scope(SCOPE_ROPE):
                 # [xt ; x0]: both halves of the row carry positions 0..L-1
                 half = F.arange(0, l // 2, dtype="int32")
-                pos = F.concat(half, half, dim=0)
-                q = F.rope(q, pos, base=cfg.rope_base)
-                k = F.rope(k, pos, base=cfg.rope_base)
+                positions = F.concat(half, half, dim=0)
+        elif self._kind in cfg.rope_attention_types:
+            turn = cfg.rope_kwargs(self._kind)
+        # q/k norm and RoPE in one op, which also puts the heads before the
+        # rows (F.qk_norm_rope); the norms stay their weights' owners
+        def operand(proj, norm, heads):
+            given = [proj]
+            if norm is not None:
+                given.append(norm.scale())
+            if turn is not None and positions is not None:
+                given.append(positions)
+            return F.qk_norm_rope(*given, heads=heads, norm=norm is not None,
+                                  eps=cfg.rms_eps, turn=turn is not None,
+                                  **(turn or {}))
+
+        if turn is not None:
+            part = SCOPE_ROPE
+        else:
+            part = SCOPE_NORM if cfg.qk_norm else SCOPE_ATTENTION_PROJ
+        with jax.named_scope(part):
+            q = operand(q, getattr(self, "q_norm", None), cfg.num_heads)
+            k = operand(k, getattr(self, "k_norm", None), cfg.num_kv_heads)
+        sm_scale = 1.0 / math.sqrt(hd)
+        if cfg.block_diffusion:
             o = F.flash_attention(q, k, v, segment_ids,
                                   mask="block_diffusion",
                                   mask_block=cfg.block_diffusion,
-                                  sm_scale=1.0 / math.sqrt(hd))
+                                  sm_scale=sm_scale)
+        elif self._kind == "window":
+            o = F.flash_attention(q, k, v, segment_ids, mask="window",
+                                  window=cfg.attention_window,
+                                  sm_scale=sm_scale)
         else:
-            if self._kind in cfg.rope_attention_types:
-                turn = cfg.rope_kwargs(self._kind)
-                with jax.named_scope(SCOPE_ROPE):
-                    q = F.rope(q, positions, **turn)
-                    k = F.rope(k, positions, **turn)
-            if self._kind == "window":
-                o = F.flash_attention(q, k, v, segment_ids, mask="window",
-                                      window=cfg.attention_window,
-                                      sm_scale=1.0 / math.sqrt(hd))
-            else:
-                o = F.flash_attention(q, k, v, segment_ids, causal=True,
-                                      sm_scale=1.0 / math.sqrt(hd))
+            o = F.flash_attention(q, k, v, segment_ids, causal=True,
+                                  sm_scale=sm_scale)
         with jax.named_scope(SCOPE_ATTENTION_PROJ):
             o = o.transpose((0, 2, 1, 3)).reshape(
                 (b, l, cfg.num_heads * hd))

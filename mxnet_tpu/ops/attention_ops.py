@@ -134,18 +134,10 @@ def yarn_rope_parameters(head_dim, base, factor, original_max_position,
     return tuple(float(f) for f in inv_freq), float(attention_factor)
 
 
-@register("rope")
-def rope(x, positions=None, base=10000.0, scale=1.0, inv_freq=None,
-         magnitude=1.0):
-    """Rotary position embedding over the last dim.
-
-    x (B, H, L, D) with D even; positions (L,) or (B, L) (defaults to
-    arange).  Half-split convention (Llama).  ``inv_freq`` (D / 2 numbers)
-    takes the place of ``base``'s ``base ** (-2n / D)`` where a scaling of
-    the frequencies gives its own (``yarn_rope_parameters``); ``magnitude``
-    multiplies cos and sin."""
+def rope_angles(positions, l, d, base=10000.0, scale=1.0, inv_freq=None):
+    """float32 angles of RoPE, ``(l, d / 2)`` for positions ``(l,)``
+    (``None``: arange) and ``(b, l, d / 2)`` for ``(b, l)``."""
     jnp = _jnp()
-    b, h, l, d = x.shape
     if positions is None:
         positions = jnp.arange(l)
     positions = jnp.asarray(positions) * scale
@@ -158,7 +150,22 @@ def rope(x, positions=None, base=10000.0, scale=1.0, inv_freq=None,
 
             raise MXNetError(f"rope: inv_freq holds {freqs.shape} numbers "
                              f"for a head of {d}; wants {d // 2}")
-    angles = positions[..., None] * freqs                  # (..., L, d/2)
+    return positions[..., None] * freqs                    # (..., L, d/2)
+
+
+@register("rope")
+def rope(x, positions=None, base=10000.0, scale=1.0, inv_freq=None,
+         magnitude=1.0):
+    """Rotary position embedding over the last dim.
+
+    x (B, H, L, D) with D even; positions (L,) or (B, L) (defaults to
+    arange).  Half-split convention (Llama).  ``inv_freq`` (D / 2 numbers)
+    takes the place of ``base``'s ``base ** (-2n / D)`` where a scaling of
+    the frequencies gives its own (``yarn_rope_parameters``); ``magnitude``
+    multiplies cos and sin."""
+    jnp = _jnp()
+    b, h, l, d = x.shape
+    angles = rope_angles(positions, l, d, base, scale, inv_freq)
     if angles.ndim == 2:        # (L, d/2): shared across batch and heads
         angles = angles[None, None]
     elif angles.ndim == 3:      # (B, L, d/2): per-batch, broadcast over heads

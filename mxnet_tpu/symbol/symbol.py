@@ -706,11 +706,15 @@ def load_json(json_str):
     data = json.loads(json_str)
     nodes = []
     for entry in data["nodes"]:
-        attrs = {k: _parse_attr(v) for k, v in entry.get("attrs", {}).items()}
+        raw = entry.get("attrs", {})
+        attrs = {k: _parse_attr(v) for k, v in raw.items()}
         op = entry["op"]
         if op == "null":
             if attrs.pop("__const__", None):
-                value = _np.asarray(json.loads(attrs.pop("__value__")),
+                # the value from the string as written: ``_parse_attr`` has
+                # already made a list of an array's
+                del attrs["__value__"]
+                value = _np.asarray(json.loads(raw["__value__"]),
                                     dtype=attrs.pop("__dtype__", "float32"))
                 nodes.append(_Node(None, entry["name"], attrs, value=value))
             else:

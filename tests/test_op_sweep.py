@@ -304,6 +304,8 @@ add("CTCLoss", lambda rs: [rs.uniform(-1, 1, (4, 1, 5)).astype("f"),
 # --------------------------- attention / transformer -----------------------
 add("swiglu", P((2, 3), (2, 3)))
 add("rope", P((1, 2, 4, 4)))
+add("_contrib_qk_norm_rope", P((1, 4, 8), (4,)),
+    kwargs={"heads": 2, "norm": True})
 add("_contrib_flash_attention", P((1, 2, 4, 4), (1, 2, 4, 4), (1, 2, 4, 4)),
     kwargs={"causal": True}, rtol=3e-2, atol=3e-3)
 add("_contrib_interleaved_matmul_selfatt_qk", P((3, 1, 12)),

@@ -1,4 +1,5 @@
-"""The Pallas attention forward and backward, compiled at real widths for a
+"""The Pallas kernels (attention forward and backward, q/k norm and RoPE) and
+the expert layer, compiled at real widths for a
 TPU v5e that is described and not attached: what the chip's compiler refuses
 (an operand type, a slice off the tiling, too much VMEM) the TPU interpreter
 of ``test_chip_smoke.py`` lets through.  Nothing runs, so nothing here is a
@@ -238,6 +239,116 @@ def test_segment_flash_backward_kernel_compiles_for_v5e(one_chip, shape, dim,
     assert ("mxnet_flash_attention_bwd_window_segments" if window
             else "mxnet_flash_attention_bwd_segments") in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# q/k norm and RoPE in one pass (ISSUE 36), at the three decoder cells'
+# shapes: rows a sample, samples, and how the positions come (one row of the
+# block-diffusion halves' for every sample, none, a row a sample)
+QK_SHAPES = [(2, 8192, "row"), (1, 8192, None), (1, 16384, "sample")]
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("heads", [32, 4])
+@pytest.mark.parametrize("b,l,positions", QK_SHAPES)
+def test_qk_norm_rope_kernels_compile_for_v5e(one_chip, b, l, positions,
+                                              heads, dtype):
+    """Forward and backward kernels through the op's ``custom_vjp``: block
+    maps that transpose, a lane roll, a column block of the projection, a
+    block of one row of ``dgamma``'s partial sums; and nothing of the
+    output's size in HBM beside the operands."""
+    from mxnet_tpu.ops import qk_norm_rope as qnr
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    hd = 128
+    op = qnr._make_kernel_op(heads, 1e-6)
+    x, gamma = spec((b, l, heads * hd), dtype), spec((hd,), "float32")
+    table = spec((b, l, 2 * hd) if positions == "sample" else (l, 2 * hd),
+                 "float32")
+    g = spec((b, heads, l, hd), dtype)
+    text = jax.jit(op).lower(x, gamma, table).compile().as_text()
+    assert "tpu_custom_call" in text and qnr.KERNEL_FWD in text
+
+    def backward(x, gamma, table, g):
+        return jax.vjp(op, x, gamma, table)[1](g)[:2]
+
+    compiled = jax.jit(backward).lower(x, gamma, table, g).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and qnr.KERNEL_BWD in text
+    assert qnr.KERNEL_FWD not in text       # nothing of the forward is kept
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("norm,turn", [(True, False), (False, True)])
+def test_qk_norm_rope_alone_norm_or_turn_compiles_for_v5e(one_chip, norm,
+                                                          turn):
+    """The window cell's full layers (the norm alone) and a configuration
+    without q/k norm (the turn alone, whose backward reads no projection)."""
+    from mxnet_tpu.ops import qk_norm_rope as qnr
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    op = qnr._make_kernel_op(32, 1e-6)
+    x = spec((1, 8192, 4096), "bfloat16")
+    gamma = spec((128,), "float32") if norm else None
+    table = spec((8192, 256), "float32") if turn else None
+    g = spec((1, 32, 8192, 128), "bfloat16")
+    compiled = jax.jit(lambda g, *given: jax.vjp(op, *given)[1](g)).lower(
+        g, x, gamma, table).compile()
+    assert qnr.KERNEL_BWD in compiled.as_text()
+    assert qnr.KERNEL_FWD in jax.jit(op).lower(
+        x, gamma, table).compile().as_text()
+
+
+def test_the_steps_table_resolves_the_qk_kernels_to_their_part(one_chip,
+                                                              monkeypatch):
+    """A small decoder's fused step with both gates open, compiled for the
+    chip: the op's kernels (forward, recomputed forward and backward) are
+    rows of ``mx_rope`` where the layer turns and of ``mx_norm`` where it
+    only normalises, by the scope they were called under: a custom call is
+    fused with nothing, so none of them reads ``mixed``."""
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import profiler
+    from mxnet_tpu.gluon.model_zoo.language import llama
+    from mxnet_tpu.ops import flash_attention as fa
+    from mxnet_tpu.ops import qk_norm_rope as qnr
+    from mxnet_tpu.parallel.data_parallel import TrainStep
+
+    monkeypatch.setattr(fa, "_use_pallas", lambda q: True)
+    monkeypatch.setattr(qnr, "_use_pallas", lambda x, hd: True)
+    net = llama.LlamaForCausalLM(llama.LlamaConfig(
+        vocab_size=512, hidden_size=256, num_layers=2, num_heads=2,
+        num_kv_heads=1, head_dim=128, intermediate_size=256, qk_norm=True,
+        attention_types=("window", "full"), attention_window=128,
+        rope_attention_types=("window",), remat=True))
+    net.initialize()
+
+    def loss(logits, labels):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        return -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+
+    step = TrainStep(net, loss, optimizer="adam", dtype="bfloat16",
+                     optimizer_params={"learning_rate": 1e-4})
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=one_chip)
+
+    ids = np.zeros((1, 256), np.int32)
+    args = jax.tree_util.tree_map(spec, (
+        TrainStep._plain_tree(step.train_params),
+        TrainStep._plain_tree(step.rest_params),
+        TrainStep._plain_tree(step.opt_state), jax.random.PRNGKey(0),
+        ids, ids))
+    table = profiler.scopes_of(step._step.lower(*args).compile())
+    for kernel, calls in ((qnr.KERNEL_FWD, 4), (qnr.KERNEL_BWD, 2)):
+        parts = [row["part"] for name, row in table.items()
+                 if name.startswith(kernel)]
+        assert sorted(parts) == sorted(
+            [profiler.SCOPE_ROPE, profiler.SCOPE_NORM] * calls), kernel
 
 
 def _gathered_and_scattered(text):
