@@ -15,8 +15,8 @@ import numpy as _np
 
 from ....base import MXNetError
 from ....profiler import (SCOPE_ATTENTION_PROJ, SCOPE_EMBED, SCOPE_FFN,
-                          SCOPE_HEAD, SCOPE_MOE_SHARED, SCOPE_NORM,
-                          SCOPE_ROPE)
+                          SCOPE_HEAD, SCOPE_KDA, SCOPE_MIXER_GATE,
+                          SCOPE_MOE_SHARED, SCOPE_NORM, SCOPE_ROPE)
 from ...block import HybridBlock
 from ...parameter import Parameter
 from ... import nn
@@ -41,7 +41,11 @@ class LlamaConfig:
                  num_dense_layers=0, moe_shared_intermediate_size=0,
                  moe_score="softmax", moe_route_scale=1.0,
                  moe_renorm_eps=0.0, moe_select_bias=False,
-                 rope_parameters=None):
+                 rope_parameters=None, moe_groups=None,
+                 attention_heads_held=None, kv_lora_rank=0,
+                 qk_nope_head_dim=None, qk_rope_head_dim=None,
+                 v_head_dim=None, rope_interleave=False, kda_conv_size=4,
+                 kda_lower_bound=-5.0):
         # num_experts > 0: an MoE FFN (parallel.expert_parallel) replaces
         # the dense SwiGLU MLP in every layer after the first
         # num_dense_layers; num_experts is the router's width.
@@ -76,19 +80,52 @@ class LlamaConfig:
         # moe_shared_intermediate_size > 0: a dense SwiGLU of that width
         # that every token passes, added to the routed part (on a share of
         # an expert-parallel deployment it is computed whole).
+        # moe_groups = (n_group, topk_group): group-limited routing, the
+        # choice confined to a token's topk_group best of n_group runs of
+        # the router's outputs, a group scored by the sum of its two
+        # largest biased scores (parallel.expert_parallel.limit_to_groups).
+        self.moe_groups = tuple(moe_groups) if moe_groups else None
         self.num_dense_layers = num_dense_layers
         self.moe_score = moe_score
         self.moe_route_scale = moe_route_scale
         self.moe_renorm_eps = moe_renorm_eps
         self.moe_select_bias = moe_select_bias
         self.moe_shared_intermediate_size = moe_shared_intermediate_size
-        # a layer's attention is "full" (causal) or "window" (causal over
-        # the last attention_window keys): attention_types names each
-        # layer's, default all full; RoPE turns the q and k of the kinds in
-        # rope_attention_types.  attention_gate: o * sigmoid(x Wz) before
-        # the output projection.  post_norms: an RMSNorm on each sublayer's
-        # output before it joins the residual, beside the one on its
-        # input.  embed_scale multiplies the embeddings.
+        # a layer's mixer is "full" (causal softmax attention), "window"
+        # (causal over the last attention_window keys), "kda" (Kimi delta
+        # attention, LlamaDeltaAttention: a gated delta rule over a state a
+        # head, no positions) or "mla" (latent attention,
+        # LlamaLatentAttention): attention_types names each layer's,
+        # default all full; RoPE turns the q and k of the "full" and
+        # "window" kinds in rope_attention_types, and always "mla"'s
+        # qk_rope_head_dim.  attention_gate: o * sigmoid(x Wz) before the
+        # output projection, Wz as wide as o, or with "head_wise" one
+        # number a head ("mla" alone; "kda" has its own gate).  post_norms:
+        # an RMSNorm on each sublayer's output before it joins the
+        # residual, beside the one on its input.  embed_scale multiplies
+        # the embeddings.
+        # attention_heads_held = (first, count): the heads of num_heads
+        # that this net holds, one chip's share of a deployment that
+        # divides the heads (default all), for the kinds whose heads stand
+        # alone ("kda", "mla"): projections, gates and the output
+        # projection's rows are those heads', and what the absent heads
+        # would add to the mixer's output is left out.
+        # "mla": kv_lora_rank the latent's width (normed, shared by the
+        # heads), qk_nope_head_dim + qk_rope_head_dim a head's q and k,
+        # v_head_dim its v; rope_interleave: RoPE's pairs are neighbours.
+        # "kda": heads of head_dim for k and v, a causal depthwise
+        # convolution of kda_conv_size taps with SiLU on q, k and v, the
+        # log-decay in (kda_lower_bound, 0); computed in chunks of KDA_CHUNK
+        # rows, so a row is a whole number of them.
+        self.attention_heads_held = tuple(attention_heads_held) \
+            if attention_heads_held is not None else (0, num_heads)
+        self.kv_lora_rank = kv_lora_rank
+        self.qk_nope_head_dim = qk_nope_head_dim
+        self.qk_rope_head_dim = qk_rope_head_dim
+        self.v_head_dim = v_head_dim
+        self.rope_interleave = rope_interleave
+        self.kda_conv_size = kda_conv_size
+        self.kda_lower_bound = float(kda_lower_bound)
         self.attention_types = tuple(attention_types) \
             if attention_types is not None else ("full",) * num_layers
         self.attention_window = attention_window
@@ -116,11 +153,39 @@ class LlamaConfig:
                 "the block-diffusion layout (block_diffusion > 0) turns both "
                 "halves of its row by rope_base; it takes no rope_parameters "
                 "by kind")
-        if len(self.attention_types) != num_layers or set(
-                self.attention_types) - {"full", "window"}:
+        kinds = set(self.attention_types)
+        if len(self.attention_types) != num_layers \
+                or kinds - {"full", "window", "kda", "mla"}:
             raise MXNetError(
                 f"attention_types names each of the {num_layers} layers "
-                f"'full' or 'window'; got {self.attention_types}")
+                "'full', 'window', 'kda' or 'mla'; got "
+                f"{self.attention_types}")
+        first, count = self.attention_heads_held
+        if not (0 <= first and 0 < count and first + count <= num_heads):
+            raise MXNetError(
+                f"attention_heads_held {self.attention_heads_held} is no "
+                f"run of the {num_heads} heads (num_heads)")
+        if count != num_heads and kinds & {"full", "window"}:
+            raise MXNetError(
+                "a share of the heads (attention_heads_held) is written for "
+                "the kinds 'kda' and 'mla'; 'full' and 'window' layers "
+                "share key-value heads and hold them all")
+        if kinds & {"kda", "mla"} and block_diffusion:
+            raise MXNetError(
+                "'kda' and 'mla' layers are causal: they do not take the "
+                "block-diffusion layout (block_diffusion > 0)")
+        if "mla" in kinds and not (
+                kv_lora_rank and qk_nope_head_dim and qk_rope_head_dim
+                and v_head_dim):
+            raise MXNetError(
+                "an 'mla' layer needs kv_lora_rank, qk_nope_head_dim, "
+                "qk_rope_head_dim and v_head_dim")
+        if attention_gate not in (False, True, "head_wise") or (
+                attention_gate == "head_wise" and kinds - {"mla", "kda"}):
+            raise MXNetError(
+                "attention_gate is False, True or 'head_wise' (one number a "
+                "head, for 'mla' layers; 'kda' has its own gate); got "
+                f"{attention_gate!r} with {self.attention_types}")
         if "window" in self.attention_types and (
                 attention_window < 1 or block_diffusion):
             raise MXNetError(
@@ -297,6 +362,170 @@ class LlamaAttention(HybridBlock):
             return self.o_proj(o)
 
 
+class LlamaLatentAttention(HybridBlock):
+    """Latent attention (MLA, DeepSeek-V2's form without a q latent), in its
+    training form: ``q_h = x Wq_h`` of ``qk_nope_head_dim +
+    qk_rope_head_dim``; ``[c ; kr] = x Wkva`` (``kv_lora_rank +
+    qk_rope_head_dim``), ``c`` normed; ``[k_h ; v_h] = c Wkvb_h``; RoPE on
+    ``q_h``'s last ``qk_rope_head_dim`` and on ``kr``, which every head
+    shares; causal softmax attention over ``[q_nope ; q_rope] . [k_h ;
+    kr]`` through ``F.flash_attention`` at its two widths; a gate a head
+    (``attention_gate="head_wise"``); the heads held alone
+    (``attention_heads_held``)."""
+
+    def __init__(self, cfg, **kwargs):
+        super().__init__(**kwargs)
+        self._cfg = cfg
+        d, held = cfg.hidden_size, cfg.attention_heads_held[1]
+        nope, turned = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        with self.name_scope():
+            self.q_proj = nn.Dense(held * (nope + turned), use_bias=False,
+                                   flatten=False, in_units=d,
+                                   prefix="q_proj_")
+            self.kv_a_proj = nn.Dense(cfg.kv_lora_rank + turned,
+                                      use_bias=False, flatten=False,
+                                      in_units=d, prefix="kv_a_proj_")
+            self.kv_a_norm = RMSNorm(cfg.kv_lora_rank, cfg.rms_eps,
+                                     prefix="kv_a_norm_")
+            self.kv_b_proj = nn.Dense(held * (nope + cfg.v_head_dim),
+                                      use_bias=False, flatten=False,
+                                      in_units=cfg.kv_lora_rank,
+                                      prefix="kv_b_proj_")
+            self.o_proj = nn.Dense(d, use_bias=False, flatten=False,
+                                   in_units=held * cfg.v_head_dim,
+                                   prefix="o_proj_")
+            if cfg.attention_gate:
+                self.gate_proj = nn.Dense(
+                    held * (1 if cfg.attention_gate == "head_wise"
+                            else cfg.v_head_dim),
+                    use_bias=False, flatten=False, in_units=d,
+                    prefix="gate_proj_")
+
+    def hybrid_forward(self, F, x, segment_ids=None, positions=None):
+        import jax
+
+        cfg = self._cfg
+        if segment_ids is not None:
+            raise MXNetError(
+                "an 'mla' layer does not take segment_ids yet: packed "
+                "documents under latent attention are not written")
+        b, l = x.shape[0], x.shape[1]
+        held = cfg.attention_heads_held[1]
+        nope, turned, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                            cfg.v_head_dim)
+
+        def heads(t, width):
+            return t.reshape((b, l, held, width)).transpose((0, 2, 1, 3))
+
+        with jax.named_scope(SCOPE_ATTENTION_PROJ):
+            q = heads(self.q_proj(x), nope + turned)
+            latent = self.kv_a_proj(x)
+        with jax.named_scope(SCOPE_NORM):
+            c = self.kv_a_norm(F.slice_axis(latent, axis=2, begin=0,
+                                            end=cfg.kv_lora_rank))
+        with jax.named_scope(SCOPE_ATTENTION_PROJ):
+            kv = heads(self.kv_b_proj(c), nope + dv)
+            k_nope = F.slice_axis(kv, axis=3, begin=0, end=nope)
+            v = F.slice_axis(kv, axis=3, begin=nope, end=nope + dv)
+        with jax.named_scope(SCOPE_ROPE):
+            turn = dict(base=cfg.rope_base, interleave=cfg.rope_interleave)
+            q_rope = F.rope(F.slice_axis(q, axis=3, begin=nope,
+                                         end=nope + turned), **turn)
+            kr = F.rope(F.slice_axis(latent, axis=2, begin=cfg.kv_lora_rank,
+                                     end=cfg.kv_lora_rank + turned)
+                        .reshape((b, 1, l, turned)), **turn)
+            q = F.concat(F.slice_axis(q, axis=3, begin=0, end=nope), q_rope,
+                         dim=3)
+            k = F.concat(k_nope, F.broadcast_to(
+                kr, shape=(b, held, l, turned)), dim=3)
+        o = F.flash_attention(q, k, v, causal=True,
+                              sm_scale=1.0 / math.sqrt(nope + turned))
+        if cfg.attention_gate:
+            with jax.named_scope(SCOPE_MIXER_GATE):
+                gate = F.sigmoid(self.gate_proj(x))
+                o = o * heads(gate, gate.shape[2] // held)
+        with jax.named_scope(SCOPE_ATTENTION_PROJ):
+            return self.o_proj(o.transpose((0, 2, 1, 3)).reshape(
+                (b, l, held * dv)))
+
+
+# rows of a chunk of the delta rule (``ops/kda.py``): one value until two
+# workloads need two
+KDA_CHUNK = 64
+
+
+class LlamaDeltaAttention(HybridBlock):
+    """Kimi delta attention (Kimi Linear, arXiv:2510.26692): q, k and v
+    through a causal depthwise convolution and SiLU, q and k l2-normed a
+    head, a bounded log-decay a channel and a beta a head from the same
+    input, the gated delta rule over a state a head (``F.kda``), then an
+    RMSNorm over the head (one learned vector) times a full-width sigmoid
+    gate, and the output projection.  No positions.  The heads held alone
+    (``attention_heads_held``)."""
+
+    def __init__(self, cfg, **kwargs):
+        super().__init__(**kwargs)
+        self._cfg = cfg
+        d, hd, held = (cfg.hidden_size, cfg.head_dim,
+                       cfg.attention_heads_held[1])
+        wide = dict(use_bias=False, flatten=False, in_units=d)
+        with self.name_scope():
+            self.q_proj = nn.Dense(held * hd, prefix="q_proj_", **wide)
+            self.k_proj = nn.Dense(held * hd, prefix="k_proj_", **wide)
+            self.v_proj = nn.Dense(held * hd, prefix="v_proj_", **wide)
+            self.q_conv = self.params.get(
+                "q_conv_weight", shape=(cfg.kda_conv_size, held * hd))
+            self.k_conv = self.params.get(
+                "k_conv_weight", shape=(cfg.kda_conv_size, held * hd))
+            self.v_conv = self.params.get(
+                "v_conv_weight", shape=(cfg.kda_conv_size, held * hd))
+            self.f_proj = nn.Dense(held * hd, prefix="f_proj_", **wide)
+            self.a_log = self.params.get("a_log", shape=(held,),
+                                         init="zeros")
+            self.dt_bias = self.params.get("dt_bias", shape=(held * hd,),
+                                           init="zeros")
+            self.b_proj = nn.Dense(held, prefix="b_proj_", **wide)
+            self.gate_proj = nn.Dense(held * hd, prefix="gate_proj_", **wide)
+            self.o_norm = RMSNorm(hd, cfg.rms_eps, prefix="o_norm_")
+            self.o_proj = nn.Dense(d, use_bias=False, flatten=False,
+                                   in_units=held * hd, prefix="o_proj_")
+
+    def hybrid_forward(self, F, x, segment_ids=None, positions=None, *,
+                       q_conv, k_conv, v_conv, a_log, dt_bias):
+        import jax
+
+        cfg = self._cfg
+        if segment_ids is not None:
+            raise MXNetError(
+                "a 'kda' layer does not take segment_ids yet: a state and "
+                "a convolution that start again at a document's boundary "
+                "are not written")
+        b, l = x.shape[0], x.shape[1]
+        hd, held = cfg.head_dim, cfg.attention_heads_held[1]
+        with jax.named_scope(SCOPE_ATTENTION_PROJ):
+            q, k, v = self.q_proj(x), self.k_proj(x), self.v_proj(x)
+            f, beta, z = self.f_proj(x), self.b_proj(x), self.gate_proj(x)
+        with jax.named_scope(SCOPE_MIXER_GATE):
+            q = F.l2_norm_heads(F.short_conv(q, q_conv), heads=held,
+                                scale=1.0 / math.sqrt(hd))
+            k = F.l2_norm_heads(F.short_conv(k, k_conv), heads=held)
+            v = F.short_conv(v, v_conv).reshape(
+                (b, l, held, hd)).transpose((0, 2, 1, 3))
+            g = F.kda_decay(f, a_log, dt_bias, heads=held,
+                            lower_bound=cfg.kda_lower_bound)
+            beta = F.sigmoid(beta).transpose((0, 2, 1))
+        with jax.named_scope(SCOPE_KDA):
+            o = F.kda(q, k, v, g, beta, chunk=KDA_CHUNK)
+        with jax.named_scope(SCOPE_MIXER_GATE):
+            o = self.o_norm(o.transpose((0, 2, 1, 3))).reshape(
+                (b, l, held * hd)) * F.sigmoid(z)
+        with jax.named_scope(SCOPE_ATTENTION_PROJ):
+            return self.o_proj(o)
+
+
+MIXERS = {"kda": LlamaDeltaAttention, "mla": LlamaLatentAttention}
+
+
 class LlamaMLP(HybridBlock):
     def __init__(self, cfg, width=None, **kwargs):
         super().__init__(**kwargs)
@@ -333,12 +562,13 @@ class LlamaMoEMLP(HybridBlock):
         N = cfg.moe_experts_held[1]
         if cfg.moe_capacity_factor is not None and (
                 N != E or cfg.moe_top_k != 1 or cfg.moe_score != "softmax"
-                or cfg.moe_select_bias or cfg.moe_route_scale != 1.0):
+                or cfg.moe_select_bias or cfg.moe_route_scale != 1.0
+                or cfg.moe_groups):
             raise MXNetError(
                 "a moe_capacity_factor is the switch top-1 layer over every "
                 "expert, softmax gates; moe_top_k > 1, moe_experts_held, "
-                "moe_score, moe_select_bias and moe_route_scale route "
-                "dropless (moe_capacity_factor=None)")
+                "moe_score, moe_select_bias, moe_route_scale and moe_groups "
+                "route dropless (moe_capacity_factor=None)")
         with self.name_scope():
             self.router = self.params.get("router_weight", shape=(H, E))
             self.gate_proj = self.params.get("gate_proj_weight",
@@ -363,6 +593,7 @@ class LlamaMoEMLP(HybridBlock):
             return F.moe_swiglu(x, router, gate_proj, up_proj, down_proj,
                                 capacity_factor=cfg.moe_capacity_factor,
                                 aux_loss_weight=cfg.moe_aux_loss_weight)
+        n_group, topk_group = cfg.moe_groups or (1, 1)
         out = F.moe_swiglu(x, router, gate_proj, up_proj, down_proj,
                            select_bias, capacity_factor=0.0,
                            top_k=cfg.moe_top_k,
@@ -370,7 +601,8 @@ class LlamaMoEMLP(HybridBlock):
                            experts_first=cfg.moe_experts_held[0],
                            score=cfg.moe_score,
                            route_scale=cfg.moe_route_scale,
-                           renorm_eps=cfg.moe_renorm_eps)
+                           renorm_eps=cfg.moe_renorm_eps, n_group=n_group,
+                           topk_group=topk_group)
         if cfg.moe_shared_intermediate_size:
             import jax
 
@@ -391,8 +623,10 @@ class LlamaDecoderLayer(HybridBlock):
         with self.name_scope():
             self.input_layernorm = RMSNorm(cfg.hidden_size, cfg.rms_eps,
                                            prefix="input_layernorm_")
-            self.self_attn = LlamaAttention(
-                cfg, kind=cfg.attention_types[index], prefix="self_attn_")
+            kind = cfg.attention_types[index]
+            self.self_attn = MIXERS[kind](cfg, prefix="self_attn_") \
+                if kind in MIXERS else LlamaAttention(
+                    cfg, kind=kind, prefix="self_attn_")
             self.post_attention_layernorm = RMSNorm(
                 cfg.hidden_size, cfg.rms_eps,
                 prefix="post_attention_layernorm_")
@@ -631,8 +865,9 @@ class LlamaForCausalLM(HybridBlock):
         if not cfg.layers_alike():
             raise MXNetError(
                 "pipeline_decompose streams equal stages of like layers; "
-                "this net's layers are of several kinds (attention_types, "
-                "num_dense_layers)")
+                "this net's layers are of several kinds (attention_types: "
+                f"{sorted(set(cfg.attention_types))}, num_dense_layers "
+                f"{cfg.num_dense_layers})")
         if L % n_stages:
             raise MXNetError(
                 f"num_layers {L} not divisible by pipeline stages "
@@ -788,6 +1023,11 @@ def _embed(params, cfg, ids):
 def _refuse_unserved(cfg):
     """The serving forwards know the plain decoder alone: every layer full
     causal attention with RoPE and a dense FFN."""
+    if set(cfg.attention_types) & set(MIXERS):
+        raise MXNetError(
+            "incremental decode does not support 'kda' or 'mla' layers yet: "
+            "a recurrent state with a convolution's tail, and a latent "
+            "cache, are not written")
     if cfg.num_experts > 0:
         raise MXNetError("incremental decode does not support MoE FFNs yet")
     if "window" in cfg.attention_types \

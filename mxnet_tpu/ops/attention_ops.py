@@ -155,11 +155,13 @@ def rope_angles(positions, l, d, base=10000.0, scale=1.0, inv_freq=None):
 
 @register("rope")
 def rope(x, positions=None, base=10000.0, scale=1.0, inv_freq=None,
-         magnitude=1.0):
+         magnitude=1.0, interleave=False):
     """Rotary position embedding over the last dim.
 
     x (B, H, L, D) with D even; positions (L,) or (B, L) (defaults to
-    arange).  Half-split convention (Llama).  ``inv_freq`` (D / 2 numbers)
+    arange).  Half-split convention (Llama): pair ``n`` is ``(x[n], x[n +
+    D / 2])``; with ``interleave`` it is ``(x[2n], x[2n + 1])``, at the
+    same frequencies.  ``inv_freq`` (D / 2 numbers)
     takes the place of ``base``'s ``base ** (-2n / D)`` where a scaling of
     the frequencies gives its own (``yarn_rope_parameters``); ``magnitude``
     multiplies cos and sin."""
@@ -174,6 +176,10 @@ def rope(x, positions=None, base=10000.0, scale=1.0, inv_freq=None,
     if magnitude != 1.0:
         cos, sin = cos * magnitude, sin * magnitude
     cos, sin = cos.astype(x.dtype), sin.astype(x.dtype)
+    if interleave:
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                         axis=-1).reshape(x.shape)
     x1, x2 = x[..., : d // 2], x[..., d // 2:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1)
@@ -207,7 +213,7 @@ def swiglu(gate, up):
 def moe_swiglu(x, router_weight, gate_proj, up_proj, down_proj,
                select_bias=None, capacity_factor=1.25, aux_loss_weight=0.0,
                top_k=1, renormalize=False, experts_first=0, score="softmax",
-               route_scale=1.0, renorm_eps=0.0):
+               route_scale=1.0, renorm_eps=0.0, n_group=1, topk_group=1):
     """MoE SwiGLU FFN over stacked expert weights (net-new vs the
     reference).  Registered as a first-class op so MoE models trace to
     Symbol and export/SymbolBlock-import like any other graph (fused RNN
@@ -226,7 +232,9 @@ def moe_swiglu(x, router_weight, gate_proj, up_proj, down_proj,
     products over the pairs sorted by expert
     (``parallel.expert_parallel.moe_apply``, which says how ``score``
     (``"softmax"`` or ``"sigmoid"``), ``select_bias (E,)``, ``renormalize``
-    with ``renorm_eps`` and ``route_scale`` make the gates).  Router logits,
+    with ``renorm_eps`` and ``route_scale`` make the gates, and how
+    ``n_group > 1`` confines the choice to a token's ``topk_group`` best
+    groups of outputs).  Router logits,
     scores, bias and gates are float32 whatever the products' dtype, which
     is the expert weights' (under AMP the target dtype: ``x``, the router
     and the bias are exempt from the cast, contrib/amp/lists.py).  The
@@ -263,7 +271,11 @@ def moe_swiglu(x, router_weight, gate_proj, up_proj, down_proj,
             top_k=int(top_k), renormalize=bool(renormalize),
             held=(int(experts_first), gate_proj.shape[0]), score=score,
             select_bias=select_bias, scale=float(route_scale),
-            renorm_eps=float(renorm_eps))
+            renorm_eps=float(renorm_eps),
+            groups=(int(n_group), int(topk_group)) if int(n_group) > 1
+            else None)
+        if int(n_group) > 1:
+            telemetry.MOE_GROUP_LIMITED_CALLS.inc()
         telemetry.step_scalar(telemetry.MOE_ROUTED_PAIRS.name,
                               aux["routed_pairs"])
         telemetry.step_scalar(telemetry.MOE_WALKED_ROWS.name,

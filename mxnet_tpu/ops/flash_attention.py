@@ -54,17 +54,39 @@ def batch_sharded(mesh, batch_axes):
         _SCOPE.value = prev
 
 
-def _use_pallas(q):
+def _padded_width(d):
+    """The width the kernels' q and k tiles take for heads of ``d``: ``d``
+    itself where it is 64 or whole lane tiles, the next whole lane tile for
+    a width of whole half tiles above one (192 -> 256: latent attention's
+    128 + 64; the pad is zeros, which add nothing to a score), else None."""
+    if d == 64 or d % 128 == 0:
+        return d
+    return d + 64 if d > 128 and d % 64 == 0 else None
+
+
+def _use_pallas(q, v=None):
     """Static gate for the Pallas forward, and the first half of the
-    backward's (``_use_pallas_bwd``): a head size the kernels tile, a
-    sequence long enough to pay for them, and a TPU to compile them for.
-    The platform is JAX's default backend, not where ``q`` lives (a tracer
-    lives nowhere), so a CPU-context call on a TPU host is not covered."""
+    backward's (``_use_pallas_bwd``): head sizes the kernels tile (q and
+    k's, which may be padded to one, ``_padded_width``, and ``v``'s, which
+    is never padded; ``v`` None: as wide as ``q``), a sequence long enough
+    to pay for them, and a TPU to compile them for.  The platform is JAX's
+    default backend, not where ``q`` lives (a tracer lives nowhere), so a
+    CPU-context call on a TPU host is not covered."""
     import jax
 
-    if q.shape[-1] % 128 != 0 and q.shape[-1] not in (64, 128, 256):
+    dv = q.shape[-1] if v is None else v.shape[-1]
+    if _padded_width(q.shape[-1]) is None or _padded_width(dv) != dv:
         return False
     return jax.default_backend() == "tpu" and q.shape[-2] >= 256
+
+
+def _pad_width(x, width):
+    """``x`` with zeros after its last axis up to ``width``."""
+    import jax.numpy as jnp
+
+    more = width - x.shape[-1]
+    return x if not more else jnp.pad(
+        x, [(0, 0)] * (x.ndim - 1) + [(0, more)])
 
 
 # --------------------------------------------------------------------------
@@ -428,8 +450,9 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
     walking every tile the mask alone shows, bit for bit.
 
     Grid: (batch*heads, num_q_blocks).  Block shapes:
-      q_ref (block_q, d) VMEM; k_ref/v_ref (seq_k, d) VMEM (whole K/V row
-      for this head — fine at the seq lengths VMEM allows; longer sequences
+      q_ref (block_q, d) VMEM; k_ref (seq_k, d) and v_ref (seq_k, dv) VMEM
+      (whole K/V row for this head; ``v`` and the output may be narrower
+      or wider than ``q`` and ``k`` — fine at the seq lengths VMEM allows; longer sequences
       ring through context parallelism instead).
 
     The matmuls take their operands in the input's dtype and accumulate in
@@ -450,7 +473,7 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    block_q, d = q_ref.shape
+    block_q = q_ref.shape[0]
     qi = pl.program_id(1)
     num_kb = seq_k // block_k
     segmented = qseg_ref is not None
@@ -510,7 +533,7 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
 
         carry = (jnp.full((block_q, 1), NEG_INF, dtype=jnp.float32),
                  jnp.zeros((block_q, 1), dtype=jnp.float32),
-                 jnp.zeros((block_q, d), dtype=jnp.float32))
+                 jnp.zeros((block_q, v_ref.shape[-1]), dtype=jnp.float32))
         if segmented:
             # the live K tiles of this sample's ids (a superset: their hull)
             at = _sample_of(pl.program_id(0), heads) * pl.num_programs(1) + qi
@@ -587,7 +610,7 @@ _VMEM_DEFAULT_LIMIT = 16 << 20
 _VMEM_MOST = 96 << 20
 
 
-def _fa_fwd_vmem_limit(lk, d, itemsize, block_q, segmented):
+def _fa_fwd_vmem_limit(lk, d, itemsize, block_q, segmented, dv=None):
     """The scoped VMEM limit a forward call states, or None.  One grid step
     may hold two buffers of the head's whole K and V rows, twice
     ``_TILE_VMEM_BUDGET`` (what the tile choice may spend on a step's score
@@ -599,7 +622,8 @@ def _fa_fwd_vmem_limit(lk, d, itemsize, block_q, segmented):
     every call under the default states none and is compiled as it always
     was (8,192 keys of 128 in bf16 stand exactly at it: tested)."""
     ids = 2 * 4 * (8 * lk + 128 * block_q) if segmented else 0
-    need = 4 * lk * d * itemsize + 2 * _TILE_VMEM_BUDGET + ids
+    rows = d + (d if dv is None else dv)    # a K row and a V row
+    need = 2 * lk * rows * itemsize + 2 * _TILE_VMEM_BUDGET + ids
     return None if need <= _VMEM_DEFAULT_LIMIT else min(need, _VMEM_MOST)
 
 
@@ -612,8 +636,11 @@ def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q=None, block_k=None,
 
     mask = _Mask.of(mask)
     seg = mask.seg
+    # q and k at a width the tiles take (zeros after a head's own), v and
+    # the output at v's own
+    q, k = (_pad_width(x, _padded_width(x.shape[-1])) for x in (q, k))
     b, h, lq, d = q.shape
-    lk = k.shape[2]
+    lk, dv = k.shape[2], v.shape[-1]
     if block_q is None or block_k is None:
         bq, bk = _fa_block_sizes(lq, lk, d, q.dtype.itemsize)
         block_q = bq if block_q is None else block_q
@@ -626,7 +653,7 @@ def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q=None, block_k=None,
     grid = (b * h, lq // block_q)
     qf = q.reshape(b * h, lq, d)
     kf = k.reshape(b * h, lk, d)
-    vf = v.reshape(b * h, lk, d)
+    vf = v.reshape(b * h, lk, dv)
 
     static = dict(block_k=block_k, causal=causal, sm_scale=sm_scale, seq_k=lk,
                   diag_offset=lk - lq, mask=mask.key)
@@ -635,15 +662,15 @@ def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q=None, block_k=None,
     in_specs = [
         pl.BlockSpec((None, block_q, d), lambda bh, qi, *_: (bh, qi, 0)),
         pl.BlockSpec((None, lk, d), lambda bh, qi, *_: (bh, 0, 0)),
-        pl.BlockSpec((None, lk, d), lambda bh, qi, *_: (bh, 0, 0)),
+        pl.BlockSpec((None, lk, dv), lambda bh, qi, *_: (bh, 0, 0)),
     ]
     out_specs = [
-        pl.BlockSpec((None, block_q, d), lambda bh, qi, *_: (bh, qi, 0)),
+        pl.BlockSpec((None, block_q, dv), lambda bh, qi, *_: (bh, qi, 0)),
         pl.BlockSpec((None, 8, block_q), lambda bh, qi, *_: (bh, 0, qi)),
     ]
     operands = [qf, kf, vf]
     limit = _fa_fwd_vmem_limit(lk, d, q.dtype.itemsize, block_q,
-                               seg is not None)
+                               seg is not None, dv)
     params = {} if limit is None else {
         "compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)}
     if seg is None:
@@ -670,13 +697,13 @@ def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q=None, block_k=None,
     o, lse = pl.pallas_call(
         kernel,
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, lq, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, lq, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, 8, lq), jnp.float32),
         ],
         name=_kernel_name(KERNEL_ATTENTION_FWD, mask),
         **params,
     )(*operands)
-    return o.reshape(b, h, lq, d), lse[:, 0, :].reshape(b, h, lq)
+    return o.reshape(b, h, lq, dv), lse[:, 0, :].reshape(b, h, lq)
 
 
 def _per_batch_shard(fn, sharded):
@@ -721,16 +748,17 @@ def _fa_bwd_block_sizes(lq, lk):
     return _largest_tile(lq), _largest_tile(lk)
 
 
-def _fa_bwd_vmem_bytes(lq, d, itemsize, block_q, block_k):
+def _fa_bwd_vmem_bytes(lq, d, itemsize, block_q, block_k, dv=None):
     """What one grid step of the backward kernel holds in VMEM: the row of
     ``dq`` (float32 accumulator, and the output's two buffers), two buffers
     of each operand and result tile, the ``dk`` / ``dv`` accumulators, and
     the float32 score-shaped tiles (``s``, ``p``, ``dp``, ``ds``, and the
     narrow copies of ``p`` and ``ds``, with room for what Mosaic keeps
     beside them)."""
+    both = d + (d if dv is None else dv)    # q and g, k and v, dk and dv
     return (lq * d * (4 + 2 * itemsize)
-            + 4 * (block_q + 2 * block_k) * d * itemsize
-            + 2 * block_k * d * 4 + 8 * block_q * block_k * 4)
+            + 2 * (block_q + 2 * block_k) * both * itemsize
+            + block_k * both * 4 + 8 * block_q * block_k * 4)
 
 
 @functools.lru_cache(maxsize=64)
@@ -832,8 +860,7 @@ def _fa_bwd_kernel(pairs_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    block_q, d = q_ref.shape
-    block_k = k_ref.shape[0]
+    block_q, block_k = q_ref.shape[0], k_ref.shape[0]
     t = pl.program_id(1)
     ids = None if qseg_ref is None else (qseg_ref[...], kseg_ref[...])
     row = t if ids is None else table_row(pl.program_id(0), t)
@@ -922,15 +949,18 @@ def _fa_backward_pallas(q, k, v, o, lse, g, causal, sm_scale, mask=None):
     mask = _Mask.of(mask)
     seg = mask.seg
     with jax.named_scope(SCOPE_ATTENTION_BWD):
+        # q and k padded as the forward has them; dq and dk lose the pad
+        widths = q.shape[-1], k.shape[-1]
+        q, k = (_pad_width(x, _padded_width(x.shape[-1])) for x in (q, k))
         b, h, lq, d = q.shape
-        lk = k.shape[2]
+        lk, dv = k.shape[2], v.shape[-1]
         block_q, block_k = _fa_bwd_block_sizes(lq, lk)
         pairs = _fa_bwd_pairs(causal, mask.key, lq, lk, block_q, block_k)
         nq = lq // block_q
 
         delta = jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
         rows = lambda x: x.astype(jnp.float32).reshape(b * h, nq, 1, block_q)
-        flat = lambda x: x.reshape(b * h, x.shape[2], d)
+        flat = lambda x: x.reshape(b * h, *x.shape[2:])
 
         # the table's row of grid step (bh, t): row t, or under ids row t
         # of the sample's own table
@@ -941,12 +971,17 @@ def _fa_backward_pallas(q, k, v, o, lse, g, causal, sm_scale, mask=None):
                               (bh, pairs[0, at(bh, t)], 0))
         k_tile = pl.BlockSpec((None, block_k, d), lambda bh, t, pairs:
                               (bh, pairs[1, at(bh, t)], 0))
+        g_tile = pl.BlockSpec((None, block_q, dv), lambda bh, t, pairs:
+                              (bh, pairs[0, at(bh, t)], 0))
+        v_tile = pl.BlockSpec((None, block_k, dv), lambda bh, t, pairs:
+                              (bh, pairs[1, at(bh, t)], 0))
         q_row = pl.BlockSpec((None, None, 1, block_q), lambda bh, t, pairs:
                              (bh, pairs[0, at(bh, t)], 0, 0))
-        need = _fa_bwd_vmem_bytes(lq, d, q.dtype.itemsize, block_q, block_k)
+        need = _fa_bwd_vmem_bytes(lq, d, q.dtype.itemsize, block_q, block_k,
+                                  dv)
         static = dict(causal=causal, sm_scale=sm_scale, seq_q=lq, seq_k=lk,
                       mask=mask.key, table_row=at)
-        in_specs = [q_tile, k_tile, k_tile, q_tile, q_row, q_row]
+        in_specs = [q_tile, k_tile, v_tile, g_tile, q_row, q_row]
         operands = [flat(q), flat(k), flat(v), flat(g), rows(lse),
                     rows(delta)]
         if seg is None:
@@ -975,22 +1010,23 @@ def _fa_backward_pallas(q, k, v, o, lse, g, causal, sm_scale, mask=None):
                 in_specs=in_specs,
                 out_specs=[pl.BlockSpec((None, lq, d),
                                         lambda bh, t, pairs: (bh, 0, 0)),
-                           k_tile, k_tile],
+                           k_tile, v_tile],
                 scratch_shapes=[pltpu.VMEM((nq, d, block_q), jnp.float32),
                                 pltpu.VMEM((block_k, d), jnp.float32),
-                                pltpu.VMEM((block_k, d), jnp.float32)]),
+                                pltpu.VMEM((block_k, dv), jnp.float32)]),
             out_shape=[jax.ShapeDtypeStruct((b * h, lq, d), q.dtype),
                        jax.ShapeDtypeStruct((b * h, lk, d), k.dtype),
-                       jax.ShapeDtypeStruct((b * h, lk, d), v.dtype)],
+                       jax.ShapeDtypeStruct((b * h, lk, dv), v.dtype)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary"),
                 vmem_limit_bytes=max(need, _VMEM_DEFAULT_LIMIT)),
             name=_kernel_name(SCOPE_ATTENTION_BWD, mask),
         )(table, *operands)
-    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
+    return (dq.reshape(q.shape)[..., :widths[0]],
+            dk.reshape(k.shape)[..., :widths[1]], dv.reshape(v.shape))
 
 
-def _use_pallas_bwd(q, k):
+def _use_pallas_bwd(q, k, v=None):
     """Static gate for the Pallas backward: where ``_use_pallas`` takes the
     forward kernel, the inputs are bf16, both lengths divide into its tiles
     and a head's ``dq`` row fits VMEM beside the tiles.  float32 inputs take
@@ -999,14 +1035,15 @@ def _use_pallas_bwd(q, k):
     call at (16, 12, 512, 512, 64); PERF.md section 6, PR 27)."""
     import jax.numpy as jnp
 
-    lq, lk, d = q.shape[2], k.shape[2], q.shape[3]
+    lq, lk = q.shape[2], k.shape[2]
     block_q, block_k = _fa_bwd_block_sizes(lq, lk)
-    if not _use_pallas(q) or q.dtype != jnp.bfloat16:
+    if not _use_pallas(q, v) or q.dtype != jnp.bfloat16:
         return False
     if block_q is None or block_k is None:
         return False
-    return _fa_bwd_vmem_bytes(lq, d, q.dtype.itemsize, block_q,
-                              block_k) <= _VMEM_MOST
+    return _fa_bwd_vmem_bytes(
+        lq, _padded_width(q.shape[3]), q.dtype.itemsize, block_q, block_k,
+        None if v is None else v.shape[3]) <= _VMEM_MOST
 
 
 def _fa_backward(q, k, v, o, lse, g, causal, sm_scale, mask=None,
@@ -1019,7 +1056,7 @@ def _fa_backward(q, k, v, o, lse, g, causal, sm_scale, mask=None,
     from .. import telemetry
 
     mask = _Mask.of(mask)
-    pallas = _use_pallas_bwd(q, k)
+    pallas = _use_pallas_bwd(q, k, v)
     telemetry.counter(
         "mxnet_flash_attention_bwd_calls_total",
         "flash_attention backward calls traced, by the path they took",
@@ -1107,7 +1144,7 @@ def _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
 
         if live.all():
             kb = k.reshape(b, h, nkb, block_k, d)
-            vb = v.reshape(b, h, nkb, block_k, d)
+            vb = v.reshape(b, h, nkb, block_k, v.shape[3])
             q_pos = jnp.arange(lq)[:, None]
 
             def step(dq, idx):
@@ -1121,7 +1158,7 @@ def _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
             dq0 = jnp.zeros(q.shape, acc_t)
             dq, (dks, dvs) = jax.lax.scan(step, dq0, jnp.arange(nkb))
             dk = jnp.moveaxis(dks, 0, 2).reshape(b, h, lk, d)
-            dv = jnp.moveaxis(dvs, 0, 2).reshape(b, h, lk, d)
+            dv = jnp.moveaxis(dvs, 0, 2).reshape(v.shape)
         else:
             # K tile by K tile, so that a K tile's gradient is finished
             # before the next one's begins
@@ -1178,7 +1215,7 @@ def _make_flash(causal, sm_scale_key, mask=None, sharded=None):
         from .. import telemetry
 
         how = _Mask(mask, *operands)
-        pallas = _use_pallas(q)
+        pallas = _use_pallas(q, v)
         telemetry.counter(
             "mxnet_flash_attention_fwd_calls_total",
             "flash_attention forward calls traced, by the path they took "
@@ -1224,7 +1261,7 @@ def _count_pairs(q, k, causal, mask):
     if mask.key is not None:
         seen = jnp.minimum(seen, mask.key[1])
     walked = jnp.float32(b * lq * lk)
-    if _use_pallas(q):
+    if _use_pallas(q):   # under ids q, k and v are of one width
         block_q, block_k = _fa_block_sizes(lq, lk, q.shape[3],
                                            q.dtype.itemsize)
         lo, hi = _fa_fwd_bounds(_segment_tiles(
@@ -1237,7 +1274,10 @@ def _count_pairs(q, k, causal, mask):
 
 def flash_attention(q, k, v, causal=False, sm_scale=None, mask=None,
                     mask_block=0, window=0, segment_ids=None):
-    """q (B,Hq,Lq,D); k,v (B,Hkv,Lk,D) with Hq % Hkv == 0 (GQA).
+    """q (B,Hq,Lq,D); k (B,Hkv,Lk,D), v (B,Hkv,Lk,Dv) with Hq % Hkv == 0
+    (GQA).  ``Dv`` may differ from ``D`` (latent attention's 192 and 128):
+    the output is ``(B,Hq,Lq,Dv)``, and the kernels pad q and k to a width
+    they tile (``_padded_width``), never v.
 
     ``mask="block_diffusion"`` with ``mask_block`` the block length: the
     training mask of block diffusion over rows of a noised copy followed by
@@ -1259,6 +1299,13 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, mask=None,
     less."""
     import jax.numpy as jnp
 
+    if q.shape[-1] != k.shape[-1] or k.shape[:3] != v.shape[:3]:
+        from ..base import MXNetError
+
+        raise MXNetError(
+            "flash_attention: q and k share a head size, and k and v their "
+            f"heads and rows (v's head size is its own); got q {q.shape}, k "
+            f"{k.shape}, v {v.shape}")
     mask = _mask_key(mask, mask_block, causal, window)
     _check_mask_shape(mask, q.shape[2], k.shape[2])
     d = q.shape[-1]

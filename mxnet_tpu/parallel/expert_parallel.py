@@ -14,7 +14,8 @@ Capability upgrade over the reference (MXNet 1.x has no MoE).
   the result only.  The (token, expert) pairs that land here are sorted by
   expert and go through grouped matrix products (``expert_fn`` over rows
   and group sizes, ``jax.lax.ragged_dot`` inside it).  No pair is ever
-  dropped: the sorted rows are cut into parts of ``_PART_ROWS``, the groups
+  dropped: the sorted rows are cut into parts of ``_PART_ROWS`` (fewer for
+  a share that holds few of the experts: ``_PART_EVEN_LOADS``), the groups
   of a part are the pairs it holds and nothing else, and the layer walks
   the parts that hold a pair, ``ceil(pairs held / part)`` of them, a device
   number: one loop forward, and one loop backward that takes the ``jax.vjp``
@@ -45,7 +46,7 @@ from ..base import MXNetError
 from ..profiler import SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTE
 
 __all__ = ["moe_apply", "stack_expert_params", "inject_aux_loss",
-           "dispatch", "combine"]
+           "dispatch", "combine", "limit_to_groups"]
 
 # Rows of the sorted (token, expert) pairs that the dropless path computes at
 # once, the static bound on what one grouped product sees: what is live of
@@ -54,8 +55,19 @@ __all__ = ["moe_apply", "stack_expert_params", "inject_aux_loss",
 # number of parts; the walk takes the first ``ceil(pairs held / part)`` of
 # them.  The products and the gathers follow the pairs, the scatter-add is
 # of a whole part: a load a few pairs over a multiple of this pays one
-# granule's gathers and one part's scatter-add more.
+# granule's gathers and one part's scatter-add more.  A share that holds few
+# of the router's experts is sized by what it may see instead: a part holds
+# at most ``_PART_EVEN_LOADS`` times the pairs of an even load over the
+# experts (``tokens x top_k x held / experts``), so that the part's buffers
+# follow the share and not the whole router.  Eight, because that is what
+# ``_PART_ROWS`` already is for the thinnest share it was measured on (8 of
+# 128 experts at 8,192 tokens of 8: 4,096 pairs even), so no share that ran
+# before gets another part: every share walks one part up to eight times
+# its even load, and a share of 8 of 512 experts gets parts of 8,192 rows in
+# place of 32,768 (1.1 GiB less scratch in a step of six such layers at
+# hidden 2,560); a fuller share walks more parts and drops nothing.
 _PART_ROWS = 32768
+_PART_EVEN_LOADS = 8
 # Rows of a part that one step of its sorted walk gathers.  On a v5e a
 # step costs what its rows cost (16 gathers of 2,048 rows take what one of
 # 32,768 takes; 4,096 times the same in every load tried), so the granule is
@@ -75,7 +87,7 @@ def stack_expert_params(per_expert):
 def moe_apply(expert_fn, expert_params, router_weight, x, mesh=None,
               axis="ep", capacity_factor=1.25, top_k=1, renormalize=False,
               held=None, score="softmax", select_bias=None, scale=1.0,
-              renorm_eps=0.0):
+              renorm_eps=0.0, groups=None):
     """One MoE layer over tokens ``x (T, d)`` with ``router_weight (d, E)``.
 
     With a ``capacity_factor`` (switch top-1): ``expert_fn(params_one_expert,
@@ -92,6 +104,11 @@ def moe_apply(expert_fn, expert_params, router_weight, x, mesh=None,
     the scores plus ``select_bias (E,)`` where one is given, and the gates
     their scores (the bias moves the choice and never a gate), divided by
     their sum plus ``renorm_eps`` when ``renormalize``, times ``scale``.
+    ``groups = (n_group, topk_group)`` confines the choice (group-limited
+    routing): the router's outputs are ``n_group`` runs of ``E / n_group``,
+    a group's score is the sum of the two largest of its (biased) scores,
+    the ``topk_group`` best groups stay, and the ``top_k`` experts are the
+    largest among theirs (``limit_to_groups``).
     aux: ``routed_pairs`` (pairs computed
     here), ``walked_rows`` (rows that the sorted walks covered to gather
     them: whole granules), ``live_parts`` of ``parts`` (the parts of the
@@ -110,13 +127,14 @@ def moe_apply(expert_fn, expert_params, router_weight, x, mesh=None,
                              "'softmax', 'sigmoid'")
         return _moe_dropless(expert_fn, expert_params, router_weight, x,
                              int(top_k), bool(renormalize), held, score,
-                             select_bias, float(scale), float(renorm_eps))
+                             select_bias, float(scale), float(renorm_eps),
+                             groups)
     if top_k != 1 or held is not None or score != "softmax" \
-            or select_bias is not None or scale != 1.0:
+            or select_bias is not None or scale != 1.0 or groups is not None:
         raise MXNetError("a capacity_factor is the switch top-1 path over "
                          "every expert, softmax gates; top_k > 1, held=, "
-                         "score=, select_bias= and scale= route dropless "
-                         "(capacity_factor=None)")
+                         "score=, select_bias=, scale= and groups= route "
+                         "dropless (capacity_factor=None)")
     return _moe_switch(expert_fn, expert_params, router_weight, x, mesh, axis,
                        capacity_factor)
 
@@ -255,8 +273,28 @@ def combine(out, y, gates, order, n_live):
     return _walks()[1](out, y, gates, order, n_live)
 
 
+def limit_to_groups(scores, n_group, topk_group):
+    """``scores (T, E)`` with every output outside a token's ``topk_group``
+    best groups at ``-inf``: the groups are ``n_group`` runs of ``E /
+    n_group`` outputs, a group's score the sum of its two largest (its
+    largest where it holds one), ties to the lower group."""
+    import jax
+    import jax.numpy as jnp
+
+    T, E = scores.shape
+    if not (0 < topk_group <= n_group and E % n_group == 0):
+        raise MXNetError(f"groups ({n_group}, {topk_group}) do not fit a "
+                         f"router of {E} experts")
+    size = E // n_group
+    best = jax.lax.top_k(scores.reshape(T, n_group, size), min(2, size))[0]
+    _, kept = jax.lax.top_k(jnp.sum(best, axis=-1), topk_group)
+    stays = jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
+    return jnp.where(jnp.repeat(stays, size, axis=1), scores, -jnp.inf)
+
+
 def _moe_dropless(expert_fn, expert_params, router_weight, x, top_k,
-                  renormalize, held, score, select_bias, scale, renorm_eps):
+                  renormalize, held, score, select_bias, scale, renorm_eps,
+                  groups=None):
     import jax
     import jax.numpy as jnp
 
@@ -269,7 +307,9 @@ def _moe_dropless(expert_fn, expert_params, router_weight, x, top_k,
     pairs = T * top_k
     # a part is a whole number of granules
     granule = min(_GRANULE, pairs)
-    part = -(-min(_PART_ROWS, pairs) // granule) * granule
+    even = -(-pairs * count // E)
+    part = -(-min(_PART_ROWS, pairs, _PART_EVEN_LOADS * even)
+             // granule) * granule
     n_parts = -(-pairs // part)
 
     with jax.named_scope(SCOPE_MOE_ROUTE):
@@ -278,11 +318,14 @@ def _moe_dropless(expert_fn, expert_params, router_weight, x, top_k,
                          precision=jax.lax.Precision.HIGHEST)    # (T, E)
         scores = jax.nn.softmax(logits, axis=-1) if score == "softmax" \
             else jax.nn.sigmoid(logits)
-        if select_bias is None:
+        if select_bias is None and groups is None:
             gates, chosen = jax.lax.top_k(scores, top_k)
         else:
-            _, chosen = jax.lax.top_k(
-                scores + select_bias.astype(jnp.float32), top_k)
+            choice = scores if select_bias is None \
+                else scores + select_bias.astype(jnp.float32)
+            if groups is not None:
+                choice = limit_to_groups(choice, *groups)
+            _, chosen = jax.lax.top_k(choice, top_k)
             gates = jnp.take_along_axis(scores, chosen, axis=-1)
         if renormalize:
             norm = jnp.sum(gates, axis=-1, keepdims=True)

@@ -55,6 +55,17 @@ SCOPE_MOE_EXPERTS = "mx_moe_experts"
 # the shared expert beside them (model_zoo/language/llama.py::LlamaMoEMLP):
 # a dense SwiGLU that every token passes
 SCOPE_MOE_SHARED = "mx_moe_shared"
+# the mixers that are no softmax attention over per-head K and V
+# (model_zoo/language/llama.py): the chunked gated delta rule (ops/kda.py),
+# whose forward and hand-written backward are named inside it by
+# KERNEL_KDA_FWD / KERNEL_KDA_BWD (scopes of its passes, no parts of their
+# own); and what stands around a mixer's core: the short convolution with
+# its SiLU, the l2 norm of q and k, decay and beta, the gated norm of the
+# delta rule's output, latent attention's head-wise gate
+SCOPE_KDA = "mx_kda"
+SCOPE_MIXER_GATE = "mx_mixer_gate"
+KERNEL_KDA_FWD = "mxnet_kda_fwd"
+KERNEL_KDA_BWD = "mxnet_kda_bwd"
 # the transformer block's own parts (model_zoo/language/llama.py, bert.py;
 # the loss in parallel/data_parallel.py::TrainStep), entered at the call
 # sites one after the other, so that no op's own name holds two of them:

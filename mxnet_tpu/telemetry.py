@@ -429,6 +429,21 @@ MOE_LOAD_MAX_OVER_MEAN = histogram(
     buckets=exponential_buckets(1.0, 1.1, 30))
 
 
+MOE_GROUP_LIMITED_CALLS = counter(
+    "mxnet_moe_group_limited_calls_total",
+    "dropless expert layers traced whose choice of experts is confined to "
+    "the best groups of the router's outputs (moe_swiglu's n_group > 1), one "
+    "count a layer a trace")
+KDA_CALLS = counter(
+    "mxnet_kda_calls_total",
+    "kda (chunked gated delta rule) calls traced, by the path their state "
+    "pass took", ("path",))
+KDA_CHUNKS = counter(
+    "mxnet_kda_chunks_total",
+    "chunks of a call's rows that the traced kda calls walk, heads and "
+    "samples not counted: rows / chunk, one count a call a trace")
+
+
 ATTENTION_VISIBLE_PAIRS = counter(
     "mxnet_attention_visible_pairs_total",
     "(query, key) pairs that the mask and the segment ids show, over the "

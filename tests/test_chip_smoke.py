@@ -160,7 +160,7 @@ def test_sharded_step_runs_the_kernel_per_batch_shard(toy_bert, monkeypatch):
     from mxnet_tpu.parallel.mesh import make_mesh
 
     # what the gate answers where the default backend is the chip
-    monkeypatch.setattr(fa, "_use_pallas", lambda q: q.shape[-2] >= 256)
+    monkeypatch.setattr(fa, "_use_pallas", lambda q, v=None: q.shape[-2] >= 256)
     mesh = make_mesh(devices=jax.devices()[:4])
 
     step = TrainStep(toy_bert, chip_smoke.pretrain_loss, optimizer="adam",
@@ -199,7 +199,7 @@ def test_sharded_backward_runs_the_kernel_per_batch_shard(monkeypatch):
     from mxnet_tpu.ops import flash_attention as fa
     from mxnet_tpu.parallel.mesh import make_mesh
 
-    monkeypatch.setattr(fa, "_use_pallas", lambda q: q.shape[-2] >= 256)
+    monkeypatch.setattr(fa, "_use_pallas", lambda q, v=None: q.shape[-2] >= 256)
     mesh = make_mesh(devices=jax.devices()[:4])
     rs = np.random.RandomState(1)
     q, k, v, g = (jnp.asarray(rs.randn(4, 2, 256, 64).astype("f")).astype(

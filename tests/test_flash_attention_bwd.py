@@ -164,7 +164,7 @@ def test_the_gate_and_the_counter_of_the_path_taken(monkeypatch):
     assert (_calls("pallas"), _calls("blockwise")) == \
         (before[0], before[1] + 1)
     # what the forward's gate answers where the default backend is the chip
-    monkeypatch.setattr(fa, "_use_pallas", lambda q: q.shape[-2] >= 256)
+    monkeypatch.setattr(fa, "_use_pallas", lambda q, v=None: q.shape[-2] >= 256)
     x = lambda lq, dim=64, dtype="bfloat16": jax.ShapeDtypeStruct(
         (1, 2, lq, dim), dtype)
     assert fa._use_pallas_bwd(x(512), x(512))

@@ -532,7 +532,7 @@ def test_over_a_mesh_each_batch_shard_builds_its_own_table(monkeypatch):
 
     from mxnet_tpu.parallel.mesh import make_mesh
 
-    monkeypatch.setattr(fa, "_use_pallas", lambda q: q.shape[-2] >= 256)
+    monkeypatch.setattr(fa, "_use_pallas", lambda q, v=None: q.shape[-2] >= 256)
     mesh = make_mesh(devices=jax.devices()[:4])
     rs = np.random.RandomState(5)
     q, k, v, g = (jnp.asarray(rs.randn(4, 2, 512, 64).astype("f")).astype(
@@ -642,7 +642,7 @@ def test_walked_pairs_of_the_kernels_tiles(monkeypatch, documents, full,
                                            band):
     """Where the kernel runs, the walked pairs are those of the tiles its
     table of live tiles walks, summed on the device from the batch's ids."""
-    monkeypatch.setattr(fa, "_use_pallas", lambda q: True)
+    monkeypatch.setattr(fa, "_use_pallas", lambda q, v=None: True)
     q = jax.ShapeDtypeStruct((1, 32, 16384, 128), jnp.bfloat16)
     seg = segments_of([documents], "rising")
     for (causal, mask), tiles in ((_call(0, seg), full),

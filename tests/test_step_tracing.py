@@ -352,6 +352,37 @@ def toy_decoder():
     return net
 
 
+@pytest.fixture(autouse=True)
+def chunks_of_16(monkeypatch):
+    """``toy_hybrid``'s rows are 16 tokens: one chunk of the delta rule."""
+    from mxnet_tpu.gluon.model_zoo.language import llama
+
+    monkeypatch.setattr(llama, "KDA_CHUNK", 16)
+
+
+@pytest.fixture(scope="module")
+def toy_hybrid():
+    """The mixers that are no softmax attention over per-head K and V (PR
+    38): a dense delta-rule layer, then a latent-attention layer of routed
+    experts chosen inside groups, beside a shared one; two of four heads
+    held, per-layer recomputation."""
+    from mxnet_tpu.gluon.model_zoo.language import llama
+
+    mx.random.seed(0)
+    net = llama.LlamaForCausalLM(llama.LlamaConfig(
+        vocab_size=64, hidden_size=32, num_layers=2, num_heads=4,
+        num_kv_heads=4, head_dim=16, intermediate_size=32, num_experts=8,
+        moe_capacity_factor=None, moe_top_k=2, moe_groups=(4, 2),
+        num_dense_layers=1, attention_types=("kda", "mla"),
+        attention_gate="head_wise", attention_heads_held=(2, 2),
+        kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, rope_interleave=True,
+        moe_shared_intermediate_size=32, moe_intermediate_size=32,
+        remat=True))
+    net.initialize()
+    return net
+
+
 def _token_loss(logits, labels):
     import jax.numpy as jnp
 
@@ -365,11 +396,15 @@ def _decoder_batch():
             rng.integers(0, 64, (2, 16), dtype=np.int32))
 
 
-@pytest.fixture(params=["decoder", "bert"])
+@pytest.fixture(params=["decoder", "hybrid", "bert"])
 def net_of(request):
     """``(net, loss, batch, the parts it has)``."""
-    if request.param == "decoder":
+    if request.param == "decoder":   # softmax attention alone
         return (request.getfixturevalue("toy_decoder"), _token_loss,
+                _decoder_batch(), set(PART_SCOPES) - {
+                    profiler.SCOPE_KDA, profiler.SCOPE_MIXER_GATE})
+    if request.param == "hybrid":
+        return (request.getfixturevalue("toy_hybrid"), _token_loss,
                 _decoder_batch(), set(PART_SCOPES))
     # the encoder has no RoPE and no experts; its toy runs under the gate
     return (request.getfixturevalue("toy_bert"), _loss, _batch(), {

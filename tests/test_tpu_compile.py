@@ -318,7 +318,7 @@ def test_the_steps_table_resolves_the_qk_kernels_to_their_part(one_chip,
     from mxnet_tpu.ops import qk_norm_rope as qnr
     from mxnet_tpu.parallel.data_parallel import TrainStep
 
-    monkeypatch.setattr(fa, "_use_pallas", lambda q: True)
+    monkeypatch.setattr(fa, "_use_pallas", lambda q, v=None: True)
     monkeypatch.setattr(qnr, "_use_pallas", lambda x, hd: True)
     net = llama.LlamaForCausalLM(llama.LlamaConfig(
         vocab_size=512, hidden_size=256, num_layers=2, num_heads=2,
@@ -509,3 +509,52 @@ def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
             assert profiler._scope_classes([scope]) == [want], (name, scope)
             assert want in table[name]["classes"], name
     assert sum("transpose(" in own for own, _, _ in loops) == int(backward)
+
+
+# --------------------------------------------------------------------------
+# the Ling cell's mixers (PR 38): the chunked delta rule, forward and
+# backward (the triangular solve, the scans, the decays a sub-block), and
+# both attention kernels at q/k 192 beside v 128, each at the cell's shape
+# --------------------------------------------------------------------------
+@pytest.mark.parametrize("backward", [False, True])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_kda_compiles_for_v5e(one_chip, dtype, backward):
+    from mxnet_tpu.ops import kda
+
+    wide = jax.ShapeDtypeStruct((1, 8, 8192, 128), dtype, sharding=one_chip)
+    decay = jax.ShapeDtypeStruct((1, 8, 8192, 128), "float32",
+                                 sharding=one_chip)
+    beta = jax.ShapeDtypeStruct((1, 8, 8192), dtype, sharding=one_chip)
+    fn = kda.kda
+    if backward:
+        fn = jax.grad(lambda *a: jnp.sum(kda.kda(*a).astype(jnp.float32)),
+                      argnums=(0, 1, 2, 3, 4))
+    compiled = jax.jit(fn).lower(wide, wide, wide, decay, beta).compile()
+    text = compiled.as_text()
+    assert "mxnet_kda_fwd" in text and ("mxnet_kda_bwd" in text) == backward
+    # what the op needs beside its operands stays a fraction of a GiB
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_latent_attention_kernels_compile_for_v5e(one_chip):
+    """q and k of 192 go in padded to 256, v and the output stay 128; the
+    backward hands back gradients of 192."""
+    from mxnet_tpu.ops.flash_attention import (_fa_backward_pallas,
+                                               _fa_forward_pallas)
+
+    scale = 1.0 / 192 ** 0.5
+    qk = jax.ShapeDtypeStruct((1, 8, 8192, 192), "bfloat16",
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 8, 8192, 128), "bfloat16",
+                             sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((1, 8, 8192), "float32", sharding=one_chip)
+    fwd = jax.jit(functools.partial(_fa_forward_pallas, causal=True,
+                                    sm_scale=scale)).lower(qk, qk, v)
+    assert [x.shape for x in fwd.out_info] == [(1, 8, 8192, 128),
+                                               (1, 8, 8192)]
+    assert "mxnet_flash_attention_fwd" in fwd.compile().as_text()
+    bwd = jax.jit(functools.partial(_fa_backward_pallas, causal=True,
+                                    sm_scale=scale)).lower(qk, qk, v, v, lse,
+                                                           v)
+    assert [x.shape for x in bwd.out_info] == [qk.shape, qk.shape, v.shape]
+    assert "mxnet_flash_attention_bwd" in bwd.compile().as_text()

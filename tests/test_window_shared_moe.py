@@ -144,7 +144,7 @@ def test_flash_attention_op_takes_the_window_with_gqa(monkeypatch, path):
     counted by path and mask."""
     from jax.experimental.pallas import tpu as pltpu
 
-    monkeypatch.setattr(fa, "_use_pallas_bwd", lambda q, k: path == "pallas")
+    monkeypatch.setattr(fa, "_use_pallas_bwd", lambda q, k, v=None: path == "pallas")
     rs = np.random.RandomState(3)
     q, g = (jnp.asarray(rs.randn(2, 4, 256, 64).astype("f")) for _ in "qg")
     k, v = (jnp.asarray(rs.randn(2, 2, 256, 64).astype("f")) for _ in "kv")
