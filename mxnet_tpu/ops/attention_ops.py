@@ -240,10 +240,11 @@ def moe_swiglu(x, router_weight, gate_proj, up_proj, down_proj,
     and the bias are exempt from the cast, contrib/amp/lists.py).  The
     layer's routed pairs, the rows and the parts its routing walked and its
     load imbalance go to ``telemetry.step_scalar``."""
-    from jax import lax, nn
+    from jax import nn
 
     from .. import telemetry
     from ..parallel.expert_parallel import inject_aux_loss, moe_apply
+    from .grouped_matmul import group_plan, grouped_dot, swiglu as gated
 
     capacity_factor = float(capacity_factor)
     aux_loss_weight = float(aux_loss_weight)
@@ -253,18 +254,15 @@ def moe_swiglu(x, router_weight, gate_proj, up_proj, down_proj,
 
     if capacity_factor <= 0:
         def grouped_fn(p, rows, sizes):
-            jnp = _jnp()
             rows = rows.astype(p["g"].dtype)
-            # float32 operands follow the process's matmul precision;
-            # narrower ones are one exact MXU pass (the TPU's grouped
-            # product refuses them a float32 contraction, as the attention
-            # kernel does)
-            dot = functools.partial(
-                lax.ragged_dot, group_sizes=sizes,
-                precision=None if rows.dtype == jnp.float32
-                else lax.Precision.DEFAULT)
-            hidden = nn.silu(dot(rows, p["g"])) * dot(rows, p["u"])
-            return dot(hidden, p["d"])
+            # the kernels' walk of the part, made once for its three
+            # products, their transposes and SwiGLU between them; None
+            # where the gate sends the products to ``lax.ragged_dot`` and
+            # SwiGLU to ``nn.silu`` (``ops/grouped_matmul.py``)
+            plan = group_plan(sizes, rows, p["g"])
+            dot = functools.partial(grouped_dot, sizes=sizes, plan=plan)
+            return dot(gated(dot(rows, p["g"]), dot(rows, p["u"]), plan),
+                       p["d"])
 
         out, aux = moe_apply(
             grouped_fn, params, router_weight, toks, capacity_factor=None,

@@ -1,4 +1,5 @@
-"""The Pallas kernels (attention forward and backward, q/k norm and RoPE) and
+"""The Pallas kernels (attention forward and backward, q/k norm and RoPE, the
+experts' grouped products) and
 the expert layer, compiled at real widths for a
 TPU v5e that is described and not attached: what the chip's compiler refuses
 (an operand type, a slice off the tiling, too much VMEM) the TPU interpreter
@@ -366,7 +367,7 @@ def _gathered_and_scattered(text):
     return gathered, scattered
 
 
-# the two decoder cells' expert layers: rows, hidden, the router's width, the
+# the four decoder cells' expert layers: rows, hidden, the router's width, the
 # experts held and their width, experts a token, and how the router scores
 EXPERT_LAYERS = {
     # 16,384 rows choose 8 of 128 by softmax, 16 of width 768 held: four
@@ -376,6 +377,14 @@ EXPERT_LAYERS = {
     # held: two parts, a quarter of one filled
     "window": (8192, 2048, 128, 8, 1024, 8,
                {"score": "sigmoid", "scale": 2.826, "renorm_eps": 1e-20}),
+    # 16,384 packed rows choose 8 of 64 by softmax, 8 of width 896 held:
+    # widths of 18 and 7 lane tiles, no multiples of 256
+    "packed": (16384, 2304, 64, 8, 896, 8, {}),
+    # 8,192 rows choose 8 of 512 within 4 of 8 groups, 8 of width 768 held:
+    # the thin share's parts of 8,192 rows (``_PART_EVEN_LOADS``)
+    "ling": (8192, 2560, 512, 8, 768, 8,
+             {"score": "sigmoid", "scale": 2.5, "renorm_eps": 1e-20,
+              "groups": (8, 4)}),
 }
 
 
@@ -407,8 +416,10 @@ def _loops_over_parts(text):
 @pytest.mark.parametrize("backward", [False, True])
 @pytest.mark.parametrize("cell", list(EXPERT_LAYERS))
 def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
-        one_chip, cell, backward):
-    """Both decoder cells' expert layers, the experts held in bf16, under a
+        one_chip, monkeypatch, cell, backward):
+    """The four decoder cells' expert layers as ``moe_swiglu`` runs them, the
+    experts held in bf16 and their grouped products the Pallas kernels of
+    ``ops/grouped_matmul.py`` at whole widths (2304 x 896 too), under a
     layer's checkpoint and the step's forward scope: loops with a traced
     trip count (over the parts that hold a pair, and inside them the walks
     of ``dispatch`` and ``combine``, custom VJPs all) are the chip's
@@ -427,35 +438,40 @@ def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
     program named (the counter and the bound's compare, which part, the
     accumulators' adds, the walks, the products' element-wise ops) is under
     exactly one of ``mx_moe_route`` and ``mx_moe_experts``, the grouped
-    products under XLA's own name, and the backward loop's are of the class
+    products' kernels too, and the backward loop's are of the class
     ``backward`` by their own names; the ``while`` instruction itself carries its caller's
     scope (one around it would name its body's products too), and what the
     compiler adds without a name (copies, a buffer's fill sunk into the
     body) carries none."""
     from mxnet_tpu import profiler
-    from mxnet_tpu.parallel.expert_parallel import (_GRANULE, _PART_ROWS,
-                                                    moe_apply)
+    from mxnet_tpu.ops import grouped_matmul as gm
+    from mxnet_tpu.ops.attention_ops import moe_swiglu
+    from mxnet_tpu.parallel.expert_parallel import (_GRANULE,
+                                                    _PART_EVEN_LOADS,
+                                                    _PART_ROWS)
 
     tokens, hidden, experts, held, width, top_k, scoring = EXPERT_LAYERS[cell]
-
-    def grouped(p, rows, sizes):
-        rows = rows.astype(p["g"].dtype)
-        dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
-                                precision=jax.lax.Precision.DEFAULT)
-        return dot(jax.nn.silu(dot(rows, p["g"])) * dot(rows, p["u"]),
-                   p["d"])
+    scoring = dict(scoring)
+    n_group, topk_group = scoring.pop("groups", (1, 1))
+    part = min(_PART_ROWS, tokens * top_k,
+               _PART_EVEN_LOADS * tokens * top_k * held // experts)
+    # the layer as the decoders call it, its own grouped products: with a
+    # TPU as JAX's backend the gate sends them to the kernels
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     @jax.checkpoint
     def layer(x, router, p, bias):
-        out, aux = moe_apply(grouped, p, router, x, capacity_factor=None,
-                             top_k=top_k, renormalize=True, held=(16, held),
-                             select_bias=bias, **scoring)
-        return out, aux["walked_rows"]
+        return moe_swiglu(
+            x[None], router, p["g"], p["u"], p["d"], bias, capacity_factor=0,
+            top_k=top_k, renormalize=True, experts_first=16,
+            score=scoring.get("score", "softmax"),
+            route_scale=scoring.get("scale", 1.0),
+            renorm_eps=scoring.get("renorm_eps", 0.0), n_group=n_group,
+            topk_group=topk_group)[0]
 
     @jax.named_scope(profiler.SCOPE_FORWARD)
     def loss(x, router, p, bias):
-        out, walked = layer(x, router, p, bias)
-        return jnp.sum(jnp.sin(out)), walked
+        return jnp.sum(jnp.sin(layer(x, router, p, bias)))
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -466,15 +482,14 @@ def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
              "u": spec((held, hidden, width), "bfloat16"),
              "d": spec((held, width, hidden), "bfloat16")},
             spec((experts,), "float32") if scoring else None)
-    fn = jax.value_and_grad(loss, (0, 1, 2), has_aux=True) if backward \
-        else loss
+    fn = jax.value_and_grad(loss, (0, 1, 2)) if backward else loss
     compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
     gathered, scattered = _gathered_and_scattered(text)
     # besides the whole part's, the router's top-k scatters a token's 8
     # gates back and a granule's gates' gradients go to their pairs:
     # scalars both
-    whole = [shape for shape in scattered if shape == (_PART_ROWS, hidden)]
+    whole = [shape for shape in scattered if shape == (part, hidden)]
     assert len(whole) == (2 if backward else 1)
     assert all(hidden not in shape[1:] for shape in scattered
                if shape not in whole)
@@ -482,8 +497,8 @@ def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
     # rows and a granule's gates
     rows = [shape[0] for shape in gathered if shape[-1] == hidden]
     assert rows.count(_GRANULE) >= (3 if backward else 1)
-    assert rows.count(_PART_ROWS) == len(whole)
-    assert set(rows) == {_GRANULE, _PART_ROWS}
+    assert rows.count(part) == len(whole)
+    assert set(rows) == {_GRANULE, part}
     assert all(tokens * top_k not in shape for shape in gathered)
 
     loops = _loops_over_parts(text)
@@ -500,8 +515,6 @@ def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
                  not in ("constant", "get-tuple-element")]
         assert len(named) > 20
         for name, scope in named:
-            if scope.startswith("ragged-dot"):
-                continue
             assert (profiler.SCOPE_MOE_ROUTE in scope) \
                 != (profiler.SCOPE_MOE_EXPERTS in scope), (name, scope)
             # (a fusion may take in an op that the forward named as well)
@@ -509,6 +522,32 @@ def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
             assert profiler._scope_classes([scope]) == [want], (name, scope)
             assert want in table[name]["classes"], name
     assert sum("transpose(" in own for own, _, _ in loops) == int(backward)
+    # the loops hold the kernels' custom calls, XLA's grouped product
+    # nowhere: three products forward; backward those again, their three
+    # transposes and the three weights' gradients.  Each is a row of the
+    # experts' part whose scope holds ``ragged_dot``, which is how the
+    # benchmark's readers find a grouped product
+    assert "ragged-dot" not in text
+    kernels = {name: row for name, row in table.items()
+               if name.startswith(gm.KERNEL)}
+    assert len(kernels) == (12 if backward else 3)
+    in_loops = {name for _, _, body in loops for name, _, _ in body}
+    for name, row in kernels.items():
+        assert name in in_loops, name
+        assert row["part"] == profiler.SCOPE_MOE_EXPERTS, (name, row)
+        assert "ragged_dot" in row["scope"], (name, row)
+    names = sorted(name.split(".", 1)[0] for name in kernels)
+    assert names == sorted(
+        [gm.KERNEL] * (6 if backward else 3)
+        + [gm.KERNEL_TRANSPOSED, gm.KERNEL_DWEIGHTS] * (3 * backward))
+    # SwiGLU between them, over the live row tiles: forward, and backward
+    # the forward again and its gradient
+    glu = sorted(name.split(".", 1)[0] for name, row in table.items()
+                 if name.startswith(gm.KERNEL_SWIGLU)
+                 and row["part"] == profiler.SCOPE_MOE_EXPERTS
+                 and name in in_loops)
+    assert glu == [gm.KERNEL_SWIGLU] * (1 + backward) \
+        + [gm.KERNEL_SWIGLU_BWD] * backward
 
 
 # --------------------------------------------------------------------------
