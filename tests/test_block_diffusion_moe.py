@@ -6,8 +6,6 @@ worst imbalance loses nothing); and the program's loss and gradients against
 the configuration's plain reference at a small size of the same shape of
 layer.  All on the CPU, seeded random weights."""
 import functools
-import json
-import os
 
 import jax
 import jax.numpy as jnp
@@ -15,13 +13,13 @@ import numpy as np
 import pytest
 
 import mxnet_tpu as mx
-from mxnet_tpu import nd, telemetry
+from mxnet_tpu import nd
 from mxnet_tpu.ops import flash_attention as fa
 from mxnet_tpu.parallel import expert_parallel
 from mxnet_tpu.parallel.expert_parallel import (_PART_ROWS, combine, dispatch,
                                                 moe_apply)
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+import decoder_parity as parity
 
 
 # --------------------------------------------------------------------------
@@ -121,8 +119,9 @@ def test_masked_attention_backward_matches_dense_mask(length, block, block_q,
                   for _ in range(4))
     mask = (fa.BLOCK_DIFFUSION, block)
     seen = dense_mask(length, block)
-    want = jax.grad(lambda *a: jnp.sum(dense_attention(*a, seen, 0.2) * g),
-                    (0, 1, 2))(q, k, v)
+    want = jax.jit(jax.grad(
+        lambda *a: jnp.sum(dense_attention(*a, seen, 0.2) * g),
+        (0, 1, 2)))(q, k, v)
     o, lse = fa._mha_with_lse(q, k, v, False, 0.2, mask)
     for bq, bk in ((block_q or n, block_k or n), (n, block_k or n)):
         if n // bq > 1:
@@ -139,8 +138,9 @@ def test_causal_backward_skips_dead_tiles_and_matches():
     q, k, v, g = (jnp.asarray(rs.randn(1, 2, 256, 32).astype("f"))
                   for _ in range(4))
     seen = np.tril(np.ones((256, 256), bool))
-    want = jax.grad(lambda *a: jnp.sum(dense_attention(*a, seen, 0.2) * g),
-                    (0, 1, 2))(q, k, v)
+    want = jax.jit(jax.grad(
+        lambda *a: jnp.sum(dense_attention(*a, seen, 0.2) * g),
+        (0, 1, 2)))(q, k, v)
     o, lse = fa._mha_with_lse(q, k, v, True, 0.2)
     assert not fa._live_tiles(True, None, 256, 256, 64, 64).all()
     got = fa._fa_backward_blockwise(q, k, v, o, lse, g, True, 0.2,
@@ -625,25 +625,6 @@ def test_amp_keeps_the_router_float32_and_feeds_the_experts_bf16():
 # --------------------------------------------------------------------------
 # the decoder by configuration, against the configuration's reference
 # --------------------------------------------------------------------------
-def _small_sdar():
-    """The benchmark's configuration at a small size of the same shape of
-    layer: top-2 of 8 experts with 4 held (the second share), block length
-    4, L = 32, GQA 4 over 2, q/k norm, two layers."""
-    from chipbench.harness.cell import ROOT as BENCH_ROOT, _module
-
-    with open(os.path.join(ROOT, "chipbench", "configs", "sdar_30b_a3b",
-                           "config.json")) as f:
-        cfg = json.load(f)
-    cfg.update(vocab_size=96, hidden_size=64, num_hidden_layers=2,
-               num_attention_heads=4, num_key_value_heads=2, head_dim=16,
-               moe_intermediate_size=32, num_experts=4, router_width=8,
-               num_experts_per_tok=2, experts_first=4)
-    cfg["assumed"] = dict(cfg["assumed"], mask_token_id=95)
-    mods = [_module(BENCH_ROOT, "configs", "sdar_30b_a3b", name)
-            for name in ("build", "reference")]
-    return (cfg, *mods, _module(BENCH_ROOT, "drivers", "fused_step"))
-
-
 # float32 against float32: the gap is the order of the sums (the program sorts
 # pairs by expert, the reference runs every expert on every token): a few
 # 1e-7 measured, 1e-5 allowed
@@ -667,35 +648,13 @@ BF16_GAPS = {"loss_gap": 2e-3, "first_gradient_gap": 0.03,
 ])
 def test_program_matches_the_reference_loss_and_every_gradient(
         monkeypatch, amp, part_rows, tolerance):
-    from chipbench.harness import check, loop
-
     if part_rows:
         monkeypatch.setattr(expert_parallel, "_GRANULE", 16)
         monkeypatch.setattr(expert_parallel, "_PART_ROWS", part_rows)
-    cfg, build, reference, driver = _small_sdar()
-    spec = {"batch": 2, "seq": 32, "optimizer": "adam", "amp_dtype": amp,
-            "optimizer_params": {"learning_rate": 1e-6}}
-    telemetry.reset()
-    runner = driver.Runner(spec, cfg, build, reference.init_params(cfg, 5))
-    pool = loop.make_pool(build, cfg, spec, 5)
-    feed = loop.open_feed(pool)
-    try:
-        got = loop.first_steps(runner, feed, 2)
-    finally:
-        feed.close()
-    ref = check.follow(reference, cfg, "float32",
-                       reference.init_params(cfg, 5), pool[:2], spec)
-    assert set(got["first_gradient"]) == set(reference.param_shapes(cfg))
-    stats = check.compare(got, ref)
-    for name, (value, where) in stats.items():
-        assert value <= tolerance[name], (name, value, where)
     # every leaf got a gradient of its own, the router's and the norms' too
-    for leaf, g in got["first_gradient"].items():
-        assert np.abs(g).max() > 0, leaf
-
+    _, metrics = parity.matches("sdar_30b_a3b", amp, tolerance)
     # the layers' device scalars left the steps beside the loss: 2 steps x 2
     # layers, about tokens * k * held / width pairs each
-    metrics = telemetry.snapshot()["metrics"]
     pairs = metrics["mxnet_moe_routed_pairs_total"]["samples"][0]["value"]
     load = metrics["mxnet_moe_expert_load_max_over_mean"]["samples"][0]
     assert load["count"] == 4 and load["sum"] / 4 >= 1.0
@@ -723,7 +682,7 @@ def test_remat_is_real_in_the_fused_step_and_scopes_are_in_its_table():
     from mxnet_tpu import profiler
     from mxnet_tpu.parallel.data_parallel import TrainStep
 
-    cfg, build, reference, _ = _small_sdar()
+    cfg, build, _, _ = parity.small("sdar_30b_a3b")
     net = build.build_net(cfg, mx.current_context())
     step = TrainStep(net, build.step_loss, optimizer="adam",
                      optimizer_params={"learning_rate": 1e-6})
@@ -741,13 +700,9 @@ def test_remat_is_real_in_the_fused_step_and_scopes_are_in_its_table():
 
 def test_counts_of_the_configuration():
     """``counts.py`` against the mask and a hand value."""
-    from chipbench.configs.sdar_30b_a3b import counts
-
+    cfg, counts = parity.published("sdar_30b_a3b")
     assert counts.visible_pairs(32, 4) == dense_mask(32, 4).sum()
     assert counts.visible_pairs(4096, 4) == 16_793_600   # a quarter of 8192^2
-    with open(os.path.join(ROOT, "chipbench", "configs", "sdar_30b_a3b",
-                           "config.json")) as f:
-        cfg = json.load(f)
     assert counts.expected_pairs_per_token(cfg) == 1.0
     assert counts.routed_pair_fwd_flops(cfg) == 6 * 2048 * 768
     # the issue's hand values for one sample's forward, TFLOP

@@ -39,7 +39,7 @@ def dense_grads(q, k, v, g, causal, mask, scale):
                                   v) * g)
 
     with jax.default_matmul_precision("highest"):
-        return jax.grad(attend, (0, 1, 2))(q, k, v)
+        return jax.jit(jax.grad(attend, (0, 1, 2)))(q, k, v)
 
 
 def inputs(lq, lk, dim, dtype, seed=0):

@@ -450,20 +450,26 @@ def test_chaos_dispatch_seam_transient_is_retried():
 
 def test_chaos_dispatch_seam_exhaustion_fails_over():
     """Trips past the retry budget exhaust the attempt; the failover
-    requeue hands the request to the other replica."""
+    requeue hands the request to the other replica.  What is read is the
+    order of events (one failed attempt on the home, then the other serves),
+    with the probe thread out of the way: a probe's success between the
+    failure and the lines below makes the home healthy again and zeroes its
+    failures, which at a probe every 20 ms it did under six xdist workers."""
     prompt = [9, 9, 1]
     ids = ["r1", "r2"]
     home = rendezvous_order(prefix_key(prompt), sorted(ids))[0]
     other = [r for r in ids if r != home][0]
     reps = {r: FakeReplica(r) for r in ids}
-    router = mk_router([reps["r1"], reps["r2"]], retry_budget=0).start()
+    router = mk_router([reps["r1"], reps["r2"]], retry_budget=0,
+                       probe_interval_ms=3_600_000).start()
     try:
         with fault.inject("router.dispatch", error=ConnectionError,
                           times=1):
-            req = router.submit(prompt, deadline_ms=10_000)
-            res = req.response(timeout=10)
-        assert res["rid"] == other
-        assert reps[home].health.consecutive_failures >= 1
+            req = router.submit(prompt, deadline_ms=120_000)
+            res = req.response(timeout=120)
+        assert res["rid"] == other and req.attempts == 2
+        assert reps[home].served == [] and reps[other].served == [req.id]
+        assert reps[home].health.consecutive_failures == 1
     finally:
         router.close()
 

@@ -17,9 +17,14 @@ from mxnet_tpu.ops import grouped_matmul as gm
 
 ROWS = 768      # three row tiles of 256
 
-# the four decoder cells' expert layers: hidden, width, experts held
+# the four decoder cells' expert layers: hidden, width, experts held; and the
+# narrowest the gate lets through.  At every one of them the kernels' blocks
+# hold the whole widths, so which rows a visit reads and writes (what the
+# layouts are about) is the same walk at a lane tile as at a cell's widths,
+# and the interpreter's time goes with rows x widths
 CELLS = {"block diffusion": (2048, 768, 16), "packed": (2304, 896, 8),
-         "window": (2048, 1024, 8), "ling": (2560, 768, 8)}
+         "window": (2048, 1024, 8), "ling": (2560, 768, 8),
+         "a lane tile": (128, 128, 8)}
 
 
 def layout(name, held):
@@ -65,12 +70,12 @@ def operands(k, n, held, sizes, dtype, seed=0):
 
 
 @pytest.mark.parametrize("cell,rows_layout", [
-    # every layout at the widths that are no multiple of 256, and each other
-    # cell's widths at the layout with most in it (the interpreter is slow)
-    ("packed", "empty groups"), ("packed", "boundaries inside tiles"),
-    ("packed", "one group"), ("packed", "a fraction of the rows"),
-    ("block diffusion", "empty groups"), ("window", "empty groups"),
-    ("ling", "empty groups")])
+    # every layout at a lane tile of width, and each cell's own widths (the
+    # packed cell's are no multiple of 256) at the layout with most in it
+    ("a lane tile", "empty groups"), ("a lane tile", "boundaries inside tiles"),
+    ("a lane tile", "one group"), ("a lane tile", "a fraction of the rows"),
+    ("packed", "empty groups"), ("block diffusion", "empty groups"),
+    ("window", "empty groups"), ("ling", "empty groups")])
 def test_kernels_interpreted_match_ragged_dot_and_its_vjp(monkeypatch, cell,
                                                           rows_layout):
     """Forward, the rows' gradient and the weights', bf16: each within the

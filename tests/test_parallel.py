@@ -235,7 +235,8 @@ def test_pipeline_parallel_matches_sequential():
             h = stage_fn(p, h)
         return h.sum()
 
-    g_pp = jax.grad(loss_pp)(stacked)
+    # (jitted: an eager gradient of the shard_map runs the schedule op by op)
+    g_pp = jax.jit(jax.grad(loss_pp))(stacked)
     g_seq = stack_stage_params(jax.grad(loss_seq)(per_stage))
     for k in ("w", "b"):
         assert float(jnp.abs(g_pp[k] - g_seq[k]).max()) < 1e-4, k
@@ -389,7 +390,7 @@ def test_pipeline_nan_safe_backward():
             h = stage_fn(p, h)
         return h.sum()
 
-    g_pp = jax.grad(loss_pp)(stacked)
+    g_pp = jax.jit(jax.grad(loss_pp))(stacked)
     assert np.isfinite(np.asarray(g_pp["w"])).all()
     g_seq = stack_stage_params(jax.grad(loss_seq)(per))
     assert float(jnp.abs(g_pp["w"] - g_seq["w"]).max()) < 1e-4
@@ -478,8 +479,8 @@ def test_ulysses_attention_matches_reference_and_ring():
         ring = context_parallel_attention(q, k, v, mesh, causal=causal)
         assert float(jnp.abs(o - ring).max()) < 1e-4
 
-    g = jax.grad(lambda qq: (ulysses_context_parallel_attention(
-        qq, k, v, mesh, causal=True) ** 2).sum())(q)
+    g = jax.jit(jax.grad(lambda qq: (ulysses_context_parallel_attention(
+        qq, k, v, mesh, causal=True) ** 2).sum()))(q)
     gref = jax.grad(lambda qq: (_mha_reference(
         qq, k, v, True, 1.0 / np.sqrt(16)) ** 2).sum())(q)
     assert float(jnp.abs(g - gref).max()) < 1e-3

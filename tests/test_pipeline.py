@@ -45,27 +45,31 @@ def test_pipeline_schedule_grads_match_sequential(S, M, schedule):
 
     def loss(st, xx):
         y = pipeline_apply(_stage_fn, st, xx, mesh, M, schedule=schedule)
-        return (y * y).sum()
+        return (y * y).sum(), y
 
     def loss_seq(pl, xx):
         h = xx
         for i in range(S):
             h = _stage_fn(pl[i], h)
-        return (h * h).sum()
+        return (h * h).sum(), h
 
-    y = pipeline_apply(_stage_fn, stacked, x, mesh, M, schedule=schedule)
-    ref = x
-    for i in range(S):
-        ref = _stage_fn(per[i], ref)
+    # one compiled program a schedule for the output and both gradients (the
+    # pipeline's three used to be traced and compiled one after the other,
+    # 20 to 40 s a case), and one for the pipeline outside any gradient,
+    # which for 1F1B is another function than its custom_vjp's forward
+    def both(fn, *args):
+        (_, y), grads = jax.jit(jax.value_and_grad(
+            fn, (0, 1), has_aux=True))(*args)
+        return y, grads
+
+    y, (g, gx) = both(loss, stacked, x)
+    ref, (g_seq, gx_seq) = both(loss_seq, per, x)
+    plain = jax.jit(lambda st, xx: loss(st, xx)[1])(stacked, x)
     assert float(jnp.abs(y - ref).max()) < 1e-5
-
-    g = jax.grad(loss)(stacked, x)
-    g_seq = jax.grad(loss_seq)(per, x)
+    assert float(jnp.abs(plain - ref).max()) < 1e-5
     for k in ("w", "b"):
         seq = jnp.stack([g_seq[i][k] for i in range(S)])
         assert float(jnp.abs(g[k] - seq).max()) < 1e-4, k
-    gx = jax.grad(lambda xx: loss(stacked, xx))(x)
-    gx_seq = jax.grad(lambda xx: loss_seq(per, xx))(x)
     assert float(jnp.abs(gx - gx_seq).max()) < 1e-4
 
 

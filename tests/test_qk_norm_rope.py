@@ -58,14 +58,21 @@ def as_on_a_tpu(monkeypatch):
 
 
 def grads(fn, x, g, gamma, positions, heads, turn):
+    """``(out, dx[, dgamma])`` of ``fn`` as one jitted program (eagerly the
+    forward and the gradient each trace, compile and run op by op: two
+    thirds of a case's seconds)."""
     def loss(x, gamma):
         return jnp.sum((fn(x, gamma, positions, heads, turn) * g).astype(
             jnp.float32))
 
-    if gamma is None:
-        return fn(x, None, positions, heads, turn), jax.grad(loss)(x, None)
-    return (fn(x, gamma, positions, heads, turn),
-            *jax.grad(loss, (0, 1))(x, gamma))
+    @jax.jit
+    def both(x, gamma):
+        out = fn(x, gamma, positions, heads, turn)
+        if gamma is None:
+            return out, jax.grad(loss)(x, None)
+        return (out, *jax.grad(loss, (0, 1))(x, gamma))
+
+    return both(x, gamma)
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])

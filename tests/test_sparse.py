@@ -366,11 +366,14 @@ def test_fm_example_kvstore_mode_matches_local_trajectory():
     fm = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(fm)
 
-    kw = dict(num_features=400, rank=4, batch_size=32, steps=12, lr=0.5,
+    # five steps: every step's batch touches another number of rows, which is
+    # another shape for every eager op of the step to compile (6 s a step of
+    # the 12 this ran), and a wrong update shows from the second loss on
+    kw = dict(num_features=400, rank=4, batch_size=32, steps=5, lr=0.5,
               density=0.02, log_every=0, seed=7)
     local = fm.run(use_kvstore=False, **kw)
     kvs = fm.run(use_kvstore=True, **kw)
-    assert len(local) == len(kvs) == 12
+    assert len(local) == len(kvs) == 5
     np.testing.assert_allclose(kvs, local, rtol=2e-3, atol=2e-4)
 
 

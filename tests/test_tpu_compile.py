@@ -1,11 +1,22 @@
 """The Pallas kernels (attention forward and backward, q/k norm and RoPE, the
-experts' grouped products) and
-the expert layer, compiled at real widths for a
-TPU v5e that is described and not attached: what the chip's compiler refuses
-(an operand type, a slice off the tiling, too much VMEM) the TPU interpreter
-of ``test_chip_smoke.py`` lets through.  Nothing runs, so nothing here is a
-time or a result.  All of these stay in this one file: the worker that is
-given it is the only one that loads the TPU's library."""
+experts' grouped products, the delta rule) and the expert layer, compiled at
+real widths for a TPU v5e that is described and not attached: what the chip's
+compiler refuses (an operand type, a slice off the tiling, too much VMEM) the
+TPU interpreter of ``test_chip_smoke.py`` lets through.  Nothing runs, so
+nothing here is a time or a result.
+
+All of these stay in this one file: the worker that is given it is the only
+one that loads the TPU's library, so the file cannot be spread over workers
+and has to stay short as it is.  Its budget is 200 s under tier-1's six
+workers (ROADMAP.md, D8) and it stands at it: 186 to 202 s in PR 41's runs, of
+which the four cells' expert layers are 105 (293 before, with each layer
+compiled twice).  So a new kernel's compile joins its cell's case: one more
+assertion on a program that is compiled already (``_expert_layer_for_v5e``
+holds a cell's layer, forward and backward in one program; a cell's attention
+shapes are rows of one table), at the cell's own shape and no other, and a
+case a shape only where the shape changes what the compiler is asked to
+accept.  A sweep over layouts belongs under the TPU interpreter at a lane
+tile of width (``test_grouped_matmul.py``)."""
 import functools
 import os
 
@@ -36,6 +47,33 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
+def _attention_for_v5e(one_chip, backward, shape, dim, dtype, ids=False,
+                       **call):
+    """An attention kernel at ``shape`` (batch, heads, lq, lk) with heads of
+    ``dim``, compiled for the chip: the forward, or with ``backward`` the
+    backward kernel.  ``call`` goes to the path (``causal``, the static
+    ``mask``, the forward's blocks); ``ids`` hands it segment ids (batch,
+    lk) beside that mask."""
+    from mxnet_tpu.ops.flash_attention import (_Mask, _fa_backward_pallas,
+                                               _fa_forward_pallas)
+
+    def spec(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    b, h, lq, lk = shape
+    q, kv, lse = spec((b, h, lq, dim)), spec((b, h, lk, dim)), \
+        spec((b, h, lq), "float32")
+    operands = (q, kv, kv, q, lse, q) if backward else (q, kv, kv)
+    path = _fa_backward_pallas if backward else _fa_forward_pallas
+    call = dict({"causal": False, "sm_scale": dim ** -0.5}, **call)
+    if not ids:
+        return jax.jit(functools.partial(path, **call)).lower(
+            *operands).compile()
+    key = call.pop("mask", None)
+    return jax.jit(lambda seg, *a: path(*a, mask=_Mask(key, seg), **call)
+                   ).lower(spec((b, lk), "int32"), *operands).compile()
+
+
 @pytest.mark.parametrize("shape,dim,dtype,causal,blocks", [
     # BERT-base as the benchmark's cell runs it: one pass over the K row
     ((16, 12, 512, 512), 64, "bfloat16", False, (None, None)),
@@ -51,15 +89,9 @@ def one_chip():
 ])
 def test_flash_forward_compiles_for_v5e(one_chip, shape, dim, dtype, causal,
                                         blocks):
-    from mxnet_tpu.ops.flash_attention import _fa_forward_pallas
-
-    b, h, lq, lk = shape
-    fwd = functools.partial(_fa_forward_pallas, causal=causal,
-                            sm_scale=1.0 / dim ** 0.5, block_q=blocks[0],
-                            block_k=blocks[1])
-    q = jax.ShapeDtypeStruct((b, h, lq, dim), dtype, sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((b, h, lk, dim), dtype, sharding=one_chip)
-    text = jax.jit(fwd).lower(q, kv, kv).compile().as_text()
+    text = _attention_for_v5e(one_chip, False, shape, dim, dtype,
+                              causal=causal, block_q=blocks[0],
+                              block_k=blocks[1]).as_text()
     assert "tpu_custom_call" in text and "mxnet_flash_attention_fwd" in text
 
 
@@ -75,16 +107,11 @@ def test_flash_forward_compiles_for_v5e(one_chip, shape, dim, dtype, causal,
 ])
 def test_masked_flash_forward_compiles_for_v5e(one_chip, shape, dim, dtype,
                                                block, blocks):
-    from mxnet_tpu.ops.flash_attention import (BLOCK_DIFFUSION,
-                                               _fa_forward_pallas)
+    from mxnet_tpu.ops.flash_attention import BLOCK_DIFFUSION
 
-    b, h, lq, lk = shape
-    fwd = functools.partial(_fa_forward_pallas, causal=False,
-                            sm_scale=1.0 / dim ** 0.5, block_q=blocks[0],
-                            block_k=blocks[1], mask=(BLOCK_DIFFUSION, block))
-    q = jax.ShapeDtypeStruct((b, h, lq, dim), dtype, sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((b, h, lk, dim), dtype, sharding=one_chip)
-    text = jax.jit(fwd).lower(q, kv, kv).compile().as_text()
+    text = _attention_for_v5e(one_chip, False, shape, dim, dtype,
+                              mask=(BLOCK_DIFFUSION, block),
+                              block_q=blocks[0], block_k=blocks[1]).as_text()
     assert "tpu_custom_call" in text and "mxnet_flash_attention_fwd" in text
 
 
@@ -121,17 +148,11 @@ def test_flash_backward_kernel_compiles_for_v5e(one_chip, shape, dim, dtype,
     float32 accumulator, a branch on a prefetched flag and bf16 operands at
     ``DEFAULT`` are all the chip's compiler's to accept or refuse.  Under
     the mask it needs no more scratch in HBM than the scan's gigabyte."""
-    from mxnet_tpu.ops.flash_attention import (BLOCK_DIFFUSION,
-                                               _fa_backward_pallas)
+    from mxnet_tpu.ops.flash_attention import BLOCK_DIFFUSION
 
-    b, h, lq, lk = shape
-    bwd = functools.partial(
-        _fa_backward_pallas, causal=causal, sm_scale=dim ** -0.5,
+    compiled = _attention_for_v5e(
+        one_chip, True, shape, dim, dtype, causal=causal,
         mask=(BLOCK_DIFFUSION, block) if block else None)
-    q = jax.ShapeDtypeStruct((b, h, lq, dim), dtype, sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((b, h, lk, dim), dtype, sharding=one_chip)
-    lse = jax.ShapeDtypeStruct((b, h, lq), "float32", sharding=one_chip)
-    compiled = jax.jit(bwd).lower(q, kv, kv, q, lse, q).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "mxnet_flash_attention_bwd" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
@@ -152,14 +173,10 @@ def test_window_flash_forward_compiles_for_v5e(one_chip, shape, dim, dtype,
     """The forward under ``mask="window"``: the band's one range of K tiles
     as a loop with traced bounds, the predicate's two comparisons joined,
     under a name that tells the call from a full one."""
-    from mxnet_tpu.ops.flash_attention import WINDOW, _fa_forward_pallas
+    from mxnet_tpu.ops.flash_attention import WINDOW
 
-    b, h, lq, lk = shape
-    fwd = functools.partial(_fa_forward_pallas, causal=False,
-                            sm_scale=dim ** -0.5, mask=(WINDOW, window))
-    q = jax.ShapeDtypeStruct((b, h, lq, dim), dtype, sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((b, h, lk, dim), dtype, sharding=one_chip)
-    text = jax.jit(fwd).lower(q, kv, kv).compile().as_text()
+    text = _attention_for_v5e(one_chip, False, shape, dim, dtype,
+                              mask=(WINDOW, window)).as_text()
     assert "tpu_custom_call" in text
     assert "mxnet_flash_attention_fwd_window" in text
 
@@ -169,15 +186,10 @@ def test_window_flash_backward_kernel_compiles_for_v5e(one_chip, shape, dim,
                                                        dtype, window):
     """The backward kernel over the band's tile pairs alone (at the cell's
     shape 70 of the causal 136), a K tile that no query sees walked once."""
-    from mxnet_tpu.ops.flash_attention import WINDOW, _fa_backward_pallas
+    from mxnet_tpu.ops.flash_attention import WINDOW
 
-    b, h, lq, lk = shape
-    bwd = functools.partial(_fa_backward_pallas, causal=False,
-                            sm_scale=dim ** -0.5, mask=(WINDOW, window))
-    q = jax.ShapeDtypeStruct((b, h, lq, dim), dtype, sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((b, h, lk, dim), dtype, sharding=one_chip)
-    lse = jax.ShapeDtypeStruct((b, h, lq), "float32", sharding=one_chip)
-    compiled = jax.jit(bwd).lower(q, kv, kv, q, lse, q).compile()
+    compiled = _attention_for_v5e(one_chip, True, shape, dim, dtype,
+                                  mask=(WINDOW, window))
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "mxnet_flash_attention_bwd_window" in text
@@ -193,17 +205,12 @@ SEGMENT_SHAPES = [((1, 32, 16384, 16384), 128, "bfloat16", 0),
                   ((2, 4, 512, 512), 64, "float32", 0)]
 
 
-def _segment_call(one_chip, path, shape, dim, dtype, window):
+def _under_ids_for_v5e(one_chip, backward, shape, dim, dtype, window):
     from mxnet_tpu.ops.flash_attention import WINDOW
 
-    b, h, lq, lk = shape
-    fn = functools.partial(path, causal=not window, sm_scale=dim ** -0.5)
-    key = (WINDOW, window) if window else None
-    q = jax.ShapeDtypeStruct((b, h, lq, dim), dtype, sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((b, h, lk, dim), dtype, sharding=one_chip)
-    seg = jax.ShapeDtypeStruct((b, lk), "int32", sharding=one_chip)
-    lse = jax.ShapeDtypeStruct((b, h, lq), "float32", sharding=one_chip)
-    return fn, key, q, kv, seg, lse
+    return _attention_for_v5e(
+        one_chip, backward, shape, dim, dtype, ids=True, causal=not window,
+        mask=(WINDOW, window) if window else None)
 
 
 @pytest.mark.parametrize("shape,dim,dtype,window", SEGMENT_SHAPES)
@@ -212,12 +219,8 @@ def test_segment_flash_forward_compiles_for_v5e(one_chip, shape, dim, dtype,
     """The forward with segment ids as blocked operands beside q and k (a
     column of the q block's, the row's whole along the lanes), causal and
     under the window, each under its own name."""
-    from mxnet_tpu.ops.flash_attention import _Mask, _fa_forward_pallas
-
-    fn, key, q, kv, seg, _ = _segment_call(one_chip, _fa_forward_pallas, shape,
-                                      dim, dtype, window)
-    text = jax.jit(lambda q, k, v, seg: fn(q, k, v, mask=_Mask(key, seg))
-                   ).lower(q, kv, kv, seg).compile().as_text()
+    text = _under_ids_for_v5e(one_chip, False, shape, dim, dtype,
+                              window).as_text()
     assert "tpu_custom_call" in text
     assert ("mxnet_flash_attention_fwd_window_segments" if window
             else "mxnet_flash_attention_fwd_segments") in text
@@ -228,13 +231,7 @@ def test_segment_flash_backward_kernel_compiles_for_v5e(one_chip, shape, dim,
                                                         dtype, window):
     """The backward kernel with the ids of the pair's tiles (the q tile's a
     row, the K tile's a column), every pair walked masked."""
-    from mxnet_tpu.ops.flash_attention import _Mask, _fa_backward_pallas
-
-    fn, key, q, kv, seg, lse = _segment_call(one_chip, _fa_backward_pallas, shape,
-                                        dim, dtype, window)
-    compiled = jax.jit(lambda q, k, v, o, lse, g, seg: fn(
-        q, k, v, o, lse, g, mask=_Mask(key, seg))).lower(
-            q, kv, kv, q, lse, q, seg).compile()
+    compiled = _under_ids_for_v5e(one_chip, True, shape, dim, dtype, window)
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert ("mxnet_flash_attention_bwd_window_segments" if window
@@ -352,18 +349,22 @@ def test_the_steps_table_resolves_the_qk_kernels_to_their_part(one_chip,
             [profiler.SCOPE_ROPE, profiler.SCOPE_NORM] * calls), kernel
 
 
-def _gathered_and_scattered(text):
+def _gathered_and_scattered(text, backward):
     """From a compiled program's text: the shapes of what its gathers
-    produce and of what its scatters are given to put."""
+    produce and of what its scatters are given to put; with ``backward``
+    those of the whole program, without it those of its forward pass alone
+    (the instructions that no transpose named)."""
     import re
 
     dims = {name: tuple(int(n) for n in shape.split(",") if n)
             for name, shape in re.findall(
                 r"%?([\w.-]+) = \w+\[([\d,]*)\]", text)}
-    gathered = [dims[name] for name in re.findall(
-        r"%?([\w.-]+) = \S+ gather\(", text)]
-    scattered = [dims[updates] for updates in re.findall(
-        r" scatter\(%?[\w.-]+, %?[\w.-]+, %?([\w.-]+)\)", text)]
+    lines = [line for line in text.splitlines()
+             if backward or "transpose(" not in line]
+    gathered = [dims[name] for line in lines for name in re.findall(
+        r"%?([\w.-]+) = \S+ gather\(", line)]
+    scattered = [dims[updates] for line in lines for updates in re.findall(
+        r" scatter\(%?[\w.-]+, %?[\w.-]+, %?([\w.-]+)\)", line)]
     return gathered, scattered
 
 
@@ -413,41 +414,18 @@ def _loops_over_parts(text):
     return found
 
 
-@pytest.mark.parametrize("backward", [False, True])
-@pytest.mark.parametrize("cell", list(EXPERT_LAYERS))
-def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
-        one_chip, monkeypatch, cell, backward):
-    """The four decoder cells' expert layers as ``moe_swiglu`` runs them, the
-    experts held in bf16 and their grouped products the Pallas kernels of
-    ``ops/grouped_matmul.py`` at whole widths (2304 x 896 too), under a
-    layer's checkpoint and the step's forward scope: loops with a traced
-    trip count (over the parts that hold a pair, and inside them the walks
-    of ``dispatch`` and ``combine``, custom VJPs all) are the chip's
-    compiler's to accept.
-
-    What it compiled gathers rows a granule of the sorted rows at a time
-    and no gate for each of the ``rows x 8`` pairs; a part's 32,768 rows
-    move at once only in the scatter-add (``combine`` forward, ``dispatch``
-    backward), which XLA does as a sort of the indices, a gather of the
-    rows into that order and a sorted scatter.
-
-    The loops over parts: one forward and, backward, one more (what the
-    layer's checkpoint computes again is the loop's inputs, so its second
-    forward loop is dead).  Their trip count is a number they carry, not
-    the static number of parts.  Every instruction of theirs that the
-    program named (the counter and the bound's compare, which part, the
-    accumulators' adds, the walks, the products' element-wise ops) is under
-    exactly one of ``mx_moe_route`` and ``mx_moe_experts``, the grouped
-    products' kernels too, and the backward loop's are of the class
-    ``backward`` by their own names; the ``while`` instruction itself carries its caller's
-    scope (one around it would name its body's products too), and what the
-    compiler adds without a name (copies, a buffer's fill sunk into the
-    body) carries none."""
+@functools.lru_cache(maxsize=None)
+def _expert_layer_for_v5e(cell, one_chip):
+    """``(compiled, part)``: a cell's expert layer as ``moe_swiglu`` runs
+    it, the experts held in bf16, under a layer's checkpoint and the step's
+    forward scope, its value and gradients compiled for the described chip
+    once a cell (20 to 40 s each); and the rows of a part.  The program
+    holds the forward pass whole, so the forward case reads that part of it
+    and compiles nothing (until PR 41 it compiled the forward again alone:
+    113 s of this file's 273)."""
     from mxnet_tpu import profiler
-    from mxnet_tpu.ops import grouped_matmul as gm
     from mxnet_tpu.ops.attention_ops import moe_swiglu
-    from mxnet_tpu.parallel.expert_parallel import (_GRANULE,
-                                                    _PART_EVEN_LOADS,
+    from mxnet_tpu.parallel.expert_parallel import (_PART_EVEN_LOADS,
                                                     _PART_ROWS)
 
     tokens, hidden, experts, held, width, top_k, scoring = EXPERT_LAYERS[cell]
@@ -455,9 +433,6 @@ def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
     n_group, topk_group = scoring.pop("groups", (1, 1))
     part = min(_PART_ROWS, tokens * top_k,
                _PART_EVEN_LOADS * tokens * top_k * held // experts)
-    # the layer as the decoders call it, its own grouped products: with a
-    # TPU as JAX's backend the gate sends them to the kernels
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     @jax.checkpoint
     def layer(x, router, p, bias):
@@ -482,10 +457,53 @@ def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
              "u": spec((held, hidden, width), "bfloat16"),
              "d": spec((held, width, hidden), "bfloat16")},
             spec((experts,), "float32") if scoring else None)
-    fn = jax.value_and_grad(loss, (0, 1, 2)) if backward else loss
-    compiled = jax.jit(fn).lower(*args).compile()
+    # the layer as the decoders call it, its own grouped products: with a
+    # TPU as JAX's backend the gate sends them to the kernels
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
+            *args).compile(), part
+
+
+@pytest.mark.parametrize("backward", [False, True])
+@pytest.mark.parametrize("cell", list(EXPERT_LAYERS))
+def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
+        one_chip, cell, backward):
+    """The four decoder cells' expert layers as ``moe_swiglu`` runs them, the
+    experts held in bf16 and their grouped products the Pallas kernels of
+    ``ops/grouped_matmul.py`` at whole widths (2304 x 896 too), under a
+    layer's checkpoint and the step's forward scope: loops with a traced
+    trip count (over the parts that hold a pair, and inside them the walks
+    of ``dispatch`` and ``combine``, custom VJPs all) are the chip's
+    compiler's to accept.  One program a cell (``_expert_layer_for_v5e``):
+    ``backward`` reads the whole of it, the other case its forward pass.
+
+    What it compiled gathers rows a granule of the sorted rows at a time
+    and no gate for each of the ``rows x 8`` pairs; a part's 32,768 rows
+    move at once only in the scatter-add (``combine`` forward, ``dispatch``
+    backward), which XLA does as a sort of the indices, a gather of the
+    rows into that order and a sorted scatter.
+
+    The loops over parts: one forward and, backward, one more (what the
+    layer's checkpoint computes again is the loop's inputs, so its second
+    forward loop is dead).  Their trip count is a number they carry, not
+    the static number of parts.  Every instruction of theirs that the
+    program named (the counter and the bound's compare, which part, the
+    accumulators' adds, the walks, the products' element-wise ops) is under
+    exactly one of ``mx_moe_route`` and ``mx_moe_experts``, the grouped
+    products' kernels too, and the backward loop's are of the class
+    ``backward`` by their own names; the ``while`` instruction itself carries its caller's
+    scope (one around it would name its body's products too), and what the
+    compiler adds without a name (copies, a buffer's fill sunk into the
+    body) carries none."""
+    from mxnet_tpu import profiler
+    from mxnet_tpu.ops import grouped_matmul as gm
+    from mxnet_tpu.parallel.expert_parallel import _GRANULE
+
+    tokens, hidden, _, _, _, top_k, _ = EXPERT_LAYERS[cell]
+    compiled, part = _expert_layer_for_v5e(cell, one_chip)
     text = compiled.as_text()
-    gathered, scattered = _gathered_and_scattered(text)
+    gathered, scattered = _gathered_and_scattered(text, backward)
     # besides the whole part's, the router's top-k scatters a token's 8
     # gates back and a granule's gates' gradients go to their pairs:
     # scalars both
@@ -501,7 +519,8 @@ def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
     assert set(rows) == {_GRANULE, part}
     assert all(tokens * top_k not in shape for shape in gathered)
 
-    loops = _loops_over_parts(text)
+    loops = [loop for loop in _loops_over_parts(text)
+             if backward or "transpose(" not in loop[0]]
     assert len(loops) == (2 if backward else 1)
     table = profiler.scopes_of(compiled)
     for own, cond, body in loops:
@@ -528,10 +547,11 @@ def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
     # experts' part whose scope holds ``ragged_dot``, which is how the
     # benchmark's readers find a grouped product
     assert "ragged-dot" not in text
-    kernels = {name: row for name, row in table.items()
-               if name.startswith(gm.KERNEL)}
-    assert len(kernels) == (12 if backward else 3)
     in_loops = {name for _, _, body in loops for name, _, _ in body}
+    kernels = {name: row for name, row in table.items()
+               if name.startswith(gm.KERNEL)
+               and (backward or name in in_loops)}
+    assert len(kernels) == (12 if backward else 3)
     for name, row in kernels.items():
         assert name in in_loops, name
         assert row["part"] == profiler.SCOPE_MOE_EXPERTS, (name, row)

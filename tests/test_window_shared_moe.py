@@ -7,9 +7,6 @@ tokens; the shares of an expert-parallel deployment with the shared expert
 counted once against the uncut reference; and a small net of the same shape
 of layer through ``TrainStep`` against the configuration's plain reference.
 All on the CPU, seeded random weights."""
-import json
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,9 +18,8 @@ from mxnet_tpu.gluon.model_zoo.language import llama
 from mxnet_tpu.ops import flash_attention as fa
 from mxnet_tpu.parallel.expert_parallel import moe_apply
 
+import decoder_parity as parity
 from test_block_diffusion_moe import _expert_weights, _grouped, dense_attention
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # --------------------------------------------------------------------------
@@ -121,8 +117,9 @@ def test_window_attention_backward_matches_dense_mask(lq, lk, window, block_q,
                   for n in (lq, lk, lk, lq))
     mask = (fa.WINDOW, window)
     seen = dense_window(lq, lk, window)
-    want = jax.grad(lambda *a: jnp.sum(dense_attention(*a, seen, 0.125) * g),
-                    (0, 1, 2))(q, k, v)
+    want = jax.jit(jax.grad(
+        lambda *a: jnp.sum(dense_attention(*a, seen, 0.125) * g),
+        (0, 1, 2)))(q, k, v)
     o, lse = fa._mha_with_lse(q, k, v, False, 0.125, mask)
     got = [fa._fa_backward_blockwise(q, k, v, o, lse, g, False, 0.125,
                                      block_k=block_k or lk, mask=mask,
@@ -271,89 +268,14 @@ def test_the_switch_layer_refuses_the_dropless_router_options():
 # --------------------------------------------------------------------------
 # the decoder by configuration, against the configuration's reference
 # --------------------------------------------------------------------------
-def _small_trinity(**changes):
-    """The benchmark's configuration at a small size of the same shape of
-    layer: five layers (window, window, window, full, window; the first
-    dense), a window of 8 over L = 32, GQA 4 over 2, top-2 of 8 routed
-    experts with 2 held (the first of 4 shares, of which the bias favours
-    2), a shared expert, the attention gate and the norms after."""
-    from chipbench.harness.cell import ROOT as BENCH_ROOT, _module
-
-    with open(os.path.join(ROOT, "chipbench", "configs", "trinity_mini",
-                           "config.json")) as f:
-        cfg = json.load(f)
-    cfg.update(vocab_size=96, hidden_size=64, num_attention_heads=4,
-               num_key_value_heads=2, head_dim=16, intermediate_size=96,
-               moe_intermediate_size=32, num_experts=2, router_width=8,
-               num_experts_per_tok=2, experts_first=0, sliding_window=8)
-    cfg["assumed"] = dict(cfg["assumed"],
-                          expert_bias={"value": 1.0, "shares": [0, 1]})
-    cfg.update(changes)
-    mods = [_module(BENCH_ROOT, "configs", "trinity_mini", name)
-            for name in ("build", "reference")]
-    return (cfg, *mods, _module(BENCH_ROOT, "drivers", "fused_step"))
-
-
 def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_block():
-    """The model-configs guide's test of the cut: 32 routed experts in 16
-    shares of 2, 4 a token, random routers and a random bias.  The routed
-    part of each share's ``LlamaMoEMLP`` (its output less the shared
-    expert, which every share computes alike) summed over the shares, plus
-    the shared expert once, is the uncut reference's expert block before
-    the norm after it.  2e-5: float32 sums of 5 terms of size 0.1 in
-    another order."""
-    cfg, _, reference, _ = _small_trinity(
-        num_experts=32, router_width=32, num_experts_per_tok=4)
-    rs = np.random.RandomState(2)
-    shapes = {"moe.router": (64, 32), "moe.gate": (32, 64, 32),
-              "moe.up": (32, 64, 32), "moe.down": (32, 32, 64),
-              "shared.gate": (32, 64), "shared.up": (32, 64),
-              "shared.down": (64, 32)}
-    p = {k: jnp.asarray(0.3 * rs.randn(*s).astype("f"))
-         for k, s in shapes.items()}
-    bias = jnp.asarray((rs.rand(32) * (rs.rand(32) < 0.5)).astype("f"))
-    h = jnp.asarray(rs.randn(2, 24, 64).astype("f"))
-    with jax.default_matmul_precision("highest"):
-        whole = reference.shared_expert(lambda x: x, h.reshape(-1, 64), p) \
-            + reference.routed_experts(cfg, lambda x: x, h.reshape(-1, 64),
-                                       p, bias, 0, 32)
-
-    names = {"router_weight": "moe.router", "gate_proj_weight": "moe.gate",
-             "up_proj_weight": "moe.up", "down_proj_weight": "moe.down",
-             "shared_gate_proj_weight": "shared.gate",
-             "shared_up_proj_weight": "shared.up",
-             "shared_down_proj_weight": "shared.down"}
-    routed, shared = 0.0, None
-    for share in range(16):
-        layer = llama.LlamaMoEMLP(llama.LlamaConfig(
-            hidden_size=64, num_heads=4, num_kv_heads=2, num_experts=32,
-            moe_capacity_factor=None, moe_top_k=4, moe_renormalize=True,
-            moe_renorm_eps=1e-20, moe_score="sigmoid",
-            moe_route_scale=cfg["route_scale"], moe_select_bias=True,
-            moe_experts_held=(2 * share, 2), moe_intermediate_size=32,
-            moe_shared_intermediate_size=32))
-        layer.initialize()
-        for name, param in layer.collect_params().items():
-            suffix = name.split("llamamoemlp")[1].split("_", 1)[1]
-            if suffix == "select_bias":
-                param.set_data(nd.array(bias))
-                continue
-            value = p[names[suffix]]
-            if value.ndim == 3:
-                value = value[2 * share:2 * share + 2]
-            param.set_data(nd.array(value))
-        shared = layer.shared(nd.array(h))._get()
-        routed = routed + layer(nd.array(h))._get() - shared
-    np.testing.assert_allclose((routed + shared).reshape(-1, 64), whole,
-                               atol=2e-5)
-
-
-@pytest.fixture
-def flash_calls_from_nothing():
-    """``telemetry.reset`` zeroes a label and keeps it, so the forward's calls
-    by mask would list the masks of the process's earlier tests: the family
-    goes, and the next call registers it anew."""
-    telemetry._FAMILIES.pop("mxnet_flash_attention_fwd_calls_total", None)
+    """32 routed experts in 16 shares of 2, 4 a token, random routers and a
+    random bias, the shared expert counted once."""
+    cfg, _, _, _ = parity.small("trinity_mini")
+    parity.shares_add_up(
+        "trinity_mini", 32, 2, 4, 2e-5, moe_renorm_eps=1e-20,
+        moe_score="sigmoid", moe_route_scale=cfg["route_scale"],
+        moe_select_bias=True, moe_shared_intermediate_size=32)
 
 
 @pytest.mark.parametrize("amp,tolerance", [
@@ -371,36 +293,15 @@ def flash_calls_from_nothing():
     ("bfloat16", {"loss_gap": 2e-3, "first_gradient_gap": 0.1,
                   "first_gradient_error": 0.4, "change_gap": 0.05}),
 ])
-def test_program_matches_the_reference_loss_and_every_gradient(
-        flash_calls_from_nothing, amp, tolerance):
-    from chipbench.harness import check, loop
+def test_program_matches_the_reference_loss_and_every_gradient(amp,
+                                                               tolerance):
     from mxnet_tpu import profiler
 
-    cfg, build, reference, driver = _small_trinity()
-    spec = {"batch": 2, "seq": 32, "optimizer": "adam", "amp_dtype": amp,
-            "optimizer_params": {"learning_rate": 1e-6}}
-    telemetry.reset()
-    runner = driver.Runner(spec, cfg, build, reference.init_params(cfg, 5))
-    pool = loop.make_pool(build, cfg, spec, 5)
-    feed = loop.open_feed(pool)
-    try:
-        got = loop.first_steps(runner, feed, 2)
-    finally:
-        feed.close()
-    ref = check.follow(reference, cfg, "float32",
-                       reference.init_params(cfg, 5), pool[:2], spec)
-    assert set(got["first_gradient"]) == set(reference.param_shapes(cfg))
-    stats = check.compare(got, ref)
-    for name, (value, where) in stats.items():
-        assert value <= tolerance[name], (name, value, where)
     # every leaf got a gradient of its own: the gate's, the shared
     # expert's, the router's and the norms' after a sublayer too
-    for leaf, g in got["first_gradient"].items():
-        assert np.abs(g).max() > 0, leaf
-
+    _, metrics = parity.matches("trinity_mini", amp, tolerance)
     # the assumed routers send this share exactly one pair a token a sparse
     # layer: 2 steps x 4 layers x 64 tokens, whatever the seed
-    metrics = telemetry.snapshot()["metrics"]
     pairs = metrics["mxnet_moe_routed_pairs_total"]["samples"][0]["value"]
     assert pairs == 2 * 4 * 64
     # 64 tokens choose 8: one part of 512 sorted rows a layer, and it holds
@@ -420,58 +321,32 @@ def test_program_matches_the_reference_loss_and_every_gradient(
                for row in table.values())
 
 
-@pytest.mark.parametrize("left_out", ["window", "gate", "shared", "bias",
-                                      "scale"])
+LEFT_OUT = {
+    "window": dict(broken={"sliding_window": 32}),  # every key: a full layer
+    "gate": dict(mistaken=parity.zeroed("attn.z")),  # sigmoid(z) is a half
+    "shared": dict(mistaken=parity.zeroed("shared.down")),  # adds nothing
+    # the third share of four, which the bias favours and the order of the
+    # router's tied columns does not
+    "bias": dict(
+        cfg={"experts_first": 4,
+             "assumed": {"expert_bias": {"value": 1.0, "shares": [2, 3]}}},
+        broken={"assumed": {"expert_bias": {"value": 0.0, "shares": []}}}),
+    "scale": dict(broken={"route_scale": 1.0}),
+}
+
+
+@pytest.mark.parametrize("left_out", list(LEFT_OUT))
 def test_the_parity_test_sees_each_part_left_out(left_out):
     """The reference with one part of the layer left out of the *program's*
     configuration no longer agrees: the float32 comparison above would fail
     by ``first_gradient_error`` or ``loss_gap``, a hundred times over its
-    tolerance."""
-    from chipbench.harness import check, loop
-
-    cfg, build, reference, driver = _small_trinity()
-    if left_out == "bias":
-        # the third share of four, which the bias favours and the order of
-        # the router's tied columns does not
-        cfg = dict(cfg, experts_first=4, assumed=dict(
-            cfg["assumed"], expert_bias={"value": 1.0, "shares": [2, 3]}))
-    broken = dict(cfg)
-    if left_out == "window":
-        broken["sliding_window"] = 32          # every key: a full layer
-    elif left_out == "bias":
-        broken["assumed"] = dict(cfg["assumed"],
-                                 expert_bias={"value": 0.0, "shares": []})
-    elif left_out == "scale":
-        broken["route_scale"] = 1.0
-    spec = {"batch": 2, "seq": 32, "optimizer": "adam", "amp_dtype": None,
-            "optimizer_params": {"learning_rate": 1e-6}}
-    weights = reference.init_params(cfg, 5)
-    if left_out == "gate":       # sigmoid(z) is a half everywhere
-        weights = {k: 0.0 * v if k.endswith("attn.z") else v
-                   for k, v in weights.items()}
-    elif left_out == "shared":   # the shared expert adds nothing
-        weights = {k: 0.0 * v if k.endswith("shared.down") else v
-                   for k, v in weights.items()}
-    runner = driver.Runner(spec, broken, build, weights)
-    pool = loop.make_pool(build, cfg, spec, 5)
-    feed = loop.open_feed(pool)
-    try:
-        got = loop.first_steps(runner, feed, 1)
-    finally:
-        feed.close()
-    ref = check.follow(reference, cfg, "float32",
-                       reference.init_params(cfg, 5), pool[:1], spec)
-    stats = check.compare(got, ref)
+    tolerance.  One sparse window layer holds every part."""
+    stats = parity.left_out("trinity_mini", **LEFT_OUT[left_out])
     assert max(stats["first_gradient_error"][0], stats["loss_gap"][0]) > 1e-3
 
 
 def test_counts_of_the_configuration():
-    from chipbench.harness.cell import ROOT as BENCH_ROOT, _module
-
-    counts = _module(BENCH_ROOT, "configs", "trinity_mini", "counts")
-    with open(os.path.join(ROOT, "chipbench", "configs", "trinity_mini",
-                           "config.json")) as f:
-        cfg = json.load(f)
+    cfg, counts = parity.published("trinity_mini")
     for length, window in ((96, 40), (64, 64), (16, 64)):
         assert counts.window_pairs(length, window) \
             == dense_window(length, length, window).sum()
