@@ -46,6 +46,11 @@ class _State(threading.local):
 
 _STATE = _State()
 
+# ``NDArray._grad`` of a variable marked without a gradient buffer (the data
+# of a Gluon parameter): the first that asks for the buffer makes it,
+# ``NDArray.grad`` zeros and ``backward`` its cotangent
+UNMADE = object()
+
 
 def is_recording():
     return _STATE.recording
@@ -287,6 +292,14 @@ def _accum_grad(entry, c, written):
         return
     grad_nd = var._grad
     if grad_nd is None:
+        return
+    if grad_nd is UNMADE:
+        # c itself where "write" would store it, zeros plus c under "add"
+        import jax.numpy as jnp
+
+        var._make_grad(jnp.zeros((), var.dtype) + c if req == "add"
+                       else c.astype(var.dtype))
+        written.add(id(var))
         return
     if req == "add":
         grad_nd._set(grad_nd._get() + c)

@@ -27,6 +27,30 @@ __all__ = ["LlamaConfig", "LlamaModel", "LlamaForCausalLM", "llama3_8b",
 
 
 class LlamaConfig:
+    """The zoo's decoder, by its numbers (the comments in ``__init__`` say
+    what each group means).
+
+    ``remat=True``: under any jax trace of the net (``TrainStep``,
+    ``hybridize()``, ``jax.jit`` / ``jax.grad`` over it) every decoder layer
+    is one ``jax.checkpoint`` whose policy keeps, beside the layer's input,
+    what the attention op names: its output ``o`` and row statistics ``lse``
+    (``ops.flash_attention.KEPT_O`` / ``KEPT_LSE``), and of a ``"kda"`` layer
+    the delta rule's output and chunk states (``ops.kda.KEPT_O`` /
+    ``KEPT_STATES``).  The backward computes everything else of the layer
+    again (norms, projections, q/k norm and RoPE, the router and the
+    experts, q, k and v) and runs the attention's backward kernel on the
+    kept values: no attention forward runs twice.  Kept a layer, for ``rows
+    = batch x length``: ``rows x hidden`` (the input) plus ``rows x heads x
+    head size`` (``o``), both in the activations' dtype, plus ``rows x
+    heads`` float32 (``lse``): 130 MiB for ``o`` and ``lse`` at 16,384 rows
+    and 32 heads of 128 in bf16; a ``"kda"`` layer keeps ``o`` and ``rows /
+    64 x heads x key size x head size`` float32 of states.  One path, no
+    option beside ``remat`` itself.  A net without ``remat``, the eager
+    autograd tape and ``export()`` keep every activation as before (the last
+    two warn that ``remat`` has no effect there); ``TrainStep(remat=True)``'s
+    own whole-net checkpoint has no policy, so the names keep nothing inside
+    it."""
+
     def __init__(self, vocab_size=128256, hidden_size=4096, num_layers=32,
                  num_heads=32, num_kv_heads=8, intermediate_size=14336,
                  rope_base=500000.0, max_seq_len=8192, rms_eps=1e-5,
@@ -191,9 +215,13 @@ class LlamaConfig:
             raise MXNetError(
                 "a window layer needs attention_window >= 1 and the causal "
                 "layout (block_diffusion=0)")
-        # remat: rematerialize each decoder layer's activations in backward
-        # (jax.checkpoint) — trades ~1/3 more FLOPs for O(num_layers) less
-        # activation HBM, the standard lever for bigger per-chip batches
+        # remat: each decoder layer is a jax.checkpoint that keeps its input
+        # and the attention op's output and row statistics (the delta rule's
+        # output and chunk states) and computes the rest again in backward:
+        # one more forward of the layer less the op's, (1 - a) / 3 more
+        # FLOPs than no remat where a is the op's share of the layer's
+        # forward FLOPs (1/3 more when the op ran twice), for O(num_layers)
+        # less activation HBM; the class docstring has the bytes a layer
         self.remat = remat
         # qk_norm: an RMSNorm over the head size on q and on k before RoPE
         # (a learned vector each, shared by the heads)
@@ -676,10 +704,13 @@ class LlamaDecoderLayer(HybridBlock):
                 # under a jax trace (TrainStep's fused step, hybridize()'s
                 # cached op, any jax.jit/grad over the net): checkpoint the
                 # whole layer — closed-over parameter tracers differentiate
-                # normally, activations are recomputed in backward
+                # normally, activations are recomputed in backward, all but
+                # the attention op's output and row statistics: with those
+                # kept, the recomputation holds no attention forward
                 # what the layer gives to telemetry.step_scalar leaves the
                 # checkpoint as an output and is given again outside
                 from .... import telemetry as _telemetry
+                from ....ops import flash_attention as _fa, kda as _kda
 
                 def body_pure(*values):
                     ctx = getattr(x, "context", None)
@@ -688,8 +719,13 @@ class LlamaDecoderLayer(HybridBlock):
                                            for v in values))._get()
                     return out, scalars.stacked()
 
-                out, scalars = jax.checkpoint(body_pure)(
-                    xv, *(p._get() for p in packed))
+                with _fa.checkpoint_keeps():
+                    out, scalars = jax.checkpoint(
+                        body_pure,
+                        policy=jax.checkpoint_policies.save_only_these_names(
+                            _fa.KEPT_O, _fa.KEPT_LSE, _kda.KEPT_O,
+                            _kda.KEPT_STATES))(
+                                xv, *(p._get() for p in packed))
                 for name, values in scalars.items():
                     _telemetry.step_scalar(name, values)
                 return NDArray._from_jax(out, getattr(x, "context", None))

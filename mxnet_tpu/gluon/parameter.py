@@ -30,6 +30,20 @@ class DeferredInitializationError(MXNetError):
 
 
 class Parameter:
+    """A weight of a Block: its data a context, and how its gradient is kept
+    (``grad_req``: ``"write"``, ``"add"`` or ``"null"``).
+
+    ``initialize`` marks the data of a trained parameter as an autograd
+    variable and allocates no gradient buffer: the first ``grad()`` /
+    ``list_grad()`` (or ``data().grad``) makes zeros of the weight's shape,
+    dtype and context, the first ``backward`` that reaches the weight stores
+    its gradient, and either way the same ``NDArray`` is returned ever after
+    (``p.grad() is p.data().grad``).  So ``grad()`` before any backward
+    gives zeros, as in MXNet 1.x, which allocates at ``initialize``; a net
+    trained through ``parallel.data_parallel.TrainStep``, whose gradients
+    live inside the compiled step, never asks and holds no buffers
+    (``mxnet_parameter_grad_buffers_total`` counts those made)."""
+
     def __init__(self, name, grad_req="write", shape=None, dtype=_np.float32,
                  lr_mult=1.0, wd_mult=1.0, init=None, allow_deferred_init=False,
                  differentiable=True, stype="default", grad_stype="default"):
@@ -45,7 +59,6 @@ class Parameter:
         self._allow_deferred_init = allow_deferred_init
         self._differentiable = differentiable
         self._data = None      # dict ctx -> NDArray
-        self._grad = None      # dict ctx -> NDArray
         self._deferred_init = ()
         self._ctx_list = None
         self._stype = stype
@@ -82,9 +95,12 @@ class Parameter:
         if self._grad_req == req:
             return
         self._grad_req = req
+        if self._data is None:
+            return
         if req == "null":
-            self._grad = None
-        elif self._data is not None:
+            for d in self._data.values():
+                d._grad = None     # a backward then leaves it alone
+        else:
             self._init_grad()
 
     def _shape_known(self):
@@ -142,11 +158,11 @@ class Parameter:
             self._init_grad()
 
     def _init_grad(self):
-        self._grad = OrderedDict()
-        for c, d in self._data.items():
-            g = _ndm.invoke("zeros_like", [d], {})
-            self._grad[c] = g
-            d._mark_variable(g, self._grad_req)
+        """Marks every context's copy as a variable with no gradient buffer
+        yet: the first ``grad()`` or backward makes it.  One made before is
+        dropped."""
+        for d in self._data.values():
+            d._mark_variable(None, self._grad_req)
 
     # -- access ------------------------------------------------------------
     def _check_initialized(self, ctx=None):
@@ -180,17 +196,20 @@ class Parameter:
         self._check_initialized()
         return list(self._data.values())
 
-    def grad(self, ctx=None):
+    def _check_trained(self):
         self._check_initialized()
-        if self._grad is None:
+        if self._grad_req == "null":
             raise MXNetError(f"Parameter {self.name} has grad_req='null'")
+
+    def grad(self, ctx=None):
+        self._check_trained()
         if ctx is None:
-            return next(iter(self._grad.values()))
-        return self._grad[ctx]
+            return next(iter(self._data.values())).grad
+        return self._data[ctx].grad
 
     def list_grad(self):
-        self._check_initialized()
-        return list(self._grad.values())
+        self._check_trained()
+        return [d.grad for d in self._data.values()]
 
     def list_ctx(self):
         if self._data is None and self._deferred_init:
@@ -199,10 +218,10 @@ class Parameter:
         return list(self._data.keys())
 
     def zero_grad(self):
-        if self._grad is None:
+        if self._data is None:
             return
-        for c, g in self._grad.items():
-            g._set(_ndm.invoke("zeros_like", [g], {})._get())
+        for d in self._data.values():
+            d.zero_grad()
 
     def set_data(self, data):
         self.shape = data.shape
@@ -230,7 +249,7 @@ class Parameter:
         with autograd.pause():
             for c in list(self._data):
                 self._data[c] = self._data[c].astype(dtype)
-            if self._grad is not None:
+            if self._grad_req != "null":
                 self._init_grad()
 
     def var(self):

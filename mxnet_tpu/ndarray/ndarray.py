@@ -192,6 +192,8 @@ class NDArray:
 
     @property
     def grad(self):
+        if self._grad is _ag.UNMADE:
+            self._make_grad()
         return self._grad
 
     @property
@@ -244,13 +246,28 @@ class NDArray:
         self._mark_variable(g, grad_req)
 
     def _mark_variable(self, grad_nd, grad_req="write"):
-        self._grad = grad_nd
+        """``grad_nd`` the gradient buffer, or ``None`` (a Gluon parameter's
+        data): the first that asks for the buffer makes it, ``grad`` zeros
+        and a backward its cotangent (``_make_grad``)."""
+        self._grad = _ag.UNMADE if grad_nd is None else grad_nd
         self._grad_req = grad_req
         self._ag_entry = _ag.Entry(variable=self, grad_req=grad_req,
                                    shape=self.shape, dtype=self.dtype)
 
+    def _make_grad(self, value=None):
+        """The buffer of a variable marked without one: ``value`` (a
+        backward's first cotangent), or zeros of this array's shape, dtype
+        and context."""
+        from .. import telemetry
+
+        if value is None:
+            value = _jnp().zeros_like(self._get())
+        self._grad = NDArray._from_jax(value, self.context)
+        telemetry.PARAMETER_GRAD_BUFFERS.inc()
+        telemetry.PARAMETER_GRAD_BYTES.inc(value.size * value.dtype.itemsize)
+
     def zero_grad(self):
-        if self._grad is not None:
+        if self._grad is not None and self._grad is not _ag.UNMADE:
             jnp = _jnp()
             self._grad._set(jnp.zeros(self._grad.shape, self._grad.dtype))
 

@@ -1194,24 +1194,66 @@ def _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
 # --------------------------------------------------------------------------
 # public op with custom vjp
 # --------------------------------------------------------------------------
+# The names under which the op's output and its row statistics are kept by
+# a checkpoint whose policy asks for them (a decoder layer's,
+# ``LlamaDecoderLayer``): with both kept, the checkpoint's backward holds no
+# second forward of the op.
+KEPT_O = "mxnet_flash_attention_o"
+KEPT_LSE = "mxnet_flash_attention_lse"
+
+_KEEPING = threading.local()   # .value: True while such a checkpoint's body
+#                                is being traced, see checkpoint_keeps
+
+
+@contextlib.contextmanager
+def checkpoint_keeps():
+    """Trace-time scope of a ``jax.checkpoint`` whose policy keeps what the
+    ops name (``save_only_these_names`` over ``KEPT_O``, ``KEPT_LSE`` and
+    ``kda``'s): an op called inside it names those values in its
+    ``custom_vjp`` rule.  Outside it an op names nothing and its program is
+    what it was without the names."""
+    prev = keeping()
+    _KEEPING.value = True
+    try:
+        yield
+    finally:
+        _KEEPING.value = prev
+
+
+def keeping():
+    """Whether the call is traced inside ``checkpoint_keeps``.  Read when
+    the op is called: its rule is traced after the scope has closed."""
+    return getattr(_KEEPING, "value", False)
+
+
+def kept(name, value):
+    """``value`` under ``name`` for ``jax.checkpoint_policies.
+    save_only_these_names``, its bytes counted once a trace in
+    ``mxnet_layer_checkpoint_kept_bytes_total{name}``."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    from .. import telemetry
+
+    telemetry.LAYER_CHECKPOINT_KEPT_BYTES.labels(name=name).inc(
+        value.size * value.dtype.itemsize)
+    return checkpoint_name(value, name)
+
+
 @functools.lru_cache(maxsize=None)
-def _make_flash(causal, sm_scale_key, mask=None, sharded=None):
+def _make_flash(causal, sm_scale_key, mask=None, sharded=None, keeps=False):
     """The op for one static configuration: ``flash(q, k, v, *operands)``,
     the operands those of the call's ``_Mask`` (none, or the segment ids:
     integers, so no gradient goes back to them), of which ``mask`` is the
     static key.  ``sharded`` is the ``batch_sharded`` scope the call was
     traced under: the backward is traced after that scope has closed
     (``value_and_grad`` transposes once the forward has returned), so it is
-    kept here and not read again."""
+    kept here and not read again; ``keeps``, whether the call stood inside
+    ``checkpoint_keeps``, for the same reason."""
     import jax
 
     sm_scale = float(sm_scale_key)
 
-    @jax.custom_vjp
-    def flash(q, k, v, *operands):
-        return _dispatch_fwd(q, k, v, *operands)[0]
-
-    def _dispatch_fwd(q, k, v, *operands):
+    def forward(q, k, v, *operands):
         from .. import telemetry
 
         how = _Mask(mask, *operands)
@@ -1225,9 +1267,27 @@ def _make_flash(causal, sm_scale_key, mask=None, sharded=None):
                 path="pallas" if pallas else "plain",
                 mask=how.label(causal)).inc()
         if pallas:
-            o, lse = _fa_forward(q, k, v, causal, sm_scale, how, sharded)
-        else:
-            o, lse = _mha_with_lse(q, k, v, causal, sm_scale, how)
+            return _fa_forward(q, k, v, causal, sm_scale, how, sharded)
+        return _mha_with_lse(q, k, v, causal, sm_scale, how)
+
+    @jax.custom_vjp
+    def flash(q, k, v, *operands):
+        return forward(q, k, v, *operands)[0]
+
+    def _dispatch_fwd(q, k, v, *operands):
+        o, lse = forward(q, k, v, *operands)
+        if keeps:
+            # named here, inside the rule: the residuals are then the named
+            # values themselves (a name put on the op's result names a copy)
+            o, lse = kept(KEPT_O, o), kept(KEPT_LSE, lse)
+            # What the checkpoint computes again after the op no longer
+            # waits for q, k and v, and XLA's scheduler then holds a layer's
+            # backward differently: 1.1 to 1.4 GiB more scratch a step in
+            # two of three decoder cells on a v5e, the logits among it.  The
+            # barrier gives the kept o the place in the order that the
+            # recomputed one had.  Only here: in a step without a checkpoint
+            # it cost 3.4% (BERT at 512; PERF.md section 6, PR 42).
+            q, k, v, o, lse = jax.lax.optimization_barrier((q, k, v, o, lse))
         return o, (q, k, v, o, lse, operands)
 
     def bwd(res, g):
@@ -1324,7 +1384,8 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, mask=None,
     if how.seg is not None:
         _count_pairs(q, k, bool(causal), how)
     return _make_flash(bool(causal), float(sm_scale), mask,
-                       getattr(_SCOPE, "value", None))(q, k, v, *how.operands)
+                       getattr(_SCOPE, "value", None),
+                       keeping())(q, k, v, *how.operands)
 
 
 # registry entry --------------------------------------------------------------

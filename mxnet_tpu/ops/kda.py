@@ -47,7 +47,7 @@ import functools
 
 from ..base import MXNetError
 from ..profiler import KERNEL_KDA_BWD, KERNEL_KDA_FWD
-from .flash_attention import _operand_precision
+from .flash_attention import _operand_precision, keeping, kept
 from .registry import register
 
 _SUB = 16     # rows of a sub-block of a chunk, for the decays between rows
@@ -55,6 +55,10 @@ _SUB = 16     # rows of a sub-block of a chunk, for the decays between rows
 # as few runs as memory allows, since the TPU's triangular solve costs 0.65 ms
 # a call whatever its batch (128 or 1,024 matrices of 64 x 64 alike)
 _BACK_CHUNKS = 64
+# the names of the op's output and of its chunk states for a checkpoint that
+# keeps them (``flash_attention.checkpoint_keeps``)
+KEPT_O = "mxnet_kda_o"
+KEPT_STATES = "mxnet_kda_states"
 
 
 def _f32(x):
@@ -226,7 +230,7 @@ def _backward(q, k, v, g, beta, s, do):
 
 
 @functools.lru_cache(maxsize=None)
-def _make_kda(chunk):
+def _make_kda(chunk, keeps=False):
     import jax
 
     import jax.numpy as jnp
@@ -240,13 +244,23 @@ def _make_kda(chunk):
     def whole(x, like):
         return jnp.moveaxis(x, 0, 1).reshape(like.shape).astype(like.dtype)
 
+    def forward(q, k, v, g, beta):
+        o, s = _forward(*map(chunked, (q, k, v, g, beta)))
+        return whole(o, v), s
+
     @jax.custom_vjp
     def op(q, k, v, g, beta):
-        return fwd(q, k, v, g, beta)[0]
+        return forward(q, k, v, g, beta)[0]
 
     def fwd(q, k, v, g, beta):
-        o, s = _forward(*map(chunked, (q, k, v, g, beta)))
-        return whole(o, v), (q, k, v, g, beta, s)
+        o, s = forward(q, k, v, g, beta)
+        if keeps:
+            # named inside the rule, as the attention op's: a checkpoint
+            # that keeps both (``checkpoint_keeps``) runs no second forward
+            o, s = kept(KEPT_O, o), kept(KEPT_STATES, s)
+            q, k, v, g, beta, o, s = jax.lax.optimization_barrier(
+                (q, k, v, g, beta, o, s))
+        return o, (q, k, v, g, beta, s)
 
     def bwd(res, do):
         *inputs, s = res
@@ -309,7 +323,7 @@ def kda(q, k, v, g, beta, chunk=64):
             f"{g.shape}, beta {beta.shape}")
     telemetry.KDA_CALLS.labels(path="scan").inc()
     telemetry.KDA_CHUNKS.inc(l // chunk)
-    return _make_kda(chunk)(q, k, v, g, beta)
+    return _make_kda(chunk, keeping())(q, k, v, g, beta)
 
 
 @register("_contrib_short_conv", aliases=("short_conv",))

@@ -444,6 +444,24 @@ KDA_CHUNKS = counter(
     "samples not counted: rows / chunk, one count a call a trace")
 
 
+LAYER_CHECKPOINT_KEPT_BYTES = counter(
+    "mxnet_layer_checkpoint_kept_bytes_total",
+    "bytes of the values an op names for a decoder layer's checkpoint to "
+    "keep beside the layer's input (the attention op's output and row "
+    "statistics, the delta rule's output and chunk states), from their "
+    "shapes: one count a named value a layer a trace",
+    ("name",))
+PARAMETER_GRAD_BUFFERS = counter(
+    "mxnet_parameter_grad_buffers_total",
+    "gradient buffers of Gluon parameters made, one a parameter a context, "
+    "by the first grad() or backward that asked: 0 after a fused TrainStep "
+    "run, which never asks")
+PARAMETER_GRAD_BYTES = counter(
+    "mxnet_parameter_grad_bytes_total",
+    "bytes of the gradient buffers that mxnet_parameter_grad_buffers_total "
+    "counts")
+
+
 ATTENTION_VISIBLE_PAIRS = counter(
     "mxnet_attention_visible_pairs_total",
     "(query, key) pairs that the mask and the segment ids show, over the "

@@ -197,7 +197,7 @@ def test_trainer_bucketing_sparse_and_host_keys_bypass():
     ctx = params[1].list_ctx()[0]
     rsp = row_sparse_array((np.ones((1,) + params[1].shape[1:], "f"), [0]),
                            shape=params[1].shape)
-    params[1]._grad[ctx] = rsp
+    params[1].data(ctx)._grad = rsp
     from mxnet_tpu.kvstore import _HostRowSparseTable
 
     rec._kv._store["2"] = _HostRowSparseTable(

@@ -342,6 +342,11 @@ def test_the_steps_table_resolves_the_qk_kernels_to_their_part(one_chip,
         TrainStep._plain_tree(step.opt_state), jax.random.PRNGKey(0),
         ids, ids))
     table = profiler.scopes_of(step._step.lower(*args).compile())
+    # the layers' checkpoints keep the attention op's output and statistics:
+    # one forward kernel a layer (the plain checkpoint's step held two), and
+    # the q/k kernel before it still runs again
+    assert len([name for name in table
+                if name.startswith(profiler.KERNEL_ATTENTION_FWD)]) == 2
     for kernel, calls in ((qnr.KERNEL_FWD, 4), (qnr.KERNEL_BWD, 2)):
         parts = [row["part"] for name, row in table.items()
                  if name.startswith(kernel)]
