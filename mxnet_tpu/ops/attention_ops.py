@@ -278,6 +278,8 @@ def moe_swiglu(x, router_weight, gate_proj, up_proj, down_proj,
                               aux["routed_pairs"])
         telemetry.step_scalar(telemetry.MOE_WALKED_ROWS.name,
                               aux["walked_rows"])
+        telemetry.step_scalar(telemetry.MOE_ADDED_ROWS.name,
+                              aux["added_rows"])
         telemetry.step_scalar(telemetry.MOE_LIVE_PARTS.name,
                               aux["live_parts"])
         telemetry.step_scalar(telemetry.MOE_PARTS.name, aux["parts"])

@@ -29,12 +29,21 @@ Capability upgrade over the reference (MXNet 1.x has no MoE).
   tokens' rows into the sorted order a granule of ``_GRANULE`` at a time
   and stops at the last pair, and so does ``combine``'s backward, for the
   rows and for their gates.  The way back (``combine``, ``dispatch``'s
-  backward) is one scatter-add of the whole part with the rows past the
-  last pair as zeros: XLA's scatter-add on a TPU pays a pass over the
-  indices and the target before its first row, so a granule at a time it
-  costs more than the part at once.  Each of the two is the other's
-  transpose, written by hand (``jax.custom_vjp``) because the walk's trip
-  count is a device number.
+  backward) adds the rows that hold a pair into their tokens' rows, in
+  place: on a TPU the Pallas kernel of ``ops/moe_add_rows.py`` over the
+  (row tile, group) visits of the part's groups, a row a DMA each way, the
+  gate multiplied in inside it, the target float32 as the kernel holds it
+  (``(T, 1, d)``: a token's row contiguous) from the loop's first part to
+  the one pass that rounds the result.  It leans on what the sort gives: in
+  one group no token twice (``ops/moe_add_rows.py`` has the contract), so
+  the layer hands its groups in (``sizes``) and a caller of ``dispatch`` /
+  ``combine`` who does not, or whom the kernel's gate turns away (the CPU,
+  a mesh being traced, a width that is no whole lane tiles), gets one
+  scatter-add of the whole part with the rows past the last pair as zeros:
+  XLA's scatter-add on a TPU pays a pass over the indices and the target
+  before its first row and every row of the part after, pair or not.  Each
+  of the two is the other's transpose, written by hand (``jax.custom_vjp``)
+  because the walk's trip count is a device number.
   What the experts held elsewhere would add is left out; the exchange that
   brings it in is not written yet.
 """
@@ -42,7 +51,9 @@ from __future__ import annotations
 
 import functools
 
+from .. import telemetry
 from ..base import MXNetError
+from ..ops import moe_add_rows
 from ..profiler import SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTE
 
 __all__ = ["moe_apply", "stack_expert_params", "inject_aux_loss",
@@ -53,9 +64,11 @@ __all__ = ["moe_apply", "stack_expert_params", "inject_aux_loss",
 # one part (its tokens, the experts' hidden rows, its result) is about
 # 0.8 GiB at hidden 2048 and width 768.  ``tokens x top_k`` rows are a static
 # number of parts; the walk takes the first ``ceil(pairs held / part)`` of
-# them.  The products and the gathers follow the pairs, the scatter-add is
-# of a whole part: a load a few pairs over a multiple of this pays one
-# granule's gathers and one part's scatter-add more.  A share that holds few
+# them.  The products, the gathers and (through its kernel) the way back
+# follow the pairs: a load a few pairs over a multiple of this pays one
+# granule's gathers and one trip of the loops more; where the way back is
+# XLA's scatter-add (off the kernel's gate) it is of a whole part, and that
+# trip costs a part's.  A share that holds few
 # of the router's experts is sized by what it may see instead: a part holds
 # at most ``_PART_EVEN_LOADS`` times the pairs of an even load over the
 # experts (``tokens x top_k x held / experts``), so that the part's buffers
@@ -111,7 +124,9 @@ def moe_apply(expert_fn, expert_params, router_weight, x, mesh=None,
     largest among theirs (``limit_to_groups``).
     aux: ``routed_pairs`` (pairs computed
     here), ``walked_rows`` (rows that the sorted walks covered to gather
-    them: whole granules), ``live_parts`` of ``parts`` (the parts of the
+    them: whole granules), ``added_rows`` (rows that the way back walked to
+    add them: the pairs themselves under the kernel, whole parts under
+    XLA's scatter-add), ``live_parts`` of ``parts`` (the parts of the
     sorted rows that hold a pair, which the layer walks, of the static
     number that the shape allows), ``expert_load`` (count,),
     ``load_max_over_mean``, ``dropped`` 0.
@@ -187,14 +202,21 @@ def _walks():
     import jax
     import jax.numpy as jnp
 
-    def add_rows(out, rows, tokens, n_live):
+    def add_rows(out, rows, tokens, n_live, plan=None, gates=None):
         """``out`` with the first ``n_live`` of ``rows`` added to their
-        tokens' rows: one scatter-add of the whole part, the rows past
-        ``n_live`` as zeros (module docstring)."""
+        tokens' rows.  With a ``plan`` (``moe_add_rows.group_plan``: the
+        groups given and the kernel's gate open) the Pallas kernel over the
+        rows that hold a pair, each times its gate where ``gates (part,)``
+        are given; without one XLA's scatter-add of the whole part, the rows
+        past ``n_live`` as zeros."""
+        telemetry.MOE_ADD_ROWS_CALLS.labels(
+            path="scatter" if plan is None else "pallas").inc()
+        if plan is not None:
+            return moe_add_rows.add_rows(out, rows, tokens, plan, gates)
         live = (jnp.arange(tokens.shape[0]) < n_live)[:, None]
         return out.at[tokens].add(jnp.where(live, rows, 0).astype(out.dtype))
 
-    def dispatch_rows(tokens_count, x, tokens, n_live):
+    def dispatch_rows(tokens_count, x, tokens, n_live, plan):
         """``tokens_count`` is ``x``'s, static, for the backward: the
         residuals hold nothing of ``x``'s shape."""
         def body(lo, live, rows):
@@ -205,26 +227,37 @@ def _walks():
                 tokens.shape[0], n_live, body,
                 jnp.zeros(tokens.shape + x.shape[1:], x.dtype))
 
-    def dispatch_fwd(tokens_count, x, tokens, n_live):
-        return dispatch_rows(tokens_count, x, tokens, n_live), (tokens,
-                                                                n_live)
+    def dispatch_fwd(tokens_count, x, tokens, n_live, plan):
+        return dispatch_rows(tokens_count, x, tokens, n_live, plan), (
+            tokens, n_live, plan)
 
     def dispatch_bwd(tokens_count, res, g):
-        tokens, n_live = res
+        tokens, n_live, plan = res
         with jax.named_scope(SCOPE_MOE_ROUTE):
-            dx = add_rows(jnp.zeros((tokens_count,) + g.shape[1:], g.dtype),
-                          g, tokens, n_live)
-        return dx, None, None
+            if plan is None:
+                dx = add_rows(jnp.zeros((tokens_count,) + g.shape[1:],
+                                        g.dtype), g, tokens, n_live)
+            else:
+                # the kernel's sums are float32, rounded once
+                dx = add_rows(jnp.zeros((tokens_count, 1) + g.shape[1:],
+                                        jnp.float32), g, tokens, n_live,
+                              plan).reshape(-1, g.shape[1]).astype(g.dtype)
+        return dx, None, None, None
 
-    def combine_rows(out, y, gates, order, n_live):
+    def combine_rows(out, y, gates, order, n_live, plan):
         with jax.named_scope(SCOPE_MOE_ROUTE):
-            return add_rows(
-                out, y.astype(out.dtype) * gates.reshape(-1)[order][:, None],
-                order // gates.shape[1], n_live)
+            if plan is None:
+                return add_rows(
+                    out,
+                    y.astype(out.dtype) * gates.reshape(-1)[order][:, None],
+                    order // gates.shape[1], n_live)
+            # the gate of a row is multiplied in inside the kernel
+            return add_rows(out, y, order // gates.shape[1], n_live, plan,
+                            gates.reshape(-1)[order])
 
-    def combine_fwd(out, y, gates, order, n_live):
-        return combine_rows(out, y, gates, order, n_live), (y, gates, order,
-                                                            n_live)
+    def combine_fwd(out, y, gates, order, n_live, plan):
+        return combine_rows(out, y, gates, order, n_live, plan), (
+            y, gates, order, n_live)
 
     def combine_bwd(res, g):
         y, gates, order, n_live = res
@@ -242,7 +275,7 @@ def _walks():
             dy, dgates = _sorted_walk(
                 order.shape[0], n_live, body,
                 (jnp.zeros_like(y), jnp.zeros(gates.size, gates.dtype)))
-            return g, dy, dgates.reshape(gates.shape), None, None
+            return g, dy, dgates.reshape(gates.shape), None, None, None
 
     dispatch = jax.custom_vjp(dispatch_rows, nondiff_argnums=(0,))
     combine = jax.custom_vjp(combine_rows)
@@ -251,26 +284,38 @@ def _walks():
     return dispatch, combine
 
 
-def dispatch(x, tokens, n_live):
+def dispatch(x, tokens, n_live, sizes=None):
     """The tokens of a part's pairs, in the sorted order: ``rows[i] =
     x[tokens[i]]`` for ``i < n_live`` and zero past it, ``(part, d)`` in
     ``x``'s dtype; ``tokens (part,)`` int32, ``n_live`` a traced count.
     Only the granules that hold a row before ``n_live`` are gathered.  The
-    backward is one scatter-add of the whole part, whatever ``n_live``: call
-    it where the part holds a pair."""
-    return _walks()[0](x.shape[0], x, tokens, n_live)
+    backward adds the cotangent's rows into their tokens': given the
+    part's group ``sizes (G,)`` (they sum to ``n_live``, the rows sorted by
+    group, **no token twice in one group**) and a TPU, by the kernel of
+    ``ops/moe_add_rows.py`` over the rows that hold a pair, in float32 with
+    one rounding; else by one scatter-add of the whole part, whatever
+    ``n_live``: call it where the part holds a pair."""
+    return _walks()[0](x.shape[0], x, tokens, n_live,
+                       moe_add_rows.group_plan(sizes, tokens.shape[0],
+                                               x.shape[1]))
 
 
-def combine(out, y, gates, order, n_live):
+def combine(out, y, gates, order, n_live, sizes=None):
     """``out (T, d)`` (float32) with ``gates[pair] * y[i]`` added to the
-    token of each sorted row ``i < n_live`` of the part, by one scatter-add
-    of the whole part, whatever ``n_live``: call it where the part holds a
-    pair.  ``order (part,)``
+    token of each sorted row ``i < n_live`` of the part.  ``order (part,)``
     holds the rows' pairs (token * top_k + choice), ``gates (T, top_k)``
     every pair's gate; the rows of ``y`` past ``n_live`` may hold anything,
-    NaN included.  The backward walks the granules that hold a row before
-    ``n_live``, for ``y`` and the gates alike."""
-    return _walks()[1](out, y, gates, order, n_live)
+    NaN included.  Given the part's group ``sizes`` under ``dispatch``'s
+    contract, and a TPU, the kernel of ``ops/moe_add_rows.py`` adds the
+    rows that hold a pair and reads no other (``out`` may then be handed in
+    as the kernel holds it, ``(T, 1, d)``, and comes back so: the layer's
+    forward loop does, and nothing differentiates through that form); else
+    one scatter-add of the whole part, whatever ``n_live``: call it where
+    the part holds a pair.  The backward walks the granules that hold a row
+    before ``n_live``, for ``y`` and the gates alike."""
+    return _walks()[1](out, y, gates, order, n_live,
+                       moe_add_rows.group_plan(sizes, order.shape[0],
+                                               out.shape[-1]))
 
 
 def limit_to_groups(scores, n_group, topk_group):
@@ -351,6 +396,9 @@ def _moe_dropless(expert_fn, expert_params, router_weight, x, top_k,
         live_parts = -(-total // part)
         lo, hi = starts[:, None], starts[:, None] + part
         sizes = jnp.clip(ends, lo, hi) - jnp.clip(ends - load, lo, hi)
+        # the way back walks the pairs under its kernel, whole parts else
+        kernel = moe_add_rows.use_pallas(part, d)
+        added = total if kernel else live_parts * part
         out = jnp.zeros((T, d), jnp.float32)
 
     def add_part(out, x, params, gates, order, sizes, n_live):
@@ -359,20 +407,28 @@ def _moe_dropless(expert_fn, expert_params, router_weight, x, top_k,
         in memory, NaN included, forward and backward: ``combine`` and
         ``dispatch``'s backward take those rows as zeros."""
         with jax.named_scope(SCOPE_MOE_ROUTE):
-            rows = dispatch(x, order // top_k, n_live)
+            rows = dispatch(x, order // top_k, n_live, sizes)
         with jax.named_scope(SCOPE_MOE_EXPERTS):
             y = expert_fn(params, rows, sizes)
-        return combine(out, y, gates, order, n_live)
+        return combine(out, y, gates, order, n_live, sizes)
 
     out = _walk_live_parts(add_part, out, x, expert_params, gates,
-                           (order, sizes, n_live), live_parts)
+                           (order, sizes, n_live), live_parts,
+                           carried=(T, 1, d) if kernel else (T, d))
     aux = {"routed_pairs": total, "walked_rows": walked,
-           "live_parts": live_parts,
+           "added_rows": added, "live_parts": live_parts,
            "parts": jnp.asarray(n_parts, jnp.int32), "expert_load": load,
            "load_max_over_mean": jnp.max(load) * count
            / jnp.maximum(total, 1).astype(jnp.float32),
            "dropped": jnp.zeros((), jnp.int32)}
-    return out.astype(x.dtype), aux
+    out = out.astype(x.dtype)
+    if kernel:
+        # the result leaves as one array of its own: XLA, left to fuse the
+        # pass from the kernel's layout into each of its readers, keeps a
+        # float32 copy a layer alive for the backward pass (0.13 GiB of the
+        # window and the packed cells' peaks, by their compiled steps)
+        out = jax.lax.optimization_barrier(out)
+    return out, aux
 
 
 def _over_live_parts(body, init, parts, live_parts, last_first=False):
@@ -400,24 +456,28 @@ def _over_live_parts(body, init, parts, live_parts, last_first=False):
                               (jnp.zeros_like(live_parts), init))[1]
 
 
-def _walk_live_parts(add_part, out, x, params, gates, parts, live_parts):
+def _walk_live_parts(add_part, out, x, params, gates, parts, live_parts,
+                     carried):
     """``out = add_part(out, x, params, gates, *part_i)`` over the first
-    ``live_parts`` parts.  ``live_parts`` is a device number, so the
-    backward is by hand: a loop over the same parts, the last first as JAX
-    would take them, that adds each part's cotangents (``jax.vjp`` of
-    ``add_part``, whose forward is computed again there) into those of
-    ``x``, ``params`` and ``gates``; ``out`` enters ``add_part`` by an
-    addition, so its cotangent passes through.  The residuals are the
-    loop's inputs.  The accumulators start as zeros: the first part's
-    cotangents in their place would take a second copy of the part in the
-    program, outside the loop."""
+    ``live_parts`` parts, ``out`` carried through the forward loop in the
+    shape ``carried`` (where the way back is its kernel, the shape under
+    which the kernel updates it in place; the last reshape is fused into
+    whoever reads the result, the first into the zeros).  ``live_parts`` is
+    a device number, so the backward is by hand: a loop over the same
+    parts, the last first as JAX would take them, that adds each part's
+    cotangents (``jax.vjp`` of ``add_part``, whose forward is computed again
+    there) into those of ``x``, ``params`` and ``gates``; ``out`` enters
+    ``add_part`` by an addition, so its cotangent passes through.  The
+    residuals are the loop's inputs.  The accumulators start as zeros: the
+    first part's cotangents in their place would take a second copy of the
+    part in the program, outside the loop."""
     import jax
     import jax.numpy as jnp
 
     def forward(out, x, params, gates, parts, live_parts):
         return _over_live_parts(
-            lambda out, *mine: add_part(out, x, params, gates, *mine), out,
-            parts, live_parts)
+            lambda out, *mine: add_part(out, x, params, gates, *mine),
+            out.reshape(carried), parts, live_parts).reshape(out.shape)
 
     def walk_fwd(out, x, params, gates, parts, live_parts):
         return (forward(out, x, params, gates, parts, live_parts),

@@ -52,6 +52,9 @@ KERNEL_SUFFIX_SEGMENTS = "_segments"
 # experts held.  Backward ops carry transpose(jvp(<scope>))
 SCOPE_MOE_ROUTE = "mx_moe_route"
 SCOPE_MOE_EXPERTS = "mx_moe_experts"
+# the way back's Pallas kernel (ops/moe_add_rows.py): a part's live rows
+# added into their tokens' rows in place; a row of mx_moe_route
+KERNEL_MOE_ADD_ROWS = "mxnet_moe_add_rows"
 # the shared expert beside them (model_zoo/language/llama.py::LlamaMoEMLP):
 # a dense SwiGLU that every token passes
 SCOPE_MOE_SHARED = "mx_moe_shared"

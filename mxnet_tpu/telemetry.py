@@ -429,6 +429,18 @@ MOE_LOAD_MAX_OVER_MEAN = histogram(
     buckets=exponential_buckets(1.0, 1.1, 30))
 
 
+MOE_ADD_ROWS_CALLS = counter(
+    "mxnet_moe_add_rows_calls_total",
+    "the dropless expert layers' ways back traced (combine's forward, "
+    "dispatch's backward), by the path they took: the Pallas kernel over "
+    "the rows that hold a pair, or XLA's scatter-add of a whole part",
+    ("path",))
+MOE_ADDED_ROWS = counter(
+    "mxnet_moe_added_rows_total",
+    "rows that the ways back of the dropless expert layers walked to add "
+    "their pairs, forward (step scalar): over mxnet_moe_routed_pairs_total, "
+    "1.0 is the rows that hold a pair alone (the kernel); XLA's scatter-add "
+    "walks whole parts and reads above it")
 MOE_GROUP_LIMITED_CALLS = counter(
     "mxnet_moe_group_limited_calls_total",
     "dropless expert layers traced whose choice of experts is confined to "

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from mxnet_tpu import telemetry
-from mxnet_tpu.ops import grouped_matmul as gm
+from mxnet_tpu.ops import grouped_matmul as gm, moe_add_rows
 
 ROWS = 768      # three row tiles of 256
 
@@ -203,6 +203,8 @@ def test_off_the_gate_the_layer_is_the_parents_line_for_line(
 
     if on_a_tpu:
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # (the way back has a gate of its own: ``tests/test_moe_add_rows.py``)
+    monkeypatch.setattr(moe_add_rows, "use_pallas", lambda *a: False)
     assert not gm._use_pallas(jnp.zeros((rows_count, k), dtype), g)
     before = _calls("pallas"), _calls("ragged_dot")
     text, got = lowered_and_grads()
@@ -217,9 +219,9 @@ def test_off_the_gate_the_layer_is_the_parents_line_for_line(
         assert np.array_equal(a, b)
 
 
-def _calls(path):
+def _calls(path, family="mxnet_moe_grouped_dot_calls_total"):
     s = [s for s in telemetry.snapshot()["metrics"].get(
-        "mxnet_moe_grouped_dot_calls_total", {"samples": []})["samples"]
+        family, {"samples": []})["samples"]
         if s["labels"] == {"path": path}]
     return s[0]["value"] if s else 0
 
@@ -329,8 +331,9 @@ def test_a_steps_module_holds_one_kernel_a_shape_whatever_the_layers(
         assert _calls("ragged_dot") == before[1]
         sites.append(_calls("pallas") - before[0])
     # gate/up and down forward, their two transposes and their two weights'
-    # gradients, SwiGLU and its backward
-    assert counts[0] == counts[1] == 8
+    # gradients, SwiGLU and its backward; and the way back's two
+    # (``tests/test_moe_add_rows.py``)
+    assert counts[0] == counts[1] == 8 + 2
     assert sites[1] == 2 * sites[0] and sites[0] >= 2 * 9
 
 
@@ -343,6 +346,8 @@ def test_a_step_off_the_gate_lowers_no_kernel(monkeypatch, why, dtype, width,
                                               on_a_tpu):
     if on_a_tpu:
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # the way back has a gate of its own, which asks nothing of the experts
+    monkeypatch.setattr(moe_add_rows, "use_pallas", lambda *a: False)
     step, args = _decoder_step(2, dtype, width)
     before = _calls("pallas"), _calls("ragged_dot")
     assert _kernels_lowered(step, args) == 0

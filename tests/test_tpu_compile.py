@@ -484,10 +484,14 @@ def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
     ``backward`` reads the whole of it, the other case its forward pass.
 
     What it compiled gathers rows a granule of the sorted rows at a time
-    and no gate for each of the ``rows x 8`` pairs; a part's 32,768 rows
-    move at once only in the scatter-add (``combine`` forward, ``dispatch``
-    backward), which XLA does as a sort of the indices, a gather of the
-    rows into that order and a sorted scatter.
+    and no gate for each of the ``rows x 8`` pairs; nothing of a part's
+    32,768 rows moves at once any more: the way back (``combine`` forward,
+    ``dispatch`` backward) is the kernel of ``ops/moe_add_rows.py`` over the
+    rows that hold a pair, once forward and once more backward (the
+    ``combine`` that the backward loop's ``jax.vjp`` computes again is dead:
+    the kernel has no side effect), inside the loops, a row of the routing's
+    part, its target ``(T, 1, d)`` float32 updated in place: no copy of the
+    target stands before it.
 
     The loops over parts: one forward and, backward, one more (what the
     layer's checkpoint computes again is the loop's inputs, so its second
@@ -501,6 +505,8 @@ def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
     scope (one around it would name its body's products too), and what the
     compiler adds without a name (copies, a buffer's fill sunk into the
     body) carries none."""
+    import re
+
     from mxnet_tpu import profiler
     from mxnet_tpu.ops import grouped_matmul as gm
     from mxnet_tpu.parallel.expert_parallel import _GRANULE
@@ -509,19 +515,14 @@ def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
     compiled, part = _expert_layer_for_v5e(cell, one_chip)
     text = compiled.as_text()
     gathered, scattered = _gathered_and_scattered(text, backward)
-    # besides the whole part's, the router's top-k scatters a token's 8
-    # gates back and a granule's gates' gradients go to their pairs:
-    # scalars both
-    whole = [shape for shape in scattered if shape == (part, hidden)]
-    assert len(whole) == (2 if backward else 1)
-    assert all(hidden not in shape[1:] for shape in scattered
-               if shape not in whole)
-    # forward: the tokens' rows; backward: those again, the cotangent's
-    # rows and a granule's gates
+    # the router's top-k scatters a token's 8 gates back and a granule's
+    # gates' gradients go to their pairs: scalars both
+    assert all(hidden not in shape[1:] for shape in scattered)
+    # forward: the tokens' rows; backward: those again and the cotangent's
+    # rows (the rows' gates are gathered a part at a time: scalars)
     rows = [shape[0] for shape in gathered if shape[-1] == hidden]
     assert rows.count(_GRANULE) >= (3 if backward else 1)
-    assert rows.count(part) == len(whole)
-    assert set(rows) == {_GRANULE, part}
+    assert set(rows) == {_GRANULE}
     assert all(tokens * top_k not in shape for shape in gathered)
 
     loops = [loop for loop in _loops_over_parts(text)
@@ -573,6 +574,20 @@ def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
                  and name in in_loops)
     assert glu == [gm.KERNEL_SWIGLU] * (1 + backward) \
         + [gm.KERNEL_SWIGLU_BWD] * backward
+    # the way back: once forward, once more backward, in the loops, the
+    # routing's; and no copy of its target before it
+    back = {name: row for name, row in table.items()
+            if name.startswith(profiler.KERNEL_MOE_ADD_ROWS)
+            and (backward or name in in_loops)}
+    assert len(back) == 1 + backward
+    for name, row in back.items():
+        assert name in in_loops, name
+        assert row["part"] == profiler.SCOPE_MOE_ROUTE, (name, row)
+    target = rf"f32\[{tokens},1,{hidden}\]"
+    assert len(re.findall(target + r"\S* custom-call\(", text)) == 2
+    # (in the kernel's layout, a token's row contiguous: the pass that
+    # brings the result back to rows of tiles is fused into whoever reads it)
+    assert not re.search(target + r"\{2,1,0:T\(1,128\)\S* copy\(", text)
 
 
 # --------------------------------------------------------------------------
