@@ -624,11 +624,10 @@ def bench_planner():
 
 
 def bench_elastic():
-    """Zero-downtime elasticity (ISSUE 13): restart-to-first-step for a
-    cold (trace + XLA compile) vs warm (persistent compile cache)
-    TrainStep resume, live ZeRO resharding vs the checkpoint-restore
-    round trip, and serving replica handoff join-to-first-token —
-    the ROADMAP's target metrics, measured rather than asserted."""
+    """Zero-downtime elasticity (ISSUE 13): live ZeRO resharding vs the
+    checkpoint-restore round trip, and serving replica handoff
+    join-to-first-token — the ROADMAP's target metrics, measured rather
+    than asserted."""
     import os
     import tempfile
     import time
@@ -636,24 +635,17 @@ def bench_elastic():
     import numpy as np
 
     import mxnet_tpu as mx
-    from mxnet_tpu import compile_cache as cc
     from mxnet_tpu import gluon, nd, telemetry
     from mxnet_tpu import autograd
-    from mxnet_tpu.parallel import planner, resharding
-    from mxnet_tpu.parallel.data_parallel import TrainStep
+    from mxnet_tpu.parallel import planner
     from mxnet_tpu.parallel.functional import functionalize
 
     out = {}
     tmp = tempfile.mkdtemp(prefix="bench_elastic_")
-    cache = cc.CompileCache(os.path.join(tmp, "compile_cache"))
 
     def make_net(seed=0):
         np.random.seed(seed)
         mx.random.seed(seed)
-        from mxnet_tpu.gluon import block as _block
-
-        _block._NAME_SCOPE.counters.clear()
-        del _block._NAME_SCOPE.scope_stack[:]
         net = gluon.nn.HybridSequential()
         net.add(gluon.nn.Dense(64, activation="relu", in_units=8),
                 gluon.nn.Dense(64, activation="relu", in_units=64),
@@ -661,31 +653,6 @@ def bench_elastic():
                 gluon.nn.Dense(4, in_units=64))
         net.initialize()
         return net
-
-    def loss_fn(o, y):
-        return (o - y) ** 2
-
-    # -- restart-to-first-step: cold trace vs warm compile-cache load --
-    def first_step_s(use_cache):
-        net = make_net()
-        rng = np.random.RandomState(7)
-        x = rng.randn(8, 8).astype("f")
-        y = (rng.randn(8, 4) > 0).astype("f")
-        t0 = time.perf_counter()
-        step = TrainStep(net, loss_fn, optimizer="sgd",
-                         optimizer_params={"learning_rate": 0.1},
-                         compile_cache=cache if use_cache else None)
-        np.asarray(step(x, y))
-        dt = time.perf_counter() - t0
-        resharding.observe_restart_to_first_step(dt)
-        return dt
-
-    cold = first_step_s(use_cache=True)    # populates the cache
-    warm = first_step_s(use_cache=True)    # loads the executable
-    out["restart_to_first_step"] = {
-        "cold_s": round(cold, 4), "warm_s": round(warm, 4),
-        "speedup": round(cold / max(warm, 1e-9), 2),
-        "cache": cache.stats()}
 
     # -- live ZeRO reshard vs checkpoint-restore round trip ------------
     def plan_for(net, dp):
@@ -740,10 +707,9 @@ def bench_elastic():
             "resharded_bytes": moved,
             # at this toy scale the "disk" is tmpfs and the payload is
             # KB, so the checkpoint arm is unrealistically cheap; the
-            # live path's win is (a) no retrace (see
-            # restart_to_first_step) and (b) O(state/dp) device moves
-            # vs O(state) host round trips at real scale — the real-pod
-            # numbers are the ROADMAP's outstanding TPU round
+            # live path's win is O(state/dp) device moves vs O(state)
+            # host round trips at real scale — the real-pod numbers are
+            # the ROADMAP's outstanding TPU round
             "note": "toy-scale: tmpfs checkpoint, KB payload"}
     finally:
         os.environ.pop("MXNET_ZERO", None)
@@ -760,7 +726,7 @@ def bench_elastic():
     lnet.initialize(ctx=mx.current_context())
     lnet(mx.nd.zeros((1, 8), dtype="int32"))
     kw = dict(batch_buckets=[1], prefill_buckets=[8], kv_pages=16,
-              page_size=4, max_batch=1, compile_cache=cache)
+              page_size=4, max_batch=1)
 
     def ttft(engine):
         t0 = time.monotonic()
@@ -769,9 +735,9 @@ def bench_elastic():
         return time.monotonic() - t0
 
     cold_eng = ServingEngine(lnet, **kw)
-    cold_ttft = ttft(cold_eng)             # AOT-compiles + caches
+    cold_ttft = ttft(cold_eng)             # AOT-compiles
     joiner = ServingEngine.join_replica(lnet, cold_eng, **kw)
-    join_ttft = ttft(joiner)               # donated params + warm cache
+    join_ttft = ttft(joiner)               # donated params
     joiner.close()
     cold_eng.close()
     out["serving_replica_handoff"] = {
@@ -1193,9 +1159,7 @@ def bench_observability():
             np.asarray(step(ids, labels))    # per-step sync loop
         wall = time.perf_counter() - t0
         online = introspection.utilization()
-        # MRU head: multi-axis meshes can hold >1 AOT variant per sig
-        _, flops_per_step = step._compiled[
-            next(iter(step._compiled))][0]
+        _, flops_per_step = step._compiled[next(iter(step._compiled))]
         ndev = max(1, jax.device_count())
         offline = (iters * (flops_per_step or 0)
                    / (wall * peak * ndev)) if flops_per_step else None
@@ -1639,9 +1603,9 @@ def main():
     except Exception as e:
         extra["planner"] = {"error": repr(e)[:200]}
     try:
-        # zero-downtime elasticity (ISSUE 13): restart-to-first-step
-        # cold vs warm (compile cache), live ZeRO reshard vs checkpoint
-        # round trip, serving replica handoff join-to-first-token
+        # zero-downtime elasticity (ISSUE 13): live ZeRO reshard vs
+        # checkpoint round trip, serving replica handoff
+        # join-to-first-token
         extra["elastic"] = bench_elastic()
     except Exception as e:
         extra["elastic"] = {"error": repr(e)[:200]}

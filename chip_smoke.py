@@ -129,9 +129,8 @@ def bert_leg(net, batch, seq, steps, mesh=None):
                      batch_axes=("dp",))
     x, y = step._stage_batch(ids), step._stage_batch(labels)
     lowered = step._step.lower(
-        step._plain_tree(step.train_params),
-        step._plain_tree(step.rest_params),
-        step._plain_tree(step.opt_state), jax.random.PRNGKey(0), x, y)
+        step.train_params, step.rest_params, step.opt_state,
+        jax.random.PRNGKey(0), x, y)
     mosaic_call = "tpu_custom_call" in lowered.as_text()
 
     losses, t = [], [time.perf_counter()]

@@ -6,8 +6,8 @@ proves exactly that, against REAL engine processes:
 
 1. spawns a router + 3 engine replica processes through the
    :class:`FleetManager` warm path (one shared
-   ``MXNET_COMPILE_CACHE_DIR``: replica 1 pays the AOT compiles cold,
-   replicas 2-3 must come up measurably faster warm);
+   ``JAX_COMPILATION_CACHE_DIR``: replica 1 pays XLA's compiles cold,
+   replicas 2-3 load them and must come up measurably faster warm);
 2. drives a closed-loop healthy baseline and records replica-reported
    TTFT p99;
 3. SIGKILLs one replica mid-load: every request must complete —
@@ -114,7 +114,10 @@ def fleet_kill_run(cache_dir):
     def spawn_cmd(rid):
         return ([sys.executable, child_path],
                 {"JAX_PLATFORMS": "cpu",
-                 "MXNET_COMPILE_CACHE_DIR": cache_dir,
+                 "JAX_COMPILATION_CACHE_DIR": cache_dir,
+                 # the toy's programs compile in under the 1 s the
+                 # cache skips by default
+                 "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
                  "MXNET_TELEMETRY_PORT": "0"})
 
     mgr = FleetManager(spawn_cmd=spawn_cmd, replicas=3,

@@ -105,8 +105,8 @@ case "$LANE" in
     #    dp=4 -> dp=2 mid-run and reshards IN-FLIGHT (transfer-plan
     #    digest identical across two children), resuming bit-identically
     #    with no checkpoint round trip; a warm restart against the
-    #    shared compile cache performs ZERO fresh traces and beats the
-    #    cold restart-to-first-step
+    #    shared compile cache compiles nothing new and beats the cold
+    #    restart-to-first-step
     JAX_PLATFORMS=cpu python ci/elastic_smoke.py
     # 4) distributed flight recorder (ISSUE 15): a real 2-process run
     #    where a SIGSTOP'd child must yield a correct hang-blame

@@ -131,7 +131,6 @@ class CheckpointManager:
         self.directory = directory
         self.max_to_keep = max_to_keep
         self.logger = logger or _LOGGER
-        self._compile_cache = None
         # verify() verdict cache: step -> {file: (size, mtime_ns)} at the
         # time the step last hashed clean
         self._valid_steps = {}
@@ -581,22 +580,6 @@ class CheckpointManager:
             tpath = os.path.join(d, "trainer.states")
             if os.path.exists(tpath):
                 trainer.load_states(tpath)
-
-    @property
-    def compile_cache(self):
-        """The warm-start compile cache living beside these checkpoints
-        (``<directory>/compile_cache``; see :mod:`mxnet_tpu.
-        compile_cache`).  None when ``MXNET_COMPILE_CACHE=0``.  Lazy —
-        constructing a manager must not touch the cache dir.  Safe for
-        every process to share: entries are content-addressed and
-        published by atomic rename, so concurrent writers converge on
-        identical files."""
-        from . import compile_cache as _cc
-
-        if self._compile_cache is None and _cc.enabled():
-            self._compile_cache = _cc.CompileCache(
-                os.path.join(self.directory, "compile_cache"))
-        return self._compile_cache
 
     def read_meta(self, step):
         with open(os.path.join(self._step_dir(step), "meta.json")) as f:

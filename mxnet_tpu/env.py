@@ -209,14 +209,6 @@ Wired vars (read at ``import mxnet_tpu``):
 - ``MXNET_RESHARD_INFLIGHT_MB``: in-flight byte budget per live
   resharding transfer round (default 64 MiB; the arXiv:2112.01075
   memory bound — see :mod:`mxnet_tpu.parallel.resharding`).
-- ``MXNET_COMPILE_CACHE``: persistent warm-start compile-cache gate
-  (default 1; a cache additionally needs a directory — see
-  :mod:`mxnet_tpu.compile_cache`).
-- ``MXNET_COMPILE_CACHE_DIR``: directory for the session-default
-  compile cache (unset = only the per-checkpoint-dir caches exist).
-- ``MXNET_COMPILE_CACHE_SALT``: manual compile-cache invalidation key
-  component (bump when Python-side semantics change under an unchanged
-  signature).
 - ``MXNET_NUM_WORKERS``: launcher-provided world size for
   ``parallel.distributed.init`` (``DMLC_NUM_WORKER`` is the legacy
   alias; default 1 = single process).
@@ -482,29 +474,6 @@ def reshard_inflight_mb():
     round (MXNET_RESHARD_INFLIGHT_MB, default 64 MiB; see
     parallel/resharding.py — the arXiv:2112.01075 memory bound)."""
     return max(1, get_int("MXNET_RESHARD_INFLIGHT_MB", 64))
-
-
-def compile_cache_enabled():
-    """Whether the persistent warm-start compile cache may be used
-    (MXNET_COMPILE_CACHE, default on; a cache still needs a directory —
-    MXNET_COMPILE_CACHE_DIR or the one CheckpointManager keeps beside
-    its checkpoints)."""
-    return get_bool("MXNET_COMPILE_CACHE", True)
-
-
-def compile_cache_dir():
-    """Explicit directory for the session-default compile cache
-    (MXNET_COMPILE_CACHE_DIR, unset = no session default; checkpoint
-    managers still attach their own beside the checkpoint dir)."""
-    return get_str("MXNET_COMPILE_CACHE_DIR")
-
-
-def compile_cache_salt():
-    """Extra cache-key component for manual invalidation
-    (MXNET_COMPILE_CACHE_SALT, default empty — bump it when Python-side
-    semantics change under an unchanged signature, e.g. a rewritten
-    loss closure)."""
-    return get_str("MXNET_COMPILE_CACHE_SALT", "") or ""
 
 
 def launcher_rank():
@@ -874,14 +843,6 @@ def describe():
         ("MXNET_RESHARD_INFLIGHT_MB", "in-flight byte budget per live "
          "resharding transfer round (default 64 MiB; "
          "parallel/resharding.py)"),
-        ("MXNET_COMPILE_CACHE", "persistent warm-start compile cache "
-         "gate (default 1; needs a directory — see "
-         "MXNET_COMPILE_CACHE_DIR; mxnet_tpu/compile_cache.py)"),
-        ("MXNET_COMPILE_CACHE_DIR", "directory for the session-default "
-         "compile cache (unset = only checkpoint-side caches)"),
-        ("MXNET_COMPILE_CACHE_SALT", "manual cache-invalidation key "
-         "component (bump when Python semantics change under an "
-         "unchanged signature)"),
         ("MXNET_SUBGRAPH_BACKEND", "subgraph backend applied at Module "
          "bind time (mxnet_tpu.subgraph; unset = none)"),
         ("MXNET_NUM_WORKERS", "launcher world size for distributed.init "

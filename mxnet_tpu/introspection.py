@@ -4,16 +4,15 @@ The bench rounds compute MFU offline, once per round, from analytic
 FLOP formulas.  This module makes utilization a *live* metric: every
 compiled executable's FLOP count is captured ONCE at compile time from
 XLA's own cost model (``lower(...).compile().cost_analysis()`` — the
-TrainStep AOT path, the serving prefill/decode/sample grid, and
-compile-cache warm loads, which carry the count in the cache entry so a
-warm start never re-derives it), and every steady-state dispatch does
+TrainStep AOT path and the serving prefill/decode/sample grid), and
+every steady-state dispatch does
 nothing but a host-side float add into a trailing window.  From the
 window and a per-device peak-FLOPs registry two gauges fall out:
 
 - ``mxnet_model_flops_utilization`` — dispatched FLOPs over
   ``elapsed × peak × device_count`` for the trailing window.  The gauge
   is created LAZILY: when ``cost_analysis`` is unavailable (platform
-  quirk, warm load without a recorded count) or the device peak is
+  quirk) or the device peak is
   unknown (non-TPU backend, no ``MXNET_DEVICE_PEAK_FLOPS`` override),
   the gauge is simply **absent** — never present-but-wrong.
 - ``mxnet_executable_flops_total{kind}`` — raw dispatched FLOPs by

@@ -143,8 +143,7 @@ class TrainStep:
     def __init__(self, net, loss_fn, optimizer="sgd", optimizer_params=None,
                  mesh=None, param_sharding="replicated", extra_param_specs=None,
                  batch_axes=("dp", "fsdp"), donate=True, train_mode=True,
-                 dtype=None, pipeline=None, remat=False, plan=None,
-                 compile_cache=None):
+                 dtype=None, pipeline=None, remat=False, plan=None):
         """``pipeline``: dict enabling pipeline parallelism over a mesh
         axis — {'num_microbatches': M, 'axis': 'pp', 'schedule':
         'gpipe'|'1f1b', 'remat_stage': bool}.  The net must implement
@@ -304,10 +303,11 @@ class TrainStep:
             params = OrderedDict((k, jnp.array(v, copy=True))
                                  for k, v in params.items())
 
-        train_names = self._train_names
-        self.train_params = OrderedDict((k, params[k]) for k in train_names)
-        self.rest_params = OrderedDict(
-            (k, v) for k, v in params.items() if k not in self.train_params)
+        # plain dicts, as the step hands them back: a compiled executable
+        # takes the tree structure it was lowered with and no other
+        self.train_params = {k: params[k] for k in self._train_names}
+        self.rest_params = {k: v for k, v in params.items()
+                            if k not in self.train_params}
         self.opt_state = init(self.train_params)
         if mesh is not None:
             self.opt_state = jax.tree_util.tree_map(
@@ -446,51 +446,27 @@ class TrainStep:
         train_step.__name__ = train_step.__qualname__ = \
             f"train_step_{_profiler.scope_digest()}"
         donate_argnums = (0, 1, 2) if donate else ()
-        self._step = jax.jit(train_step, donate_argnums=donate_argnums)
+        jit_kw = {}
+        if mesh is not None:
+            # a mesh step returns its state in the layout it was placed
+            # with, so the executable takes what it gave and the plan's
+            # layout is the layout that runs
+            replicated = NamedSharding(mesh, P())
+            jit_kw["out_shardings"] = (replicated,) + jax.tree_util.tree_map(
+                lambda leaf: leaf.sharding,
+                (self.train_params, self.rest_params, self.opt_state)) \
+                + (replicated,)
+        self._step = jax.jit(train_step, donate_argnums=donate_argnums,
+                             **jit_kw)
         self._rng_seed = 0
         self.step_count = 0      # steps taken (lifecycle train_state)
         self._seen_sigs = set()  # telemetry: (x, y) avals already compiled
-        # warm-start compile cache (mxnet_tpu/compile_cache.py): a
-        # cached lowered executable for this exact signature skips the
-        # trace entirely on resume — zero fresh traces, compile-tracer
-        # visible only as a cache hit.  Static config the avals cannot
-        # see rides the key: optimizer/pipeline/AMP config, the net's
-        # structural repr (gluon reprs carry layer classes, units and
-        # activations, so an architecture edit under unchanged param
-        # shapes misses), and loss_fn's qualname.  Python BODY edits
-        # under an unchanged structure/name are the one thing no key
-        # component can see — bump MXNET_COMPILE_CACHE_SALT (README
-        # "Elasticity" documents the invalidation matrix).
-        from .. import compile_cache as _ccache
-
-        self._cc = _ccache.resolve(compile_cache)
-        self._cc_fns = {}        # batch sig -> cached callable | None
-        self._cc_meta = {}       # batch sig -> cache-entry meta (flops)
-        self._cc_pending = {}    # batch sig -> (key, avals) to store
-        # per-signature AOT executables (lower().compile() on the cold
-        # path): the compiled object is what steady state dispatches,
-        # and its cost_analysis() FLOP count — captured ONCE here, at
-        # compile time — feeds the online MFU gauge with zero
-        # steady-state work (mxnet_tpu/introspection.py).  Each sig
-        # keeps a small MRU list of (compiled, flops) variants: GSPMD
-        # may hand the first step's outputs back in a different layout
-        # than the plan placed, and the re-lower at the drifted-stable
-        # layout is the same silent recompile jit dispatch performed
-        # here before the AOT path existed
-        self._compiled = {}      # batch sig -> [(compiled, flops)]
-        pipe_key = None
-        if self._pipeline is not None:
-            pipe_key = (self._pipeline["M"], self._pipeline["axis"],
-                        self._pipeline["schedule"],
-                        self._pipeline["remat_stage"],
-                        self._pipeline["batch_axes"])
-        self._cc_extra = (
-            optimizer, tuple(sorted(opt_params.items())), str(dtype),
-            bool(remat), pipe_key, bool(train_mode), bool(donate),
-            getattr(loss_fn, "__qualname__", None) or repr(loss_fn),
-            " ".join(repr(net).split()),
-            tuple(sorted((k, str(v)) for k, v in
-                         (extra_param_specs or {}).items())))
+        # one AOT executable a batch signature (lower().compile() on the
+        # cold path): the compiled object is what steady state dispatches,
+        # and its cost_analysis() FLOP count, captured once at compile
+        # time, feeds the online MFU gauge with no steady-state work
+        # (mxnet_tpu/introspection.py)
+        self._compiled = {}      # batch sig -> (compiled, flops)
 
     @property
     def params(self):
@@ -514,65 +490,6 @@ class TrainStep:
         from ..gluon.data.prefetcher import stage_leaf
 
         return stage_leaf(v, self._batch_shard)
-
-    @staticmethod
-    def _plain_tree(t):
-        """Canonicalize mapping containers to plain dicts.  The step's
-        state trees drift between OrderedDict and dict across calls
-        (``step`` rebuilds ``rest_params`` with ``dict()``); jax.jit
-        shrugs, but an exported artifact's calling convention is
-        structure-STRICT — so the compile-cache path speaks plain dicts
-        on both the export and every invocation.  Key-based flattening
-        means the leaf mapping is unchanged."""
-        if isinstance(t, dict):
-            return {k: TrainStep._plain_tree(v) for k, v in t.items()}
-        if isinstance(t, tuple):
-            return tuple(TrainStep._plain_tree(v) for v in t)
-        if isinstance(t, list):
-            return [TrainStep._plain_tree(v) for v in t]
-        return t
-
-    def _cc_avals(self, rng, x, y):
-        """ShapeDtypeStruct pytree mirroring one _step invocation's
-        operands (shardings preserved — a resharded layout must key
-        differently), canonicalized to plain-dict structure."""
-        import jax
-
-        def aval(v):
-            return jax.ShapeDtypeStruct(
-                tuple(v.shape), v.dtype,
-                sharding=getattr(v, "sharding", None))
-
-        return self._plain_tree((
-            jax.tree_util.tree_map(aval, self.train_params),
-            jax.tree_util.tree_map(aval, self.rest_params),
-            jax.tree_util.tree_map(aval, self.opt_state),
-            aval(rng), jax.tree_util.tree_map(aval, x), aval(y)))
-
-    def _cc_lookup(self, sig, rng, x, y):
-        """Resolve the cached executable for one batch signature (once
-        per sig): a hit replaces self._step for that sig; a miss
-        schedules an export right after the first (tracing) call."""
-        import jax
-        import jax.numpy as jnp
-
-        from .. import compile_cache as _ccache
-
-        x = jax.tree_util.tree_map(
-            lambda v: v if hasattr(v, "shape") else jnp.asarray(v), x)
-        y = y if hasattr(y, "shape") else jnp.asarray(y)
-        avals = self._cc_avals(rng, x, y)
-        key = self._cc.key(
-            f"train_step:{type(self._net).__name__}",
-            (_ccache.aval_signature(avals), self._cc_extra),
-            plan_digest=self._plan.digest()
-            if self._plan is not None else None)
-        fn, meta = self._cc.load_executable_entry(key)
-        self._cc_fns[sig] = fn
-        self._cc_meta[sig] = meta
-        if fn is None:
-            self._cc_pending[sig] = (key, avals)
-        return fn
 
     def __call__(self, x, y):
         """One step on the batch ``(x, y)``.  ``x`` is the net's input, or a
@@ -599,20 +516,6 @@ class TrainStep:
                         + (y,)
                         for part in (tuple(getattr(v, "shape", ())),
                                      str(getattr(v, "dtype", ""))))
-            step_fn = self._step
-            flops = None
-            if self._cc is not None:
-                cached = self._cc_fns[sig] if sig in self._cc_fns else \
-                    self._cc_lookup(sig, rng, x, y)
-                if cached is not None:
-                    # warm start: no trace happens, so no compile event —
-                    # the cache-hit counter carries the observability and
-                    # the zero-fresh-trace assertion holds by construction.
-                    # The FLOP count rides the cache entry (stored with
-                    # the executable), so MFU accounting stays warm too.
-                    step_fn = cached
-                    self._seen_sigs.add(sig)
-                    flops = self._cc_meta.get(sig, {}).get("flops")
             fresh = sig not in self._seen_sigs \
                 and len(self._seen_sigs) < 4096
             if fresh:
@@ -620,22 +523,12 @@ class TrainStep:
 
                 self._seen_sigs.add(sig)
                 t0 = _t.perf_counter()
-            # plain-dict calling convention for EVERY dispatch (see
-            # _plain_tree): the step's state trees drift OrderedDict→dict
-            # across calls, and both the AOT executable and a cached
-            # exported artifact are structure-strict; key-based flattening
-            # keeps the leaf mapping identical either way
-            args = (self._plain_tree(self.train_params),
-                    self._plain_tree(self.rest_params),
-                    self._plain_tree(self.opt_state), rng, x, y)
-        if step_fn is self._step:
-            # per-signature AOT: the cold path lowers + compiles ONCE
-            # (capturing XLA's cost_analysis FLOPs while the executable
-            # is in hand); steady state is one dict lookup + dispatch —
-            # no retrace, no host sync, no new work
-            out, flops = self._call_aot(sig, args)
-        else:
-            out = self._execute(step_fn, args)
+            args = (self.train_params, self.rest_params, self.opt_state,
+                    rng, x, y)
+        # the cold path lowers + compiles once (capturing XLA's
+        # cost_analysis FLOPs while the executable is in hand); steady
+        # state is one dict lookup + dispatch: no retrace, no host sync
+        out, flops = self._call_aot(sig, args)
         loss, self.train_params, self.rest_params, self.opt_state, \
             scalars = out
         # read when they are there, by a later call or by who reads the
@@ -651,18 +544,6 @@ class TrainStep:
                 "train_step", type(self._net).__name__,
                 _t.perf_counter() - t0,
                 "new_step" if len(self._seen_sigs) == 1 else "new_shape")
-            pending = self._cc_pending.pop(sig, None)
-            if pending is not None:
-                # cold path: persist the executable so the NEXT process
-                # with this signature starts warm (the export re-traces
-                # once — still the cold path, and our tracer already
-                # recorded this signature's compile above).  The FLOP
-                # count rides the entry so the warm process keeps its
-                # MFU gauge without a compile to ask.
-                key, avals = pending
-                self._cc.store_executable(
-                    key, self._step, *avals,
-                    meta={"flops": flops} if flops else None)
         return loss
 
     @staticmethod
@@ -686,35 +567,13 @@ class TrainStep:
         return (compiled, _introspection.flops_of(compiled))
 
     def _call_aot(self, sig, args):
-        """Dispatch one step through the per-signature AOT executables;
-        returns ``(outputs, flops)``.
-
-        A compiled object is layout-STRICT: when GSPMD hands a step's
-        outputs back in a different sharding than it was lowered with
-        (observed on multi-axis meshes — the plan places ``P('tp',
-        None)``, the executable returns ``P('fsdp')``), the next call
-        raises ValueError.  jit dispatch used to absorb exactly this
-        with a silent recompile; here ANY ValueError from a compiled
-        variant falls through to a fresh re-lower at the current
-        operand layout (the error message wording is not a stable API,
-        so no substring matching) — a genuine error reproduces on the
-        freshly-lowered executable and propagates from there, costing
-        one extra compile, never masking.  The small MRU variant list
-        keeps a ping-ponging layout from recompiling every step."""
-        variants = self._compiled.setdefault(sig, [])
-        if not variants:
-            variants.append(self._aot_step(args))
-        for i, (compiled, flops) in enumerate(variants):
-            try:
-                out = self._execute(compiled, args)
-            except ValueError:
-                continue
-            if i:
-                variants.insert(0, variants.pop(i))
-            return out, flops
-        entry = self._aot_step(args)
-        variants.insert(0, entry)
-        del variants[4:]
+        """Dispatch one step through the signature's AOT executable,
+        compiled at its first call; returns ``(outputs, flops)``.  An
+        error from the executable propagates: it takes the layout it
+        returns, so there is no other to lower for."""
+        entry = self._compiled.get(sig)
+        if entry is None:
+            entry = self._compiled[sig] = self._aot_step(args)
         compiled, flops = entry
         return self._execute(compiled, args), flops
 

@@ -40,7 +40,6 @@ from collections import deque
 
 import numpy as _np
 
-from .. import compile_cache as _ccache
 from .. import env as _env
 from .. import fault as _fault
 from .. import introspection as _introspection
@@ -131,8 +130,7 @@ class ServingEngine:
     def __init__(self, net, *, batch_buckets=None, prefill_buckets=None,
                  kv_pages=None, page_size=None, queue_bound=None,
                  max_batch=None, deadline_ms=None, name=None, plan=None,
-                 params_from=None, compile_cache=None,
-                 trace_requests=None):
+                 params_from=None, trace_requests=None):
         from ..gluon.model_zoo.language.llama import (LlamaForCausalLM,
                                                       serving_params)
 
@@ -159,10 +157,6 @@ class ServingEngine:
         self._plan = plan
         self._serve_mesh = None
         self._rep_sharding = None
-        # warm-start compile cache (explicit > MXNET_COMPILE_CACHE_DIR
-        # session default > none): a warm engine start loads every AOT
-        # executable instead of tracing it — zero compile events
-        self._cc = _ccache.resolve(compile_cache)
         # replica handoff (join_replica): a RUNNING donor engine hands
         # its frozen params over through the live-resharding transfer
         # (donor plan -> this plan) while it keeps serving — its param
@@ -457,33 +451,6 @@ class ServingEngine:
         # serving's logits gather before sampling
         jit_kw = {} if self._plan is None else \
             {"out_shardings": self._rep_sharding}
-        # warm-start path: a persisted executable for this exact
-        # signature (avals + plan digest + jax fingerprint) skips the
-        # trace AND the XLA compile — no compile event is recorded
-        # because no trace happened (the cache-hit counter carries the
-        # observability; the PR 3 zero-fresh-trace assertions rely on
-        # exactly this)
-        ckey = None
-        if self._cc is not None:
-            # cfg fields ride the key: two configs with identical param
-            # shapes (rope_base, rms_eps, ...) compile DIFFERENT math
-            cfg_fp = tuple(sorted(
-                (k, repr(v)) for k, v in vars(self._cfg).items()))
-            ckey = self._cc.key(
-                f"serving:{self._name}:{phase}",
-                (repr(key), cfg_fp,
-                 _ccache.aval_signature(param_avals),
-                 _ccache.aval_signature(pool_aval)),
-                plan_digest=self._plan.digest()
-                if self._plan is not None else None)
-            cached, cmeta = self._cc.load_executable_entry(ckey)
-            if cached is not None:
-                # warm load: the FLOP count rides the cache entry, so
-                # online MFU accounting stays fed with no compile to ask
-                with self._lock:
-                    self._exec[key] = cached
-                    self._exec_flops[key] = cmeta.get("flops")
-                return cached
         if phase == "prefill":
             jit_fn = jax.jit(self._prefill_body(dims["L"], dims["P"]),
                              donate_argnums=(1, 2), **jit_kw)
@@ -508,10 +475,6 @@ class ServingEngine:
                          [f"{k}{v}" for k, v in sorted(dims.items())])
         _telemetry.compile_event("serving", label,
                                  time.perf_counter() - t0, cause)
-        if ckey is not None:
-            self._cc.store_executable(
-                ckey, jit_fn, *aot_args,
-                meta={"flops": flops} if flops else None)
         return compiled
 
     def _aot_warmup(self):

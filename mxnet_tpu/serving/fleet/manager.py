@@ -12,10 +12,9 @@ Two replica modes share the machinery:
 - **process mode** (:class:`ProcessReplica`): the manager launches
   ``spawn_cmd(rid)``'s argv, waits for the engine's ready line on
   stdout, and talks HTTP through the transport funnel.  Warm
-  replacement = every replica sharing one ``MXNET_COMPILE_CACHE_DIR``:
-  the first replica pays the AOT compiles, every later spawn loads
-  the persisted executables and serves its first token several times
-  faster (the PR 13 warm-restart property, now a fleet recovery
+  replacement = every replica sharing one ``JAX_COMPILATION_CACHE_DIR``:
+  the first replica pays XLA's compiles, every later spawn traces its
+  manifest and loads the executables from that cache (a fleet recovery
   bound).
 - **local mode** (``engine_factory``): in-process replicas for unit
   tests, bench, and embedders.  The factory receives a running donor

@@ -11,10 +11,9 @@ existing layers:
   deterministic candidate schedule, scored by the live PR 14 gauges
   (step time / MFU for training arms, tokens/s + p99 TTFT for
   serving);
-- **persistence** — :mod:`.db`: winners on disk, keyed like
-  compile-cache entries (signature + plan digest + device kind + jax
-  fingerprint), sha256-verified, atomic publish, corrupt = silent
-  miss.
+- **persistence** — :mod:`.db`: winners on disk, keyed by signature +
+  plan digest + device kind + jax fingerprint, sha256-verified, atomic
+  publish, corrupt = silent miss.
 
 Every consumer — ``TrainStep``/kvstore bucketing, the graph
 ``PassPipeline``, the prefetcher, the ``ServingEngine`` — resolves its

@@ -1,11 +1,9 @@
-"""Persistent per-signature tuning DB: search winners on disk, keyed
-like compile-cache entries.
+"""Persistent per-signature tuning DB: search winners on disk.
 
 The persistence half of the TVM loop (PAPERS.md, arXiv:1802.04799):
 an offline ``bench.py --tune`` run measures candidates and publishes
 the winner; every later process — same program, same plan, same
-device kind, same jax — replays it with **zero search trials**.  The
-on-disk discipline is ``compile_cache.py``'s, byte for byte in spirit:
+device kind, same jax — replays it with **zero search trials**.
 
 Key = sha256 over:
 
@@ -110,7 +108,7 @@ def resolve_db(explicit):
 
 class TuningDB:
     """One on-disk winner directory (content-addressed, atomic-publish,
-    sha256-verified — the compile-cache discipline)."""
+    sha256-verified)."""
 
     def __init__(self, directory, logger=None):
         self.directory = directory

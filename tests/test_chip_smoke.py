@@ -168,9 +168,8 @@ def test_sharded_step_runs_the_kernel_per_batch_shard(toy_bert, monkeypatch):
     x = step._stage_batch(np.zeros((4, 256), "int32"))
     y = step._stage_batch(np.zeros((4, 257), "int32"))
     text = step._step.trace(
-        step._plain_tree(step.train_params),
-        step._plain_tree(step.rest_params),
-        step._plain_tree(step.opt_state), jax.random.PRNGKey(0), x, y,
+        step.train_params, step.rest_params, step.opt_state,
+        jax.random.PRNGKey(0), x, y,
     ).lower(lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" in text and "sdy.manual_computation" in text
 

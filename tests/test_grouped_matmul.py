@@ -301,9 +301,7 @@ def _decoder_step(layers, dtype="bfloat16", width=256):
                      dtype=None if dtype == "float32" else dtype,
                      optimizer_params={"learning_rate": 1e-4})
     ids = np.zeros((1, 128), np.int32)
-    return step, (TrainStep._plain_tree(step.train_params),
-                  TrainStep._plain_tree(step.rest_params),
-                  TrainStep._plain_tree(step.opt_state),
+    return step, (step.train_params, step.rest_params, step.opt_state,
                   jax.random.PRNGKey(0), ids, ids)
 
 

@@ -1,5 +1,6 @@
-"""Zero-downtime elasticity: plan-to-plan live resharding + the
-warm-start compile cache (ISSUE 13).
+"""Zero-downtime elasticity: plan-to-plan live resharding + the fused
+step's one dispatch route and the one persistent cache a restart warms
+from (ISSUE 13, ISSUE 44).
 
 Acceptance pins:
 - the transfer plan is pure and digest-stable (identical across fresh
@@ -8,9 +9,10 @@ Acceptance pins:
   the uninterrupted run and the checkpoint-restore path;
 - a ``resharding.transfer`` fault costs one supervised retry, never
   torn state;
-- a corrupt/truncated compile-cache entry degrades to a clean miss;
-- a warm TrainStep restart performs ZERO fresh traces
-  (compile-tracer-asserted, in a real child process);
+- a warm restart (TrainStep, serving) finds every executable in JAX's
+  persistent cache and adds none (in real child processes);
+- a TrainStep keeps one executable a signature, donates its state at
+  every step and, over a mesh, hands it back in the placed layout;
 - serving replica handoff: the joiner's output bit-matches, the donor
   keeps serving, join-to-first-token is measured.
 """
@@ -23,7 +25,7 @@ import numpy as np
 import pytest
 
 import mxnet_tpu as mx
-from mxnet_tpu import autograd, compile_cache, fault, gluon, nd, telemetry
+from mxnet_tpu import autograd, fault, gluon, nd, telemetry
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.checkpoint import CheckpointManager, run_with_recovery
 from mxnet_tpu.parallel import planner, resharding
@@ -438,160 +440,153 @@ def test_run_with_recovery_checkpoint_progress_after_lost_live_reshard(
 
 
 # ---------------------------------------------------------------------------
-# compile cache: verification + corruption semantics
+# warm restart: the one persistent cache (JAX's) holds what a second
+# process needs
 # ---------------------------------------------------------------------------
-def test_compile_cache_roundtrip_and_stats(tmp_path):
-    cc = compile_cache.CompileCache(str(tmp_path / "cc"))
-    key = cc.key("unit", ("sig", 1), plan_digest="abc")
-    assert cc.get_bytes(key) is None            # cold miss
-    assert cc.put_bytes(key, b"payload-bytes", meta={"k": 1})
-    assert cc.get_bytes(key) == b"payload-bytes"
-    st = cc.stats()
-    assert st["entries"] == 1 and st["bytes"] > 0
-
-
-def test_compile_cache_corrupt_and_truncated_entries_miss_cleanly(
-        tmp_path):
-    cc = compile_cache.CompileCache(str(tmp_path / "cc"))
-    key = cc.key("unit", ("sig", 2))
-    cc.put_bytes(key, b"x" * 256)
-    path = cc._path(key)
-    # bit flip in the payload
-    blob = bytearray(open(path, "rb").read())
-    blob[-1] ^= 0xFF
-    open(path, "wb").write(bytes(blob))
-    assert cc.get_bytes(key) is None            # corrupt = silent miss
-    # truncation
-    cc.put_bytes(key, b"y" * 256)
-    full = open(path, "rb").read()
-    open(path, "wb").write(full[:len(full) // 2])
-    assert cc.get_bytes(key) is None
-    # torn header / not even a header
-    open(path, "wb").write(b"\x00\x01garbage")
-    assert cc.get_bytes(key) is None
-    # load_executable on garbage: also a miss, never a raise
-    cc.put_bytes(key, b"not an executable")
-    assert cc.load_executable(key) is None
-
-
-def test_compile_cache_key_components(tmp_path):
-    cc = compile_cache.CompileCache(str(tmp_path / "cc"))
-    k1 = cc.key("a", ("s",), plan_digest="p1")
-    assert k1 == cc.key("a", ("s",), plan_digest="p1")
-    assert k1 != cc.key("a", ("s",), plan_digest="p2")   # replan
-    assert k1 != cc.key("b", ("s",), plan_digest="p1")   # consumer
-    os.environ["MXNET_COMPILE_CACHE_SALT"] = "v2"
-    try:
-        assert k1 != cc.key("a", ("s",), plan_digest="p1")  # salt
-    finally:
-        os.environ.pop("MXNET_COMPILE_CACHE_SALT")
-
-
-def test_checkpoint_manager_owns_a_cache_beside_checkpoints(tmp_path):
-    mgr = CheckpointManager(str(tmp_path / "ck"))
-    cc = mgr.compile_cache
-    assert cc is not None
-    assert cc.directory == os.path.join(mgr.directory, "compile_cache")
-    os.environ["MXNET_COMPILE_CACHE"] = "0"
-    try:
-        assert CheckpointManager(
-            str(tmp_path / "ck2")).compile_cache is None
-    finally:
-        os.environ.pop("MXNET_COMPILE_CACHE")
-
-
-_WARM_CHILD = """
+_CHILD_HEAD = """
 import sys; sys.path.insert(0, {root!r})
-import os, json
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in flags:
-    os.environ["XLA_FLAGS"] = (
-        flags + " --xla_force_host_platform_device_count=8").strip()
+import json
 import numpy as np
 import mxnet_tpu as mx
-from mxnet_tpu import gluon, telemetry
-from mxnet_tpu.parallel.data_parallel import TrainStep
-from mxnet_tpu import compile_cache as cc
+"""
 
-cache = cc.CompileCache(sys.argv[1])
+_WARM_CHILDREN = {
+    "train_step": _CHILD_HEAD + """
+from mxnet_tpu import gluon
+from mxnet_tpu.parallel.data_parallel import TrainStep
+
 np.random.seed(0); mx.random.seed(0)
 net = gluon.nn.HybridSequential()
 net.add(gluon.nn.Dense(16, activation="relu", in_units=8),
         gluon.nn.Dense(4, in_units=16))
 net.initialize()
-
-def loss_fn(out, y):
-    return (out - y) ** 2
-
-before = telemetry.snapshot()["compile"]["count"]
-step = TrainStep(net, loss_fn, optimizer="sgd",
+step = TrainStep(net, lambda out, y: (out - y) ** 2, optimizer="sgd",
                  optimizer_params={{"learning_rate": 0.1,
-                                    "momentum": 0.9}},
-                 compile_cache=cache)
+                                    "momentum": 0.9}})
 rng = np.random.RandomState(7)
-losses = []
+walked = []
 for _ in range(3):
     x = rng.randn(8, 8).astype("f")
     y = (rng.randn(8, 4) > 0).astype("f")
-    losses.append(float(np.asarray(step(x, y))))
-after = telemetry.snapshot()["compile"]["count"]
-psum = float(sum(np.asarray(v).sum()
-                 for v in step.train_params.values()))
-print(json.dumps({{"traces": after - before, "losses": losses,
-                   "psum": psum}}))
-"""
+    walked.append(float(np.asarray(step(x, y))))
+walked.append(float(sum(np.asarray(v).sum()
+                        for v in step.train_params.values())))
+print(json.dumps(walked))
+""",
+    "serving": _CHILD_HEAD + """
+from mxnet_tpu.gluon.model_zoo.language import llama
+from mxnet_tpu.serving.engine import ServingEngine
+
+np.random.seed(0); mx.random.seed(0)
+net = llama.LlamaForCausalLM(llama.LlamaConfig(
+    vocab_size=32, hidden_size=16, num_layers=1, num_heads=2,
+    num_kv_heads=1, intermediate_size=32, max_seq_len=32))
+net.initialize(ctx=mx.cpu())
+eng = ServingEngine(net, batch_buckets=[1], prefill_buckets=[8],
+                    kv_pages=3, page_size=4, max_batch=1)
+eng.start()
+out = eng.submit([1, 2, 3, 4], max_new_tokens=3).result(60)
+eng.close()
+print(json.dumps(out["token_ids"]))
+""",
+}
 
 
-def test_warm_restart_zero_fresh_traces(tmp_path):
-    """The headline assertion: a second process with the same TrainStep
-    config performs ZERO fresh traces (compile-tracer-asserted) and
-    walks a bit-identical trajectory."""
-    cache_dir = str(tmp_path / "cc")
-    child = _WARM_CHILD.format(root=REPO_ROOT)
+@pytest.mark.parametrize("program", sorted(_WARM_CHILDREN))
+def test_warm_restart_adds_nothing_to_the_persistent_cache(
+        program, tmp_path):
+    """A second process of the same program finds every executable it
+    needs in JAX's persistent cache (it stores none) and walks the same
+    losses / tokens: the warm-restart property, of the one cache."""
+    cache_dir = tmp_path / "jax_cache"
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(cache_dir),
+               # the toys compile in under the 1 s the cache skips
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
 
     def run():
-        r = subprocess.run([sys.executable, "-c", child, cache_dir],
-                           capture_output=True, text=True, timeout=300)
+        r = subprocess.run(
+            [sys.executable, "-c",
+             _WARM_CHILDREN[program].format(root=REPO_ROOT)],
+            env=env, capture_output=True, text=True, timeout=300)
         assert r.returncode == 0, r.stderr
-        return json.loads(r.stdout.strip().splitlines()[-1])
+        stored = sorted(f for f in os.listdir(cache_dir)
+                        if not f.endswith("-atime"))
+        return json.loads(r.stdout.strip().splitlines()[-1]), stored
 
-    cold = run()
-    warm = run()
-    assert cold["traces"] > 0          # the cold run really traced
-    assert warm["traces"] == 0         # the warm run did NOT
-    assert warm["losses"] == cold["losses"]
-    assert warm["psum"] == cold["psum"]
+    cold, stored = run()
+    assert stored                      # the cold run filled the cache
+    warm, stored_after = run()
+    assert stored_after == stored      # the warm run compiled nothing
+    assert warm == cold
 
 
-def test_trainstep_cache_hit_in_process(tmp_path):
-    """Same-process hit path: a second TrainStep over an identical
-    config serves from the cache with no new compile events and walks
-    the identical trajectory."""
-    cache = compile_cache.CompileCache(str(tmp_path / "cc"))
+# ---------------------------------------------------------------------------
+# TrainStep: one dispatch route
+# ---------------------------------------------------------------------------
+def _sq_loss(out, y):
+    return (out - y) ** 2
 
-    def loss_fn(out, y):
-        return (out - y) ** 2
 
-    def run():
-        from mxnet_tpu.parallel.data_parallel import TrainStep
+def _tiny_step(**kw):
+    from mxnet_tpu.parallel.data_parallel import TrainStep
 
-        net = _tiny_net(seed=3)
-        step = TrainStep(net, loss_fn, optimizer="sgd",
-                         optimizer_params={"learning_rate": 0.1},
-                         compile_cache=cache)
-        rng = np.random.RandomState(5)
-        losses = [float(np.asarray(step(
-            rng.randn(8, 8).astype("f"),
-            (rng.randn(8, 4) > 0).astype("f")))) for _ in range(2)]
-        return losses
+    return TrainStep(_tiny_net(seed=3), _sq_loss, optimizer="sgd",
+                     optimizer_params={"learning_rate": 0.1,
+                                       "momentum": 0.9}, **kw)
 
-    first = run()
-    before = telemetry.snapshot()["compile"]["count"]
-    second = run()
-    after = telemetry.snapshot()["compile"]["count"]
-    assert second == first
-    assert after - before == 0
+
+def _batch(rows=8, seed=5):
+    rng = np.random.RandomState(seed)
+    return (rng.randn(rows, 8).astype("f"),
+            (rng.randn(rows, 4) > 0).astype("f"))
+
+
+def test_every_step_donates_its_state():
+    """The state a step takes is the state it gives back, in place: after
+    every call, the first one too, the buffers it was handed are gone."""
+    step = _tiny_step()
+    for _ in range(3):
+        held = jax.tree_util.tree_leaves(
+            (step.train_params, step.opt_state))
+        step(*_batch())
+        assert held and all(leaf.is_deleted() for leaf in held)
+
+
+def test_one_executable_a_signature():
+    step = _tiny_step()
+    for _ in range(5):
+        for rows in (8, 4):
+            step(*_batch(rows))
+    assert len(step._seen_sigs) == 2
+    assert sorted(step._compiled) == sorted(step._seen_sigs)
+    for compiled, flops in step._compiled.values():
+        assert callable(compiled) and (flops is None or flops > 0)
+    assert step.step_count == 10
+
+
+@pytest.mark.parametrize("fault_kind", ["shape", "device"])
+def test_a_failing_executable_raises(fault_kind, monkeypatch):
+    """A state the signature's executable cannot take is the caller's
+    error: it comes out of the call, and nothing else is lowered in the
+    executable's place."""
+    step = _tiny_step()
+    step(*_batch())
+    (sig, entry), = step._compiled.items()
+    lowered = []
+    real = step._aot_step
+    monkeypatch.setattr(
+        step, "_aot_step",
+        lambda args: lowered.append(1) or real(args))
+    name = sorted(step.train_params)[0]
+    leaf = step.train_params[name]
+    step.train_params[name] = jnp.zeros((3, 3), leaf.dtype) \
+        if fault_kind == "shape" else \
+        jax.device_put(np.asarray(leaf), jax.devices()[1])
+    with pytest.raises((TypeError, ValueError)):
+        step(*_batch())
+    assert not lowered
+    assert step._compiled == {sig: entry}
 
 
 # ---------------------------------------------------------------------------
@@ -672,25 +667,6 @@ def test_serving_decode_fault_absorbed_no_torn_state():
         assert fault.stats()["serving.decode_step"]["trips"] == 2
     finally:
         eng.close()
-
-
-def test_serving_warm_start_zero_traces_same_config(tmp_path):
-    from mxnet_tpu.serving.engine import ServingEngine
-
-    cache = compile_cache.CompileCache(str(tmp_path / "cc"))
-    net = _make_llama_net()
-    eng = ServingEngine(net, compile_cache=cache, **_SERVE_KW)
-    eng.start()
-    ref = eng.submit([1, 2, 3, 4], max_new_tokens=3).result(60)
-    eng.close()
-    before = telemetry.snapshot()["compile"]["count"]
-    eng2 = ServingEngine(net, compile_cache=cache, **_SERVE_KW)
-    eng2.start()
-    out = eng2.submit([1, 2, 3, 4], max_new_tokens=3).result(60)
-    after = telemetry.snapshot()["compile"]["count"]
-    eng2.close()
-    assert after - before == 0
-    assert out["token_ids"] == ref["token_ids"]
 
 
 # ---------------------------------------------------------------------------

@@ -79,10 +79,8 @@ def _phase_counts():
 def test_lowered_step_carries_the_scopes(toy_bert, options):
     step = _step(toy_bert, **options)
     x, y = _batch()
-    args = (TrainStep._plain_tree(step.train_params),
-            TrainStep._plain_tree(step.rest_params),
-            TrainStep._plain_tree(step.opt_state), jax.random.PRNGKey(0),
-            x, y)
+    args = (step.train_params, step.rest_params, step.opt_state,
+            jax.random.PRNGKey(0), x, y)
     hlo = step._step.lower(*args).as_text(dialect="hlo", debug_info=True)
     forward = f"jvp({profiler.SCOPE_FORWARD})/"
     assert forward in hlo
@@ -417,10 +415,8 @@ def net_of(request):
 def _lowered(net, loss, batch, debug_info=False):
     step = TrainStep(net, loss, optimizer="adam",
                      optimizer_params={"learning_rate": 1e-4})
-    args = (TrainStep._plain_tree(step.train_params),
-            TrainStep._plain_tree(step.rest_params),
-            TrainStep._plain_tree(step.opt_state), jax.random.PRNGKey(0),
-            *batch)
+    args = (step.train_params, step.rest_params, step.opt_state,
+            jax.random.PRNGKey(0), *batch)
     lowered = step._step.lower(*args)
     return lowered.as_text(dialect="hlo", debug_info=True) if debug_info \
         else lowered.as_text()

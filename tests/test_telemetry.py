@@ -624,9 +624,32 @@ def test_aot_flops_match_cost_analysis_source():
     np.asarray(step(x, y))
     per_step = telemetry.snapshot()["metrics"][
         "mxnet_executable_flops_total"]["samples"][0]["value"]
-    compiled, flops = step._compiled[next(iter(step._compiled))][0]
+    compiled, flops = step._compiled[next(iter(step._compiled))]
     assert flops == pytest.approx(per_step)
     assert introspection.flops_of(compiled) == pytest.approx(per_step)
+
+
+def test_each_signature_accounts_its_own_executables_flops():
+    """Two batch shapes are two executables; a step accounts the count
+    captured when its own was compiled, at every dispatch."""
+    from mxnet_tpu import introspection
+
+    introspection.reset()
+    step = _tiny_train_step()
+
+    def total():
+        return telemetry.snapshot()["metrics"][
+            "mxnet_executable_flops_total"]["samples"][0]["value"]
+
+    seen = 0.0
+    for rows in (4, 16, 4, 16):
+        np.asarray(step(np.ones((rows, 3), "f"), np.zeros((rows, 2), "f")))
+        sig = ((rows, 3), "float32", (rows, 2), "float32")
+        flops = step._compiled[sig][1]
+        assert total() - seen == pytest.approx(flops)
+        seen = total()
+    small, large = (step._compiled[s][1] for s in sorted(step._compiled))
+    assert large > small
 
 
 def test_goodput_ledger_preempt_resume_and_reshard(tmp_path):
@@ -761,19 +784,6 @@ def test_aggregator_tick_stride(tmp_path, monkeypatch):
         assert (tmp_path / "rank0.json").exists()
     finally:
         telemetry_agg.reset()
-
-
-def test_compile_cache_entry_carries_flops(tmp_path):
-    from mxnet_tpu.compile_cache import CompileCache
-
-    cache = CompileCache(str(tmp_path))
-    key = cache.key("t", ("sig",))
-    assert cache.put_bytes(key, b"payload", meta={"flops": 123.0})
-    payload, meta = cache.get_entry(key)
-    assert payload == b"payload" and meta == {"flops": 123.0}
-    # load_executable_entry on a miss is (None, {})
-    fn, meta2 = cache.load_executable_entry(cache.key("t", ("other",)))
-    assert fn is None and meta2 == {}
 
 
 def test_read_dir_drops_stale_departed_ranks(tmp_path):

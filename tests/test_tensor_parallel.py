@@ -85,29 +85,35 @@ def test_validate_specs_raises_on_indivisible():
         tensor_parallel.validate_specs(params, {"w": P("tp", None)}, mesh)
 
 
+def _adam_step(tp):
+    """A step over the tiny net from seed 0: replicated on one device, or
+    over dp 2 x tp 4 under the Megatron rules."""
+    mx.random.seed(0)
+    np.random.seed(0)
+    net = _tiny()
+    net.initialize()
+    net(mx.nd.zeros((1, 16), dtype="int32"))
+    kw = {}
+    if tp:
+        kw["mesh"] = make_mesh(dp=2, tp=4)
+        kw["extra_param_specs"] = tensor_parallel.megatron_specs(
+            {k: p.data() for k, p in net.collect_params().items()},
+            kw["mesh"])
+    return TrainStep(net, _loss_fn, optimizer="adam",
+                     optimizer_params={"learning_rate": 1e-3}, **kw)
+
+
 def test_tp_trainstep_matches_replicated():
     """The TP-sharded train step must produce the same losses and params
     as the replicated one (GSPMD inserts the Megatron collectives)."""
-    import jax
-
     x = np.random.RandomState(0).randint(0, 64, (4, 16)).astype("int32")
     y = np.random.RandomState(1).randint(0, 64, (4, 16)).astype("int32")
 
     losses = {}
     final_lm_head = {}
     for mode in ("replicated", "tp"):
-        mx.random.seed(0)
-        np.random.seed(0)
-        net = _tiny()
-        net.initialize()
-        net(mx.nd.zeros((1, 16), dtype="int32"))
+        step = _adam_step(tp=mode == "tp")
         if mode == "tp":
-            mesh = make_mesh(dp=2, tp=4)
-            params = {k: p.data() for k, p in net.collect_params().items()}
-            specs = tensor_parallel.megatron_specs(params, mesh)
-            step = TrainStep(net, _loss_fn, optimizer="adam",
-                             optimizer_params={"learning_rate": 1e-3},
-                             mesh=mesh, extra_param_specs=specs)
             # the q_proj weight must actually be sharded over tp
             qname = [k for k in step.train_params
                      if k.endswith("0_self_attn_q_proj_weight")][0]
@@ -115,9 +121,6 @@ def test_tp_trainstep_matches_replicated():
                       for s in step.train_params[qname].addressable_shards}
             full = step.train_params[qname].shape
             assert shards == {(full[0] // 4, full[1])}, shards
-        else:
-            step = TrainStep(net, _loss_fn, optimizer="adam",
-                             optimizer_params={"learning_rate": 1e-3})
         ls = [float(np.asarray(step(x, y))) for _ in range(3)]
         losses[mode] = ls
         lm = [k for k in step.train_params if k.endswith("lm_head_weight")][0]
@@ -127,6 +130,34 @@ def test_tp_trainstep_matches_replicated():
                                rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(final_lm_head["replicated"],
                                final_lm_head["tp"], rtol=2e-3, atol=2e-4)
+
+
+def test_mesh_step_returns_the_layout_it_was_placed_with():
+    """Over dp 2 x tp 4 every leaf of the state comes back from a step in
+    the sharding it was placed with: the plan's layout is the layout that
+    runs, at step 1 as at step 3, through one executable."""
+    import jax
+
+    step = _adam_step(tp=True)
+
+    def layout():
+        return {jax.tree_util.keystr(path): leaf.sharding
+                for path, leaf in jax.tree_util.tree_leaves_with_path(
+                    (step.train_params, step.rest_params,
+                     step.opt_state))}
+
+    placed = layout()
+    for name, leaf in step.train_params.items():
+        assert leaf.sharding == step._param_shard[name]
+        assert step.opt_state["m"][name].sharding == leaf.sharding
+    ids = np.random.RandomState(0).randint(0, 64, (4, 16)).astype("int32")
+    for n in range(3):
+        step(ids, ids)
+        if n in (0, 2):
+            now = layout()
+            assert now == placed, {k: (now[k], placed[k])
+                                   for k in placed if now[k] != placed[k]}
+    assert len(step._compiled) == 1
 
 
 def test_moe_expert_specs_and_rank_exact_rules():

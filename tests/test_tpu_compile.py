@@ -337,10 +337,8 @@ def test_the_steps_table_resolves_the_qk_kernels_to_their_part(one_chip,
 
     ids = np.zeros((1, 256), np.int32)
     args = jax.tree_util.tree_map(spec, (
-        TrainStep._plain_tree(step.train_params),
-        TrainStep._plain_tree(step.rest_params),
-        TrainStep._plain_tree(step.opt_state), jax.random.PRNGKey(0),
-        ids, ids))
+        step.train_params, step.rest_params, step.opt_state,
+        jax.random.PRNGKey(0), ids, ids))
     table = profiler.scopes_of(step._step.lower(*args).compile())
     # the layers' checkpoints keep the attention op's output and statistics:
     # one forward kernel a layer (the plain checkpoint's step held two), and
