@@ -16,7 +16,8 @@ import numpy as _np
 from ....base import MXNetError
 from ....profiler import (SCOPE_ATTENTION_PROJ, SCOPE_EMBED, SCOPE_FFN,
                           SCOPE_HEAD, SCOPE_KDA, SCOPE_MIXER_GATE,
-                          SCOPE_MOE_SHARED, SCOPE_NORM, SCOPE_ROPE)
+                          SCOPE_MOE_SHARED, SCOPE_NORM, SCOPE_ROPE,
+                          SCOPE_SSM_SCAN)
 from ...block import HybridBlock
 from ...parameter import Parameter
 from ... import nn
@@ -69,7 +70,9 @@ class LlamaConfig:
                  attention_heads_held=None, kv_lora_rank=0,
                  qk_nope_head_dim=None, qk_rope_head_dim=None,
                  v_head_dim=None, rope_interleave=False, kda_conv_size=4,
-                 kda_lower_bound=-5.0):
+                 kda_lower_bound=-5.0, differential=False, norm="rms",
+                 attention_bias=False, ssm_state_size=16, ssm_conv_size=4,
+                 ssm_expand=2, ssm_dt_rank=None, first_layer_index=0):
         # num_experts > 0: an MoE FFN (parallel.expert_parallel) replaces
         # the dense SwiGLU MLP in every layer after the first
         # num_dense_layers; num_experts is the router's width.
@@ -141,6 +144,35 @@ class LlamaConfig:
         # convolution of kda_conv_size taps with SiLU on q, k and v, the
         # log-decay in (kda_lower_bound, 0); computed in chunks of KDA_CHUNK
         # rows, so a row is a whole number of them.
+        # "ssm": a state-space block (LlamaStateSpace: Mamba-1, a scan over
+        # a state of ssm_state_size a channel of ssm_expand x hidden_size
+        # channels, a convolution of ssm_conv_size taps, steps through a
+        # rank of ssm_dt_rank, default hidden_size / 16; no positions).
+        # "gmu": a gated memory unit (LlamaGatedMemory), a gate on the scan
+        # output that layer memory_layer hands on.  "cross": attention whose
+        # K and V are those of layer kv_layer (LlamaCrossAttention: Wq and Wo
+        # alone), causal.  memory_layer / kv_layer are the last "ssm" layer
+        # before the first "gmu" and the last "full" or "window" layer
+        # before the first "cross"; such a layer returns what it hands on
+        # beside the residual stream, through its checkpoint too.
+        # differential: "full", "window" and "cross" layers take the
+        # difference of two softmax maps (arXiv:2410.05258, a pair's values
+        # side by side): query heads 2p and 2p + 1 read key heads 2g and
+        # 2g + 1 of the key-value pair g = p // (pairs of queries a pair of
+        # keys), lambda from four learned vectors a layer and lambda_init
+        # = 0.8 - 0.6 exp(-0.3 i) at the published index i = first_layer_index
+        # + the layer's own, an RMSNorm over the pair's output.
+        # norm "layer": LayerNorm with a bias for RMSNorm, in every layer and
+        # after the last.  attention_bias: a bias on the q, k, v and output
+        # projections.
+        self.differential = differential
+        self.norm = norm
+        self.attention_bias = attention_bias
+        self.ssm_state_size = ssm_state_size
+        self.ssm_conv_size = ssm_conv_size
+        self.ssm_inner_size = ssm_expand * hidden_size
+        self.ssm_dt_rank = ssm_dt_rank or -(-hidden_size // 16)
+        self.first_layer_index = first_layer_index
         self.attention_heads_held = tuple(attention_heads_held) \
             if attention_heads_held is not None else (0, num_heads)
         self.kv_lora_rank = kv_lora_rank
@@ -178,26 +210,44 @@ class LlamaConfig:
                 "halves of its row by rope_base; it takes no rope_parameters "
                 "by kind")
         kinds = set(self.attention_types)
-        if len(self.attention_types) != num_layers \
-                or kinds - {"full", "window", "kda", "mla"}:
+        if len(self.attention_types) != num_layers or kinds - {
+                "full", "window", "kda", "mla", "ssm", "gmu", "cross"}:
             raise MXNetError(
                 f"attention_types names each of the {num_layers} layers "
-                "'full', 'window', 'kda' or 'mla'; got "
-                f"{self.attention_types}")
+                "'full', 'window', 'kda', 'mla', 'ssm', 'gmu' or 'cross'; "
+                f"got {self.attention_types}")
+        self.memory_layer = self._source("gmu", ("ssm",))
+        self.kv_layer = self._source("cross", ("full", "window"))
+        if self.kv_layer is not None and (
+                qk_norm or self.attention_types[self.kv_layer]
+                in self.rope_attention_types):
+            raise MXNetError(
+                "a 'cross' layer's queries take no positions and no q/k "
+                "norm, so the layer whose K and V it reads takes none "
+                "(rope_attention_types, qk_norm)")
+        if norm not in ("rms", "layer"):
+            raise MXNetError(f"norm is 'rms' or 'layer'; got {norm!r}")
+        if differential and (num_heads % 2 or num_kv_heads % 2
+                             or block_diffusion or qk_norm or attention_gate):
+            raise MXNetError(
+                "differential attention pairs the query heads and the "
+                "key-value heads (both even) of a causal layout, and is "
+                "written without q/k norm and the attention gate")
         first, count = self.attention_heads_held
         if not (0 <= first and 0 < count and first + count <= num_heads):
             raise MXNetError(
                 f"attention_heads_held {self.attention_heads_held} is no "
                 f"run of the {num_heads} heads (num_heads)")
-        if count != num_heads and kinds & {"full", "window"}:
+        if count != num_heads and kinds & {"full", "window", "cross"}:
             raise MXNetError(
                 "a share of the heads (attention_heads_held) is written for "
-                "the kinds 'kda' and 'mla'; 'full' and 'window' layers "
-                "share key-value heads and hold them all")
-        if kinds & {"kda", "mla"} and block_diffusion:
+                "the kinds 'kda' and 'mla'; 'full', 'window' and 'cross' "
+                "layers share key-value heads and hold them all")
+        if kinds & {"kda", "mla", "ssm", "gmu", "cross"} and block_diffusion:
             raise MXNetError(
-                "'kda' and 'mla' layers are causal: they do not take the "
-                "block-diffusion layout (block_diffusion > 0)")
+                "'kda', 'mla', 'ssm', 'gmu' and 'cross' layers are causal: "
+                "they do not take the block-diffusion layout "
+                "(block_diffusion > 0)")
         if "mla" in kinds and not (
                 kv_lora_rank and qk_nope_head_dim and qk_rope_head_dim
                 and v_head_dim):
@@ -254,6 +304,21 @@ class LlamaConfig:
                 f"({num_heads}) for GQA")
         self.head_dim = head_dim or hidden_size // num_heads
 
+    def _source(self, reader, sources):
+        """The layer that hands on what the ``reader`` layers read: the last
+        layer of ``sources`` before the first reader; None where there is no
+        reader."""
+        kinds = self.attention_types
+        if reader not in kinds:
+            return None
+        before = [i for i in range(kinds.index(reader))
+                  if kinds[i] in sources]
+        if not before:
+            raise MXNetError(
+                f"a {reader!r} layer reads what an earlier layer of "
+                f"{sources} hands on; got {self.attention_types}")
+        return before[-1]
+
     def rope_kwargs(self, kind):
         """What ``F.rope`` takes for a layer of ``kind``: ``base`` alone,
         or YaRN's inverse frequencies and magnitude."""
@@ -297,27 +362,99 @@ class RMSNorm(HybridBlock):
         return self._resolve_params()["weight"]
 
 
+def _block_norm(cfg, prefix):
+    """A decoder's norm over the hidden size: RMSNorm, or LayerNorm with a
+    bias (``cfg.norm``)."""
+    if cfg.norm == "layer":
+        return nn.LayerNorm(epsilon=cfg.rms_eps, in_channels=cfg.hidden_size,
+                            prefix=prefix)
+    return RMSNorm(cfg.hidden_size, cfg.rms_eps, prefix=prefix)
+
+
+def _heads_first(t, heads, paired):
+    """``(B, L, heads * D) -> (B, heads, L, D)``; ``paired`` (differential
+    attention): the pairs' first members, then their second."""
+    b, l, width = t.shape
+    if not paired:
+        return t.reshape((b, l, heads, width // heads)).transpose(
+            (0, 2, 1, 3))
+    return t.reshape((b, l, heads // 2, 2, width // heads)).transpose(
+        (0, 3, 2, 1, 4)).reshape((b, heads, l, width // heads))
+
+
+class _DifferentialMaps(HybridBlock):
+    """What a differential attention layer learns beside its projections:
+    the four vectors of ``lambda`` and the norm of a pair's output, with the
+    layer's ``lambda_init`` (``LlamaConfig``, ``differential``)."""
+
+    def __init__(self, cfg, index, **kwargs):
+        super().__init__(**kwargs)
+        hd = cfg.head_dim
+        self._eps = cfg.rms_eps
+        self._lambda_init = 0.8 - 0.6 * math.exp(
+            -0.3 * (cfg.first_layer_index + index))
+        for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"):
+            setattr(self, name, self.params.get(name, shape=(hd,)))
+        self.subln = self.params.get("subln_weight", shape=(2 * hd,),
+                                     init="ones")
+
+    def hybrid_forward(self, F, o, lambda_q1, lambda_k1, lambda_q2,
+                       lambda_k2, subln):
+        return F.diff_attn_combine(o, lambda_q1, lambda_k1, lambda_q2,
+                                   lambda_k2, subln,
+                                   lambda_init=self._lambda_init,
+                                   eps=self._eps)
+
+
+def _attend(F, cfg, kind, q, k, v, segment_ids, maps):
+    """The attention op of a ``"full"``, ``"window"`` or ``"cross"`` layer on
+    heads-first q, k and v, and the heads side by side again ``(B, L, heads x
+    head size)``.  Differential: one call over the pairs' first maps and
+    their second (``_heads_first``'s order), a pair's values side by side as
+    one ``v`` of twice the head size under both, then ``maps``."""
+    import jax
+
+    if maps is not None:
+        v = F.concat(v, v, dim=1)
+    sm_scale = 1.0 / math.sqrt(cfg.head_dim)
+    if kind == "window":
+        o = F.flash_attention(q, k, v, segment_ids, mask="window",
+                              window=cfg.attention_window, sm_scale=sm_scale)
+    else:
+        o = F.flash_attention(q, k, v, segment_ids, causal=True,
+                              sm_scale=sm_scale)
+    if maps is not None:
+        with jax.named_scope(SCOPE_MIXER_GATE):
+            return maps(o)
+    b, heads, l, hd = o.shape
+    return o.transpose((0, 2, 1, 3)).reshape((b, l, heads * hd))
+
+
 class LlamaAttention(HybridBlock):
-    def __init__(self, cfg, kind="full", **kwargs):
+    def __init__(self, cfg, kind="full", index=0, hands_on=False, **kwargs):
         super().__init__(**kwargs)
         d, hd = cfg.hidden_size, cfg.head_dim
         self._cfg = cfg
         self._kind = kind
+        self._hands_on = hands_on    # returns its K and V beside its output
+        biased = cfg.attention_bias
         # child names matter: parallel.tensor_parallel's Megatron rules key
         # on the q/k/v/o_proj suffixes to pick column- vs row-parallel specs
         with self.name_scope():
-            self.q_proj = nn.Dense(cfg.num_heads * hd, use_bias=False,
+            self.q_proj = nn.Dense(cfg.num_heads * hd, use_bias=biased,
                                    flatten=False, in_units=d,
                                    prefix="q_proj_")
-            self.k_proj = nn.Dense(cfg.num_kv_heads * hd, use_bias=False,
+            self.k_proj = nn.Dense(cfg.num_kv_heads * hd, use_bias=biased,
                                    flatten=False, in_units=d,
                                    prefix="k_proj_")
-            self.v_proj = nn.Dense(cfg.num_kv_heads * hd, use_bias=False,
+            self.v_proj = nn.Dense(cfg.num_kv_heads * hd, use_bias=biased,
                                    flatten=False, in_units=d,
                                    prefix="v_proj_")
-            self.o_proj = nn.Dense(d, use_bias=False, flatten=False,
+            self.o_proj = nn.Dense(d, use_bias=biased, flatten=False,
                                    in_units=cfg.num_heads * hd,
                                    prefix="o_proj_")
+            if cfg.differential:
+                self.maps = _DifferentialMaps(cfg, index, prefix="maps_")
             if cfg.qk_norm:
                 self.q_norm = RMSNorm(hd, cfg.rms_eps, prefix="q_norm_")
                 self.k_norm = RMSNorm(hd, cfg.rms_eps, prefix="k_norm_")
@@ -337,6 +474,18 @@ class LlamaAttention(HybridBlock):
         hd = cfg.head_dim
         # the parts are named here, one after the other (profiler.py): the
         # attention op between them names its own kernels and backward
+        if cfg.differential:
+            # no positions, no q/k norm: the heads go first by the pairs'
+            # members, a pair's values side by side as one head of 2 hd
+            with jax.named_scope(SCOPE_ATTENTION_PROJ):
+                q = _heads_first(self.q_proj(x), cfg.num_heads, True)
+                k = _heads_first(self.k_proj(x), cfg.num_kv_heads, True)
+                v = _heads_first(self.v_proj(x), cfg.num_kv_heads // 2,
+                                 False)
+            o = _attend(F, cfg, self._kind, q, k, v, segment_ids, self.maps)
+            with jax.named_scope(SCOPE_ATTENTION_PROJ):
+                o = self.o_proj(o)
+            return (o, k, v) if self._hands_on else o
         with jax.named_scope(SCOPE_ATTENTION_PROJ):
             q, k = self.q_proj(x), self.k_proj(x)
             v = self.v_proj(x).reshape(
@@ -387,7 +536,137 @@ class LlamaAttention(HybridBlock):
                 (b, l, cfg.num_heads * hd))
             if cfg.attention_gate:
                 o = o * F.sigmoid(self.gate_proj(x))
+            o = self.o_proj(o)
+        return (o, k, v) if self._hands_on else o
+
+
+class LlamaCrossAttention(HybridBlock):
+    """Attention over another layer's K and V (``cfg.kv_layer``'s, of that
+    layer's normed input): ``Wq`` and ``Wo`` alone, causal, differential
+    where the net is (its own four vectors, norm and ``lambda_init``)."""
+
+    def __init__(self, cfg, index=0, **kwargs):
+        super().__init__(**kwargs)
+        self._cfg = cfg
+        d, width = cfg.hidden_size, cfg.num_heads * cfg.head_dim
+        with self.name_scope():
+            self.q_proj = nn.Dense(width, use_bias=cfg.attention_bias,
+                                   flatten=False, in_units=d,
+                                   prefix="q_proj_")
+            self.o_proj = nn.Dense(d, use_bias=cfg.attention_bias,
+                                   flatten=False, in_units=width,
+                                   prefix="o_proj_")
+            self.maps = _DifferentialMaps(cfg, index, prefix="maps_") \
+                if cfg.differential else None
+
+    def hybrid_forward(self, F, x, k, v):
+        import jax
+
+        cfg = self._cfg
+        with jax.named_scope(SCOPE_ATTENTION_PROJ):
+            q = _heads_first(self.q_proj(x), cfg.num_heads, cfg.differential)
+        o = _attend(F, cfg, "cross", q, k, v, None, self.maps)
+        with jax.named_scope(SCOPE_ATTENTION_PROJ):
             return self.o_proj(o)
+
+
+# rows of a chunk of the selective scan (``ops/selective_scan.py``)
+SSM_CHUNK = 64
+
+
+class LlamaStateSpace(HybridBlock):
+    """A state-space block (Mamba-1, arXiv:2312.00752): ``[xs, z] = x Win``;
+    ``xc = SiLU(conv(xs))``, a causal depthwise convolution with a bias;
+    ``[r, B, C] = xc Wx``; ``delta = softplus(r Wdt + b)``; ``A = -exp(A_log)``;
+    the selective scan over a state of ``ssm_state_size`` a channel
+    (``F.selective_scan``, its skip ``D``); ``(y * SiLU(z)) Wout``.  No
+    positions.  ``hands_on``: returns the scan's output ``y``, before the
+    gate, beside its own (the memory of the ``"gmu"`` layers)."""
+
+    def __init__(self, cfg, hands_on=False, **kwargs):
+        super().__init__(**kwargs)
+        self._cfg = cfg
+        self._hands_on = hands_on
+        d, inner, n = cfg.hidden_size, cfg.ssm_inner_size, cfg.ssm_state_size
+        plain = dict(use_bias=False, flatten=False)
+        with self.name_scope():
+            self.in_proj = nn.Dense(2 * inner, in_units=d, prefix="in_proj_",
+                                    **plain)
+            self.conv = self.params.get(
+                "conv_weight", shape=(cfg.ssm_conv_size, inner))
+            self.conv_bias = self.params.get("conv_bias", shape=(inner,),
+                                             init="zeros")
+            self.x_proj = nn.Dense(cfg.ssm_dt_rank + 2 * n, in_units=inner,
+                                   prefix="x_proj_", **plain)
+            self.dt_proj = nn.Dense(inner, use_bias=True, flatten=False,
+                                    in_units=cfg.ssm_dt_rank,
+                                    prefix="dt_proj_")
+            self.a_log = self.params.get("a_log", shape=(inner, n),
+                                         init="zeros")
+            self.d_skip = self.params.get("d_skip", shape=(inner,),
+                                          init="ones")
+            self.out_proj = nn.Dense(d, in_units=inner, prefix="out_proj_",
+                                     **plain)
+
+    def hybrid_forward(self, F, x, segment_ids=None, positions=None, *,
+                       conv, conv_bias, a_log, d_skip):
+        import jax
+
+        cfg = self._cfg
+        if segment_ids is not None:
+            raise MXNetError(
+                "an 'ssm' layer does not take segment_ids yet: a state and "
+                "a convolution that start again at a document's boundary "
+                "are not written")
+        inner, n, rank = (cfg.ssm_inner_size, cfg.ssm_state_size,
+                          cfg.ssm_dt_rank)
+
+        def part(t, begin, end):
+            return F.slice_axis(t, axis=2, begin=begin, end=end)
+
+        with jax.named_scope(SCOPE_ATTENTION_PROJ):
+            xz = self.in_proj(x)
+        with jax.named_scope(SCOPE_MIXER_GATE):
+            xc = F.short_conv(part(xz, 0, inner), conv, conv_bias)
+        with jax.named_scope(SCOPE_ATTENTION_PROJ):
+            rbc = self.x_proj(xc)
+            dt = self.dt_proj(part(rbc, 0, rank))
+        with jax.named_scope(SCOPE_MIXER_GATE):
+            delta, rate = F.ssm_delta(dt), F.ssm_rate(a_log)
+        with jax.named_scope(SCOPE_SSM_SCAN):
+            y = F.selective_scan(xc, delta, rate, part(rbc, rank, rank + n),
+                                 part(rbc, rank + n, rank + 2 * n), d_skip,
+                                 chunk=SSM_CHUNK)
+        with jax.named_scope(SCOPE_MIXER_GATE):
+            gated = F.swiglu(part(xz, inner, 2 * inner), y)
+        with jax.named_scope(SCOPE_ATTENTION_PROJ):
+            out = self.out_proj(gated)
+        return (out, y) if self._hands_on else out
+
+
+class LlamaGatedMemory(HybridBlock):
+    """A gated memory unit (SambaY, arXiv:2507.06607): ``(SiLU(x W1) * M)
+    W2`` with ``M`` the scan output that ``cfg.memory_layer`` hands on."""
+
+    def __init__(self, cfg, **kwargs):
+        super().__init__(**kwargs)
+        d, inner = cfg.hidden_size, cfg.ssm_inner_size
+        plain = dict(use_bias=False, flatten=False)
+        with self.name_scope():
+            self.in_proj = nn.Dense(inner, in_units=d, prefix="in_proj_",
+                                    **plain)
+            self.out_proj = nn.Dense(d, in_units=inner, prefix="out_proj_",
+                                     **plain)
+
+    def hybrid_forward(self, F, x, memory):
+        import jax
+
+        with jax.named_scope(SCOPE_ATTENTION_PROJ):
+            gate = self.in_proj(x)
+        with jax.named_scope(SCOPE_MIXER_GATE):
+            gated = F.swiglu(gate, memory)
+        with jax.named_scope(SCOPE_ATTENTION_PROJ):
+            return self.out_proj(gated)
 
 
 class LlamaLatentAttention(HybridBlock):
@@ -552,6 +831,10 @@ class LlamaDeltaAttention(HybridBlock):
 
 
 MIXERS = {"kda": LlamaDeltaAttention, "mla": LlamaLatentAttention}
+# the kinds that read what an earlier layer hands on, by its name, and the
+# values handed on under each name
+READS = {"gmu": "memory", "cross": "kv"}
+HANDED_VALUES = {"memory": 1, "kv": 2}
 
 
 class LlamaMLP(HybridBlock):
@@ -639,6 +922,11 @@ class LlamaMoEMLP(HybridBlock):
         return out
 
 
+def _several(out):
+    """A block's result as a tuple, whether it returned one value or more."""
+    return tuple(out) if isinstance(out, (tuple, list)) else (out,)
+
+
 class LlamaDecoderLayer(HybridBlock):
     """Layer ``index`` of the decoder: its attention of the kind
     ``cfg.attention_types`` names, its FFN dense or the expert layer
@@ -648,16 +936,31 @@ class LlamaDecoderLayer(HybridBlock):
         super().__init__(**kwargs)
         self._remat = cfg.remat
         self._post_norms = cfg.post_norms
+        kind = cfg.attention_types[index]
+        # what the layer hands on beside the residual stream ("memory": its
+        # scan's output, "kv": its K and V; None: nothing), and which of
+        # those its mixer reads, after the packed row's ids
+        self.hands_on = {cfg.memory_layer: "memory",
+                         cfg.kv_layer: "kv"}.get(index)
+        self.reads = READS.get(kind)
         with self.name_scope():
-            self.input_layernorm = RMSNorm(cfg.hidden_size, cfg.rms_eps,
-                                           prefix="input_layernorm_")
-            kind = cfg.attention_types[index]
-            self.self_attn = MIXERS[kind](cfg, prefix="self_attn_") \
-                if kind in MIXERS else LlamaAttention(
-                    cfg, kind=kind, prefix="self_attn_")
-            self.post_attention_layernorm = RMSNorm(
-                cfg.hidden_size, cfg.rms_eps,
-                prefix="post_attention_layernorm_")
+            self.input_layernorm = _block_norm(cfg, "input_layernorm_")
+            if kind in MIXERS:
+                self.self_attn = MIXERS[kind](cfg, prefix="self_attn_")
+            elif kind == "ssm":
+                self.self_attn = LlamaStateSpace(
+                    cfg, hands_on=bool(self.hands_on), prefix="self_attn_")
+            elif kind == "gmu":
+                self.self_attn = LlamaGatedMemory(cfg, prefix="self_attn_")
+            elif kind == "cross":
+                self.self_attn = LlamaCrossAttention(cfg, index,
+                                                     prefix="self_attn_")
+            else:
+                self.self_attn = LlamaAttention(
+                    cfg, kind=kind, index=index,
+                    hands_on=bool(self.hands_on), prefix="self_attn_")
+            self.post_attention_layernorm = _block_norm(
+                cfg, "post_attention_layernorm_")
             if cfg.sparse_layer(index):
                 self.mlp = LlamaMoEMLP(cfg, prefix="mlp_")
             else:
@@ -669,12 +972,17 @@ class LlamaDecoderLayer(HybridBlock):
                 self.mlp_out_layernorm = RMSNorm(
                     cfg.hidden_size, cfg.rms_eps, prefix="mlp_out_layernorm_")
 
-    def _body(self, x, *packed):
+    def _body(self, x, *rest):
+        """``rest``: the packed row's ids and positions (or nothing), then
+        what the mixer reads of earlier layers.  Returns the residual stream,
+        or a tuple of it and what the layer hands on."""
         import jax
 
         with jax.named_scope(SCOPE_NORM):
             h = self.input_layernorm(x)
-        a = self.self_attn(h, *packed)
+        if self.reads:      # a reader takes no ids: its source refused them
+            rest = rest[len(rest) - HANDED_VALUES[self.reads]:]
+        a, *handed = _several(self.self_attn(h, *rest))
         if self._post_norms:
             with jax.named_scope(SCOPE_NORM):
                 a = self.attn_out_layernorm(a)
@@ -689,11 +997,12 @@ class LlamaDecoderLayer(HybridBlock):
         if self._post_norms:
             with jax.named_scope(SCOPE_NORM):
                 m = self.mlp_out_layernorm(m)
-        return x + m
+        return (x + m, *handed) if handed else x + m
 
     def hybrid_forward(self, F, x, *packed):
         """``packed``: nothing, or the row's segment ids and positions,
-        which go on to the attention."""
+        which go on to the attention; then what the layer's mixer reads of
+        earlier layers (``reads``)."""
         if self._remat:
             import jax
 
@@ -712,23 +1021,31 @@ class LlamaDecoderLayer(HybridBlock):
                 from .... import telemetry as _telemetry
                 from ....ops import flash_attention as _fa, kda as _kda
 
-                def body_pure(*values):
-                    ctx = getattr(x, "context", None)
-                    with _telemetry.collect_step_scalars() as scalars:
-                        out = self._body(*(NDArray._from_jax(v, ctx)
-                                           for v in values))._get()
-                    return out, scalars.stacked()
+                from ....ops import selective_scan as _ssm
 
+                ctx = getattr(x, "context", None)
+
+                def body_pure(*values):
+                    with _telemetry.collect_step_scalars() as scalars:
+                        out = _several(self._body(*(
+                            NDArray._from_jax(v, ctx) for v in values)))
+                    return tuple(o._get() for o in out), scalars.stacked()
+
+                # what the layer hands on leaves the checkpoint as an output
+                # beside the residual stream, and what it reads enters as an
+                # input: their cotangents come back from every reader
                 with _fa.checkpoint_keeps():
                     out, scalars = jax.checkpoint(
                         body_pure,
                         policy=jax.checkpoint_policies.save_only_these_names(
                             _fa.KEPT_O, _fa.KEPT_LSE, _kda.KEPT_O,
-                            _kda.KEPT_STATES))(
+                            _kda.KEPT_STATES, _ssm.KEPT_Y,
+                            _ssm.KEPT_STATES))(
                                 xv, *(p._get() for p in packed))
                 for name, values in scalars.items():
                     _telemetry.step_scalar(name, values)
-                return NDArray._from_jax(out, getattr(x, "context", None))
+                out = tuple(NDArray._from_jax(o, ctx) for o in out)
+                return out if len(out) > 1 else out[0]
             # the eager tape (autograd.record) and export()'s symbolic
             # trace have no remat node: warn rather than silently skipping
             # the memory saving the user asked for
@@ -746,6 +1063,15 @@ class LlamaDecoderLayer(HybridBlock):
         return self._body(x, *packed)
 
 
+def _count_handed_on(name, values):
+    """Counts what a layer hands on, by its shapes, once a trace."""
+    from .... import telemetry
+
+    telemetry.LAYER_HANDED_ON_BYTES.labels(name=name).inc(sum(
+        int(_np.prod(v.shape)) * _np.dtype(v.dtype).itemsize
+        for v in values))
+
+
 class LlamaModel(HybridBlock):
     def __init__(self, cfg, **kwargs):
         super().__init__(**kwargs)
@@ -757,7 +1083,7 @@ class LlamaModel(HybridBlock):
             with self.layers.name_scope():
                 for i in range(cfg.num_layers):
                     self.layers.add(LlamaDecoderLayer(cfg, i, prefix=f"{i}_"))
-            self.norm = RMSNorm(cfg.hidden_size, cfg.rms_eps, prefix="norm_")
+            self.norm = _block_norm(cfg, "norm_")
 
     def hybrid_forward(self, F, input_ids, segment_ids=None):
         """``segment_ids`` (batch, L) integers: the row is documents packed
@@ -776,8 +1102,14 @@ class LlamaModel(HybridBlock):
         if segment_ids is not None:
             with jax.named_scope(SCOPE_ROPE):
                 packed = (segment_ids, F.segment_positions(segment_ids))
+        # what layers hand on to later ones: {"memory": (M,), "kv": (K, V)}
+        handed = {}
         for layer in self.layers:
-            h = layer(h, *packed)
+            h, *more = _several(layer(h, *packed,
+                                      *handed.get(layer.reads, ())))
+            if layer.hands_on:
+                handed[layer.hands_on] = more
+                _count_handed_on(layer.hands_on, more)
         with jax.named_scope(SCOPE_NORM):
             return self.norm(h)
 
@@ -788,9 +1120,11 @@ class LlamaForCausalLM(HybridBlock):
         self._cfg = cfg
         with self.name_scope():
             self.model = LlamaModel(cfg, prefix="model_")
-            self.lm_head = nn.Dense(cfg.vocab_size, use_bias=False,
-                                    flatten=False, in_units=cfg.hidden_size,
-                                    prefix="lm_head_")
+            # tie_embeddings: the logits are h E^T with the embedding E,
+            # and the net has no head of its own
+            self.lm_head = None if cfg.tie_embeddings else nn.Dense(
+                cfg.vocab_size, use_bias=False, flatten=False,
+                in_units=cfg.hidden_size, prefix="lm_head_")
 
     def hybrid_forward(self, F, input_ids, segment_ids=None):
         import jax
@@ -800,6 +1134,11 @@ class LlamaForCausalLM(HybridBlock):
             if self._cfg.block_diffusion:
                 # rows are [xt ; x0]: logits over the noised half only
                 h = F.slice_axis(h, axis=1, begin=0, end=h.shape[1] // 2)
+            if self.lm_head is None:
+                embed = self.model.embed_tokens
+                return F.FullyConnected(
+                    h, embed._resolve_params()["weight"], no_bias=True,
+                    num_hidden=self._cfg.vocab_size, flatten=False)
             return self.lm_head(h)
 
     @property
@@ -1064,6 +1403,16 @@ def _refuse_unserved(cfg):
             "incremental decode does not support 'kda' or 'mla' layers yet: "
             "a recurrent state with a convolution's tail, and a latent "
             "cache, are not written")
+    if set(cfg.attention_types) & {"ssm", "gmu", "cross"}:
+        raise MXNetError(
+            "incremental decode does not support 'ssm', 'gmu' or 'cross' "
+            "layers yet: a scan state with a convolution's tail beside one "
+            "layer's cache that every cross layer reads is not written")
+    if cfg.differential or cfg.norm != "rms" or cfg.attention_bias \
+            or cfg.tie_embeddings:
+        raise MXNetError(
+            "incremental decode does not support differential attention, "
+            "LayerNorm, projection biases or tied embeddings yet")
     if cfg.num_experts > 0:
         raise MXNetError("incremental decode does not support MoE FFNs yet")
     if "window" in cfg.attention_types \

@@ -11,6 +11,7 @@ from . import attention_ops  # noqa: F401
 from . import flash_attention  # noqa: F401
 from . import qk_norm_rope  # noqa: F401
 from . import kda  # noqa: F401
+from . import selective_scan  # noqa: F401
 from . import quantization_ops  # noqa: F401
 from . import legacy_ops  # noqa: F401
 from .registry import OP_TABLE, get_op, list_ops, register  # noqa: F401

@@ -327,11 +327,12 @@ def kda(q, k, v, g, beta, chunk=64):
 
 
 @register("_contrib_short_conv", aliases=("short_conv",))
-def short_conv(x, weight, activation="silu"):
+def short_conv(x, weight, bias=None, activation="silu"):
     """A causal depthwise convolution over time: ``x (B, L, C)``, ``weight
     (taps, C)``; ``y_t = sum_i weight[i] x_{t - (taps - 1) + i}`` (rows
-    before the first are zeros), then SiLU where ``activation`` is
-    ``"silu"``.  Computed in float32, returned in ``x``'s dtype."""
+    before the first are zeros), plus ``bias (C,)`` where one is given (a
+    state-space block's), then SiLU where ``activation`` is ``"silu"``.
+    Computed in float32, returned in ``x``'s dtype."""
     import jax
     import jax.numpy as jnp
 
@@ -339,13 +340,15 @@ def short_conv(x, weight, activation="silu"):
         raise MXNetError(f"short_conv: unknown activation {activation!r}")
 
     @jax.checkpoint     # the backward keeps x and the taps, no float32 copy
-    def conv(x, weight):
+    def conv(x, weight, *bias):
         taps, l = weight.shape[0], x.shape[1]
         padded = jnp.pad(_f32(x), ((0, 0), (taps - 1, 0), (0, 0)))
         y = sum(padded[:, i:i + l] * _f32(weight[i]) for i in range(taps))
+        for term in bias:
+            y = y + _f32(term)
         return (jax.nn.silu(y) if activation else y).astype(x.dtype)
 
-    return conv(x, weight)
+    return conv(x, weight) if bias is None else conv(x, weight, bias)
 
 
 @register("_contrib_l2_norm_heads", aliases=("l2_norm_heads",))

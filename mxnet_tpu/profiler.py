@@ -69,6 +69,12 @@ SCOPE_KDA = "mx_kda"
 SCOPE_MIXER_GATE = "mx_mixer_gate"
 KERNEL_KDA_FWD = "mxnet_kda_fwd"
 KERNEL_KDA_BWD = "mxnet_kda_bwd"
+# the selective state-space scan (ops/selective_scan.py) of a state-space
+# layer: the scope around the op, and inside it the names of its two walks
+# (the Pallas kernels' own names on a TPU, scopes of the scans elsewhere)
+SCOPE_SSM_SCAN = "mx_ssm_scan"
+KERNEL_SSM_SCAN_FWD = "mxnet_selective_scan_fwd"
+KERNEL_SSM_SCAN_BWD = "mxnet_selective_scan_bwd"
 # the transformer block's own parts (model_zoo/language/llama.py, bert.py;
 # the loss in parallel/data_parallel.py::TrainStep), entered at the call
 # sites one after the other, so that no op's own name holds two of them:

@@ -456,12 +456,30 @@ KDA_CHUNKS = counter(
     "samples not counted: rows / chunk, one count a call a trace")
 
 
+SSM_SCAN_CALLS = counter(
+    "mxnet_selective_scan_fwd_calls_total",
+    "selective_scan (chunked state-space scan) calls traced, by the path "
+    "their chunks' walk took: the Pallas kernels or the scan of scans",
+    ("path",))
+SSM_SCAN_CHUNKS = counter(
+    "mxnet_selective_scan_chunks_total",
+    "chunks of a call's rows that the traced selective_scan calls walk, "
+    "samples not counted: rows / chunk, one count a call a trace")
+
+
 LAYER_CHECKPOINT_KEPT_BYTES = counter(
     "mxnet_layer_checkpoint_kept_bytes_total",
     "bytes of the values an op names for a decoder layer's checkpoint to "
     "keep beside the layer's input (the attention op's output and row "
     "statistics, the delta rule's output and chunk states), from their "
     "shapes: one count a named value a layer a trace",
+    ("name",))
+LAYER_HANDED_ON_BYTES = counter(
+    "mxnet_layer_handed_on_bytes_total",
+    "bytes of what a decoder layer hands on to later layers beside the "
+    "residual stream (a state-space layer's scan output, the memory; an "
+    "attention layer's K and V), from their shapes: one count a value a "
+    "trace",
     ("name",))
 PARAMETER_GRAD_BUFFERS = counter(
     "mxnet_parameter_grad_buffers_total",

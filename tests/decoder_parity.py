@@ -1,7 +1,8 @@
-"""The four decoder cells' configurations at a small size, through
+"""The five decoder cells' configurations at a small size, through
 ``TrainStep`` against the configuration's plain reference: the run-and-compare
 sequence that ``test_block_diffusion_moe.py``, ``test_window_shared_moe.py``,
-``test_packed_yarn_decoder.py`` and ``test_kda_mla_decoder.py`` share, and a
+``test_packed_yarn_decoder.py``, ``test_kda_mla_decoder.py`` and
+``test_ssm_diff_decoder.py`` share, and a
 table, a row a configuration.  A new decoder's whole-step parity test is one
 more row here and a call of ``matches`` and ``left_out`` in its file
 (ROADMAP.md, D8): the reference of an unchanged configuration is followed once
@@ -87,6 +88,20 @@ ROWS = {
         # a delta layer and a latent one, both sparse
         "few": dict(num_hidden_layers=2, layer_group_size=2,
                     first_k_dense_replace=0)},
+    # the five published layers 15-19 (window, state-space, full, gated
+    # memory, cross), a window of 8 over L = 32, differential attention of 4
+    # query heads over 2 key-value heads of 16 (two query pairs on one
+    # key-value pair), 128 channels of 4 states through a rank of 4.  The
+    # tests that build it cut the scan's chunks to 16 rows
+    "phi4_mini_flash": {
+        "small": dict(
+            vocab_size=96, hidden_size=64, num_attention_heads=4,
+            num_key_value_heads=2, intermediate_size=96, sliding_window=8,
+            assumed={"state_space": {"d_state": 4, "d_conv": 4, "expand": 2,
+                                     "dt_rank": 4}}),
+        "spec": {"batch": 2, "seq": 32},
+        # every kind once is the whole of it: each reads another
+        "few": {}},
 }
 
 

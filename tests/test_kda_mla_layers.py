@@ -518,7 +518,7 @@ def test_kinds_shares_and_what_refuses_them():
         mla_first(ids, ids)
     for bad, match in (
             ({"attention_types": ("kda", "mla", "linear")},
-             "'full', 'window', 'kda' or 'mla'"),
+             "'full', 'window', 'kda', 'mla', 'ssm', 'gmu' or 'cross'"),
             ({"attention_heads_held": (3, 2)}, "no run of the 4 heads"),
             ({"attention_heads_held": (0, 0)}, "no run of the 4 heads"),
             ({"attention_types": ("kda", "full", "kda")},

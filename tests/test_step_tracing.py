@@ -400,10 +400,14 @@ def net_of(request):
     if request.param == "decoder":   # softmax attention alone
         return (request.getfixturevalue("toy_decoder"), _token_loss,
                 _decoder_batch(), set(PART_SCOPES) - {
-                    profiler.SCOPE_KDA, profiler.SCOPE_MIXER_GATE})
+                    profiler.SCOPE_KDA, profiler.SCOPE_MIXER_GATE,
+                    profiler.SCOPE_SSM_SCAN})
     if request.param == "hybrid":
+        # every part but the state-space scan's, which no layer here walks
+        # (its rows of the table: tests/test_ssm_diff_decoder.py)
         return (request.getfixturevalue("toy_hybrid"), _token_loss,
-                _decoder_batch(), set(PART_SCOPES))
+                _decoder_batch(), set(PART_SCOPES) - {
+                    profiler.SCOPE_SSM_SCAN})
     # the encoder has no RoPE and no experts; its toy runs under the gate
     return (request.getfixturevalue("toy_bert"), _loss, _batch(), {
         profiler.SCOPE_ATTENTION_PROJ, profiler.SCOPE_FFN,

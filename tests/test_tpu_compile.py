@@ -1,5 +1,6 @@
 """The Pallas kernels (attention forward and backward, q/k norm and RoPE, the
-experts' grouped products, the delta rule) and the expert layer, compiled at
+experts' grouped products, the delta rule, the selective scan) and the expert
+layer, compiled at
 real widths for a TPU v5e that is described and not attached: what the chip's
 compiler refuses (an operand type, a slice off the tiling, too much VMEM) the
 TPU interpreter of ``test_chip_smoke.py`` lets through.  Nothing runs, so
@@ -635,3 +636,122 @@ def test_latent_attention_kernels_compile_for_v5e(one_chip):
                                                            v)
     assert [x.shape for x in bwd.out_info] == [qk.shape, qk.shape, v.shape]
     assert "mxnet_flash_attention_bwd" in bwd.compile().as_text()
+
+
+# --------------------------------------------------------------------------
+# the Phi cell's mixers (PR 45): the selective scan's two kernels at the
+# cell's shape, both attention kernels at q/k 64 beside v 128 (a pair's
+# values side by side) under the causal mask and the window of 512, and a
+# small step of the five kinds of layer with every gate open
+# --------------------------------------------------------------------------
+@pytest.mark.parametrize("backward", [False, True])
+def test_selective_scan_compiles_for_v5e(one_chip, backward, monkeypatch):
+    """8,192 rows of 5120 channels x 16 states in chunks of 64: the state
+    stays on chip, so nothing the size of ``rows x 5120 x 16`` (2.7 GB) is
+    held, and what the op needs beside its operands (the channels laid out
+    in blocks for the kernels, float32) stays under a GiB."""
+    import re
+
+    from mxnet_tpu.ops import selective_scan as ss
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ss._make_scan.cache_clear()
+
+    def spec(shape, dtype="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    operands = (spec((1, 8192, 5120)), spec((1, 8192, 5120), "float32"),
+                spec((5120, 16), "float32"), spec((1, 8192, 16)),
+                spec((1, 8192, 16)), spec((5120,), "float32"))
+    fn = ss.selective_scan
+    if backward:
+        fn = jax.grad(lambda *a: jnp.sum(
+            ss.selective_scan(*a).astype(jnp.float32)), argnums=tuple(range(6)))
+    compiled = jax.jit(fn).lower(*operands).compile()
+    ss._make_scan.cache_clear()
+    text = compiled.as_text()
+    assert "mxnet_selective_scan_fwd" in text
+    assert ("mxnet_selective_scan_bwd" in text) == backward
+    assert not re.search(r"f32\[(\d+,)*8192,(\d+,)*(5120,16|16,5120|5,16,8,128)"
+                         r"\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+@pytest.mark.parametrize("call", [{"causal": True},
+                                  {"mask": ("window", 512)}],
+                         ids=["causal", "window"])
+def test_differential_attention_kernels_compile_for_v5e(one_chip, call):
+    """One call of 40 query heads: q and k of 64, v and the output of 128;
+    the backward hands back gradients of 64, 64 and 128."""
+    from mxnet_tpu.ops.flash_attention import (_fa_backward_pallas,
+                                               _fa_forward_pallas)
+
+    qk = jax.ShapeDtypeStruct((1, 40, 8192, 64), "bfloat16",
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 40, 8192, 128), "bfloat16",
+                             sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((1, 40, 8192), "float32", sharding=one_chip)
+    call = dict({"causal": False, "sm_scale": 0.125}, **call)
+    fwd = jax.jit(functools.partial(_fa_forward_pallas, **call)).lower(
+        qk, qk, v)
+    assert [x.shape for x in fwd.out_info] == [v.shape, lse.shape]
+    assert "mxnet_flash_attention_fwd" in fwd.compile().as_text()
+    bwd = jax.jit(functools.partial(_fa_backward_pallas, **call)).lower(
+        qk, qk, v, v, lse, v)
+    assert [x.shape for x in bwd.out_info] == [qk.shape, qk.shape, v.shape]
+    assert "mxnet_flash_attention_bwd" in bwd.compile().as_text()
+
+
+def test_a_small_phi_step_compiles_for_v5e_with_one_scan_a_layer(
+        one_chip, monkeypatch):
+    """The five kinds of layer (window, state-space, full, gated memory,
+    cross) in one fused bf16 step with both gates open, compiled for the
+    chip: the layers' checkpoints keep the scan's output and chunk states and
+    the attention's output and statistics, so the step holds one scan
+    forward kernel and one backward (rows of ``mx_ssm_scan``) and one
+    attention forward kernel a layer that attends."""
+    import numpy as np
+
+    import mxnet_tpu as mx  # noqa: F401
+    from mxnet_tpu import profiler
+    from mxnet_tpu.gluon.model_zoo.language import llama
+    from mxnet_tpu.ops import flash_attention as fa
+    from mxnet_tpu.ops import selective_scan as ss
+    from mxnet_tpu.parallel.data_parallel import TrainStep
+
+    monkeypatch.setattr(fa, "_use_pallas", lambda q, v=None: True)
+    monkeypatch.setattr(ss, "use_pallas",
+                        lambda x: x.shape[-1] % ss._BLOCK == 0)
+    ss._make_scan.cache_clear()
+    net = llama.LlamaForCausalLM(llama.LlamaConfig(
+        vocab_size=512, hidden_size=512, num_layers=5, num_heads=8,
+        num_kv_heads=4, head_dim=64, intermediate_size=512,
+        attention_types=("window", "ssm", "full", "gmu", "cross"),
+        attention_window=128, rope_attention_types=(), differential=True,
+        norm="layer", attention_bias=True, first_layer_index=15,
+        tie_embeddings=True, remat=True))
+    net.initialize()
+
+    def loss(logits, labels):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        return -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+
+    step = TrainStep(net, loss, optimizer="adam", dtype="bfloat16",
+                     optimizer_params={"learning_rate": 1e-4})
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=one_chip)
+
+    ids = np.zeros((1, 256), np.int32)
+    args = jax.tree_util.tree_map(spec, (
+        step.train_params, step.rest_params, step.opt_state,
+        jax.random.PRNGKey(0), ids, ids))
+    table = profiler.scopes_of(step._step.lower(*args).compile())
+    ss._make_scan.cache_clear()
+    for kernel, calls in ((profiler.KERNEL_SSM_SCAN_FWD, 1),
+                          (profiler.KERNEL_SSM_SCAN_BWD, 1)):
+        parts = [row["part"] for name, row in table.items()
+                 if name.startswith(kernel)]
+        assert parts == [profiler.SCOPE_SSM_SCAN] * calls, kernel
+    assert len([name for name in table
+                if name.startswith(profiler.KERNEL_ATTENTION_FWD)]) == 3
