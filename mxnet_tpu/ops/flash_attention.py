@@ -735,9 +735,10 @@ def _fa_forward(q, k, v, causal, sm_scale, mask=None, sharded=None):
 _TN_DIMS = (((0,), (0,)), ((), ()))
 
 # flags of a row of the backward's table of tile pairs; under segment ids a
-# dead row (past a sample's live pairs) carries that flag alone, and
-# ``_PARTLY_SEEN`` is not read (every pair walked is compared there)
-_FIRST_OF_K, _LAST_OF_K, _PARTLY_SEEN, _DEAD = 1, 2, 4, 8
+# dead row (past a sample's live pairs) carries that flag alone.  No flag
+# says whether the mask hides some pair of a tile: every pair walked is
+# compared, with ids and without (``_fa_bwd_kernel``)
+_FIRST_OF_K, _LAST_OF_K, _DEAD = 1, 2, 4
 
 
 def _fa_bwd_block_sizes(lq, lk):
@@ -766,19 +767,16 @@ def _fa_bwd_pairs(causal, mask, lq, lk, block_q, block_k):
     """The table the backward kernel walks, int32 ``(3, pairs)``: q tile, k
     tile and flags of every live tile pair (``_live_tiles``), K tile by K
     tile so that a K tile's ``dk`` and ``dv`` are finished before the next
-    one's begin.  Flags: first and last pair of their K tile, and whether
-    the mask hides some pair of the tile (``_visible`` is evaluated in those
-    alone).  Every K tile is in it, so every tile of ``dk`` and ``dv`` is
-    written: one that no query sees (a window over ``lq < lk`` leaves the
-    first keys to none) is walked once, with the first q tile and wholly
-    hidden, and so written as zeros."""
-    some, every = _tile_visibility(causal, mask, lq, lk, block_q, block_k)
-    some = some.copy()
+    one's begin.  Flags: first and last pair of their K tile.  Every K tile
+    is in it, so every tile of ``dk`` and ``dv`` is written: one that no
+    query sees (a window over ``lq < lk`` leaves the first keys to none) is
+    walked once, with the first q tile and wholly hidden, and so written as
+    zeros."""
+    some = _live_tiles(causal, mask, lq, lk, block_q, block_k).copy()
     some[0, ~some.any(axis=0)] = True
     pairs = _np.argwhere(some.T)[:, ::-1]
     turn = pairs[1:, 1] != pairs[:-1, 1]
-    flags = (_FIRST_OF_K * _np.r_[True, turn] + _LAST_OF_K * _np.r_[turn, True]
-             + _PARTLY_SEEN * ~every[pairs[:, 0], pairs[:, 1]])
+    flags = _FIRST_OF_K * _np.r_[True, turn] + _LAST_OF_K * _np.r_[turn, True]
     return _np.stack([pairs[:, 0], pairs[:, 1], flags]).astype(_np.int32)
 
 
@@ -824,18 +822,23 @@ def _fa_bwd_kernel(pairs_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, causal,
                    sm_scale, seq_q, seq_k, mask, table_row, qseg_ref=None,
                    kseg_ref=None):
-    """One live tile pair: its five products, nothing recomputed.  Under
-    segment ids (``_fa_bwd_kernel_segments``) ``qseg_ref`` (1, block_q)
-    holds the q tile's ids as a row and ``kseg_ref`` (block_k, 1) the K
-    tile's as a column, and the table is the sample's own, built on the
-    device from its ids (``_fa_bwd_pairs_under_ids``; ``table_row`` gives
-    the table's row of a grid step): the pairs whose tiles hold a pair of
-    one document, every one masked by ``_visible`` with the ids (a tile the
-    mask shows whole may still hold two documents, and a ``cond`` around the
-    compare costs more than the compare: PERF.md section 6, PR 33), then
-    ``_DEAD`` rows, whose steps do nothing.  A pair left out would have
-    added exact zeros to ``dq``, ``dk`` and ``dv``: the gradients are those
-    of walking every pair the mask alone shows, bit for bit.
+    """One live tile pair: its five products, nothing recomputed, and from
+    the first product to the add into ``dq`` no branch.  A call with
+    ``causal`` or a mask compares ``_visible`` in every pair it walks, one
+    the mask shows whole too: a ``lax.cond`` around the compare carries the
+    score tile (1 MiB at tiles of 512) between the first product and
+    ``exp`` and costs more than the compare (PERF.md section 6, PRs 33 and
+    46); a call with neither has no compare at all.  Under segment ids
+    (``_fa_bwd_kernel_segments``) ``qseg_ref`` (1, block_q) holds the q
+    tile's ids as a row and ``kseg_ref`` (block_k, 1) the K tile's as a
+    column, ``_visible`` takes them beside the mask (a tile the mask shows
+    whole may still hold two documents), and the table is the sample's own,
+    built on the device from its ids (``_fa_bwd_pairs_under_ids``;
+    ``table_row`` gives the table's row of a grid step): the pairs whose
+    tiles hold a pair of one document, then ``_DEAD`` rows, whose steps do
+    nothing.  A pair left out would have added exact zeros to ``dq``,
+    ``dk`` and ``dv``: the gradients are those of walking every pair the
+    mask alone shows, bit for bit.
 
     Grid: (batch*heads, live tile pairs), the pairs K tile by K tile
     (``_fa_bwd_pairs``, prefetched to SMEM; the block index maps read it, so
@@ -888,16 +891,12 @@ def _fa_bwd_kernel(pairs_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         else:
             s = dot(k, q, _NT_DIMS) * sm_scale
         if causal or mask is not None:
-            def hide(s):
-                q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (1, block_q), 1)
-                k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_k, 1), 0)
-                return jnp.where(_visible(jnp, q_pos, k_pos, causal, mask,
-                                          seq_q, seq_k, ids), s, NEG_INF)
-
-            s = hide(s) if ids is not None else jax.lax.cond(
-                (flags & _PARTLY_SEEN) != 0, hide, lambda s: s, s)
+            q_pos = qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block_q), 1)
+            k_pos = ki * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, 1), 0)
+            s = jnp.where(_visible(jnp, q_pos, k_pos, causal, mask, seq_q,
+                                   seq_k, ids), s, NEG_INF)
         p = jnp.exp(s - lse_ref[...])
         dv_acc[...] += dot(p.astype(g.dtype), g, _NN_DIMS)
         dp = dot(v, g, _NT_DIMS)

@@ -87,7 +87,9 @@ def test_backward_pairs_table_walks_the_live_tiles_k_tile_by_k_tile():
     assert first.sum() == last.sum() == 16      # once a K tile, at its ends
     assert first[0] and last[-1] and (first[1:] == last[:-1]).all()
     # the 24 tiles on the three diagonals hold hidden pairs, the rest none
-    assert (flags & fa._PARTLY_SEEN != 0).sum() == 24
+    # (the kernel compares in all 80: no flag tells them apart)
+    _, every = fa._tile_visibility(False, mask, 8192, 8192, 512, 512)
+    assert (~every[qt, kt]).sum() == 24
     # nothing hidden, nothing to evaluate; one tile a head at BERT's shape
     assert (fa._fa_bwd_pairs(False, None, 512, 512, 512, 512)
             == [[0], [0], [fa._FIRST_OF_K | fa._LAST_OF_K]]).all()
@@ -96,18 +98,51 @@ def test_backward_pairs_table_walks_the_live_tiles_k_tile_by_k_tile():
     assert fa._fa_bwd_pairs(True, None, 512, 256, 128, 128).shape == (3, 3)
 
 
-def _dots(jaxpr):
-    """Operand dtypes and precision of every dot_general, kernels' bodies
-    and loops' included."""
+def _eqns(jaxpr, kernel=False):
+    """``(equation, whether it lies inside a pallas_call)`` of a jaxpr,
+    kernels' bodies, loops' and branches' included."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "dot_general":
-            yield (tuple(str(x.aval.dtype) for x in eqn.invars),
-                   eqn.params["precision"])
+        yield eqn, kernel
+        inside = kernel or eqn.primitive.name == "pallas_call"
         for param in eqn.params.values():
             for sub in param if isinstance(param, (list, tuple)) else [param]:
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    yield from _dots(sub)
+                    yield from _eqns(sub, inside)
+
+
+def _dots(jaxpr):
+    """Operand dtypes and precision of every dot_general."""
+    for eqn, _ in _eqns(jaxpr):
+        if eqn.primitive.name == "dot_general":
+            yield (tuple(str(x.aval.dtype) for x in eqn.invars),
+                   eqn.params["precision"])
+
+
+@pytest.mark.parametrize("causal,mask,ids", [
+    (True, None, False), (False, (fa.WINDOW, 2048), False),
+    (False, (fa.BLOCK_DIFFUSION, 4), False), (True, None, True)],
+    ids=["causal", "window", "block_diffusion", "segment_ids"])
+def test_a_kernel_step_runs_straight_through(causal, mask, ids):
+    """From the first product to the add into ``dq`` a grid step is one
+    basic block: the mask is compared in every tile pair walked, with and
+    without ids, and the branches left (``pl.when``: the accumulators'
+    zeroing and writing, a dead row's step) return nothing, so no score
+    tile is carried through one."""
+    x = jax.ShapeDtypeStruct((1, 2, 4096, 128), "bfloat16")
+    lse = jax.ShapeDtypeStruct((1, 2, 4096), "float32")
+    seg = jax.ShapeDtypeStruct((1, 4096), "int32")
+
+    def backward(seg, q, k, v, o, lse, g):
+        return fa._fa_backward_pallas(
+            q, k, v, o, lse, g, causal, 128 ** -0.5,
+            fa._Mask(mask, seg if ids else None))
+
+    jaxpr = jax.make_jaxpr(backward)(seg, x, x, x, x, lse, x).jaxpr
+    conds = [eqn for eqn, kernel in _eqns(jaxpr)
+             if kernel and eqn.primitive.name == "cond"]
+    assert conds and all(not eqn.outvars for eqn in conds)
+    assert len(list(_dots(jaxpr))) == 5
 
 
 @pytest.mark.parametrize("path", ["pallas", "blockwise", "blockwise_pairs"])

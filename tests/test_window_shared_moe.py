@@ -70,13 +70,13 @@ def test_window_predicate_tile_range_and_pairs_table(lq, lk, window, block_q,
         assert list(np.flatnonzero(some[i])) == list(range(int(lo), int(hi)))
     # the backward's table: every live pair, K tile by K tile, and every K
     # tile at least once (one that no query sees with its first q tile,
-    # flagged as holding hidden pairs, so that it is written as zeros)
-    qt, kt, flags = fa._fa_bwd_pairs(False, mask, lq, lk, block_q, block_k)
+    # wholly hidden, so that it is written as zeros)
+    qt, kt, _ = fa._fa_bwd_pairs(False, mask, lq, lk, block_q, block_k)
     dead = ~some.any(axis=0)
     assert set(kt) == set(range(lk // block_k)) and (np.diff(kt) >= 0).all()
     assert len(qt) == some.sum() + dead.sum()
     assert (some[qt, kt] | dead[kt]).all()
-    assert (flags[dead[kt]] & fa._PARTLY_SEEN != 0).all()
+    assert (qt[dead[kt]] == 0).all()
 
 
 @pytest.mark.parametrize("lq,lk,window,block_q,block_k", WINDOW_CASES)
