@@ -18,7 +18,11 @@ chunk and ``P(x)[i, j] = sum_d x_id k_jd exp(b_id - b_jd)`` for ``j <= i``::
     S_next = Diag(e^{b_last}) S_0 + (k e^{b_last - b})^T V~
 
 in three passes: everything of a chunk that does not need its state, for all
-chunks at once (``_prepare``: the decays, ``P``, the triangular solve); the
+chunks at once (``_prepare``: the decays, ``P``, and ``T`` by doubling from
+blocks of one row, element-wise over every chunk and head at once
+(``_inverse``): no row-at-a-time triangular solve, whose 64 dependent rows
+cost the TPU 0.69 ms a call whatever its batch, 24 calls a step of six
+layers); the
 states from chunk to chunk, the one sequential part (``_states``: a
 ``lax.scan`` of two products a chunk; a Pallas kernel with the states of
 eight heads in VMEM was measured beside it on a v5e and took the same time,
@@ -28,7 +32,8 @@ over a chunk of 64 rows at a decay of -5 a row would be ``e^320``: ``P`` is
 formed a sub-block of ``_SUB`` rows at a time against the sub-block's own
 origin, its middle row, so that no exponent passes ``_SUB / 2`` times the
 decay's bound (40 at -5, well inside float32).  ``g``, ``b``, the
-exponentials and the solve are float32; the products take their operands in the inputs' dtype and
+exponentials and ``T`` with its product (``_solve``, at ``HIGHEST``) are
+float32; the other products take their operands in the inputs' dtype and
 accumulate in float32, as the attention kernels do.
 
 The backward is written by hand (``jax.custom_vjp``): it keeps the op's
@@ -44,6 +49,7 @@ training length.
 from __future__ import annotations
 
 import functools
+import types
 
 from ..base import MXNetError
 from ..profiler import KERNEL_KDA_BWD, KERNEL_KDA_FWD
@@ -52,8 +58,10 @@ from .registry import register
 
 _SUB = 16     # rows of a sub-block of a chunk, for the decays between rows
 # chunks (of every head) whose way back through ``_prepare`` is taken at once:
-# as few runs as memory allows, since the TPU's triangular solve costs 0.65 ms
-# a call whatever its batch (128 or 1,024 matrices of 64 x 64 alike)
+# as few runs as memory allows: every run builds its matrices' ``T`` again
+# (``_prepare`` runs once more inside ``jax.vjp``, one more call of
+# ``_inverse`` a run), and two runs of 64 chunks keep 0.1 GiB at the Ling
+# cell's shape, so nothing asks for shorter ones
 _BACK_CHUNKS = 64
 # the names of the op's output and of its chunk states for a checkpoint that
 # keeps them (``flash_attention.checkpoint_keeps``)
@@ -96,12 +104,129 @@ def _pairwise(xs, k, b, mm):
             .reshape(*lead, c, c) for x in xs]
 
 
+def _inverse(a):
+    """``(I + strictly_lower(a))^-1`` for ``a (..., C, C)`` float32, ``C`` a
+    multiple of ``_SUB``, by doubling: the inverse of one row is 1, and a
+    level joins the inverses of neighbouring diagonal blocks of ``h`` rows
+    into those of ``2h``: a pair ``[[top, 0], [under, low]]`` has the
+    inverse ``[[top^-1, 0], [-low^-1 under top^-1, low^-1]]``.  Nothing
+    waits on more than ``log2 C`` levels in sequence and nothing is a loop
+    on the device.  It is block forward substitution in another order:
+    against float64 it is as exact as the row-at-a-time solve on keys that
+    lie near one direction too (``tests/test_kda_mla_layers.py``), where the
+    series ``(I - N)(I + N^2)(I + N^4)...`` loses three digits.
+
+    All of it is element-wise float32 with the matrices along the last
+    axis, reached by one 2-D transpose of ``(matrices, C * C)`` and left by
+    one: an entry ``(i, j)`` of every chunk and head at once is then a row
+    of whole vectors.  Products of matrices of 1 to 32 rows by the MXU in
+    float32 (six passes) with the layout changes around them took three
+    times as long on a v5e (PERF.md section 6, PR 47).
+
+    Every level is a few whole-array ops over ``(pairs, h, h, matrices)``:
+    a product is one broadcast multiply and one sum over the inner axis,
+    and the blocks under the diagonal come by halving from the top (a
+    level's are a slice of the diagonal blocks one level up; a gather a
+    level cost 0.1 ms a call more).  The only Python loops are the two over
+    the levels, and the whole is 159 equations at a chunk of 64.  With a
+    multiply-add an inner index and a slice a pair it is 1,210, three sites
+    a layer, for the same time on the device, and the Ling cell's set-up
+    took 23 s longer (PERF.md section 6, PRs 47 and 48): a tier-1 test
+    holds the count.  A size that is no power of two is padded with rows of
+    the identity, whose inverse is theirs, and cut back."""
+    import jax.numpy as jnp
+
+    *lead, c, _ = a.shape
+    size = _SUB
+    while size < c:
+        size *= 2
+    if size != c:
+        a = jnp.pad(a, [(0, 0)] * len(lead) + [(0, size - c)] * 2)
+    blocks = a.reshape(-1, size * size).T.reshape(1, size, size, -1)
+    unders = []         # (pairs, h, h, matrices) for h = size / 2, ..., 1
+    h = size // 2
+    while h:
+        unders.append(blocks[:, h:, :h])
+        blocks = jnp.stack([blocks[:, :h, :h], blocks[:, h:, h:]], 1) \
+            .reshape(-1, h, h, blocks.shape[-1])
+        h //= 2
+
+    def product(x, y):
+        return jnp.sum(x[:, :, :, None] * y[:, None], axis=2)
+
+    d = jnp.ones_like(blocks)           # (size, 1, 1, matrices)
+    while unders:
+        d = d.reshape(-1, 2, *d.shape[1:])
+        top, low = d[:, 0], d[:, 1]
+        corner = -product(low, product(unders.pop(), top))
+        d = jnp.concatenate([
+            jnp.concatenate([top, jnp.zeros_like(top)], 2),
+            jnp.concatenate([corner, low], 2)], 1)
+    return d[0, :c, :c].reshape(c * c, -1).T.reshape(*lead, c, c)
+
+
+def _dot_f32(spec, a, b):
+    """A batched product of float32 operands to the last bit the MXU gives
+    (``HIGHEST``: six bf16 passes)."""
+    import jax
+    import jax.numpy as jnp
+
+    return jnp.einsum(spec, a, b, precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
+
+
+@functools.lru_cache(maxsize=None)
+def _entries():
+    """``inverse``, ``solve``: ``_inverse`` behind a ``jax.jit`` entry made
+    once a process (``grouped_matmul._entries`` says why: a site is then one
+    equation, traced once a shape and lowered as one function that a step's
+    18 sites call), and the solve over it with its own way back."""
+    import jax
+    import jax.numpy as jnp
+
+    inverse = jax.jit(_inverse)
+
+    def fwd(a, rhs):
+        t = inverse(a)
+        x = _dot_f32("...ij,...jm->...im", t, rhs)
+        return x, (t, x)
+
+    @jax.custom_vjp
+    def solve(a, rhs):
+        return fwd(a, rhs)[0]
+
+    # two products with the inverse at hand (``x = T rhs``, ``T = (I +
+    # A)^-1``: ``d rhs = T^T dx``, ``dA = -d rhs x^T`` where ``A`` counts),
+    # not the way back through ``_inverse``'s levels: several times the ops
+    # for the same numbers (0.6 ms more a call of the op at the Ling cell's
+    # shape: PERF.md section 6, PR 47)
+    def bwd(res, dx):
+        t, x = res
+        d_rhs = _dot_f32("...ji,...jm->...im", t, dx)
+        return (jnp.tril(-_dot_f32("...im,...jm->...ij", d_rhs, x), -1),
+                d_rhs)
+
+    solve.defvjp(fwd, bwd)
+    return types.SimpleNamespace(inverse=inverse, solve=solve)
+
+
+def _solve(a, rhs):
+    """``(I + strictly_lower(a))^-1 rhs`` for ``a (..., C, C)`` and ``rhs
+    (..., C, M)`` float32: the explicit inverse (``_inverse``) and one
+    product at ``HIGHEST``, float32 throughout."""
+    import jax
+
+    with jax.named_scope("solve"):
+        return _entries().solve(a, rhs)
+
+
 def _prepare(q, k, v, g, beta):
     """What a chunk gives without its state, for every chunk at once: inputs
     ``(..., C, K | V)`` with ``g`` float32 and ``beta (..., C)``; returns
     ``(qg, kl, w, u, aqk, decay)``: ``q e^b``, ``k e^{b_last - b}``, ``W``,
-    ``U``, ``lower(P(q))`` and ``e^{b_last} (..., K)``, float32."""
-    import jax
+    ``U``, ``lower(P(q))`` and ``e^{b_last} (..., K)``, float32.  ``W | U =
+    (I + A)^-1 [beta k e^b | beta v]`` is one ``_solve``: of ``P(k)`` only
+    what lies under the diagonal counts."""
     import jax.numpy as jnp
 
     mm = q.dtype
@@ -110,9 +235,7 @@ def _prepare(q, k, v, g, beta):
     last = b[..., -1:, :]
     grow = jnp.exp(b)
     pk, pq = _pairwise([k * beta, q], k, b, mm)
-    both = jax.lax.linalg.triangular_solve(
-        jnp.tril(pk, -1), jnp.concatenate([k * grow * beta, v * beta], -1),
-        left_side=True, lower=True, unit_diagonal=True)
+    both = _solve(pk, jnp.concatenate([k * grow * beta, v * beta], -1))
     kd = k.shape[-1]
     return (q * grow, k * jnp.exp(last - b), both[..., :kd], both[..., kd:],
             jnp.tril(pq), jnp.exp(last[..., 0, :]))
@@ -214,8 +337,8 @@ def _backward(q, k, v, g, beta, s, do):
         d_w = -dot("...cv,...vk->...ck", d_vt, s)
 
         # a run of chunks at a time, so that what ``_prepare`` keeps for
-        # its way back (the decayed keys of every sub-block, the solve) is
-        # that run's at once
+        # its way back (the decayed keys of every sub-block, ``T`` and ``W |
+        # U`` of the solve) is that run's at once
         def back(run):
             inputs, cotangents = run
             return jax.vjp(_prepare, *inputs)[1](cotangents)

@@ -121,6 +121,155 @@ def test_kda_at_the_decays_bound_for_whole_chunks(dtype):
             < KDA_TOLERANCE[dtype], name
 
 
+# --------------------------------------------------------------------------
+# the chunk solve by doubling the diagonal blocks (PRs 47 and 48)
+# --------------------------------------------------------------------------
+def _row_at_a_time_solve(a, rhs):
+    """``(I + strictly_lower(a))^-1 rhs`` as ``F.kda`` had it until PR 48:
+    the plain reference of ``kda._solve``."""
+    return jax.lax.linalg.triangular_solve(
+        jnp.tril(a, -1), rhs, left_side=True, lower=True, unit_diagonal=True)
+
+
+def _solve_inputs(kind, chunk, heads=3, kd=32, vd=16):
+    """A chunk's ``(q, k, v, g, beta)`` of ``heads`` heads, float32:
+    ``"independent"`` unit keys with beta 1 and no decay; ``"alike"`` keys
+    near one direction (the mean entry of ``A`` about 0.9: the case in which
+    a series in powers of ``A`` cancels thousands against thousands);
+    ``"bound"`` every channel's decay at -5 a row but for a hair."""
+    keys = jax.random.split(jax.random.PRNGKey(chunk), 5)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    k = jax.random.normal(keys[1], (heads, chunk, kd))
+    if kind == "alike":
+        k = jax.random.normal(keys[0], (heads, 1, kd)) + 0.3 * k
+    q = unit(jax.random.normal(keys[2], k.shape)) / math.sqrt(kd)
+    v = jax.random.normal(keys[3], (heads, chunk, vd))
+    g = jnp.zeros(k.shape)
+    beta = jnp.ones(k.shape[:2])
+    if kind == "bound":
+        g = -5.0 * jax.nn.sigmoid(jax.random.normal(keys[4], k.shape) + 20.0)
+        beta = jax.nn.sigmoid(jax.random.normal(keys[0], k.shape[:2]))
+    return q, unit(k), v, g, beta
+
+
+@pytest.mark.parametrize("kind", ["independent", "alike", "bound"])
+@pytest.mark.parametrize("chunk", [16, 32, 48, 64, 128])
+def test_the_chunk_solve_by_blocks_is_the_row_at_a_time_solve(chunk, kind,
+                                                              monkeypatch):
+    """``kda._solve`` against float64 and against ``triangular_solve`` on
+    the chunk's own ``A``, and ``_prepare``'s gradients through it against
+    the same through the old solve."""
+    x = _solve_inputs(kind, chunk)
+    q, k, v, g, beta = x
+    b = jnp.cumsum(g, axis=-2)
+    a, = jax.jit(lambda k, b: kda._pairwise(
+        [k * beta[..., None]], k, b, jnp.float32))(k, b)
+    rhs = jnp.concatenate([k * jnp.exp(b), v], -1) * beta[..., None]
+    mean = float(jnp.abs(jnp.tril(a, -1)).sum()) / (
+        a.shape[0] * chunk * (chunk - 1) / 2)
+    assert {"independent": 0.1 < mean < 0.2, "alike": 0.85 < mean < 0.95,
+            "bound": mean < 1e-2}[kind], mean
+    want = np.linalg.solve(
+        np.eye(chunk) + np.tril(np.asarray(a, np.float64), -1),
+        np.asarray(rhs, np.float64))
+    top = np.abs(want).max()
+    error = lambda got: np.abs(np.asarray(got, np.float64) - want).max() / top
+    new = error(jax.jit(kda._solve)(a, rhs))
+    old = error(jax.jit(_row_at_a_time_solve)(a, rhs))
+    # float32: 2e-6 of the largest entry, and no worse than three times the
+    # old solve (or two units in float32's last place of that entry, where
+    # the old solve is exact to the digit: ``A`` at the bound is all but 0)
+    assert new < 2e-6 and new <= max(3 * old, 2.0 ** -22), (new, old)
+
+    cots = [jax.random.normal(jax.random.PRNGKey(i), o.shape)
+            for i, o in enumerate(jax.eval_shape(kda._prepare, *x))]
+
+    def grads():
+        return jax.jit(jax.grad(lambda *x: sum(
+            jnp.sum(o * c) for o, c in zip(kda._prepare(*x), cots)),
+            argnums=(0, 1, 2, 3, 4)))(*x)
+
+    got = grads()
+    monkeypatch.setattr(kda, "_solve", _row_at_a_time_solve)
+    for name, one, other in zip("q k v g beta".split(), got, grads()):
+        assert _rel(one, other) < KDA_TOLERANCE["float32"], name
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, nested ones too."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _kda_gradient(shape, dtype):
+    """``jax.grad`` of ``F.kda`` jitted, and shapes to lower it at."""
+    x = [jax.ShapeDtypeStruct(shape, dtype)] * 3 + [
+        jax.ShapeDtypeStruct(shape, "float32"),
+        jax.ShapeDtypeStruct(shape[:3], dtype)]
+    return jax.jit(jax.grad(
+        lambda *a: jnp.sum(kda.kda(*a).astype(jnp.float32)),
+        argnums=(0, 1, 2, 3, 4))), x
+
+
+def test_kda_and_its_gradient_lower_no_triangular_solve():
+    """The lowered module of ``jax.grad`` of ``F.kda`` holds no
+    ``triangular_solve`` (the forward's, ``_backward``'s own ``_prepare``
+    and the runs' way back all take ``_solve``) and no loop but the two
+    scans over the chunks and the map over the runs; the solve's products,
+    its way back's too, are float32 at ``HIGHEST``."""
+    grad, x = _kda_gradient((1, 2, 256, 128), "bfloat16")
+    text = grad.lower(*x).as_text()
+    assert "dot_general" in text and "triangular_solve" not in text
+    assert text.count("stablehlo.while") == 3
+
+    # the product with the right-hand side and the two of the solve's own
+    # way back: the inverse itself is element-wise float32
+    a = jnp.zeros((4, 64, 64), jnp.float32)
+    found = [e for e in _eqns(jax.make_jaxpr(jax.grad(
+        lambda a, rhs: jnp.sum(kda._solve(a, rhs)), argnums=(0, 1)))(
+            a, a).jaxpr) if e.primitive.name == "dot_general"]
+    assert len(found) == 1 + 2
+    for eqn in found:
+        assert set(eqn.params["precision"]) == {jax.lax.Precision.HIGHEST}
+        assert {v.aval.dtype for v in eqn.invars + eqn.outvars} == {
+            jnp.dtype("float32")}
+
+
+def test_the_chunk_solve_stays_a_small_program(monkeypatch):
+    """What a step's set-up pays for the solve before XLA sees it, held on
+    the CPU: written with a multiply-add an inner index and a slice a pair,
+    ``_inverse`` was 1,210 equations a site and the op's gradient 6,030
+    lines at the Ling cell's shape, and the cell's ``setup_s`` rose by 23 s
+    (PERF.md section 6, PR 47).  A level is a few whole-array ops, and the
+    sites share one jitted entry."""
+    a = jnp.zeros((4, 64, 64), jnp.float32)
+    assert sum(1 for _ in _eqns(jax.make_jaxpr(kda._inverse)(a).jaxpr)) <= 300
+
+    grad, x = _kda_gradient((1, 8, 8192, 128), "bfloat16")
+    assert grad.lower(*x).as_text().count("\n") <= 2100
+
+    # two sites at one shape trace ``_inverse`` once: the entry is made
+    # once a process and its identity holds the avals alone
+    traces = []
+    inverse = kda._inverse
+    monkeypatch.setattr(kda, "_inverse",
+                        lambda a: traces.append(a.shape) or inverse(a))
+    kda._entries.cache_clear()
+    try:
+        a = jnp.zeros((5, 32, 32), jnp.float32)
+        jaxpr = jax.make_jaxpr(lambda a, rhs: kda._solve(
+            a, kda._solve(a, rhs)))(a, a).jaxpr
+        assert traces == [a.shape]
+        sites = [e for e in _eqns(jaxpr)
+                 if e.primitive.name in ("jit", "pjit")]
+        assert len(sites) == 2
+        assert sites[0].params["jaxpr"] is sites[1].params["jaxpr"]
+    finally:
+        kda._entries.cache_clear()
+
+
 def test_kda_refuses_a_length_that_is_no_whole_number_of_chunks():
     x = _kda_inputs(0, 96, "float32")
     with pytest.raises(mx.MXNetError, match="no whole number of chunks"):
