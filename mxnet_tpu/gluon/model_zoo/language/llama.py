@@ -37,10 +37,14 @@ class LlamaConfig:
     what the attention op names: its output ``o`` and row statistics ``lse``
     (``ops.flash_attention.KEPT_O`` / ``KEPT_LSE``), and of a ``"kda"`` layer
     the delta rule's output and chunk states (``ops.kda.KEPT_O`` /
-    ``KEPT_STATES``).  The backward computes everything else of the layer
-    again (norms, projections, q/k norm and RoPE, the router and the
-    experts, q, k and v) and runs the attention's backward kernel on the
-    kept values: no attention forward runs twice.  Kept a layer, for ``rows
+    ``KEPT_STATES``), and of an expert layer the router's choice
+    (``parallel.expert_parallel.KEPT``: the experts chosen, their scores or
+    a softmax router's logits, the sorted walks' plan: a megabyte a layer,
+    a softmax router's logits ``rows x experts`` float32 more).  The
+    backward computes everything else of the layer again (norms,
+    projections, q/k norm and RoPE, the experts, q, k and v) and runs the
+    attention's backward kernel on the kept values: no attention forward,
+    no router product, ``top_k`` or sort runs twice.  Kept a layer, for ``rows
     = batch x length``: ``rows x hidden`` (the input) plus ``rows x heads x
     head size`` (``o``), both in the activations' dtype, plus ``rows x
     heads`` float32 (``lse``): 130 MiB for ``o`` and ``lse`` at 16,384 rows
@@ -1022,6 +1026,7 @@ class LlamaDecoderLayer(HybridBlock):
                 from ....ops import flash_attention as _fa, kda as _kda
 
                 from ....ops import selective_scan as _ssm
+                from ....parallel import expert_parallel as _ep
 
                 ctx = getattr(x, "context", None)
 
@@ -1040,7 +1045,7 @@ class LlamaDecoderLayer(HybridBlock):
                         policy=jax.checkpoint_policies.save_only_these_names(
                             _fa.KEPT_O, _fa.KEPT_LSE, _kda.KEPT_O,
                             _kda.KEPT_STATES, _ssm.KEPT_Y,
-                            _ssm.KEPT_STATES))(
+                            _ssm.KEPT_STATES, *_ep.KEPT))(
                                 xv, *(p._get() for p in packed))
                 for name, values in scalars.items():
                     _telemetry.step_scalar(name, values)

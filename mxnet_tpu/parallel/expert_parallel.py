@@ -44,6 +44,11 @@ Capability upgrade over the reference (MXNet 1.x has no MoE).
   before its first row and every row of the part after, pair or not.  Each
   of the two is the other's transpose, written by hand (``jax.custom_vjp``)
   because the walk's trip count is a device number.
+  The router is a ``custom_vjp`` of its own (``_router``): the choice has no
+  gradient, so its backward is two products and no forward one, and under a
+  decoder layer's checkpoint the choice, the chosen scores and what the
+  walks read of the sort (``_walk_plan``) are kept by name (``KEPT``): a
+  step chooses once.
   What the experts held elsewhere would add is left out; the exchange that
   brings it in is not written yet.
 """
@@ -54,6 +59,7 @@ import functools
 from .. import telemetry
 from ..base import MXNetError
 from ..ops import moe_add_rows
+from ..ops.flash_attention import keeping, kept
 from ..profiler import SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTE
 
 __all__ = ["moe_apply", "stack_expert_params", "inject_aux_loss",
@@ -331,10 +337,166 @@ def limit_to_groups(scores, n_group, topk_group):
         raise MXNetError(f"groups ({n_group}, {topk_group}) do not fit a "
                          f"router of {E} experts")
     size = E // n_group
-    best = jax.lax.top_k(scores.reshape(T, n_group, size), min(2, size))[0]
-    _, kept = jax.lax.top_k(jnp.sum(best, axis=-1), topk_group)
+    groups = scores.reshape(T, n_group, size)
+    best = jnp.max(groups, axis=-1)
+    if size > 1:
+        # plus the second largest: the largest with the first of the
+        # largest masked (a ``top_k`` of 2 is a sort of the group on a TPU)
+        first = jnp.argmax(groups, axis=-1)[..., None]
+        best = best + jnp.max(jnp.where(
+            jnp.arange(size) == first, -jnp.inf, groups), axis=-1)
+    _, kept = jax.lax.top_k(best, topk_group)
     stays = jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
     return jnp.where(jnp.repeat(stays, size, axis=1), scores, -jnp.inf)
+
+
+# The names under which a layer's checkpoint keeps the router's choice
+# (``LlamaDecoderLayer``'s policy lists ``KEPT``): the experts chosen, what
+# the gates' gradient reads of the scores (``_router``), and what the walks
+# read of the sort (``_walk_plan``).  With them kept the checkpoint's
+# backward holds no router product, no ``top_k`` and no sort.
+KEPT_CHOSEN = "mxnet_moe_route_chosen"
+KEPT_SCORES = "mxnet_moe_route_scores"
+KEPT_WALK = "mxnet_moe_route_walk"
+KEPT = (KEPT_CHOSEN, KEPT_SCORES, KEPT_WALK)
+# What a router's backward reads of its scores, by how it scores (``_router``)
+_KEPT_FORM = {"sigmoid": "choice", "softmax": "logits"}
+
+
+@functools.lru_cache(maxsize=None)
+def _router(score, top_k, groups, keeps):
+    """``route(x (T, d), router_weight (d, E), select_bias (E,) or None) ->
+    (picked, chosen)``, both ``(T, top_k)``: the experts a token chose and
+    their scores, as ``moe_apply`` says.  The choice has no gradient and
+    the scores' reaches the logits through the chosen columns alone, so the
+    backward is by hand and computes no product forward again: its
+    residuals are ``x``, the weight, ``chosen`` and, of the scores, what
+    their gradient reads.  A sigmoid is each output's own, so that is
+    ``picked`` itself (``kept="choice"``: ``T x top_k`` numbers); a softmax
+    is a row's, so it is the logits (``kept="logits"``: ``T x E``).
+    ``keeps``: the call stood inside ``checkpoint_keeps``, and the rule
+    names ``chosen`` and those scores for the layer's checkpoint."""
+    import jax
+    import jax.numpy as jnp
+
+    form = _KEPT_FORM[score]
+
+    def product(x, weight):
+        return jnp.dot(x.astype(jnp.float32), weight.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)      # (T, E)
+
+    def choose(x, weight, select_bias):
+        logits = product(x, weight)
+        scores = jax.nn.softmax(logits, axis=-1) if score == "softmax" \
+            else jax.nn.sigmoid(logits)
+        if select_bias is None and groups is None:
+            picked, chosen = jax.lax.top_k(scores, top_k)
+        else:
+            choice = scores if select_bias is None \
+                else scores + select_bias.astype(jnp.float32)
+            if groups is not None:
+                choice = limit_to_groups(choice, *groups)
+            _, chosen = jax.lax.top_k(choice, top_k)
+            # ``take_along_axis`` without the gather: a sum of one term
+            picked = jnp.take_along_axis(scores, chosen, axis=-1)
+        return logits, picked, chosen
+
+    @jax.custom_vjp
+    def route(x, weight, select_bias):
+        return choose(x, weight, select_bias)[1:]
+
+    def route_fwd(x, weight, select_bias):
+        logits, picked, chosen = choose(x, weight, select_bias)
+        if keeps:
+            # named inside the rule, and the named values the ones that
+            # leave it: the residuals, here and of whoever reads the gates
+            # and the choice, are then the kept values themselves.  Flat: a
+            # TPU lays rows of ``top_k`` numbers out a lane tile each, 4 MiB
+            # for 8,192 rows of 8
+            chosen = kept(KEPT_CHOSEN, chosen.reshape(-1)).reshape(
+                chosen.shape)
+            picked = kept(KEPT_SCORES, picked.reshape(-1)).reshape(
+                picked.shape)
+            if form == "logits":
+                logits = kept(KEPT_SCORES, logits)
+        return (picked, chosen), (
+            x, weight, chosen, picked if form == "choice" else logits)
+
+    def route_bwd(res, g):
+        x, weight, chosen, read = res
+
+        def to_columns(of_chosen):
+            """``(T, top_k)`` into the chosen columns of ``(T, E)`` zeros: the
+            gather's transpose.  A token chooses an expert once, so each sum
+            holds one term; XLA's scatter-add of ``T x top_k`` updates is,
+            on a TPU, a sort of them, three times the pass."""
+            hit = chosen[:, :, None] == jnp.arange(weight.shape[1],
+                                                   dtype=chosen.dtype)
+            return jnp.sum(jnp.where(hit, of_chosen[:, :, None], 0), axis=1)
+
+        with jax.named_scope(SCOPE_MOE_ROUTE):
+            if form == "choice":
+                # the logistic's own rule as JAX multiplies it
+                dlogits = to_columns(g[0] * (read * (1 - read)))
+            else:
+                dlogits, = jax.vjp(functools.partial(
+                    jax.nn.softmax, axis=-1), read)[1](to_columns(g[0]))
+            dweight, = jax.linear_transpose(
+                lambda weight: product(x, weight), weight)(dlogits)
+            if keeps:
+                # the weight's gradient first: only the optimizer waits for
+                # it, and XLA's scheduler, left alone, put its product at
+                # the step's end for the last two layers and held their
+                # ``x`` and ``dlogits`` until then (0.15 GiB of the Ling
+                # cell's scratch, by its compiled step).  Tied through
+                # ``dlogits`` and not ``dx``, which the barrier would copy
+                dlogits, dweight = jax.lax.optimization_barrier(
+                    (dlogits, dweight))
+            dx, = jax.linear_transpose(lambda x: product(x, weight), x)(
+                dlogits)
+        return dx, dweight, None
+
+    route.defvjp(route_fwd, route_bwd)
+    return route
+
+
+def _walk_plan(chosen, first, count, part, n_parts, keeps):
+    """What the walks read of the choice, ``chosen (T, top_k)``, for the
+    experts ``first .. first + count - 1`` in ``n_parts`` parts of ``part``
+    sorted rows: ``(order, sizes, n_live, live_parts, load, total)``.
+    ``order (n_parts, part)``: the pairs sorted by expert, those held
+    elsewhere past the end; ``sizes (n_parts, count)``: each part's groups,
+    the pairs of each expert in it; ``n_live (n_parts,)``: the rows of each
+    part that hold a pair; ``live_parts``: the parts that hold one at all.
+    Those four are all the backward reads of the sort (the loop over parts
+    and the walks inside it keep them as residuals), so with ``keeps`` they
+    are named for the layer's checkpoint; a value that a later change keeps
+    for the backward joins them here.  ``load (count,)`` and ``total``, the
+    pairs of each expert and of all, leave forward with the layer's
+    ``aux``."""
+    import jax.numpy as jnp
+
+    pairs = chosen.size
+    # pairs held elsewhere get the key ``count`` and sort past the end
+    local = chosen.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < count), local, count)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    load = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
+                   dtype=jnp.int32)                               # (count,)
+    ends = jnp.cumsum(load)
+    total = ends[-1]
+    order = jnp.pad(order, (0, n_parts * part - pairs)) \
+        .reshape(n_parts, part)
+    starts = jnp.arange(n_parts, dtype=jnp.int32) * part
+    n_live = jnp.clip(total - starts, 0, part)
+    live_parts = -(-total // part)
+    lo, hi = starts[:, None], starts[:, None] + part
+    sizes = jnp.clip(ends, lo, hi) - jnp.clip(ends - load, lo, hi)
+    if keeps:
+        order, sizes, n_live, live_parts = (
+            kept(KEPT_WALK, value)
+            for value in (order, sizes, n_live, live_parts))
+    return order, sizes, n_live, live_parts, load, total
 
 
 def _moe_dropless(expert_fn, expert_params, router_weight, x, top_k,
@@ -357,46 +519,21 @@ def _moe_dropless(expert_fn, expert_params, router_weight, x, top_k,
              // granule) * granule
     n_parts = -(-pairs // part)
 
+    keeps = keeping()
+    telemetry.MOE_ROUTER_KEPT.labels(kept=_KEPT_FORM[score]).inc()
     with jax.named_scope(SCOPE_MOE_ROUTE):
-        logits = jnp.dot(x.astype(jnp.float32),
-                         router_weight.astype(jnp.float32),
-                         precision=jax.lax.Precision.HIGHEST)    # (T, E)
-        scores = jax.nn.softmax(logits, axis=-1) if score == "softmax" \
-            else jax.nn.sigmoid(logits)
-        if select_bias is None and groups is None:
-            gates, chosen = jax.lax.top_k(scores, top_k)
-        else:
-            choice = scores if select_bias is None \
-                else scores + select_bias.astype(jnp.float32)
-            if groups is not None:
-                choice = limit_to_groups(choice, *groups)
-            _, chosen = jax.lax.top_k(choice, top_k)
-            gates = jnp.take_along_axis(scores, chosen, axis=-1)
+        gates, chosen = _router(score, top_k, groups, keeps)(
+            x, router_weight, select_bias)
         if renormalize:
             norm = jnp.sum(gates, axis=-1, keepdims=True)
             gates = gates / (norm + renorm_eps if renorm_eps else norm)
         if scale != 1.0:
             gates = gates * scale
-        # pairs held elsewhere get the key ``count`` and sort past the end
-        local = chosen.reshape(-1) - first
-        key = jnp.where((local >= 0) & (local < count), local, count)
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        load = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
-                       dtype=jnp.int32)                           # (count,)
-        ends = jnp.cumsum(load)
-        total = ends[-1]
-        order = jnp.pad(order, (0, n_parts * part - pairs)) \
-            .reshape(n_parts, part)
-        starts = jnp.arange(n_parts, dtype=jnp.int32) * part
-        # the rows of each part that hold a pair, the whole granules of
-        # them that its sorted walk covers, the parts that hold a pair at
-        # all, and each part's groups: the pairs of each expert in it
-        n_live = jnp.clip(total - starts, 0, part)
+        order, sizes, n_live, live_parts, load, total = _walk_plan(
+            chosen, first, count, part, n_parts, keeps)
+        # the whole granules that the sorted walks cover, and the rows that
+        # the way back walks: the pairs under its kernel, whole parts else
         walked = jnp.sum(-(-n_live // granule) * granule)
-        live_parts = -(-total // part)
-        lo, hi = starts[:, None], starts[:, None] + part
-        sizes = jnp.clip(ends, lo, hi) - jnp.clip(ends - load, lo, hi)
-        # the way back walks the pairs under its kernel, whole parts else
         kernel = moe_add_rows.use_pallas(part, d)
         added = total if kernel else live_parts * part
         out = jnp.zeros((T, d), jnp.float32)

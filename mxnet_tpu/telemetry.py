@@ -446,6 +446,13 @@ MOE_GROUP_LIMITED_CALLS = counter(
     "dropless expert layers traced whose choice of experts is confined to "
     "the best groups of the router's outputs (moe_swiglu's n_group > 1), one "
     "count a layer a trace")
+MOE_ROUTER_KEPT = counter(
+    "mxnet_moe_router_kept_total",
+    "dropless routers traced, by what their backward keeps of the scores in "
+    "place of a second product: choice (a sigmoid router: the chosen "
+    "experts' scores, tokens x top_k) or logits (a softmax router: the "
+    "row's logits, tokens x experts); one count a layer a trace",
+    ("kept",))
 KDA_CALLS = counter(
     "mxnet_kda_calls_total",
     "kda (chunked gated delta rule) calls traced, by the path their state "
@@ -471,7 +478,8 @@ LAYER_CHECKPOINT_KEPT_BYTES = counter(
     "mxnet_layer_checkpoint_kept_bytes_total",
     "bytes of the values an op names for a decoder layer's checkpoint to "
     "keep beside the layer's input (the attention op's output and row "
-    "statistics, the delta rule's output and chunk states), from their "
+    "statistics, the delta rule's output and chunk states, the router's "
+    "choice, its scores and the sorted walks' plan), from their "
     "shapes: one count a named value a layer a trace",
     ("name",))
 LAYER_HANDED_ON_BYTES = counter(

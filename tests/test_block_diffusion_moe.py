@@ -433,6 +433,149 @@ def test_a_held_share_under_checkpoint_and_jit_is_the_whole_part_form(
         np.testing.assert_allclose(a, b, atol=6e-6)
 
 
+# the routers of the four decoder cells: how each scores, and what confines
+# or moves its choice
+ROUTERS = {
+    # sigmoid plus a bias, the choice within 2 of 4 groups (the Ling cell's)
+    "sigmoid, groups and a bias": dict(
+        score="sigmoid", bias=True, groups=(4, 2), scale=2.5,
+        renorm_eps=1e-20),
+    # sigmoid plus a bias (the window cell's)
+    "sigmoid and a bias": dict(score="sigmoid", bias=True, scale=2.826,
+                               renorm_eps=1e-20),
+    # softmax (the packed and the block-diffusion cells')
+    "softmax": dict(score="softmax"),
+    "softmax, gates as they are": dict(score="softmax", renormalize=False),
+}
+
+
+def _parents_router(score, top_k, groups, keeps):
+    """``expert_parallel._router`` as the layer had it until ISSUE 49: the
+    product, the scores and the choice as plain JAX, differentiated by JAX,
+    so that a layer's checkpoint computes all of it again."""
+    def route(x, weight, select_bias):
+        logits = jnp.dot(x.astype(jnp.float32), weight.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        scores = jax.nn.softmax(logits, axis=-1) if score == "softmax" \
+            else jax.nn.sigmoid(logits)
+        if select_bias is None and groups is None:
+            return jax.lax.top_k(scores, top_k)
+        choice = scores if select_bias is None \
+            else scores + select_bias.astype(jnp.float32)
+        if groups is not None:
+            choice = expert_parallel.limit_to_groups(choice, *groups)
+        _, chosen = jax.lax.top_k(choice, top_k)
+        return jnp.take_along_axis(scores, chosen, axis=-1), chosen
+
+    return route
+
+
+def _the_parents_layer(monkeypatch):
+    """The layer with the parent's router, and nothing of it named for the
+    checkpoint."""
+    monkeypatch.setattr(expert_parallel, "_router", _parents_router)
+    monkeypatch.setattr(expert_parallel, "keeping", lambda: False)
+
+
+def _checkpointed_layer(router, grad):
+    """``(jitted function, arguments)``: a share of 8 of 32 experts under a
+    decoder layer's checkpoint (its policy and its ``checkpoint_keeps``), a
+    norm's stand-in before it; the loss alone or with every gradient."""
+    how = dict(ROUTERS[router])
+    rs = np.random.RandomState(3)
+    args = [jnp.asarray(rs.randn(96, 16).astype("f")),
+            jnp.asarray(rs.randn(16, 32).astype("f")),
+            _expert_weights(rs, 8, 16, 8),
+            jnp.asarray(rs.rand(32).astype("f")) if how.pop("bias", False)
+            else None]
+
+    def layer(x, weight, p, bias):
+        return moe_apply(_grouped, p, weight, x * 1.5, capacity_factor=None,
+                         top_k=3, held=(4, 8), select_bias=bias,
+                         **{"renormalize": True, **how})[0]
+
+    def loss(*args):
+        with fa.checkpoint_keeps():
+            return jnp.sum(jnp.sin(jax.checkpoint(
+                layer, policy=jax.checkpoint_policies.save_only_these_names(
+                    *expert_parallel.KEPT))(*args)))
+
+    over = (0, 1, 2) + ((3,) if args[3] is not None else ())
+    return jax.jit(jax.value_and_grad(loss, over) if grad else loss), args
+
+
+@pytest.mark.parametrize("router", list(ROUTERS))
+def test_the_choice_kept_is_the_parents_router_computed_again(monkeypatch,
+                                                              router):
+    """The loss and every gradient (the tokens', the router's, the experts'
+    and the bias's zeros) of a layer whose checkpoint keeps the router's
+    choice equal, bit for bit in float32, those of the parent's router,
+    which the checkpoint computes again: the arithmetic has not changed.
+    The kept bytes are counted under their names, and the form the router
+    took."""
+    from mxnet_tpu import telemetry
+
+    telemetry.reset()
+    fn, args = _checkpointed_layer(router, grad=True)
+    got = fn(*args)
+    metrics = telemetry.snapshot()["metrics"]
+    kept = {s["labels"]["name"]: s["value"] for s in metrics[
+        "mxnet_layer_checkpoint_kept_bytes_total"]["samples"] if s["value"]}
+    form = "choice" if "sigmoid" in router else "logits"
+    # chosen and their scores (96, 3); a softmax keeps the logits (96, 32)
+    # too; the walks' plan: one part of 288 sorted pairs, its 8 groups, its
+    # live rows and the count of live parts
+    assert kept == {
+        expert_parallel.KEPT_CHOSEN: 96 * 3 * 4,
+        expert_parallel.KEPT_SCORES: 96 * (3 + 32 * (form == "logits")) * 4,
+        expert_parallel.KEPT_WALK: (288 + 8 + 1 + 1) * 4}
+    assert {s["labels"]["kept"]: s["value"] for s in metrics[
+        "mxnet_moe_router_kept_total"]["samples"] if s["value"]} == {form: 1}
+
+    _the_parents_layer(monkeypatch)
+    want = _checkpointed_layer(router, grad=True)[0](*args)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want), strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert float(jnp.abs(got[1][1]).max()) > 1e-3      # the router's moved
+
+
+@pytest.mark.parametrize("router", list(ROUTERS))
+def test_a_checkpointed_layers_backward_holds_no_router_forward(monkeypatch,
+                                                                router):
+    """The lowered module of a checkpointed layer's loss and gradients holds
+    the router's product once forward and its two transposes backward, and
+    the ``top_k``s and the sort of the forward alone: the backward computes
+    no product of the router again, no ``top_k`` and no sort, and scatters
+    no gradient of a gate into its column.  The parent's router under the
+    same checkpoint holds each twice, and that scatter."""
+    import re
+
+    def counted(grad):
+        fn, args = _checkpointed_layer(router, grad)
+        text = fn.lower(*args).as_text()
+        # of the router's shape: (96, 16) x (16, 32), or a transpose's
+        products = [line for line in text.splitlines()
+                    if "stablehlo.dot_general" in line
+                    and re.search(r"-> tensor<(96x32|16x32|32x16|96x16)xf32>",
+                                  line)]
+        return (len(products), text.count("chlo.top_k"),
+                text.count("stablehlo.sort"), text.count("\n"),
+                text.count("stablehlo.scatter"))
+
+    top_ks = 2 if "groups" in router else 1
+    assert counted(grad=False)[:3] == (1, top_ks, 1)
+    kept = counted(grad=True)
+    assert kept[:3] == (1 + 2, top_ks, 1)
+    _the_parents_layer(monkeypatch)
+    parents = counted(grad=True)
+    assert parents[:3] == (2 + 2, 2 * top_ks, 2)
+    assert kept[3] < parents[3]             # and the module is the smaller
+    # the gates' gradient reaches its columns without the scatter-add of
+    # ``tokens x top_k`` updates that a TPU sorts
+    assert kept[4] < parents[4]
+
+
 @pytest.mark.parametrize("held,part_rows", [((0, 8), 256), ((2, 3), 32)])
 def test_walked_rows_follow_the_routed_pairs(monkeypatch, held, part_rows):
     """``walked_rows`` is what the layer's sorted walks cover: whole

@@ -428,7 +428,9 @@ def _expert_layer_for_v5e(cell, one_chip):
     and compiles nothing (until PR 41 it compiled the forward again alone:
     113 s of this file's 273)."""
     from mxnet_tpu import profiler
+    from mxnet_tpu.ops import flash_attention as fa
     from mxnet_tpu.ops.attention_ops import moe_swiglu
+    from mxnet_tpu.parallel import expert_parallel
     from mxnet_tpu.parallel.expert_parallel import (_PART_EVEN_LOADS,
                                                     _PART_ROWS)
 
@@ -438,7 +440,6 @@ def _expert_layer_for_v5e(cell, one_chip):
     part = min(_PART_ROWS, tokens * top_k,
                _PART_EVEN_LOADS * tokens * top_k * held // experts)
 
-    @jax.checkpoint
     def layer(x, router, p, bias):
         return moe_swiglu(
             x[None], router, p["g"], p["u"], p["d"], bias, capacity_factor=0,
@@ -448,9 +449,13 @@ def _expert_layer_for_v5e(cell, one_chip):
             renorm_eps=scoring.get("renorm_eps", 0.0), n_group=n_group,
             topk_group=topk_group)[0]
 
+    # as a decoder layer's checkpoint calls it: the router's choice is kept
     @jax.named_scope(profiler.SCOPE_FORWARD)
     def loss(x, router, p, bias):
-        return jnp.sum(jnp.sin(layer(x, router, p, bias)))
+        with fa.checkpoint_keeps():
+            return jnp.sum(jnp.sin(jax.checkpoint(
+                layer, policy=jax.checkpoint_policies.save_only_these_names(
+                    *expert_parallel.KEPT))(x, router, p, bias)))
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -503,7 +508,11 @@ def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
     ``backward`` by their own names; the ``while`` instruction itself carries its caller's
     scope (one around it would name its body's products too), and what the
     compiler adds without a name (copies, a buffer's fill sunk into the
-    body) carries none."""
+    body) carries none.
+
+    The router chooses once (ISSUE 49): the layer's checkpoint keeps the
+    choice, so the backward holds the router's two transposed products and
+    no forward one, and no sort (on a TPU a ``top_k`` is one)."""
     import re
 
     from mxnet_tpu import profiler
@@ -587,6 +596,34 @@ def test_dropless_expert_layer_compiles_for_v5e_and_gathers_live_rows(
     # (in the kernel's layout, a token's row contiguous: the pass that
     # brings the result back to rows of tiles is fused into whoever reads it)
     assert not re.search(target + r"\{2,1,0:T\(1,128\)\S* copy\(", text)
+    if not backward:
+        return
+    # the router (ISSUE 49; the layer's checkpoint keeps its choice): the
+    # float32 product once forward and backward its two transposes, no
+    # third; every sort of the program (on a TPU ``top_k`` is one, and so
+    # is a scatter of 65,536 updates with indices of its own) is the
+    # forward's: the ``top_k``s and the sort of the pairs
+    _, _, experts, _, _, _, scoring = EXPERT_LAYERS[cell]
+    lines = [(line, re.search(r'op_name="([^"]*)"', line))
+             for line in text.splitlines()]
+    products = sorted(
+        (tuple(int(n) for n in re.search(
+            r"= f32\[(\d+),(\d+)\]", line).groups()),
+         "transpose(" in op.group(1))
+        for line, op in lines
+        if " convolution(" in line and "{highest,highest}" in line)
+    assert products == sorted([((tokens, experts), False),
+                               ((hidden, experts), True),
+                               ((tokens, hidden), True)])
+    sorts = [op.group(1) if op else "" for line, op in lines
+             if " sort(" in line]
+    assert len(sorts) == (3 if "groups" in scoring else 2)
+    assert all(profiler.SCOPE_MOE_ROUTE in name and "transpose(" not in name
+               for name in sorts)
+    # what set-up pays for (PERF.md section 6, PR 47): with the router
+    # computed again the Ling cell's layer compiled to 2,728 lines
+    if cell == "ling":
+        assert text.count("\n") <= 2728
 
 
 # --------------------------------------------------------------------------
