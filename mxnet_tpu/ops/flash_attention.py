@@ -17,7 +17,8 @@ over the same tiles on the same operands (`_fa_backward_blockwise`, O(L)
 memory).
 
 Layout: (batch, heads, seq, head_dim) — q_heads may be a multiple of
-kv_heads (GQA).
+kv_heads (GQA): K and V are never repeated in HBM for the kernels, whose
+block maps send a group of query heads to its one key-value head.
 """
 from __future__ import annotations
 
@@ -203,6 +204,43 @@ def _sample_of(bh, heads):
     return jax.lax.div(bh, heads)
 
 
+def _kv_row(bh, rep):
+    """The row of the flattened (batch x key-value heads) that row ``bh`` of
+    the flattened (batch x query heads) reads, ``rep`` query heads to a
+    key-value head: ``b * h + head`` over ``rep`` is ``b * hkv + head //
+    rep``.  The group lives in the kernels' block maps, not in HBM:
+    consecutive heads of a group name one block of K and of V.  Truncating,
+    as ``_sample_of``; a head of its own (``rep`` 1) keeps ``bh`` itself and
+    its kernels the maps they had."""
+    import jax
+
+    return bh if rep == 1 else jax.lax.div(bh, rep)
+
+
+def _per_query_head(x, heads):
+    """``x`` (b, hkv, l, d) with every key-value head repeated for the query
+    heads that share it, ``(b, heads, l, d)``: what the paths off the
+    kernels compute on."""
+    import jax.numpy as jnp
+
+    hkv = x.shape[1]
+    return x if hkv == heads else jnp.repeat(x, heads // hkv, axis=1)
+
+
+def _fold_group(dx, hkv):
+    """Gradients a query head ``(b, h, l, d)`` summed onto the ``hkv``
+    key-value heads their groups share: the ``reduce_sum`` that
+    ``_per_query_head``'s transpose is, in ``dx``'s dtype, so that a call
+    on ``hkv`` heads and the same call on K and V repeated by hand give
+    one ``dk`` and ``dv``, bit for bit."""
+    import jax
+
+    b, h, l, d = dx.shape
+    if h == hkv:
+        return dx
+    return jax.lax.reduce_sum(dx.reshape(b, hkv, h // hkv, l, d), axes=(2,))
+
+
 def _segment_tiles(seg, causal, mask, lq, lk, block_q, block_k):
     """The live tiles of a call under segment ids, from that batch's ids on
     the device: bool ``(batch, lq / block_q, lk / block_k)``.  A tile is
@@ -331,11 +369,7 @@ def _mha_with_lse(q, k, v, causal, sm_scale, mask=None):
     mask = _Mask.of(mask)
     with jax.named_scope(SCOPE_ATTENTION_PLAIN_FWD):
         b, hq, lq, d = q.shape
-        hkv = k.shape[1]
-        if hq != hkv:
-            rep = hq // hkv
-            k = jnp.repeat(k, rep, axis=1)
-            v = jnp.repeat(v, rep, axis=1)
+        k, v = _per_query_head(k, hq), _per_query_head(v, hq)
         scores = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                             k.astype(jnp.float32)) * sm_scale
         lk = k.shape[2]
@@ -650,10 +684,13 @@ def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q=None, block_k=None,
     assert lq % block_q == 0 and lk % block_k == 0, (
         "sequence must be padded to the attention block size")
 
+    # K and V keep their own heads: a group of rep query heads reads one row
+    hkv = k.shape[1]
+    rep = h // hkv
     grid = (b * h, lq // block_q)
     qf = q.reshape(b * h, lq, d)
-    kf = k.reshape(b * h, lk, d)
-    vf = v.reshape(b * h, lk, dv)
+    kf = k.reshape(b * hkv, lk, d)
+    vf = v.reshape(b * hkv, lk, dv)
 
     static = dict(block_k=block_k, causal=causal, sm_scale=sm_scale, seq_k=lk,
                   diag_offset=lk - lq, mask=mask.key)
@@ -661,8 +698,10 @@ def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q=None, block_k=None,
     # bounds after them
     in_specs = [
         pl.BlockSpec((None, block_q, d), lambda bh, qi, *_: (bh, qi, 0)),
-        pl.BlockSpec((None, lk, d), lambda bh, qi, *_: (bh, 0, 0)),
-        pl.BlockSpec((None, lk, dv), lambda bh, qi, *_: (bh, 0, 0)),
+        pl.BlockSpec((None, lk, d),
+                     lambda bh, qi, *_: (_kv_row(bh, rep), 0, 0)),
+        pl.BlockSpec((None, lk, dv),
+                     lambda bh, qi, *_: (_kv_row(bh, rep), 0, 0)),
     ]
     out_specs = [
         pl.BlockSpec((None, block_q, dv), lambda bh, qi, *_: (bh, qi, 0)),
@@ -938,8 +977,10 @@ def _fa_backward_pallas(q, k, v, o, lse, g, causal, sm_scale, mask=None):
     """Gradients of q, k and v from one Pallas call over the live tile
     pairs (``_fa_bwd_kernel``): those of the static table, or under segment
     ids those of the table built here from the ids; ``delta = rowsum(o *
-    g)`` is the one reduction left to XLA.  No score-shaped array reaches
-    HBM."""
+    g)`` and, where a group of query heads shares a key-value head, the sum
+    of ``dk`` and ``dv`` over the group (``_fold_group``) are the
+    reductions left to XLA.  No score-shaped array reaches HBM, and K and V
+    come on their own heads (``_kv_row``)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -952,14 +993,15 @@ def _fa_backward_pallas(q, k, v, o, lse, g, causal, sm_scale, mask=None):
         widths = q.shape[-1], k.shape[-1]
         q, k = (_pad_width(x, _padded_width(x.shape[-1])) for x in (q, k))
         b, h, lq, d = q.shape
-        lk, dv = k.shape[2], v.shape[-1]
+        hkv, lk, dv = k.shape[1], k.shape[2], v.shape[-1]
+        rep = h // hkv
         block_q, block_k = _fa_bwd_block_sizes(lq, lk)
         pairs = _fa_bwd_pairs(causal, mask.key, lq, lk, block_q, block_k)
         nq = lq // block_q
 
         delta = jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
         rows = lambda x: x.astype(jnp.float32).reshape(b * h, nq, 1, block_q)
-        flat = lambda x: x.reshape(b * h, *x.shape[2:])
+        flat = lambda x: x.reshape(-1, *x.shape[2:])   # heads: h, or hkv
 
         # the table's row of grid step (bh, t): row t, or under ids row t
         # of the sample's own table
@@ -968,12 +1010,18 @@ def _fa_backward_pallas(q, k, v, o, lse, g, causal, sm_scale, mask=None):
             lambda bh, t: _sample_of(bh, h) * n_rows + t)
         q_tile = pl.BlockSpec((None, block_q, d), lambda bh, t, pairs:
                               (bh, pairs[0, at(bh, t)], 0))
-        k_tile = pl.BlockSpec((None, block_k, d), lambda bh, t, pairs:
-                              (bh, pairs[1, at(bh, t)], 0))
         g_tile = pl.BlockSpec((None, block_q, dv), lambda bh, t, pairs:
                               (bh, pairs[0, at(bh, t)], 0))
-        v_tile = pl.BlockSpec((None, block_k, dv), lambda bh, t, pairs:
-                              (bh, pairs[1, at(bh, t)], 0))
+
+        def k_rows(width, row):
+            return pl.BlockSpec((None, block_k, width), lambda bh, t, pairs:
+                                (row(bh), pairs[1, at(bh, t)], 0))
+
+        # K and V are read a key-value head, a group of rep query heads
+        # from one row; dk and dv are written a query head
+        shared, own = (lambda bh: _kv_row(bh, rep)), (lambda bh: bh)
+        k_tile, v_tile = k_rows(d, shared), k_rows(dv, shared)
+        dk_tile, dv_tile = k_rows(d, own), k_rows(dv, own)
         q_row = pl.BlockSpec((None, None, 1, block_q), lambda bh, t, pairs:
                              (bh, pairs[0, at(bh, t)], 0, 0))
         need = _fa_bwd_vmem_bytes(lq, d, q.dtype.itemsize, block_q, block_k,
@@ -1009,7 +1057,7 @@ def _fa_backward_pallas(q, k, v, o, lse, g, causal, sm_scale, mask=None):
                 in_specs=in_specs,
                 out_specs=[pl.BlockSpec((None, lq, d),
                                         lambda bh, t, pairs: (bh, 0, 0)),
-                           k_tile, v_tile],
+                           dk_tile, dv_tile],
                 scratch_shapes=[pltpu.VMEM((nq, d, block_q), jnp.float32),
                                 pltpu.VMEM((block_k, d), jnp.float32),
                                 pltpu.VMEM((block_k, dv), jnp.float32)]),
@@ -1021,8 +1069,14 @@ def _fa_backward_pallas(q, k, v, o, lse, g, causal, sm_scale, mask=None):
                 vmem_limit_bytes=max(need, _VMEM_DEFAULT_LIMIT)),
             name=_kernel_name(SCOPE_ATTENTION_BWD, mask),
         )(table, *operands)
-    return (dq.reshape(q.shape)[..., :widths[0]],
-            dk.reshape(k.shape)[..., :widths[1]], dv.reshape(v.shape))
+    dq, dk, dv = (dq.reshape(q.shape)[..., :widths[0]],
+                  dk.reshape(b, h, *k.shape[2:])[..., :widths[1]],
+                  dv.reshape(b, h, *v.shape[2:]))
+    # the group's sum is left to XLA, in the gradients' dtype (what the
+    # transpose of a repeat before the call would sum in), as the
+    # backward's own work
+    with jax.named_scope(SCOPE_ATTENTION_BWD):
+        return dq, _fold_group(dk, hkv), _fold_group(dv, hkv)
 
 
 def _use_pallas_bwd(q, k, v=None):
@@ -1101,7 +1155,8 @@ def _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
     mask, ids = how.key, how.ids(q.shape[2])
     with jax.named_scope(SCOPE_ATTENTION_BWD):
         b, h, lq, d = q.shape
-        lk = k.shape[2]
+        hkv, lk = k.shape[1], k.shape[2]
+        k, v = _per_query_head(k, h), _per_query_head(v, h)
         block_k = min(block_k, lk)
         if lk % block_k != 0:
             block_k = lk
@@ -1187,7 +1242,8 @@ def _fa_backward_blockwise(q, k, v, o, lse, g, causal, sm_scale,
             zeros = (jnp.zeros(q.shape, acc_t), jnp.zeros(k.shape, acc_t),
                      jnp.zeros(v.shape, acc_t))
             (dq, dk, dv), _ = jax.lax.scan(step, zeros, pairs)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    return (dq.astype(q.dtype), _fold_group(dk.astype(k.dtype), hkv),
+            _fold_group(dv.astype(v.dtype), hkv))
 
 
 # --------------------------------------------------------------------------
@@ -1265,6 +1321,14 @@ def _make_flash(causal, sm_scale_key, mask=None, sharded=None, keeps=False):
             ("path", "mask")).labels(
                 path="pallas" if pallas else "plain",
                 mask=how.label(causal)).inc()
+        group = q.shape[1] // k.shape[1]
+        if pallas and group > 1:
+            telemetry.counter(
+                "mxnet_flash_attention_shared_kv_calls_total",
+                "flash_attention calls traced whose kernels read a "
+                "key-value head shared by a group of query heads through "
+                "their block maps, by the group's size",
+                ("group",)).labels(group=str(group)).inc()
         if pallas:
             return _fa_forward(q, k, v, causal, sm_scale, how, sharded)
         return _mha_with_lse(q, k, v, causal, sm_scale, how)
@@ -1334,7 +1398,10 @@ def _count_pairs(q, k, causal, mask):
 def flash_attention(q, k, v, causal=False, sm_scale=None, mask=None,
                     mask_block=0, window=0, segment_ids=None):
     """q (B,Hq,Lq,D); k (B,Hkv,Lk,D), v (B,Hkv,Lk,Dv) with Hq % Hkv == 0
-    (GQA).  ``Dv`` may differ from ``D`` (latent attention's 192 and 128):
+    (GQA): K and V keep their heads through the op and its backward, and
+    the kernels read a shared head through their block maps (``_kv_row``;
+    the paths off the kernels repeat inside, ``_per_query_head``).  ``Dv``
+    may differ from ``D`` (latent attention's 192 and 128):
     the output is ``(B,Hq,Lq,Dv)``, and the kernels pad q and k to a width
     they tile (``_padded_width``), never v.
 
@@ -1358,25 +1425,20 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, mask=None,
     less."""
     import jax.numpy as jnp
 
-    if q.shape[-1] != k.shape[-1] or k.shape[:3] != v.shape[:3]:
+    if (q.shape[-1] != k.shape[-1] or k.shape[:3] != v.shape[:3]
+            or q.shape[1] % k.shape[1]):
         from ..base import MXNetError
 
         raise MXNetError(
-            "flash_attention: q and k share a head size, and k and v their "
-            f"heads and rows (v's head size is its own); got q {q.shape}, k "
-            f"{k.shape}, v {v.shape}")
+            "flash_attention: q and k share a head size, k and v their "
+            "heads and rows (v's head size is its own), and a whole number "
+            f"of query heads a key-value head; got q {q.shape}, k {k.shape}, "
+            f"v {v.shape}")
     mask = _mask_key(mask, mask_block, causal, window)
     _check_mask_shape(mask, q.shape[2], k.shape[2])
     d = q.shape[-1]
     if sm_scale is None:
         sm_scale = 1.0 / _np.sqrt(d)
-    hq, hkv = q.shape[1], k.shape[1]
-    if hq != hkv:
-        # GQA expansion OUTSIDE the custom_vjp: jnp.repeat's own vjp folds
-        # the expanded-head grads back onto the kv heads
-        rep = hq // hkv
-        k = jnp.repeat(k, rep, axis=1)
-        v = jnp.repeat(v, rep, axis=1)
     how = _Mask(mask, None if segment_ids is None
                 else jnp.asarray(segment_ids).astype(jnp.int32))
     how.check(causal, q.shape[0], k.shape[2])

@@ -1,8 +1,12 @@
 """The Pallas backward kernel of ``flash_attention`` (ISSUE 27) under the TPU
 interpreter, against autodiff through dense attention; what reaches its
 products and the blockwise scan's; the gate between the two and the counter
-that says which a call took.  All on the CPU; ``tests/test_tpu_compile.py``
-is where the chip's compiler reads the kernel."""
+that says which a call took; a group of query heads on one key-value head
+(ISSUE 50) against K and V repeated by hand.  All on the CPU;
+``tests/test_tpu_compile.py`` is where the chip's compiler reads the
+kernel."""
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -220,3 +224,129 @@ def test_the_gate_and_the_counter_of_the_path_taken(monkeypatch):
         grads(256, 256)
     assert (_calls("pallas"), _calls("blockwise")) == \
         (before[0] + 1, before[1] + 3)
+
+
+# --------------------------------------------------------------------------
+# a group of query heads on one key-value head (ISSUE 50)
+# --------------------------------------------------------------------------
+def _shared_kv_calls(group):
+    """The counter of the calls traced whose kernels read a shared head, at
+    ``group`` query heads a key-value head."""
+    family = telemetry.snapshot()["metrics"].get(
+        "mxnet_flash_attention_shared_kv_calls_total", {"samples": []})
+    return sum(s["value"] for s in family["samples"]
+               if s["labels"] == {"group": str(group)})
+
+
+def group_equals_repeated(monkeypatch, path, rep, **call):
+    """``o``, ``dq``, ``dk`` and ``dv`` of the op on ``8 // rep`` key-value
+    heads against the same call on K and V repeated by hand to the 8 query
+    heads, bit for bit: on the CPU's path (plain forward and scan backward,
+    float32), and through both kernels under the TPU interpreter with the
+    gate opened (bf16, as the cells run them), where the heads of a group
+    read one row of K and V through the block maps and ``dk`` / ``dv`` are
+    summed over the group after the call."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    kernels = path == "kernels"
+    if kernels:
+        monkeypatch.setattr(fa, "_use_pallas", lambda q, v=None: True)
+    rs = np.random.RandomState(rep)
+    q, k, v, g = (jnp.asarray(rs.randn(2, heads, 256, 64).astype("f")).astype(
+        "bfloat16" if kernels else "float32")
+        for heads in (8, 8 // rep, 8 // rep, 8))
+
+    def run(per_query_head):
+        def op(q, k, v):
+            return fa.flash_attention(q, per_query_head(k), per_query_head(v),
+                                      **call)
+
+        @jax.jit
+        def both(q, k, v, g):
+            o, vjp = jax.vjp(op, q, k, v)
+            return (o, *vjp(g))
+
+        return both(q, k, v, g)
+
+    shared = _shared_kv_calls(rep)
+    with pltpu.force_tpu_interpret_mode() if kernels \
+            else contextlib.nullcontext():
+        got = run(lambda x: x)
+        assert _shared_kv_calls(rep) == shared + (kernels and rep > 1)
+        want = run(lambda x: jnp.repeat(x, rep, axis=1))
+    assert [x.shape for x in got] == [q.shape, q.shape, k.shape, v.shape]
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a.astype("float32")),
+                                      np.asarray(b.astype("float32")))
+
+
+@pytest.mark.parametrize("path", ["plain", "kernels"])
+@pytest.mark.parametrize("rep", [1, 2, 8])
+@pytest.mark.parametrize("call", [
+    dict(causal=True), dict(mask="window", window=100),
+    dict(mask="block_diffusion", mask_block=4)],
+    ids=["causal", "window", "block_diffusion"])
+def test_a_group_on_one_kv_head_equals_k_and_v_repeated_by_hand(
+        monkeypatch, call, rep, path):
+    group_equals_repeated(monkeypatch, path, rep, **call)
+
+
+def test_the_counter_of_the_calls_that_read_a_shared_head(monkeypatch):
+    """A traced call of 32 query heads on 4 key-value heads through the
+    kernels adds one at ``group="8"``; a head of its own adds nowhere, and
+    neither does a group off the kernels' gate (the plain path repeats)."""
+    def trace(heads, kv_heads):
+        x = lambda h: jax.ShapeDtypeStruct((1, h, 512, 128), "bfloat16")
+        jax.make_jaxpr(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True))(x(heads), x(kv_heads), x(kv_heads))
+
+    def counted():
+        return {group: _shared_kv_calls(group) for group in (1, 2, 8)}
+
+    before = counted()
+    trace(32, 4)                    # no TPU to compile for: the plain path
+    assert counted() == before
+    monkeypatch.setattr(fa, "_use_pallas", lambda q, v=None: True)
+    trace(32, 32)
+    assert counted() == before
+    trace(32, 4)
+    assert counted() == {1: before[1], 2: before[2], 8: before[8] + 1}
+    trace(40, 20)                   # Phi's pairs
+    assert counted() == {1: before[1], 2: before[2] + 1, 8: before[8] + 1}
+
+
+@pytest.mark.parametrize("kv_heads", [4, 32])
+def test_the_group_is_in_the_block_maps_and_nowhere_else(kv_heads):
+    """32 query heads on 4 key-value heads: outside the kernels nothing
+    repeats K or V (the forward is reshapes alone, the backward ``delta``,
+    reshapes and a ``reduce_sum`` a gradient of the group), K's and V's block
+    maps take the grid's row over the group by one truncating ``div`` (no
+    floor divide's ``sign`` and ``select``, ROADMAP D15) and ``dk`` / ``dv``
+    are written a query head.  A head of its own traces no division."""
+    q, kv = (jax.ShapeDtypeStruct((2, heads, 512, 128), "bfloat16")
+             for heads in (32, kv_heads))
+    lse = jax.ShapeDtypeStruct((2, 32, 512), "float32")
+
+    def outer_and_maps(fn, *args):
+        jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+        (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        return ([e.primitive.name for e in jaxpr.eqns],
+                {m.origin: [e.primitive.name
+                            for e in m.index_map_jaxpr.jaxpr.eqns]
+                 for m in call.params["grid_mapping"].block_mappings})
+
+    row = ["div"] * (kv_heads != 32)
+    names, maps = outer_and_maps(lambda q, k, v: fa._fa_forward_pallas(
+        q, k, v, True, 0.125), q, kv, kv)
+    assert set(names) <= {"reshape", "pallas_call", "slice", "squeeze"}
+    assert maps == {"args[0]": [], "args[1]": row, "args[2]": row,
+                    "outputs[0]": [], "outputs[1]": []}
+    names, maps = outer_and_maps(
+        lambda q, k, v, o, lse, g: fa._fa_backward_pallas(
+            q, k, v, o, lse, g, True, 0.125), q, kv, kv, q, lse, q)
+    assert "broadcast_in_dim" not in names
+    assert names.count("reduce_sum") == 1 + 2 * (kv_heads != 32)
+    shared = {"args[2]", "args[3]"}             # after the table: q, k, v
+    assert {origin for origin, eqns in maps.items() if "div" in eqns} \
+        == (shared if kv_heads != 32 else set())
+    assert all(set(eqns) <= {"div", "get"} for eqns in maps.values())

@@ -203,6 +203,24 @@ def test_flash_attention_op_takes_segment_ids_with_gqa(window):
     assert masks == {"window_segments" if window else "causal_segments"}
 
 
+@pytest.mark.parametrize("path", ["plain", "kernels"])
+@pytest.mark.parametrize("rep", [1, 2, 8])
+@pytest.mark.parametrize("window", [0, 100])
+def test_a_group_on_one_kv_head_under_ids_equals_k_and_v_repeated_by_hand(
+        monkeypatch, window, rep, path):
+    """Causal and under the window, both confined by segment ids (the
+    packed cell's two calls): the op on ``8 // rep`` key-value heads against
+    K and V repeated by hand, bit for bit, on the CPU's path and through
+    the interpreted kernels (``test_flash_attention_bwd.py`` has the masks
+    without ids)."""
+    from test_flash_attention_bwd import group_equals_repeated
+
+    kw = dict(mask="window", window=window) if window else dict(causal=True)
+    group_equals_repeated(monkeypatch, path, rep, **kw,
+                          segment_ids=segments_of([[130, 100, 26],
+                                                   [1, 254, 1]]))
+
+
 def test_flash_attention_op_refuses_segment_ids_misused():
     q = nd.array(np.zeros((1, 1, 16, 8), "f"))
     seg = nd.array(np.zeros((1, 16)), dtype="int32")

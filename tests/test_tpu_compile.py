@@ -52,27 +52,53 @@ def _attention_for_v5e(one_chip, backward, shape, dim, dtype, ids=False,
                        **call):
     """An attention kernel at ``shape`` (batch, heads, lq, lk) with heads of
     ``dim``, compiled for the chip: the forward, or with ``backward`` the
-    backward kernel.  ``call`` goes to the path (``causal``, the static
-    ``mask``, the forward's blocks); ``ids`` hands it segment ids (batch,
-    lk) beside that mask."""
+    backward kernel.  ``heads`` is a number, or ``(query heads, key-value
+    heads)`` as the decoder cells call the op: K and V then come with their
+    own heads, and what was compiled is held to reading them as they are
+    (``_reads_shared_heads``).  ``call`` goes to the path (``causal``, the
+    static ``mask``, the forward's blocks); ``ids`` hands it segment ids
+    (batch, lk) beside that mask."""
     from mxnet_tpu.ops.flash_attention import (_Mask, _fa_backward_pallas,
                                                _fa_forward_pallas)
 
     def spec(shape, dt=dtype):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    b, h, lq, lk = shape
-    q, kv, lse = spec((b, h, lq, dim)), spec((b, h, lk, dim)), \
+    b, heads, lq, lk = shape
+    h, hkv = heads if isinstance(heads, tuple) else (heads, heads)
+    q, kv, lse = spec((b, h, lq, dim)), spec((b, hkv, lk, dim)), \
         spec((b, h, lq), "float32")
     operands = (q, kv, kv, q, lse, q) if backward else (q, kv, kv)
     path = _fa_backward_pallas if backward else _fa_forward_pallas
     call = dict({"causal": False, "sm_scale": dim ** -0.5}, **call)
-    if not ids:
-        return jax.jit(functools.partial(path, **call)).lower(
+    if ids:
+        key = call.pop("mask", None)
+        compiled = jax.jit(
+            lambda seg, *a: path(*a, mask=_Mask(key, seg), **call)).lower(
+                spec((b, lk), "int32"), *operands).compile()
+    else:
+        compiled = jax.jit(functools.partial(path, **call)).lower(
             *operands).compile()
-    key = call.pop("mask", None)
-    return jax.jit(lambda seg, *a: path(*a, mask=_Mask(key, seg), **call)
-                   ).lower(spec((b, lk), "int32"), *operands).compile()
+    if h != hkv:
+        _reads_shared_heads(compiled, (kv,), h)
+        # the forward then needs next to nothing beside its operands (the
+        # parent planned 256 MiB at the block-diffusion cell's shape); the
+        # backward writes dk and dv a query head and sums the groups after
+        if not backward:
+            assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+    return compiled
+
+
+def _reads_shared_heads(compiled, kv, heads):
+    """A call of ``heads`` query heads on the fewer key-value heads of
+    ``kv`` (K's and V's shapes; ISSUE 50): the kernels' block maps send a
+    group to its one row of K and V, so the module broadcasts neither to the
+    query heads (the parent's ``bf16[2,4,8,8192,128] broadcast`` before
+    every call: 128 MiB each at the block-diffusion cell's shape)."""
+    broadcasts = "".join(line for line in compiled.as_text().splitlines()
+                         if " broadcast(" in line)
+    for b, hkv, lk, dim in (x.shape for x in kv):
+        assert f"[{b},{hkv},{heads // hkv},{lk},{dim}]" not in broadcasts
 
 
 @pytest.mark.parametrize("shape,dim,dtype,causal,blocks", [
@@ -97,10 +123,10 @@ def test_flash_forward_compiles_for_v5e(one_chip, shape, dim, dtype, causal,
 
 
 @pytest.mark.parametrize("shape,dim,dtype,block,blocks", [
-    # the decoder cell's own call: 2 samples of [xt ; x0], L = 4096, the 4
-    # key-value heads repeated to the 32 query heads, head 128, block 4;
-    # the whole K row of 8,192 resident and tiles of 512 from the shape
-    ((2, 32, 8192, 8192), 128, "bfloat16", 4, (None, None)),
+    # the decoder cell's own call: 2 samples of [xt ; x0], L = 4096, the 32
+    # query heads on their 4 key-value heads, head 128, block 4; the whole
+    # K row of 8,192 resident and tiles of 512 from the shape
+    ((2, (32, 4), 8192, 8192), 128, "bfloat16", 4, (None, None)),
     # a block length that is no power of two (vector integer division), a
     # K tile that holds keys of both halves, float32 operands
     ((1, 4, 768, 768), 128, "float32", 6, (256, 256)),
@@ -136,9 +162,10 @@ def test_masked_flash_backward_compiles_for_v5e(one_chip):
     # BERT-base as the benchmark's cell runs it: a head is one tile pair
     ((16, 12, 512, 512), 64, "bfloat16", False, None),
     ((16, 12, 512, 512), 64, "float32", False, None),
-    # the decoder cell's own call: 80 live pairs of 256 a head, the row of
-    # dq (4 MiB in float32) resident, the VMEM limit stated by the call
-    ((2, 32, 8192, 8192), 128, "bfloat16", False, 4),
+    # the decoder cell's own call, 32 query heads on 4 key-value heads: 80
+    # live pairs of 256 a head, the row of dq (4 MiB in float32) resident,
+    # the VMEM limit stated by the call
+    ((2, (32, 4), 8192, 8192), 128, "bfloat16", False, 4),
     # causal: the lower triangle's pairs, and lq < lk with its offset
     ((2, 16, 2048, 2048), 128, "bfloat16", True, None),
     ((2, 16, 256, 512), 128, "bfloat16", True, None),
@@ -159,11 +186,11 @@ def test_flash_backward_kernel_compiles_for_v5e(one_chip, shape, dim, dtype,
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
-# the window cell's own call (one sample of 8,192 rows, the 4 key-value
-# heads repeated to the 32 query heads, head 128, a window of 2,048: four
-# K tiles of 512 wide), a window narrower than a tile, and ``lq < lk`` with
-# K tiles that no query sees
-WINDOW_SHAPES = [((1, 32, 8192, 8192), 128, "bfloat16", 2048),
+# the window cell's own call (one sample of 8,192 rows, the 32 query heads
+# on their 4 key-value heads, head 128, a window of 2,048: four K tiles of
+# 512 wide), a window narrower than a tile, and ``lq < lk`` with K tiles
+# that no query sees
+WINDOW_SHAPES = [((1, (32, 4), 8192, 8192), 128, "bfloat16", 2048),
                  ((2, 4, 1024, 1024), 128, "bfloat16", 96),
                  ((2, 4, 256, 1024), 64, "bfloat16", 300)]
 
@@ -198,10 +225,11 @@ def test_window_flash_backward_kernel_compiles_for_v5e(one_chip, shape, dim,
 
 
 # under segment ids: the packed cell's own calls (1 sample of 16,384 rows of
-# 13 documents, 32 heads of 128; causal on the full layers, a window of
-# 1,024 on the others), then lq < lk, two samples to a grid and float32
-SEGMENT_SHAPES = [((1, 32, 16384, 16384), 128, "bfloat16", 0),
-                  ((1, 32, 16384, 16384), 128, "bfloat16", 1024),
+# 13 documents, 32 heads of 128 on 4 key-value heads; causal on the full
+# layers, a window of 1,024 on the others), then lq < lk, two samples to a
+# grid and float32
+SEGMENT_SHAPES = [((1, (32, 4), 16384, 16384), 128, "bfloat16", 0),
+                  ((1, (32, 4), 16384, 16384), 128, "bfloat16", 1024),
                   ((2, 4, 256, 1024), 128, "bfloat16", 300),
                   ((2, 4, 512, 512), 64, "float32", 0)]
 
@@ -720,25 +748,32 @@ def test_selective_scan_compiles_for_v5e(one_chip, backward, monkeypatch):
                                   {"mask": ("window", 512)}],
                          ids=["causal", "window"])
 def test_differential_attention_kernels_compile_for_v5e(one_chip, call):
-    """One call of 40 query heads: q and k of 64, v and the output of 128;
-    the backward hands back gradients of 64, 64 and 128."""
+    """One call of 40 query heads on 20 key-value heads (a pair of query
+    heads to each): q and k of 64, v and the output of 128; the backward
+    hands back gradients of 64, 64 and 128, K's and V's on their 20 heads;
+    neither is repeated to the 40."""
     from mxnet_tpu.ops.flash_attention import (_fa_backward_pallas,
                                                _fa_forward_pallas)
 
-    qk = jax.ShapeDtypeStruct((1, 40, 8192, 64), "bfloat16",
-                              sharding=one_chip)
-    v = jax.ShapeDtypeStruct((1, 40, 8192, 128), "bfloat16",
-                             sharding=one_chip)
-    lse = jax.ShapeDtypeStruct((1, 40, 8192), "float32", sharding=one_chip)
+    def spec(heads, dim, dtype="bfloat16"):
+        return jax.ShapeDtypeStruct((1, heads, 8192) + ((dim,) if dim else ()),
+                                    dtype, sharding=one_chip)
+
+    q, k, v, o = spec(40, 64), spec(20, 64), spec(20, 128), spec(40, 128)
+    lse = spec(40, None, "float32")
     call = dict({"causal": False, "sm_scale": 0.125}, **call)
     fwd = jax.jit(functools.partial(_fa_forward_pallas, **call)).lower(
-        qk, qk, v)
-    assert [x.shape for x in fwd.out_info] == [v.shape, lse.shape]
-    assert "mxnet_flash_attention_fwd" in fwd.compile().as_text()
+        q, k, v)
+    assert [x.shape for x in fwd.out_info] == [o.shape, lse.shape]
+    compiled = fwd.compile()
+    assert "mxnet_flash_attention_fwd" in compiled.as_text()
+    _reads_shared_heads(compiled, (k, v), 40)
     bwd = jax.jit(functools.partial(_fa_backward_pallas, **call)).lower(
-        qk, qk, v, v, lse, v)
-    assert [x.shape for x in bwd.out_info] == [qk.shape, qk.shape, v.shape]
-    assert "mxnet_flash_attention_bwd" in bwd.compile().as_text()
+        q, k, v, o, lse, o)
+    assert [x.shape for x in bwd.out_info] == [q.shape, k.shape, v.shape]
+    compiled = bwd.compile()
+    assert "mxnet_flash_attention_bwd" in compiled.as_text()
+    _reads_shared_heads(compiled, (k, v), 40)
 
 
 def test_a_small_phi_step_compiles_for_v5e_with_one_scan_a_layer(
