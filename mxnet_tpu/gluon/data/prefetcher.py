@@ -153,6 +153,8 @@ class PrefetchIterator:
         self._source = iter(source)
         self._error = None
         self._done = False
+        self._batches = 0   # staged so far: the next batch's number
+        self._delivered = 0  # taken from the queue so far
         if self._depth == 0:
             # disabled: stage on the caller's thread, no pipeline
             self._q = None
@@ -178,16 +180,26 @@ class PrefetchIterator:
                 continue
         return False
 
+    def _staged(self, item):
+        """Stage ``item`` under the next batch number; returns the staged
+        batch and what ``telemetry.batch_taken`` takes of it: the number
+        and the ``prefetch.stage`` span on the staging thread."""
+        n = self._batches
+        self._batches += 1
+        # a plain annotation, not a telemetry.phase: the step timeline
+        # belongs to the consumer's thread
+        t0 = _time.perf_counter()
+        with _telemetry.trace_annotation("prefetch.stage", batch=n):
+            staged = self._stage(item)
+        t1 = _time.perf_counter()
+        _STAGE.observe(t1 - t0)
+        return staged, {"batch": n, "prefetch.stage":
+                        [t0, t1, threading.get_ident()]}
+
     def _producer(self):
         try:
             for item in self._source:
-                # a plain annotation, not a telemetry.phase: the step
-                # timeline belongs to the consumer's thread
-                t0 = _time.perf_counter()
-                with _telemetry.trace_annotation("prefetch.stage"):
-                    staged = self._stage(item)
-                _STAGE.observe(_time.perf_counter() - t0)
-                if not self._put((_ITEM, staged)):
+                if not self._put((_ITEM, self._staged(item))):
                     return
             self._put((_END, None))
         except BaseException as e:  # incl. worker-liveness MXNetError
@@ -203,13 +215,19 @@ class PrefetchIterator:
             raise StopIteration
         if self._q is None:  # depth 0: plain staging pass-through
             try:
-                return self._stage(next(self._source))
+                item = next(self._source)
             except StopIteration:
                 self._done = True
                 raise
+            staged, taken = self._staged(item)
+            _telemetry.batch_taken(taken)
+            return staged
         t0 = _time.perf_counter()
         hit = not self._q.empty()
-        with _telemetry.trace_annotation("prefetch.wait"):
+        # the queue is first in, first out: the batch taken is the
+        # ``_delivered``-th staged
+        with _telemetry.trace_annotation("prefetch.wait",
+                                         batch=self._delivered):
             while True:
                 try:
                     kind, val = self._q.get(timeout=0.2)
@@ -226,13 +244,18 @@ class PrefetchIterator:
                             "prefetch thread died without delivering a "
                             "batch or an error (crashed interpreter "
                             "thread?)")
-        _WAIT.observe(_time.perf_counter() - t0)
+        t1 = _time.perf_counter()
+        _WAIT.observe(t1 - t0)
         _DEPTH.set(self._q.qsize())
         if kind == _ITEM:
             # count only delivered batches (the end-of-epoch sentinel
             # fetch is not a batch request)
             (_HITS if hit else _MISSES).inc()
-            return val
+            self._delivered += 1
+            staged, taken = val
+            taken["prefetch.wait"] = [t0, t1, threading.get_ident()]
+            _telemetry.batch_taken(taken)
+            return staged
         self._done = True
         if kind == _ERR:
             raise val

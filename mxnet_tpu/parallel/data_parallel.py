@@ -467,6 +467,9 @@ class TrainStep:
         # time, feeds the online MFU gauge with no steady-state work
         # (mxnet_tpu/introspection.py)
         self._compiled = {}      # batch sig -> (compiled, flops)
+        # the records of this step's calls (telemetry.step_records())
+        self._track = _telemetry.StepTrack(type(net).__name__)
+        self._record = None      # the open call's
 
     @property
     def params(self):
@@ -500,7 +503,11 @@ class TrainStep:
         if self._pipeline is not None and isinstance(x, (tuple, list)):
             raise MXNetError("a pipelined step streams one array through its "
                              "stages; x is a tuple of several")
-        with _telemetry.phase(PHASE_PREPARE):
+        # the call's record: the step's number and its batch's, what the
+        # process did since the previous call, and a look (no wait) at
+        # whether earlier steps are done
+        record = self._record = self._track.open(self.step_count)
+        with _telemetry.phase(PHASE_PREPARE, step=record["step"]) as prepare:
             x = self._stage_batch(x)
             y = self._stage_batch(y)
             rng = jr.PRNGKey(self._rng_seed)
@@ -519,21 +526,20 @@ class TrainStep:
             fresh = sig not in self._seen_sigs \
                 and len(self._seen_sigs) < 4096
             if fresh:
-                import time as _t
-
                 self._seen_sigs.add(sig)
-                t0 = _t.perf_counter()
             args = (self.train_params, self.rest_params, self.opt_state,
                     rng, x, y)
+        spans = record["spans"]
+        spans[PHASE_PREPARE] = prepare.stamps
         # the cold path lowers + compiles once (capturing XLA's
         # cost_analysis FLOPs while the executable is in hand); steady
         # state is one dict lookup + dispatch: no retrace, no host sync
         out, flops = self._call_aot(sig, args)
         loss, self.train_params, self.rest_params, self.opt_state, \
             scalars = out
-        # read when they are there, by a later call or by who reads the
-        # metrics; nothing waits here
-        _telemetry.defer_step_scalars(scalars)
+        # the loss and the step's scalars are read when they are there, by
+        # a later call's look or by who reads the metrics; nothing waits
+        _telemetry.defer_step_scalars(scalars, loss, record, self._track)
         self.step_count += 1
         if flops:
             from .. import introspection as _introspection
@@ -542,15 +548,17 @@ class TrainStep:
         if fresh:
             _telemetry.compile_event(
                 "train_step", type(self._net).__name__,
-                _t.perf_counter() - t0,
+                spans[PHASE_EXECUTE][1] - spans[PHASE_PREPARE][0],
                 "new_step" if len(self._seen_sigs) == 1 else "new_shape")
         return loss
 
-    @staticmethod
-    def _execute(fn, args):
+    def _execute(self, fn, args):
         """The call into the executable, alone in its phase."""
-        with _telemetry.phase(PHASE_EXECUTE):
-            return fn(*args)
+        record = self._record
+        with _telemetry.phase(PHASE_EXECUTE, step=record["step"]) as execute:
+            out = fn(*args)
+        record["spans"][PHASE_EXECUTE] = execute.stamps
+        return out
 
     def _aot_step(self, args):
         """Lower + compile one operand tuple ahead of time and capture
@@ -558,12 +566,14 @@ class TrainStep:
         no second dispatch path for the compiler to refuse again."""
         from .. import introspection as _introspection
 
-        with _telemetry.phase(PHASE_COMPILE):
+        record = self._record
+        with _telemetry.phase(PHASE_COMPILE, step=record["step"]) as compiling:
             compiled = self._step.lower(*args).compile()
             # the op-to-scope table of this executable, for whoever reads
             # a device trace of it (profiler.op_scopes)
             _profiler.register_executable(
                 f"train_step:{type(self._net).__name__}", compiled)
+        record["spans"][PHASE_COMPILE] = compiling.stamps
         return (compiled, _introspection.flops_of(compiled))
 
     def _call_aot(self, sig, args):

@@ -19,6 +19,12 @@ module is the cross-cutting layer that makes a running job diagnosable:
   the step's wall time.  Completed steps land in a bounded ring
   (``MXNET_TELEMETRY_TIMELINE_STEPS``, default 256) and, when the
   profiler is active, as ``step_phase`` spans in the Chrome trace.
+- **Fused-step records**: every ``TrainStep.__call__`` opens a record in
+  the same ring (``kind: "fused"``; ``step_records()``): one step and one
+  batch number from the producer thread's staging to the instant a later
+  call's ``is_ready()`` look first sees the step complete, with what the
+  process did between calls; a step seen after four running medians is a
+  stall, counted and kept.  Nothing of it runs before the first call.
 - **Compile-event tracer**: every fresh ``jax.jit`` trace — a registry op
   (dispatch_cache miss), a hybridized block build, or a TrainStep — is
   recorded with its elapsed time and a *cause* (``new_op`` /
@@ -42,6 +48,7 @@ durations, and ``mxnet_recovery_restarts_total``.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import threading
 import time
@@ -57,6 +64,7 @@ __all__ = ["Counter", "Gauge", "Histogram", "counter", "gauge", "histogram",
            "maybe_phase", "trace_annotation", "timeline", "compile_event",
            "compile_events", "step_scalar", "collect_step_scalars",
            "defer_step_scalars", "drain_step_scalars",
+           "StepTrack", "batch_taken", "step_records",
            "goodput_note", "goodput_summary",
            "heartbeat", "last_heartbeat", "reset"]
 
@@ -302,7 +310,8 @@ def register_collector(fn):
 # dispatch path: a step waits for nothing of this.
 # --------------------------------------------------------------------------
 _SCALARS = threading.local()     # .open: the innermost collector's dict
-_DEFERRED: deque = deque()       # one {family name: device array} a step
+_DEFERRED: deque = deque()       # (scalars, loss, record, track) a step
+_SEEN: deque = deque()           # scalars of steps seen complete, to be read
 
 
 class collect_step_scalars:
@@ -341,34 +350,63 @@ def step_scalar(name, value):
         found.setdefault(name, []).append(value)
 
 
-def defer_step_scalars(arrays):
-    """A dispatched step's scalars (``{family name: device array}``): kept
-    until they are ready.  Those of earlier steps that have completed
-    meanwhile are recorded now, without waiting."""
-    if arrays:
-        _DEFERRED.append(arrays)
-    drain_step_scalars(wait=False)
+def defer_step_scalars(arrays, loss, record, track):
+    """A dispatched step's scalars (``{family name: device array}``), its
+    loss and its record (``track.open``'s), kept until the step is seen
+    complete (``_look``, by the next ``StepTrack.open``, or whoever reads
+    the metrics).  The scalars of the steps seen complete before this one
+    was dispatched are read now, behind the dispatch."""
+    _DEFERRED.append((arrays, loss, record, track))
+    _read_seen()
 
 
-def drain_step_scalars(wait=True):
-    """Record the deferred scalars of every completed step, oldest first;
-    with ``wait`` those of the steps still running too.  ``snapshot()``
-    and ``render_prometheus()`` call it: who reads the metrics is not
-    dispatching."""
-    import numpy as np
-
+def _look(now, judged=True):
+    """See, without waiting and without reading anything, which of the
+    deferred steps are done, oldest first: stamp their records
+    ``seen_complete`` at ``now`` and leave their scalars to be read."""
     while _DEFERRED:
-        arrays = _DEFERRED[0]
-        if not wait and not all(a.is_ready() for a in arrays.values()):
+        arrays, loss, record, track = _DEFERRED[0]
+        if not (loss.is_ready() and all(a.is_ready()
+                                        for a in arrays.values())):
+            # the step completes between this reading and the next
+            record["unready_at"] = now
             return
         _DEFERRED.popleft()
-        for name, a in arrays.items():
+        if arrays:
+            _SEEN.append(arrays)
+        track.seen(record, now, judged)
+
+
+def _read_seen():
+    """Record the scalars of the steps seen complete."""
+    import numpy as np
+
+    while _SEEN:
+        for name, a in _SEEN.popleft().items():
             fam, got = _FAMILIES[name], np.asarray(a, dtype=np.float64)
             if fam.type == "counter":
                 fam.inc(float(got.sum()))
             else:
                 for v in got.ravel():
                     fam.observe(float(v))
+
+
+def drain_step_scalars(wait=True):
+    """Record the deferred scalars of every completed step, oldest first,
+    and stamp its record ``seen_complete``.  With ``wait``, read the
+    scalars of the steps still running too; a step that has none to read
+    is never waited for, and the drain ends at the first such step that
+    still runs.  ``snapshot()`` and ``render_prometheus()`` call it: who
+    reads the metrics is not dispatching, and a reader's look says when it
+    read, not how the steps ran (no interval is judged)."""
+    _look(time.perf_counter(), judged=False)
+    while wait and _DEFERRED and _DEFERRED[0][0]:
+        arrays, _, record, track = _DEFERRED.popleft()
+        _SEEN.append(arrays)
+        _read_seen()        # waits for the step
+        track.seen(record, time.perf_counter(), judged=False)
+        _look(time.perf_counter(), judged=False)
+    _read_seen()
 
 
 # --------------------------------------------------------------------------
@@ -727,21 +765,24 @@ def step_abort():
         _CUR = None
 
 
-def trace_annotation(name):
-    """``jax.profiler.TraceAnnotation("mx:" + name)``: a host span on the
-    clock of whatever JAX trace is running (the device trace's), and next
-    to nothing when none is.  For spans that are not phases, such as those
-    of a producer thread."""
+def trace_annotation(name, **ids):
+    """``jax.profiler.TraceAnnotation("mx:" + name, **ids)``: a host span on
+    the clock of whatever JAX trace is running (the device trace's), and
+    next to nothing when none is.  ``ids`` (``step=k``, ``batch=n``) are
+    the event's stats in the trace: a step's spans are found by number.
+    For spans that are not phases, such as those of a producer thread."""
     from jax.profiler import TraceAnnotation
 
-    return TraceAnnotation("mx:" + name)
+    return TraceAnnotation("mx:" + name, **ids)
 
 
 class _PhaseScope:
-    __slots__ = ("name", "_t0", "_note")
+    __slots__ = ("name", "stamps", "_ids", "_t0", "_note")
 
-    def __init__(self, name):
+    def __init__(self, name, ids):
         self.name = name
+        self.stamps = None      # [start, end] on time.perf_counter(), at exit
+        self._ids = ids
         self._t0 = None
         self._note = None
 
@@ -763,13 +804,14 @@ class _PhaseScope:
                         cur["phases"].get(oname, 0.0) + (now - ot)
                     stack[-1][1] = now
                 stack.append([self.name, now])
-        self._note = trace_annotation(self.name)
+        self._note = trace_annotation(self.name, **self._ids)
         self._note.__enter__()
         return self
 
     def __exit__(self, *exc):
         self._note.__exit__(*exc)
         now = time.perf_counter()
+        self.stamps = [self._t0, now]
         with _LOCK:
             cur = _CUR
             if cur is None or cur["thread"] != threading.get_ident():
@@ -785,13 +827,15 @@ class _PhaseScope:
         return False
 
 
-def phase(name):
+def phase(name, **ids):
     """Context manager attributing its (exclusive) duration to ``name`` in
     the active step; outside a step (or on another thread than the step's)
     it records straight to the phase histogram.  Either way it is also a
-    ``jax.profiler.TraceAnnotation`` named ``mx:<name>``, so it shows in
-    any JAX trace that is running, on the device trace's clock."""
-    return _PhaseScope(name)
+    ``jax.profiler.TraceAnnotation`` named ``mx:<name>`` with ``ids`` as
+    its stats, so it shows in any JAX trace that is running, on the device
+    trace's clock.  After exit ``.stamps`` is its ``[start, end]``, the
+    readings the histogram's observation was made from."""
+    return _PhaseScope(name, ids)
 
 
 class _NullScope:
@@ -810,7 +854,7 @@ _NULL_SCOPE = _NullScope()
 
 def maybe_phase(enabled, name):
     """``phase(name)`` when ``enabled``, else a shared no-op scope."""
-    return _PhaseScope(name) if enabled else _NULL_SCOPE
+    return _PhaseScope(name, {}) if enabled else _NULL_SCOPE
 
 
 class _StepScope:
@@ -831,9 +875,218 @@ def step_scope(step=None):
 
 
 def timeline():
-    """Completed step records, oldest first (bounded ring)."""
+    """Completed step records of ``step_begin`` / ``step_end``, oldest
+    first (bounded ring; a fused step's records share it and are
+    ``step_records()``'s)."""
     with _LOCK:
-        return [dict(r, phases=dict(r["phases"])) for r in _STEPS]
+        return [dict(r, phases=dict(r["phases"])) for r in _STEPS
+                if "kind" not in r]
+
+
+# --------------------------------------------------------------------------
+# fused-step records: one record a TrainStep call in the ring above, under
+# the step's number and the number of the batch that caused it, stamped
+# when the program first sees the step complete.  Every time is a reading
+# of time.perf_counter().
+# --------------------------------------------------------------------------
+STALL_FACTOR = 4.0     # an interval this many running medians long stalls
+STALL_MEDIAN_OF = 32   # intervals the running median looks back over
+STALL_MIN = 8          # and the fewest it judges from
+_STALLS: deque = deque(maxlen=8)     # the stalls kept from the ring's turnover
+_TAKEN = threading.local()           # .batch: what this thread took last
+_TRACK_IDS = itertools.count(1)      # a StepTrack's number in the process
+_GC2: deque = deque(maxlen=64)       # [start, end] of generation-2 collections
+# what the process has done so far, by name; a record holds the differences
+_WATCH = {"installed": False, "gc_t0": None, "last": None, "compiles": 0,
+          "cache_misses": 0, "cache_retrieval_s": 0.0,
+          "backend_compile_s": 0.0}
+_USAGE = ("ru_nivcsw", "ru_nvcsw", "ru_majflt", "ru_inblock", "ru_oublock")
+
+_INTERVAL_HIST = histogram(
+    "mxnet_train_step_interval_seconds",
+    "time between the instants at which consecutive fused steps were first "
+    "seen complete (each an upper bound by one call's spacing)")
+_STALLS_TOTAL = counter(
+    "mxnet_train_step_stalls_total",
+    "fused steps seen complete after an interval over four times the "
+    "running median of the last 32")
+
+
+def _on_gc(phase, info):
+    # generation 2 alone: the collection that pauses for milliseconds
+    if info["generation"] == 2:
+        if phase == "start":
+            _WATCH["gc_t0"] = time.perf_counter()
+        elif _WATCH["gc_t0"] is not None:
+            _GC2.append([_WATCH["gc_t0"], time.perf_counter()])
+            _WATCH["gc_t0"] = None
+
+
+def _on_jax_event(name, **kw):
+    if name == "/jax/compilation_cache/cache_misses":
+        _WATCH["cache_misses"] += 1
+
+
+def _on_jax_seconds(name, secs, **kw):
+    if name == "/jax/compilation_cache/cache_retrieval_time_sec":
+        _WATCH["cache_retrieval_s"] += secs
+    elif name == "/jax/core/compile/backend_compile_duration":
+        _WATCH["backend_compile_s"] += secs
+
+
+def _since_previous_call(now):
+    """What the process did since the previous ``StepTrack.open``: seconds,
+    the differences of one ``getrusage`` (switched out, waited, paged,
+    blocks read and written, CPU seconds), the generation-2 collections
+    that ended, compile events and the persistent cache's traffic.  None at
+    the first, which installs what listens (nothing does before it)."""
+    import resource
+
+    if not _WATCH["installed"]:
+        import gc
+
+        from jax import monitoring
+
+        _WATCH["installed"] = True
+        gc.callbacks.append(_on_gc)
+        monitoring.register_event_listener(_on_jax_event)
+        monitoring.register_event_duration_secs_listener(_on_jax_seconds)
+    usage = resource.getrusage(resource.RUSAGE_SELF)
+    cur = {name[3:]: getattr(usage, name) for name in _USAGE}
+    cur["cpu_s"] = usage.ru_utime + usage.ru_stime
+    cur["seconds"] = now
+    for name in ("compiles", "cache_misses", "cache_retrieval_s",
+                 "backend_compile_s"):
+        cur[name] = _WATCH[name]
+    last, _WATCH["last"] = _WATCH["last"], cur
+    collections = []
+    while _GC2:
+        collections.append(_GC2.popleft())
+    if last is None:
+        return None
+    since = {name: value - last[name] for name, value in cur.items()}
+    since["gc2"] = collections
+    return since
+
+
+def batch_taken(batch):
+    """``PrefetchIterator.__next__`` leaves what the calling thread took
+    last: ``{"batch": n, "prefetch.stage": [start, end, thread],
+    "prefetch.wait": [start, end, thread]}``.  The next ``StepTrack.open``
+    on this thread takes it as the batch that caused its step."""
+    _TAKEN.batch = batch
+
+
+class StepTrack:
+    """What one ``TrainStep`` keeps between calls to write its records:
+    when it last saw a step complete and the intervals before that."""
+
+    def __init__(self, net):
+        self.net = net
+        self.id = next(_TRACK_IDS)
+        self._last_seen = None
+        self._intervals = deque(maxlen=STALL_MEDIAN_OF)
+        self._stall = None      # the kept stall still short of later records
+
+    def open(self, step):
+        """Open the record of step ``step`` in the ring and return it.
+        Looks, without waiting, at whether earlier steps are done (one
+        ``is_ready()`` a step in flight) and stamps those that are; the
+        caller fills ``spans`` and hands the record to
+        ``defer_step_scalars`` with the loss."""
+        now = time.perf_counter()
+        taken = getattr(_TAKEN, "batch", None) or {}
+        _TAKEN.batch = None
+        record = {
+            "kind": "fused", "net": self.net, "track": self.id,
+            "step": int(step), "batch": taken.get("batch"),
+            "time": time.time(), "thread": threading.get_ident(),
+            "opened": now,
+            # name -> [start, end] (the batch's two: [start, end, thread])
+            "spans": {k: v for k, v in taken.items() if k != "batch"},
+            "since_previous_call": _since_previous_call(now),
+            # bracket the step's completion: the last poll that found it
+            # running and the first that found it done, a call apart
+            "unready_at": None, "seen_complete": None, "interval_s": None,
+            "in_flight": None,
+        }
+        with _LOCK:
+            _STEPS.append(record)
+        if self._stall is not None:
+            if record["step"] <= self._stall["step"] + 2:
+                self._stall["records"].append(record)
+            else:
+                self._stall = None
+        _look(now)
+        record["in_flight"] = sum(1 for held in _DEFERRED if held[3] is self)
+        return record
+
+    def seen(self, record, now, judged=True):
+        """``record``'s step was found complete at ``now``; the interval
+        since the one before is observed and ``judged`` for a stall."""
+        record["seen_complete"] = now
+        last, self._last_seen = self._last_seen, now
+        if last is None or now <= last or not judged:
+            return      # the first, or one more found by the same poll
+        record["interval_s"] = interval = now - last
+        _INTERVAL_HIST.observe(interval)
+        recent = self._intervals
+        if len(recent) >= STALL_MIN:
+            median = sorted(recent)[len(recent) // 2]
+            if interval > STALL_FACTOR * median:
+                self._stalled(record, last, median)
+        recent.append(interval)
+
+    def _stalled(self, record, last, median):
+        """Count a stall and keep from the ring's turnover its record, those
+        of the two steps on either side (the later ones join as they open)
+        and those of the calls made while it lasted (opened after ``last``:
+        their ``since_previous_call`` is what the process did meanwhile)."""
+        _STALLS_TOTAL.inc()
+        step = record["step"]
+        with _LOCK:
+            mine = [r for r in _STEPS if r.get("track") == self.id]
+        self._stall = {
+            "net": self.net, "track": self.id, "step": step,
+            "interval_s": record["interval_s"], "median_s": median,
+            "records": [r for r in mine if abs(r["step"] - step) <= 2],
+            "calls_meanwhile": [r for r in mine if r["opened"] > last]}
+        _STALLS.append(self._stall)
+        _flight_note("step_stall", net=self.net, step=step,
+                     interval_s=record["interval_s"], median_s=median,
+                     in_flight=record["in_flight"])
+
+
+def _copy_record(record):
+    out = dict(record, spans={k: list(v)
+                              for k, v in record["spans"].items()})
+    since = record["since_previous_call"]
+    if since is not None:
+        out["since_previous_call"] = dict(
+            since, gc2=[list(c) for c in since["gc2"]])
+    return out
+
+
+def step_records():
+    """``{"records": the ring's fused-step records, oldest first,
+    "stalls": the last 8 stalls}``, copies.  A record: ``net``, ``track``
+    (which ``TrainStep`` of the process), ``step`` and ``batch`` (the
+    delivering prefetcher's number, None for a batch from no prefetcher),
+    ``spans`` (``train_step.prepare`` / ``.compile`` / ``.execute`` as
+    ``[start, end]`` on ``thread``; ``prefetch.stage`` / ``prefetch.wait``
+    as ``[start, end, thread]``), ``in_flight`` (this ``TrainStep``'s steps
+    dispatched and not yet seen complete, at dispatch),
+    ``since_previous_call``, and ``seen_complete``: the first instant at
+    which the program saw the step's loss ready, an upper bound of its
+    completion by one call's spacing (``unready_at`` is the last look that
+    found it running), with ``interval_s`` since the step before."""
+    with _LOCK:
+        records = [_copy_record(r) for r in _STEPS if "kind" in r]
+        stalls = [dict(s, records=[_copy_record(r) for r in s["records"]],
+                       calls_meanwhile=[_copy_record(r)
+                                        for r in s["calls_meanwhile"]])
+                  for s in _STALLS]
+    return {"records": records, "stalls": stalls}
 
 
 # --------------------------------------------------------------------------
@@ -862,6 +1115,7 @@ def compile_event(kind, name, elapsed_s, cause, **extra):
     Extra keyword fields land verbatim on the event record."""
     now = time.perf_counter()
     with _LOCK:
+        _WATCH["compiles"] += 1
         _COMPILE_EVENTS.append(dict({"kind": kind, "name": name,
                                      "elapsed_s": float(elapsed_s),
                                      "cause": cause, "time": time.time()},
@@ -1000,8 +1254,9 @@ def render_prometheus():
 
 def snapshot():
     """JSON-able snapshot: every metric family (registered + collected),
-    the step timeline, compile events, and aggregate summaries.  Embedded
-    in ``profiler.dump()`` otherData and ``bench.py`` extras."""
+    the step timeline, the fused steps' records (``"step_records"``),
+    compile events, and aggregate summaries.  Embedded in
+    ``profiler.dump()`` otherData and ``bench.py`` extras."""
     drain_step_scalars()
     metrics = {}
     with _LOCK:
@@ -1044,6 +1299,7 @@ def snapshot():
         "metrics": metrics,
         "steps": steps,
         "step_phase_totals": phase_totals,
+        "step_records": step_records(),
         "compile_events": events,
         "compile": {"count": int(n_compiles), "total_s": compile_s,
                     "events_kept": len(events)},
@@ -1075,7 +1331,11 @@ def reset():
             if not fam.labelnames:
                 fam._children.setdefault((), fam._new_child())
         _STEPS.clear()
+        _STALLS.clear()
+        _GC2.clear()
+        _WATCH["last"] = _TAKEN.batch = None
         _DEFERRED.clear()
+        _SEEN.clear()
         _COMPILE_EVENTS.clear()
         _CUR = None
         _STEP_SEQ[0] = 0
