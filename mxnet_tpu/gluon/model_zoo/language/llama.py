@@ -1098,7 +1098,13 @@ class LlamaModel(HybridBlock):
         import jax
 
         with jax.named_scope(SCOPE_EMBED):
-            h = self.embed_tokens(input_ids)
+            if self._cfg.tie_embeddings:
+                # the table comes back beside the rows for the head to read
+                # (``F.shared_embedding``): one gradient reaches the table
+                h, table = F.shared_embedding(
+                    input_ids, self.embed_tokens._resolve_params()["weight"])
+            else:
+                h = self.embed_tokens(input_ids)
             if self._cfg.embed_scale != 1.0:
                 h = h * self._cfg.embed_scale
         # what a packed row brings to every layer: nothing, or its ids and
@@ -1116,7 +1122,8 @@ class LlamaModel(HybridBlock):
                 handed[layer.hands_on] = more
                 _count_handed_on(layer.hands_on, more)
         with jax.named_scope(SCOPE_NORM):
-            return self.norm(h)
+            h = self.norm(h)
+        return (h, table) if self._cfg.tie_embeddings else h
 
 
 class LlamaForCausalLM(HybridBlock):
@@ -1134,15 +1141,14 @@ class LlamaForCausalLM(HybridBlock):
     def hybrid_forward(self, F, input_ids, segment_ids=None):
         import jax
 
-        h = self.model(input_ids, segment_ids)
+        h, *table = _several(self.model(input_ids, segment_ids))
         with jax.named_scope(SCOPE_HEAD):
             if self._cfg.block_diffusion:
                 # rows are [xt ; x0]: logits over the noised half only
                 h = F.slice_axis(h, axis=1, begin=0, end=h.shape[1] // 2)
             if self.lm_head is None:
-                embed = self.model.embed_tokens
                 return F.FullyConnected(
-                    h, embed._resolve_params()["weight"], no_bias=True,
+                    h, table[0], no_bias=True,
                     num_hidden=self._cfg.vocab_size, flatten=False)
             return self.lm_head(h)
 

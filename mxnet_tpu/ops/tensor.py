@@ -501,12 +501,36 @@ def take(a, indices, axis=0, mode="clip"):
     return jnp.take(a, idx, axis=axis)
 
 
+def _embedding(data, weight, hands_on):
+    from .. import telemetry
+    from . import embed_add_rows
+
+    jnp = _jnp()
+    idx = jnp.clip(data.astype(_np.int32), 0, weight.shape[0] - 1)
+    by_rows = embed_add_rows.use_pallas(weight, idx.size)
+    telemetry.EMBEDDING_GRAD_CALLS.labels(
+        path="rows" if by_rows else "scatter").inc()
+    if by_rows:
+        return embed_add_rows.take_rows(weight, idx, hands_on)
+    rows = jnp.take(weight, idx, axis=0)
+    return (rows, weight) if hands_on else rows
+
+
 @register("Embedding", aliases=("embedding",))
 def embedding(data, weight, input_dim=None, output_dim=None, dtype=None,
               sparse_grad=False):
-    jnp = _jnp()
-    idx = jnp.clip(data.astype(_np.int32), 0, weight.shape[0] - 1)
-    return jnp.take(weight, idx, axis=0)
+    return _embedding(data, weight, False)
+
+
+@register("_contrib_shared_embedding", nout=2, aliases=("shared_embedding",))
+def shared_embedding(data, weight):
+    """``Embedding`` that hands its table on: ``(rows, weight)``, the table
+    unchanged, for a head tied to it to read in the embedding's place.  The
+    head's gradient of the table then comes back through this op, whose rule
+    adds the rows' gradient into it in place (``ops/embed_add_rows.py``);
+    off that kernel's gate the table handed on is the one handed in and the
+    program is ``Embedding``'s."""
+    return _embedding(data, weight, True)
 
 
 @register("one_hot", differentiable=False)

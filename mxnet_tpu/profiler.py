@@ -55,6 +55,10 @@ SCOPE_MOE_EXPERTS = "mx_moe_experts"
 # the way back's Pallas kernel (ops/moe_add_rows.py): a part's live rows
 # added into their tokens' rows in place; a row of mx_moe_route
 KERNEL_MOE_ADD_ROWS = "mxnet_moe_add_rows"
+# the embedding's way back (ops/embed_add_rows.py): one pass over the table's
+# gradient that adds the cotangent's rows into their ids' rows, fetched by
+# DMA through the sorted ids; called under SCOPE_EMBED, a row of mx_embed
+KERNEL_EMBED_ADD_ROWS = "mxnet_embed_add_rows"
 # the shared expert beside them (model_zoo/language/llama.py::LlamaMoEMLP):
 # a dense SwiGLU that every token passes
 SCOPE_MOE_SHARED = "mx_moe_shared"

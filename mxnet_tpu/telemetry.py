@@ -473,6 +473,14 @@ MOE_ADD_ROWS_CALLS = counter(
     "dispatch's backward), by the path they took: the Pallas kernel over "
     "the rows that hold a pair, or XLA's scatter-add of a whole part",
     ("path",))
+EMBEDDING_GRAD_CALLS = counter(
+    "mxnet_embedding_grad_calls_total",
+    "Embedding calls traced, by the way back their table's gradient takes: "
+    "rows (ops/embed_add_rows.py's Pallas kernel: one pass over the table's "
+    "gradient, the cotangent's rows fetched by DMA through the sorted ids "
+    "and added into their rows) or scatter (XLA's scatter-add, everything "
+    "off the kernel's gate)",
+    ("path",))
 MOE_ADDED_ROWS = counter(
     "mxnet_moe_added_rows_total",
     "rows that the ways back of the dropless expert layers walked to add "

@@ -829,3 +829,43 @@ def test_a_small_phi_step_compiles_for_v5e_with_one_scan_a_layer(
         assert parts == [profiler.SCOPE_SSM_SCAN] * calls, kernel
     assert len([name for name in table
                 if name.startswith(profiler.KERNEL_ATTENTION_FWD)]) == 3
+
+
+# --------------------------------------------------------------------------
+# the embedding's way back (PR 53): the Ling cell's table and the Phi
+# cell's, 8,192 ids each, in one program
+# --------------------------------------------------------------------------
+def test_the_embeddings_way_back_compiles_for_v5e(one_chip, monkeypatch):
+    """``jax.vjp`` of ``F.Embedding`` at ``(19648, 2560)`` and ``(25008,
+    2560)`` with 8,192 ids: the sort beside an ``iota`` and the kernel, a
+    DMA a row of the cotangent as ``(R, 1, d)`` (a row of ``(R, d)`` is a
+    slice off the tiling; a kernel in front makes that copy), a row of the
+    block in VMEM read, added to and
+    written at a sublane the ids give, the Phi table's partial last block,
+    the buffers inside the default VMEM; no scatter left."""
+    from mxnet_tpu import profiler
+    from mxnet_tpu.ops.registry import OP_TABLE
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    embedding = OP_TABLE["Embedding"].fn
+
+    def both(ids, ling, phi, g):
+        rows, pull = jax.vjp(
+            lambda a, b: (embedding(ids, a), embedding(ids, b)), ling, phi)
+        return rows, pull((g, g))
+
+    def shaped(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(both).lower(
+        shaped(1, 8192, dtype=jnp.int32), shaped(19648, 2560),
+        shaped(25008, 2560), shaped(1, 8192, 2560)).compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and " custom-call(" in line]
+    # a table: the copy that sets the cotangent's rows apart, and the kernel
+    assert len(kernels) == 4
+    assert sum(f"%{profiler.KERNEL_EMBED_ADD_ROWS}_apart" in
+               line.split(" = ")[0] for line in kernels) == 2
+    assert all(profiler.KERNEL_EMBED_ADD_ROWS in line
+               and profiler.SCOPE_EMBED in line for line in kernels)
+    assert " scatter(" not in text and text.count(" sort(") == 2
